@@ -15,8 +15,7 @@ back there; round-robin sprays turns across workers and re-prefills
 Reported per mode: returning-turn TTFT p50/p99 (where routing pays),
 first-turn TTFT (sanity: should match across modes), and the
 fleet-wide average prefix-hit rate scraped from the metrics service.
-Committed results: benchmarks/results_router_ab.json +
-benchmarks/RESULTS.md.
+Committed results (CPU): benchmarks/results_router_ab.json.
 
     python benchmarks/router_ab_bench.py            # full A/B (CPU)
     python benchmarks/router_ab_bench.py --users 4 --turns 3   # quicker

@@ -20,8 +20,7 @@ The contracts the DL3xx rules enforce:
   **arity** and the declared **axis set** (DL304).
 
 This module builds, once per program pass, the inventory those rules
-check against: every ``shard_map`` (native, ``jax.experimental``, or
-the ``utils/jaxtools.py`` compat shim), ``pjit``/sharded-``jit``, and
+check against: every ``jax.shard_map``, ``pjit``/sharded-``jit``, and
 ``with_sharding_constraint`` site inside a function body, with
 
 - the **wrapped callable** resolved to a call-graph qualname where
@@ -71,11 +70,7 @@ from dynamo_tpu.analysis.jaxsem import _argnums, _resolves_to
 # recorded as a counted miss, never guessed at
 DYNAMIC = "<dynamic>"
 
-_SHARD_MAP = (
-    "jax.shard_map",
-    "jax.experimental.shard_map.shard_map",
-    "dynamo_tpu.utils.jaxtools.shard_map",
-)
+_SHARD_MAP = ("jax.shard_map",)
 _PJIT = ("jax.experimental.pjit.pjit", "jax.pjit")
 _JIT = ("jax.jit",)
 _CONSTRAINT = (
@@ -103,7 +98,6 @@ COLLECTIVES: Dict[str, int] = {
     "jax.lax.pcast": 1,
     "jax.lax.pbroadcast": 1,
     "jax.lax.pvary": 1,
-    "dynamo_tpu.utils.jaxtools.pcast": 1,
 }
 
 

@@ -25,6 +25,7 @@ SURVEY.md §7 step 4). Design choices for TPU:
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 from typing import Any, Optional
@@ -34,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.utils.jaxtools import shard_map
 from dynamo_tpu.models.config import ModelConfig
 
 Params = dict[str, Any]
@@ -132,6 +132,12 @@ def param_specs(cfg: ModelConfig) -> dict[str, P]:
     return specs
 
 
+def _random_leaf(key, *, shape, dtype, scale: float, ones: bool):
+    if ones:
+        return jnp.ones(shape, dtype=dtype)
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
 def init_params(cfg: ModelConfig, seed: int = 0, mesh: Optional[Mesh] = None,
                 specs: Optional[dict] = None) -> Params:
     """Random init (for tests / benchmarks without weights). ``specs``
@@ -144,13 +150,19 @@ def init_params(cfg: ModelConfig, seed: int = 0, mesh: Optional[Mesh] = None,
     for (name, (shape, dtype)), k in zip(shapes.items(), keys):
         fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
         scale = 1.0 / math.sqrt(max(1, fan_in))
-        if name.endswith("norm"):
-            arr = jnp.ones(shape, dtype=dtype)
-        else:
-            arr = (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+        gen = functools.partial(
+            _random_leaf, shape=shape, dtype=dtype, scale=scale,
+            ones=name.endswith("norm"),
+        )
         if mesh is not None:
-            arr = jax.device_put(arr, NamedSharding(mesh, specs[name]))
-        params[name] = arr
+            # generated INTO the sharding: each device draws only its
+            # shard (threefry is partitionable, so the values do not
+            # depend on the mesh). Drawing the whole [L, D, F] stack in
+            # f32 on one device first would need 7.5 GB at 8B widths.
+            gen = jax.jit(
+                gen, out_shardings=NamedSharding(mesh, specs[name])
+            )
+        params[name] = gen(k)
     return params
 
 
@@ -191,11 +203,15 @@ def init_cache(
     (values, scales) pairs with per-(slot, head) f32 scales
     (ops/kv_quant.py documents the scale layout)."""
     shape = cache_shape(cfg, num_blocks, block_size)
-    k = jnp.zeros(shape, dtype=dtype)
-    v = jnp.zeros(shape, dtype=dtype)
+    # allocated INTO the sharding: a cache sized for a whole tp mesh
+    # (24.6 GB at tp=4 on v5e) never fits the one device that
+    # zeros-then-device_put would build it on first
+    sh = ssh = None
     if mesh is not None:
         sh = NamedSharding(mesh, spec if spec is not None else CACHE_SPEC)
-        k, v = jax.device_put(k, sh), jax.device_put(v, sh)
+        ssh = NamedSharding(mesh, SCALE_SPEC)
+    k = jnp.zeros(shape, dtype=dtype, device=sh)
+    v = jnp.zeros(shape, dtype=dtype, device=sh)
     if jnp.dtype(dtype) != jnp.int8:
         return k, v
     from dynamo_tpu.ops.kv_quant import kv_scale_shape
@@ -204,11 +220,8 @@ def init_cache(
         cfg.num_hidden_layers, num_blocks, block_size,
         cfg.num_key_value_heads,
     )
-    ks = jnp.ones(sshape, jnp.float32)
-    vs = jnp.ones(sshape, jnp.float32)
-    if mesh is not None:
-        ssh = NamedSharding(mesh, SCALE_SPEC)
-        ks, vs = jax.device_put(ks, ssh), jax.device_put(vs, ssh)
+    ks = jnp.ones(sshape, jnp.float32, device=ssh)
+    vs = jnp.ones(sshape, jnp.float32, device=ssh)
     return (k, ks), (v, vs)
 
 
@@ -448,6 +461,31 @@ def get_attention_mesh() -> Optional[Mesh]:
     return _ATTN_MESH
 
 
+def shard_attention_kernel(
+    kern, mesh: Mesh, *, prefill: bool, quantized: bool
+):
+    """Wrap a stacked paged-attention kernel so one instance runs per tp
+    shard: q heads and the cache's KV-head axis (dim 2 of the stacked
+    layout) are tp-sharded; layer index, tables, start positions and ctx
+    ride replicated, as do the other mesh axes (dp/ep/sp). int8 scale
+    arrays shard on their head axis (SCALE_SPEC). Argument order is the
+    kernels' own: (q, k, v, layer, tables, [start,] ctx[, ks, vs]).
+
+    The shard_map is manual over EVERY mesh axis: a Mosaic kernel cannot
+    sit in a partly automatic region (the chip's compiler refuses it,
+    "Mosaic kernels cannot be automatically partitioned"), even when the
+    axes left automatic have size 1."""
+    qspec = P(None, None, "tp", None) if prefill else P(None, "tp", None)
+    in_specs = (qspec, CACHE_SPEC, CACHE_SPEC, P(), P(None, None))
+    in_specs += (P(None), P(None)) if prefill else (P(None),)
+    if quantized:
+        in_specs += (SCALE_SPEC, SCALE_SPEC)
+    return jax.shard_map(
+        kern, mesh=mesh, in_specs=in_specs, out_specs=qspec,
+        check_vma=False,
+    )
+
+
 def pallas_attention_active() -> bool:
     """True when the model will ACTUALLY dispatch the Pallas attention
     kernels (the predicate attend_mlp uses) — impl choice AND a usable
@@ -603,30 +641,8 @@ def make_layer_parts(
                 )
         mesh = _ATTN_MESH
         if mesh is not None and mesh.size > 1:
-            # one kernel per tp shard: q heads and the cache's KV-head
-            # axis (dim 2 of the stacked layout) are tp-sharded; layer
-            # index, tables and ctx ride replicated. Other mesh axes
-            # (dp/ep/sp) are unmapped (replicated through the kernel).
-            # int8 scale arrays shard on their hk-major minor dim —
-            # contiguous tp chunks are exactly each shard's heads
-            # (SCALE_SPEC).
-            in_specs = (
-                P(None, "tp", None),
-                P(None, None, "tp", None),
-                P(None, None, "tp", None),
-                P(),
-                P(None, None),
-                P(None),
-            )
-            if ksc is not None:
-                in_specs += (SCALE_SPEC, SCALE_SPEC)
-            kern = shard_map(
-                kern,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=P(None, "tp", None),
-                axis_names={"tp"},
-                check_vma=False,
+            kern = shard_attention_kernel(
+                kern, mesh, prefill=False, quantized=ksc is not None
             )
         args = (q[:, 0], k_cache, v_cache, layer_idx, block_tables,
                 context_lens)
@@ -667,24 +683,8 @@ def make_layer_parts(
                 )
         mesh = _ATTN_MESH
         if mesh is not None and mesh.size > 1:
-            in_specs = (
-                P(None, None, "tp", None),
-                P(None, None, "tp", None),
-                P(None, None, "tp", None),
-                P(),
-                P(None, None),
-                P(None),
-                P(None),
-            )
-            if ksc is not None:
-                in_specs += (SCALE_SPEC, SCALE_SPEC)
-            kern = shard_map(
-                kern,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=P(None, None, "tp", None),
-                axis_names={"tp"},
-                check_vma=False,
+            kern = shard_attention_kernel(
+                kern, mesh, prefill=True, quantized=ksc is not None
             )
         args = (q, k_cache, v_cache, layer_idx, block_tables,
                 positions[:, 0], context_lens)
@@ -1155,7 +1155,7 @@ def _moe_mlp_sparse(cfg: ModelConfig, lp: Params, h: jax.Array) -> jax.Array:
             out = local_compute(lp_e, x_r, topw_r, topi_r, shard)
             return jax.lax.psum(out, ("ep", "tp"))
 
-        out = shard_map(
+        out = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(lp_specs, P(None, None), P(None, None), P(None, None)),

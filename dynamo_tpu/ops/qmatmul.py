@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import functools
 import json
+import logging
 import os
 import tempfile
 from pathlib import Path
@@ -63,6 +64,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+log = logging.getLogger(__name__)
 
 # M (token-rows) buckets the tune table is keyed on; the wrapper pads
 # every call up to its bucket (padded rows compute zeros and are sliced
@@ -238,12 +241,28 @@ def _candidate_tiles(mb: int, K: int, N: int, kind: str):
                     yield t
 
 
+def _kind_fn(kind: str, w, s, res, tiles):
+    """The EXACT kernel variant the serving path dispatches for this
+    kind — the residual epilogue streams an extra [bm, bn] input per
+    tile, a different traffic profile than the plain kernel."""
+    if kind == "gate_up":
+        return lambda a: qmm_gate_up(a, w, s, w, s, tiles=tiles)
+    if kind == "residual":
+        return lambda a: qmm(a, w, s, residual=res, tiles=tiles)
+    if kind == "lm_head":
+        return lambda a: qmm_lm_head(a, w, s, tiles=tiles)
+    return lambda a: qmm(a, w, s, tiles=tiles)
+
+
 def autotune(
     m: int, K: int, N: int, kind: str, dtype=jnp.bfloat16, repeats: int = 3
 ) -> tuple[int, int, int]:
     """Measure candidate tilings on the real device and persist the
     winner. TPU only — interpret-mode timings would tune for the
-    emulator; off-TPU this returns the default untouched."""
+    emulator; off-TPU this returns the default untouched. A candidate
+    the compiler refuses is counted and logged, never silently dropped;
+    when every candidate is refused this raises (the kernel cannot
+    serve this shape at all)."""
     import time
 
     if jax.default_backend() != "tpu":
@@ -255,36 +274,58 @@ def autotune(
     s = jnp.full((N,), 0.01, jnp.float32)
     best, best_t = None, float("inf")
     res = jnp.zeros((mb, N), dtype)
+    refused = 0
     for tiles in _candidate_tiles(mb, K, N, kind):
+        fn = jax.jit(_kind_fn(kind, w, s, res, tiles))
         try:
-            # measure the EXACT kernel variant the serving path
-            # dispatches for this kind — the residual epilogue streams
-            # an extra [bm, bn] input per tile, a different traffic
-            # profile than the plain kernel
-            if kind == "gate_up":
-                fn = jax.jit(lambda a: qmm_gate_up(a, w, s, w, s, tiles=tiles))
-            elif kind == "residual":
-                fn = jax.jit(
-                    lambda a: qmm(a, w, s, residual=res, tiles=tiles)
-                )
-            elif kind == "lm_head":
-                fn = jax.jit(lambda a: qmm_lm_head(a, w, s, tiles=tiles))
-            else:
-                fn = jax.jit(lambda a: qmm(a, w, s, tiles=tiles))
             jax.block_until_ready(fn(x))  # compile
-            t0 = time.monotonic()
-            for _ in range(repeats):
-                out = fn(x)
-            jax.block_until_ready(out)
-            dt = (time.monotonic() - t0) / repeats
-        except Exception:
-            continue  # a candidate Mosaic rejects is just not a candidate
+        except Exception as e:  # Mosaic/XLA refusals share no base type
+            refused += 1
+            log.warning(
+                "qmatmul autotune %s: tiles %s refused by the compiler: %s",
+                tune_key(m, K, N, kind), tiles, str(e).splitlines()[0][:200],
+            )
+            continue
+        t0 = time.monotonic()
+        for _ in range(repeats):
+            out = fn(x)
+        jax.block_until_ready(out)
+        dt = (time.monotonic() - t0) / repeats
         if dt < best_t:
             best, best_t = tiles, dt
-    if best is not None:
-        record_tiles(m, K, N, kind, best)
-        return best
-    return tile_config(m, K, N, kind)
+    if best is None:
+        raise RuntimeError(
+            f"qmatmul autotune {tune_key(m, K, N, kind)}: the compiler "
+            f"refused all {refused} candidate tilings"
+        )
+    if refused:
+        log.warning(
+            "qmatmul autotune %s: %d candidate tilings refused, winner %s",
+            tune_key(m, K, N, kind), refused, best,
+        )
+    record_tiles(m, K, N, kind, best)
+    return best
+
+
+def verify_compiles(
+    m: int, K: int, N: int, kind: str, dtype=jnp.bfloat16
+) -> None:
+    """Compile (never run) the kernel for this shape with the tiling
+    that WILL be used; raises what the compiler raises. The engine
+    calls this at start-up when no prewarm will compile the step
+    functions, so a refused tiling fails there and not at the first
+    request."""
+    mb = m_bucket(m)
+    sds = jax.ShapeDtypeStruct
+
+    # weights ride as arguments here: shapes only, nothing is allocated
+    def call(a, w, s, res):
+        return _kind_fn(kind, w, s, res, None)(a)
+
+    jax.jit(call).lower(
+        sds((mb, K), dtype), sds((K, N), jnp.int8), sds((N,), jnp.float32),
+        sds((mb, N), dtype),
+    ).compile()
 
 
 # ---------------------------------------------------------------------------
@@ -292,13 +333,23 @@ def autotune(
 # ---------------------------------------------------------------------------
 
 
-def _act(name: str, g: jax.Array) -> jax.Array:
-    """Gate activation, mirroring models.llama.mlp_act (same failure
-    contract: silently substituting silu would serve corrupt logits)."""
+def _act(name: str, g: jax.Array, dtype) -> jax.Array:
+    """Gate activation on f32 values that hold ``dtype``-rounded numbers,
+    mirroring models.llama.mlp_act (same failure contract: silently
+    substituting silu would serve corrupt logits).
+
+    The math runs in f32 — v5e has no bf16 vector unit and Mosaic
+    refuses the bf16 ``logistic`` lowering — and ``rnd`` re-rounds to
+    the output dtype where the reference's ``dtype`` ops round: silu is
+    ``g * sigmoid(g)`` (two ops, two roundings); tanh-gelu is a longer
+    chain that is rounded once at its end here."""
+    def rnd(v: jax.Array) -> jax.Array:
+        return v.astype(dtype).astype(jnp.float32)
+
     if name == "gelu":
-        return jax.nn.gelu(g, approximate=True)
+        return rnd(jax.nn.gelu(g, approximate=True))
     if name == "silu":
-        return jax.nn.silu(g)
+        return rnd(g * rnd(jax.nn.sigmoid(g)))
     raise ValueError(f"unsupported activation {name!r}")
 
 
@@ -358,9 +409,12 @@ def _qmm_kernel(
             # round each dequantized matmul to the output dtype BEFORE
             # the activation — the same rounding points as the reference
             # mlp_act(mm(gate)) * mm(up) composition
-            g = (accg_ref[:] * sg_ref[:]).astype(o_ref.dtype)
-            u = (accu_ref[:] * su_ref[:]).astype(o_ref.dtype)
-            o_ref[:] = _act(act, g) * u
+            # (a product of two bf16 values is exact in f32, so the
+            # final f32 multiply + one rounding equals a bf16 multiply)
+            dt = o_ref.dtype
+            g = (accg_ref[:] * sg_ref[:]).astype(dt).astype(jnp.float32)
+            u = (accu_ref[:] * su_ref[:]).astype(dt).astype(jnp.float32)
+            o_ref[:] = (_act(act, g, dt) * u).astype(dt)
         elif fused == "residual":
             # residual add in the output dtype (reference: x + mm(...)
             # .astype(x.dtype) — the cast happens before the add)
@@ -496,19 +550,25 @@ def qmm_lm_head(
 
 
 def ensure_tuned(
-    shapes: list[tuple[int, int, int, str]], tune: Optional[bool] = None
+    shapes: list[tuple[int, int, int, str]],
+    tune: Optional[bool] = None,
+    verify: bool = False,
 ) -> None:
     """Engine-prewarm hook: make sure every reachable (M, K, N, kind)
     has a tile config ready before the step functions trace. With
     DYN_QMATMUL_TUNE=1 on TPU this measures and persists winners (a few
     compiles per missing shape — one-time, cached on disk); otherwise
     the heuristic defaults serve, and any previously-tuned entries load
-    from the cache."""
+    from the cache. ``verify`` compiles each kernel with its resolved
+    tiling (TPU only) — see :func:`verify_compiles`."""
     if tune is None:
         tune = os.environ.get("DYN_QMATMUL_TUNE") == "1"
     table = _load_table()
+    on_tpu = jax.default_backend() == "tpu"
     for m, K, N, kind in shapes:
         if tune and tune_key(m, K, N, kind) not in table:
             autotune(m, K, N, kind)
         else:
             tile_config(m, K, N, kind)  # validates/loads the entry
+            if verify and on_tpu:
+                verify_compiles(m, K, N, kind)

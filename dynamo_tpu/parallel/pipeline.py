@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dynamo_tpu.utils.jaxtools import pcast, shard_map
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
     Params,
@@ -164,7 +163,7 @@ def forward_pp(
             x_next = jax.lax.ppermute(y, "pp", perm)
             return (x_next, kc, vc, outs), None
 
-        varying = lambda a: pcast(a, ("pp",), to="varying")
+        varying = lambda a: jax.lax.pcast(a, ("pp",), to="varying")
         init = (
             varying(jnp.zeros_like(x_mb[0])),
             kc,
@@ -178,7 +177,7 @@ def forward_pp(
         outs = jax.lax.psum(outs, "pp").astype(x_mb.dtype)
         return outs, kc, vc
 
-    outs, new_k, new_v = shard_map(
+    outs, new_k, new_v = jax.shard_map(
         stage,
         mesh=mesh,
         in_specs=(

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from dynamo_tpu.utils.jaxtools import shard_map
 
 
 def _merge(m, l, acc, m_new, l_new, acc_new):
@@ -113,7 +112,7 @@ def ring_attention(
         )
 
     spec = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -166,7 +165,7 @@ def ulysses_attention(
         out = jax.lax.all_to_all(out, axis_name, split_axis=1, concat_axis=2)
         return out.reshape(B, T_loc, H, Dh)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec_seq, spec_seq, spec_seq),

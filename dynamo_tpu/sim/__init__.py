@@ -2,8 +2,8 @@
 
 No TPUs, no real sleeps — a virtual clock (``sim/core.py``) drives
 arrival traces (``sim/traces.py``: diurnal, bursty MMPP, heavy-tail
-lengths) through modeled workers (``sim/worker.py``, parameterized from
-BENCH_r0x data) while the REAL Planner and AdmissionController run
+lengths) through modeled workers (``sim/worker.py``, assumed service-time
+defaults) while the REAL Planner and AdmissionController run
 against it in driven mode, and PR-5 ``FaultPlan``s compose in at
 simulated timestamps (``sim/faults.py``). See docs/autoscaling.md.
 """

@@ -1,4 +1,4 @@
-"""Worker service-time model, parameterized from the BENCH_r0x data.
+"""Worker service-time model, with assumed (not chip-measured) defaults.
 
 One ``SimWorker`` stands in for a single-chip decode worker running the
 native engine. Three resources bound it, mirroring the real scheduler:
@@ -9,8 +9,8 @@ native engine. Three resources bound it, mirroring the real scheduler:
   saturating curve — per-sequence inter-token latency grows linearly
   with occupancy, ``itl(n) = (n + n_half) / decode_tok_s_max``, which
   makes fleet ITL the load signal SLO scaling reacts to. The defaults
-  (2000 tok/s ceiling, n_half 16) track the BENCH_r04/r05 single-chip
-  batch ladder (B=32 ≈ 1514, B=64 ≈ 2181 tok/s).
+  (2000 tok/s ceiling, n_half 16) are an assumed single-chip batch
+  ladder: not measured on the attached chip.
 
 Speculative decoding is modeled as a throughput/KV trade: when enabled
 it multiplies decode speed by ``spec_speedup`` but charges

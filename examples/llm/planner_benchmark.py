@@ -18,9 +18,8 @@ workload:
 
 Outputs one JSON line per fleet: served tokens, goodput (served /
 offered), worker-ticks (the resource-hours analogue), tokens per
-worker-tick (efficiency), and peak backlog. Recorded numbers live in
-benchmarks/RESULTS.md; tests/test_examples.py asserts the planner's
-win holds.
+worker-tick (efficiency), and peak backlog. tests/test_examples.py
+asserts the planner's win holds.
 
     python -m examples.llm.planner_benchmark
 """

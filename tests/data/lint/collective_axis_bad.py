@@ -5,7 +5,7 @@ itself and one call level down."""
 import jax
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.utils.jaxtools import shard_map
+from jax import shard_map
 
 
 def forward(mesh, x):

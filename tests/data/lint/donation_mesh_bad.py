@@ -9,7 +9,7 @@ import functools
 import jax
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.utils.jaxtools import shard_map
+from jax import shard_map
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

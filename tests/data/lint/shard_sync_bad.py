@@ -5,7 +5,7 @@ nested closures, and helpers the body calls."""
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.utils.jaxtools import shard_map
+from jax import shard_map
 
 
 def ring_forward(mesh):

@@ -5,7 +5,7 @@ never declared."""
 
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.utils.jaxtools import shard_map
+from jax import shard_map
 
 
 def too_few(mesh, q, k, v):

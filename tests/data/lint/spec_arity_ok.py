@@ -4,7 +4,7 @@ to counted misses rather than guessed indices."""
 
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.utils.jaxtools import shard_map
+from jax import shard_map
 
 
 def matched(mesh, q, k, v):

@@ -213,7 +213,15 @@ def test_drain_subcommand_retires_one_worker():
         # exactly the targeted worker exited, cleanly; its peer serves on
         remaining = _instance_keys(store_port, "dd")
         assert remaining == [k for k in keys if not k.endswith(target_hex)]
-        exited = [w for w in workers if w.poll() is not None]
+        # the subcommand returns once discovery shows the instance gone;
+        # the worker's own process exit follows (runtime shutdown,
+        # interpreter teardown), so give it a moment
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline:
+            exited = [w for w in workers if w.poll() is not None]
+            if exited:
+                break
+            time.sleep(0.2)
         assert len(exited) == 1
         assert exited[0].returncode == 0
         fleet.forget(exited[0])

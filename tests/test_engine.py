@@ -13,7 +13,6 @@ import pytest
 
 from dynamo_tpu.engine.allocator import BlockAllocator, NoBlocksError
 from dynamo_tpu.engine.config import EngineConfig
-from dynamo_tpu.utils.jaxtools import partial_auto_shard_map_supported
 from dynamo_tpu.engine.scheduler import Scheduler, Sequence
 from dynamo_tpu.protocols.common import (
     FinishReason,
@@ -410,10 +409,6 @@ def test_prefill_batch_admits_free_rows_under_pinned_buckets():
     assert arrays["tokens"].shape == (8, 16)
 
 
-@pytest.mark.skipif(
-    not partial_auto_shard_map_supported(),
-    reason="pp x tp engine path needs partial-auto shard_map; this jax's\n    experimental fallback lowers it to a PartitionId op XLA SPMD rejects\n    (UNIMPLEMENTED) — see ROADMAP open item 1",
-)
 async def test_multi_step_with_pipeline_parallelism():
     """Fused multi-step decode composes with pp stage rotation: output
     must match the plain single-device single-step engine."""

@@ -1,143 +1,26 @@
-"""jaxtools shard_map shim (ISSUE 16 satellite): the axis_names ->
-auto-complement mapping the DL3xx sharding inventory models, the
-partial-auto support probe's memoization, and the pcast identity
-fallback's checked soundness contract."""
+"""The fully-manual shard_map mode the sharded model code relies on
+(attention kernels per tp shard, MoE over ep x tp) executes on the CPU
+test backend against the installed ``jax.shard_map``."""
 
 import jax
-import jax.experimental.shard_map as esm_mod
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-
-from dynamo_tpu.utils import jaxtools
-
-
-def _two_axis_mesh() -> Mesh:
-    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
-
-
-# ---------------------------------------------------------------------------
-# axis_names -> auto complement (the mapping shardsem.py's DL302/DL304
-# model statically: declared manual axes vs the mesh's full axis set)
-# ---------------------------------------------------------------------------
-
-
-def test_axis_names_maps_to_auto_complement(monkeypatch):
-    captured = {}
-
-    def stub(f, *, mesh, in_specs, out_specs, check_rep, auto):
-        captured.update(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_rep, auto=auto,
-        )
-        return f
-
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    monkeypatch.setattr(esm_mod, "shard_map", stub)
-    mesh = _two_axis_mesh()
-
-    jaxtools.shard_map(
-        lambda x: x, mesh=mesh, in_specs=(P("tp"),), out_specs=P("tp"),
-        axis_names={"tp"},
-    )
-    # manual {tp} over a (dp, tp) mesh: dp stays auto
-    assert captured["auto"] == frozenset({"dp"})
-    assert captured["check_rep"] is False
-
-    jaxtools.shard_map(
-        lambda x: x, mesh=mesh, in_specs=(P(),), out_specs=P(),
-        axis_names={"dp", "tp"},
-    )
-    assert captured["auto"] == frozenset()
-
-    # omitted axis_names means fully manual: nothing left auto
-    jaxtools.shard_map(
-        lambda x: x, mesh=mesh, in_specs=(P(),), out_specs=P(),
-    )
-    assert captured["auto"] == frozenset()
 
 
 def test_fully_manual_two_axis_mesh_executes_on_cpu():
-    """The fully-manual mode must EXECUTE on the pinned jax (only the
-    partial-auto mixed mode needs the version probe): both declared
-    axes are live inside the body as collective targets."""
-    mesh = _two_axis_mesh()
+    """Both declared axes are live inside the body as collective
+    targets."""
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
 
     def body(x):
         # psum over size-1 axes is identity; naming both axes proves
         # they are manual (an auto axis would reject the collective)
         return x * jax.lax.psum(1, "dp") * jax.lax.psum(1, "tp")
 
-    mapped = jaxtools.shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(P(),), out_specs=P(),
         axis_names={"dp", "tp"},
     )
     out = mapped(jnp.arange(4.0))
     assert np.allclose(np.asarray(jax.device_get(out)), np.arange(4.0))
-
-
-# ---------------------------------------------------------------------------
-# probe memoization
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture
-def fresh_probe():
-    jaxtools._partial_auto_supported = None
-    yield
-    jaxtools._partial_auto_supported = None
-
-
-def test_partial_auto_probe_is_memoized(fresh_probe, monkeypatch):
-    first = jaxtools.partial_auto_shard_map_supported()
-    assert isinstance(first, bool)
-    # flip what a re-probe WOULD see; the memo must keep the first answer
-    if first:
-        monkeypatch.delattr(jax, "shard_map", raising=False)
-    else:
-        monkeypatch.setattr(jax, "shard_map", lambda *a, **k: None,
-                            raising=False)
-    assert jaxtools.partial_auto_shard_map_supported() is first
-    assert jaxtools._partial_auto_supported is first
-
-
-def test_partial_auto_probe_tracks_native_shard_map(fresh_probe, monkeypatch):
-    monkeypatch.setattr(jax, "shard_map", lambda *a, **k: None,
-                        raising=False)
-    assert jaxtools.partial_auto_shard_map_supported() is True
-    jaxtools._partial_auto_supported = None
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    assert jaxtools.partial_auto_shard_map_supported() is False
-
-
-# ---------------------------------------------------------------------------
-# pcast soundness contract
-# ---------------------------------------------------------------------------
-
-
-def test_pcast_identity_only_without_native_shard_map(monkeypatch):
-    monkeypatch.delattr(jax.lax, "pcast", raising=False)
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    x = jnp.arange(3.0)
-    assert jaxtools.pcast(x, ("tp",)) is x  # check_rep=False world: sound
-
-    # native shard_map (vma tracking) WITHOUT pcast: the identity would
-    # be silently wrong — the contract raises instead
-    monkeypatch.setattr(jax, "shard_map", lambda *a, **k: None,
-                        raising=False)
-    with pytest.raises(RuntimeError, match="unsound"):
-        jaxtools.pcast(x, ("tp",))
-
-
-def test_pcast_prefers_native(monkeypatch):
-    calls = {}
-
-    def native(x, axis_names, to="varying"):
-        calls.update(axis_names=axis_names, to=to)
-        return x
-
-    monkeypatch.setattr(jax.lax, "pcast", native, raising=False)
-    x = jnp.arange(2.0)
-    assert jaxtools.pcast(x, ("tp",), to="invariant") is x
-    assert calls == {"axis_names": ("tp",), "to": "invariant"}

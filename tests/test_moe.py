@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dynamo_tpu.utils.jaxtools import partial_auto_shard_map_supported
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import (
@@ -71,10 +70,6 @@ def test_sparse_matches_dense_int8():
     _assert_close(dense, sparse)
 
 
-@pytest.mark.skipif(
-    not partial_auto_shard_map_supported(),
-    reason="ep x tp sparse dispatch needs partial-auto shard_map; this jax's\n    experimental fallback lowers it to a PartitionId op XLA SPMD rejects\n    (UNIMPLEMENTED) — see ROADMAP open item 1",
-)
 @pytest.mark.parametrize("quantize", [False, True])
 def test_sparse_ep_sharded_matches_dense(quantize):
     """Fully-manual ep×tp shard_map: every shard computes only its

@@ -21,20 +21,6 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-# XLA capability probe (jax 0.4.37): the CPU backend cannot run
-# computations spanning jax.distributed processes — the very first
-# cross-process device_put trips multihost_utils.assert_equal's
-# broadcast psum with "INVALID_ARGUMENT: Multiprocess computations
-# aren't implemented on the CPU backend". Nothing downstream (lockstep
-# steps, mirrored gathers) can work either, so the 2-process protocol
-# tests skip on this toolchain instead of failing — ROADMAP item 1
-# style, like jaxtools.partial_auto_shard_map_supported. On a real
-# multi-chip backend (or a jaxlib with CPU collectives) they run.
-_CPU_MULTIPROCESS_UNSUPPORTED = (
-    "Multiprocess computations aren't implemented on the CPU backend"
-)
-
-
 def _run_pair(kv_dtype: str) -> dict:
     coord = f"127.0.0.1:{_free_port()}"
     env = dict(os.environ, PYTHONPATH=REPO)
@@ -51,15 +37,6 @@ def _run_pair(kv_dtype: str) -> dict:
         for p in procs:
             out, _ = p.communicate(timeout=240)
             outs.append(out)
-        if any(_CPU_MULTIPROCESS_UNSUPPORTED in o for o in outs):
-            pytest.skip(
-                "XLA CPU backend lacks multiprocess computations "
-                "(jax 0.4.37: cross-process device_put/psum raise "
-                f"INVALID_ARGUMENT {_CPU_MULTIPROCESS_UNSUPPORTED!r}); "
-                "the 2-process step protocol needs a backend with "
-                "cross-host collectives — multi-chip tier, ROADMAP "
-                "open item 1"
-            )
         assert all(p.returncode == 0 for p in procs), (
             f"rank0:\n{outs[0][-3000:]}\nrank1:\n{outs[1][-3000:]}"
         )

@@ -15,7 +15,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import forward, init_cache, init_params
 from dynamo_tpu.parallel.mesh import MeshConfig, build_mesh
-from dynamo_tpu.utils.jaxtools import partial_auto_shard_map_supported
 from dynamo_tpu.parallel.pipeline import (
     PP_CACHE_SPEC,
     forward_pp,
@@ -90,10 +89,6 @@ def test_pp_only():
     _run_pp(pp=4, tp=1)
 
 
-@pytest.mark.skipif(
-    not partial_auto_shard_map_supported(),
-    reason="pp x tp needs partial-auto shard_map (manual pp, auto tp); this jax's\n    experimental fallback lowers it to a PartitionId op XLA SPMD rejects\n    (UNIMPLEMENTED) — see ROADMAP open item 1",
-)
 def test_pp_times_tp():
     # tp=2 divides both H=4 and Hkv=2 in the test config
     _run_pp(pp=2, tp=2)
@@ -103,19 +98,11 @@ def test_pp_more_microbatches_than_stages():
     _run_pp(pp=2, tp=1, B=8, microbatches=4)
 
 
-@pytest.mark.skipif(
-    not partial_auto_shard_map_supported(),
-    reason="pp x tp needs partial-auto shard_map (manual pp, auto tp); this jax's\n    experimental fallback lowers it to a PartitionId op XLA SPMD rejects\n    (UNIMPLEMENTED) — see ROADMAP open item 1",
-)
 def test_pp_decode_step():
     # T=1 decode: every microbatch is one token per sequence
     _run_pp(pp=2, tp=2, B=4, T=1, L=2)
 
 
-@pytest.mark.skipif(
-    not partial_auto_shard_map_supported(),
-    reason="pp x tp needs partial-auto shard_map (manual pp, auto tp); this jax's\n    experimental fallback lowers it to a PartitionId op XLA SPMD rejects\n    (UNIMPLEMENTED) — see ROADMAP open item 1",
-)
 async def test_engine_serves_with_pipeline_parallelism():
     """A pp=2 x tp=2 engine must produce the same greedy tokens as the
     single-device engine for the same weights/config (the pp path is a

@@ -57,13 +57,33 @@ def test_allocator():
     alloc = TpuAllocator(total_chips=4)
     a = alloc.allocate("w1", {"tpu": 2})
     assert a.chip_ids == [0, 1]
-    assert "TPU_VISIBLE_DEVICES" in a.env()
+    # the visible chips alone do not confine a process: libtpu needs
+    # the per-process bounds too (sdk/allocator.py)
+    assert a.env() == {
+        "TPU_VISIBLE_CHIPS": "0,1",
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "2,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
     b = alloc.allocate("cp", {})
     assert b.env() == {"DYN_JAX_PLATFORM": "cpu"}
     with pytest.raises(AllocationError):
-        alloc.allocate("w2", {"tpu": 3})
+        alloc.allocate("w2", {"tpu": 3})  # no such box
+    with pytest.raises(AllocationError):
+        alloc.allocate("w2", {"tpu": 4})  # only 2 free
     alloc.release("w1")
     assert alloc.free_chips == 4
+
+
+def test_allocator_one_chip_workers_get_distinct_aligned_chips():
+    alloc = TpuAllocator(total_chips=4)
+    envs = [alloc.allocate(f"w{i}", {"tpu": 1}).env() for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    alloc.release("w0")
+    alloc.release("w3")
+    # chips 0 and 3 are free but are not a 2x1 box
+    with pytest.raises(AllocationError, match="aligned"):
+        alloc.allocate("pair", {"tpu": 2})
 
 
 async def test_serve_service_and_dependency_calls():
