@@ -1953,8 +1953,8 @@ def main() -> None:
     # name the device every number below was taken on; without
     # DYN_BENCH_PLATFORM=cpu a run that found no TPU stops here, before
     # any model is built (JAX falls back to the CPU silently)
-    device = describe_devices()
-    del device["ids"]
+    device = {k: v for k, v in describe_devices().items()
+              if k in ("platform", "kind", "count")}
     if not cpu_mode and device["platform"] != "tpu":
         print(json.dumps({
             "ok": False, "device": device,

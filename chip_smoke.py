@@ -39,11 +39,13 @@ import concurrent.futures
 import contextlib
 import json
 import os
+import shutil
 import signal
 import socket
 import subprocess
 import sys
 import tempfile
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -57,7 +59,11 @@ EXPECT = {
     "platform": "tpu",
     "attn_pallas_active": True,
     "matmul_pallas_active": True,  # one-chip int8 path only
-    "kernels_interpreted": False,
+    # the lowered first step holds Mosaic kernels (tpu_custom_call): a
+    # Pallas kernel that ran interpreted would lower to plain HLO
+    "mosaic_in_step": True,
+    # each confined worker holds a chip of its own (--chips 4)
+    "distinct_chips": True,
 }
 # Llama-3.1-8B / DeepSeek-R1-Distill-Llama-8B (bench.py _build_config)
 GEOMETRY = dict(
@@ -76,10 +82,15 @@ READY_TIMEOUT_S = 900.0
 REQUEST_TIMEOUT_S = 300.0
 KERNEL_SPEC = dict(
     D=4096, F=14336, V=128256, H=32, Hk=8, Dh=128, block_size=128, m=64,
-    ctx=[1, 130, 1000, 4000], prefill=[1024, 1024], seed=0,
+    m_large=2048, ctx=[1, 130, 1000, 4000], prefill=[1024, 1024], seed=0,
 )
 LOGPROB_TOLERANCE = 0.1  # nats; tp=1 vs tp=4 chosen-token logprobs
 REPLICAS = 4  # one-chip workers behind the router (--chips 4)
+# their prompts: four share a prefix (shared + own tail), eight do not
+REPLICA_PROMPT_TOKENS = (600, 40, 300)
+# what a one-chip int8 engine's device report is held to
+ONE_CHIP_CHECKS = ("platform", "attn_pallas_active", "matmul_pallas_active",
+                   "mosaic_in_step")
 
 SPECIALS = {
     "<|begin_of_text|>": 0, "<|start_header_id|>": 1,
@@ -243,9 +254,12 @@ def reaped(servers: list[Server], require_clean: bool = True):
     try:
         yield
     except BaseException:
+        keep = os.path.join(HERE, "chiprun_out", "chip_smoke_logs")
+        os.makedirs(keep, exist_ok=True)  # git-ignored; the tool returns it
         for s in servers:
-            print(f"--- {s.tag} log tail ---\n" + s.log_tail(4000),
+            print(f"--- {s.tag} log tail ---\n" + s.log_tail(3500),
                   file=sys.stderr)
+            shutil.copyfile(s.log_path, os.path.join(keep, f"{s.tag}.log"))
         for s in reversed(servers):
             say(phase="shutdown", **s.stop(grace_s=10))
         raise
@@ -254,6 +268,29 @@ def reaped(servers: list[Server], require_clean: bool = True):
         say(phase="shutdown", **stopped)
         if require_clean and (stopped["killed"] or stopped["exit_code"] != 0):
             raise SmokeFailure(f"{s.tag} did not shut down cleanly: {stopped}")
+
+
+class StallProbe(threading.Thread):
+    """How late this idle, JAX-free process wakes from a 0.1 s sleep:
+    the host's longest freeze while servers start. A four-chip host
+    froze for 7.8 s while four 8B workers cold-started — which is what
+    once cost the frontend its 10 s store lease (runtime/config.py)."""
+
+    def __init__(self) -> None:
+        super().__init__(daemon=True)
+        self.worst_s = 0.0
+        self._done = threading.Event()
+
+    def run(self) -> None:
+        last = time.monotonic()
+        while not self._done.wait(0.1):
+            now = time.monotonic()
+            self.worst_s = max(self.worst_s, now - last - 0.1)
+            last = now
+
+    def stop(self) -> float:
+        self._done.set()
+        return round(self.worst_s, 3)
 
 
 def engine_server(
@@ -378,6 +415,8 @@ def check_reply(name: str, reply: dict, min_prompt_tokens: int) -> None:
 
 
 def check_device(report: dict, keys: tuple[str, ...], who: str) -> None:
+    report = {**report,
+              "mosaic_in_step": bool(report.get("mosaic_calls_in_step"))}
     for key in keys:
         if report.get(key) != EXPECT[key]:
             raise SmokeFailure(
@@ -443,16 +482,20 @@ def serve_phase(tmp: str) -> dict:
     say(phase="config", geometry=GEOMETRY, quantization="int8", engine=ENGINE,
         note="max_model_len narrows the prewarmed shape set; no width or "
              "depth is cut; other engine options are the CLI defaults")
+    stalls = StallProbe()
+    stalls.start()
     server, url = engine_server(
         "server", tmp, model_dir, ENGINE, ["--quantization", "int8"]
     )
     with reaped([server]):
         ready_s = wait_ready(server, url, READY_TIMEOUT_S)
+        host_stall_max_s = stalls.stop()
         eng = engine_state(url)
         dev = eng["device"]
         cache_events = dev.get("compile_cache_events") or {}
         entries_after = cache_entries()
         say(phase="engine_up", startup_s=round(ready_s, 1),
+            host_stall_max_s=host_stall_max_s,
             init_s=dev.get("init_s"), prewarm_s=dev.get("prewarm_s"),
             device=dev, models=[m["id"] for m in
                                 get_json(f"{url}/v1/models")["data"]],
@@ -465,7 +508,7 @@ def serve_phase(tmp: str) -> dict:
                 **cache_events,
             },
             memory_after_warmup=eng.get("hbm"))
-        check_device(dev, tuple(EXPECT), "serving process")
+        check_device(dev, ONE_CHIP_CHECKS, "serving process")
 
         short = words(SHORT_PROMPT_TOKENS, 0, vocab)
         long_a = words(LONG_PROMPT_TOKENS, 1000, vocab)
@@ -603,8 +646,8 @@ def tp_phase(tmp: str) -> dict:
         dtype="bfloat16", ready_s=eng["ready_s"], device=dev,
         weight_bytes=eng["hbm"].get("weight_bytes"),
         bytes_in_use_per_device=per_dev)
-    check_device(dev, ("platform", "attn_pallas_active",
-                       "kernels_interpreted"), "tp=4 engine")
+    check_device(dev, ("platform", "attn_pallas_active", "mosaic_in_step"),
+                 "tp=4 engine")
     if dev.get("count") != 4 or len(per_dev) != 4:
         raise SmokeFailure(f"tp=4 engine is not on four devices: {dev}")
     if None not in per_dev and max(per_dev) > 1.25 * min(per_dev):
@@ -622,19 +665,42 @@ def worker_device(server: Server) -> dict | None:
     return json.loads(line.split("device=", 1)[1].split(", mesh=", 1)[0])
 
 
+def check_distinct_chips(devices: list[dict]) -> None:
+    """Each worker holds device nodes no other holds, as the kernel
+    lists them (``chip_nodes``, utils/jaxtools.py). ``visible_chips``
+    only repeats what this script asked for, and a confined device
+    calls itself id 0 at (0,0,0) on every chip."""
+    held = [d.get("chip_nodes") or [] for d in devices]
+    nodes = [n for h in held for n in h]
+    if EXPECT["distinct_chips"] and (
+        not all(held) or len(set(nodes)) != len(nodes)
+    ):
+        raise SmokeFailure(f"workers share a chip, or hold none: {held}")
+
+
+def lease_remarks(servers: list[Server]) -> list[str]:
+    """Every late renewal or lost lease the children logged."""
+    out = []
+    for s in servers:
+        for line in s.log_tail(10_000_000).splitlines():
+            if any(mark in line for mark in (
+                    "lease renewed", "lease lost", "lease sweep woke",
+                    "store unreachable")):
+                out.append(f"{s.tag}: {line.strip()[:200]}")
+    return out
+
+
 def replicas_phase(tmp: str) -> None:
-    """store + four one-chip int8 workers (each confined to its chip by
-    ``--tpu-chips``) + the discovery frontend with KV routing."""
+    """store + the discovery frontend with KV routing + four one-chip
+    int8 workers (each confined to its chip by ``--tpu-chips``), all
+    started TOGETHER with nothing about the store lease overridden: the
+    frontend has to hold its lease while four 8B workers cold-start."""
     vocab = GEOMETRY["vocab_size"]
     model_dir = make_model_dir(tmp, GEOMETRY)
     store_port = free_port()
     started: list[Server] = []
-    # Four 8B workers cold-starting at once saturate the host's cores
-    # for minutes, and a process that cannot renew its 10 s store lease
-    # in time shuts itself down (seen on the chip: "primary lease
-    # lost"). So the control plane gets a longer lease here, and the
-    # frontend starts only once the workers serve.
-    lease = {"DYN_LEASE_TTL_S": "60"}
+    stalls = StallProbe()
+    stalls.start()
     # the store and the frontend are not held to a clean exit code
     with reaped(started, require_clean=False):
         started.append(Server(
@@ -644,12 +710,21 @@ def replicas_phase(tmp: str) -> None:
         time.sleep(2.0)
         started[0].check_alive()
         store = ["--store-host", "127.0.0.1", "--store-port", str(store_port)]
+        port = free_port()
+        front = Server(
+            "frontend",
+            ["run", "--in", "http", "--out", "auto", "--router-mode", "kv",
+             "--http-host", "127.0.0.1", "--http-port", str(port), *store],
+            tmp, child_env(DYN_JAX_PLATFORM="cpu"),
+        )
+        started.append(front)
+        url = f"http://127.0.0.1:{port}"
         workers = []
         for chip in range(REPLICAS):
             w, _ = engine_server(
                 f"worker{chip}", tmp, model_dir, ENGINE,
                 ["--quantization", "int8", "--tpu-chips", str(chip), *store],
-                in_mode="dyn://dynamo.backend.generate", **lease,
+                in_mode="dyn://dynamo.backend.generate",
             )
             workers.append(w)
             started.append(w)
@@ -664,20 +739,14 @@ def replicas_phase(tmp: str) -> None:
             for s in started:
                 s.check_alive()
             devices = [worker_device(w) for w in workers]
-        port = free_port()
-        front = Server(
-            "frontend",
-            ["run", "--in", "http", "--out", "auto", "--router-mode", "kv",
-             "--http-host", "127.0.0.1", "--http-port", str(port), *store],
-            tmp, child_env(DYN_JAX_PLATFORM="cpu", **lease),
-        )
-        started.append(front)
-        url = f"http://127.0.0.1:{port}"
+        workers_ready_s = round(time.monotonic() - workers[0].t0, 1)
         wait_ready(front, url, 120.0)
         time.sleep(5.0)  # let the frontend's watcher see every instance
-        shared = words(600, 777, vocab)
-        prompts = [shared + " " + words(40, 1000 * i, vocab) for i in range(4)]
-        prompts += [words(300, 5000 * (i + 1), vocab) for i in range(8)]
+        n_shared, n_tail, n_other = REPLICA_PROMPT_TOKENS
+        shared = words(n_shared, 777, vocab)
+        prompts = [shared + " " + words(n_tail, 1000 * i, vocab)
+                   for i in range(4)]
+        prompts += [words(n_other, 5000 * (i + 1), vocab) for i in range(8)]
         # one shared-prefix request first, so its blocks are indexed
         # before the others that share the prefix are routed
         first = post(url, *request_body("completions", prompts[0], False),
@@ -691,7 +760,7 @@ def replicas_phase(tmp: str) -> None:
             ))
         decisions = []
         for i, r in enumerate(replies):
-            check_reply(f"replica_request{i}", r, 300)
+            check_reply(f"replica_request{i}", r, n_other)
             rec = get_json(f"{url}/debug/request/smoke-r{i}")
             route = (rec.get("router") or [{}])[0]
             decisions.append({
@@ -701,14 +770,19 @@ def replicas_phase(tmp: str) -> None:
                 "total_blocks": route.get("total_blocks"),
                 "seconds": r["seconds"],
             })
-        say(phase="replicas", workers=[
-            {"worker": i, "device": d} for i, d in enumerate(devices)
-        ], router_decisions=decisions)
-        chips = {d.get("visible_chips") for d in devices}
-        if len(chips) != REPLICAS:
-            raise SmokeFailure(f"workers share a chip: {chips}")
+        for s in started:
+            s.check_alive()  # nobody lost its lease along the way
+        say(phase="replicas", lease="defaults (DYN_LEASE_TTL_S unset)",
+            frontend_started="with the workers",
+            workers_ready_s=workers_ready_s,
+            host_stall_max_s=stalls.stop(),
+            lease_remarks=lease_remarks(started),
+            workers=[{"worker": i, "device": d}
+                     for i, d in enumerate(devices)],
+            router_decisions=decisions)
+        check_distinct_chips(devices)
         for i, d in enumerate(devices):
-            check_device(d, tuple(EXPECT), f"worker {i}")
+            check_device(d, ONE_CHIP_CHECKS, f"worker {i}")
         if len({d["worker"] for d in decisions if d["worker"]}) < 2:
             raise SmokeFailure("the router used fewer than two workers")
 

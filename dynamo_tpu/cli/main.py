@@ -116,11 +116,11 @@ def build_parser() -> argparse.ArgumentParser:
                           "narrow rectangles cost the same padded "
                           "budget, so the swap is free at any "
                           "occupancy when few prompts are prefilling)")
-    run.add_argument("--tpu-chips", default=None,
-                     help="comma-separated chip ids of this host to "
-                          "confine this process to (e.g. 2, or 0,1 for "
-                          "tp=2): one process per chip set, so several "
-                          "workers can share a multi-chip host")
+    run.add_argument("--tpu-chips", type=int, default=None, metavar="ID",
+                     help="the one chip of this host to confine this "
+                          "process to (e.g. 2), so several one-chip "
+                          "workers can share a multi-chip host; unset, "
+                          "the process takes every chip (tp>1)")
     run.add_argument("--tensor-parallel-size", type=int, default=1)
     run.add_argument("--pipeline-parallel-size", type=int, default=1,
                      help="GPipe stage rotation over a pp mesh axis")
@@ -1814,14 +1814,12 @@ def main(argv: Optional[list[str]] = None) -> None:
 
         sys.exit(cmd_autopsy(args))
     init_logging()
-    if getattr(args, "tpu_chips", None):
+    if getattr(args, "tpu_chips", None) is not None:
         # before anything can load libtpu: it takes what the
         # environment shows it when the first backend initialises
         from dynamo_tpu.sdk.allocator import chip_env
 
-        os.environ.update(
-            chip_env([int(c) for c in args.tpu_chips.split(",")])
-        )
+        os.environ.update(chip_env(args.tpu_chips))
     from dynamo_tpu.utils.jaxtools import configure_from_env
 
     configure_from_env()

@@ -847,8 +847,6 @@ class JaxEngine:
         )
 
         dev = describe_devices(devices)
-        # the kernels run interpreted exactly when Pallas is forced on a
-        # non-TPU backend (tests); on the chip this must read False
         self.device_report.update({
             **dev,
             "attn_impl": attn_impl(),
@@ -856,9 +854,6 @@ class JaxEngine:
             "matmul_impl": matmul_impl(),
             "matmul_pallas_active": (
                 pallas_matmul_active() and cfg.quantization == "int8"
-            ),
-            "kernels_interpreted": dev["platform"] != "tpu" and (
-                pallas_attention_active() or pallas_matmul_active()
             ),
             "prewarm": bool(prewarm),
             "compile_cache_dir": compile_cache_dir(),
@@ -1033,13 +1028,23 @@ class JaxEngine:
                     for pv, tv, bv in feat_variants:
                         a = prefill_arrays(b, chunk)
                         s = sampling_for(b, penalties=pv, toplp=tv, bias=bv)
-                        out = self._step_fn(
+                        step_args = (
                             self.params, self.k_cache, self.v_cache,
                             a["tokens"], a["positions"],
                             a["slot_mapping"], a["block_tables"],
                             a["context_lens"], a["last_token_idx"],
                             s.arrays,
                         )
+                        if "mosaic_calls_in_step" not in self.device_report:
+                            # what the first step hands the chip's
+                            # compiler: a Pallas kernel that runs
+                            # interpreted lowers to plain HLO and is
+                            # not counted
+                            self.device_report["mosaic_calls_in_step"] = (
+                                self._step_fn.lower(*step_args)
+                                .as_text().count("tpu_custom_call")
+                            )
+                        out = self._step_fn(*step_args)
                         self.k_cache, self.v_cache = out[-2], out[-1]
                         if not (pv or tv or bv):
                             # retained for the overlap-glue warm below

@@ -27,7 +27,15 @@ class RuntimeConfig:
     # host other processes should use to reach this worker
     advertise_host: str = "127.0.0.1"
     worker_port: int = 0  # 0 = ephemeral
-    lease_ttl_s: float = 10.0
+    # The TTL has to outlast the renewal interval plus the longest freeze
+    # a LIVE host shows: a four-chip v5e host froze for 7.8 s while four
+    # 8B workers cold-started (an idle process's wake-up delay; my chip
+    # run, PR 21), and 3 + 7.8 > the 10 s this was. A store on the same
+    # host forgives that (its sweeper extends leases by its own deaf
+    # time); one on another host cannot know. Process death is noticed
+    # at once either way (a dropped connection revokes its leases), so
+    # the TTL only bounds how long a vanished HOST stays listed.
+    lease_ttl_s: float = 20.0
     lease_keepalive_s: float = 3.0
     request_timeout_s: float = 600.0
     log_level: str = "INFO"

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import asyncio
 import itertools
+import logging
 import time
 from collections import defaultdict, deque
 from dataclasses import dataclass, field
@@ -23,6 +24,8 @@ from dynamo_tpu.store.base import (
     WatchEvent,
     subject_matches,
 )
+
+log = logging.getLogger("dynamo_tpu.store.memory")
 
 
 class _MemWatch(Watch):
@@ -228,9 +231,24 @@ class MemoryStore(Store):
             self._sweeper = asyncio.get_running_loop().create_task(self._sweep_loop())
 
     async def _sweep_loop(self) -> None:
+        swept_at = time.monotonic()
         while not self._closed:
             await asyncio.sleep(self._sweep_interval)
             now = time.monotonic()
+            # A sweep that wakes late means this loop was blocked, or the
+            # host starved or frozen: renewals sent meanwhile are still
+            # unread in their sockets. That deaf time is not charged to
+            # the leases (as etcd extends leases over a leader change).
+            deaf = now - swept_at - self._sweep_interval
+            swept_at = now
+            if deaf > self._sweep_interval:
+                if deaf > 1.0 and self._leases:
+                    log.warning(
+                        "lease sweep woke %.1fs late; %d lease(s) extended "
+                        "by as much", deaf, len(self._leases),
+                    )
+                for lease in self._leases.values():
+                    lease.expires_at += deaf
             expired = [l.id for l in self._leases.values() if l.expires_at <= now]
             for lid in expired:
                 await self.lease_revoke(lid)

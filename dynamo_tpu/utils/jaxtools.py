@@ -17,7 +17,8 @@ itself and no code sets another directory; where it is not, the cache
 is ``<checkout>/.jax_cache`` (fixed: the path is part of the cache
 key, so a directory that moves never hits). Either way the choice is
 exported to the environment, so every child process lands on the same
-directory. ``DYN_COMPILE_CACHE=0`` turns the cache off.
+directory. ``DYN_COMPILE_CACHE=0`` turns the cache off, also where
+``JAX_COMPILATION_CACHE_DIR`` is set (``jax_enable_compilation_cache``).
 """
 
 from __future__ import annotations
@@ -68,11 +69,15 @@ def enable_compile_cache() -> Optional[str]:
     step variants of a 32-layer model — so every restart after the
     first should read them back. Touches only jax.config and the
     environment: no backend is initialised here."""
-    cache_dir = compile_cache_dir()
-    if cache_dir is None:
-        return None
     import jax
 
+    cache_dir = compile_cache_dir()
+    if cache_dir is None:
+        # JAX reads JAX_COMPILATION_CACHE_DIR itself, so "off" has to be
+        # said to JAX — and to the children, through its own variable
+        os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
+        jax.config.update("jax_enable_compilation_cache", False)
+        return None
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
         jax.config.update("jax_compilation_cache_dir", cache_dir)
@@ -88,9 +93,30 @@ def enable_compile_cache() -> Optional[str]:
     return cache_dir
 
 
+def held_chip_nodes() -> list[str]:
+    """The TPU device nodes this process holds open, as the kernel lists
+    them (/proc/self/fd): ``/dev/vfio/<n>`` on a v5e host (``/dev/accel<n>``
+    on older ones). It is the one thing that tells confined processes
+    apart — inside a process confined to one chip the device calls
+    itself id 0 at coords (0,0,0) whichever chip it is (seen on a
+    four-chip v5e host, libtpu 0.0.34)."""
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue  # closed meanwhile
+        # /dev/vfio/vfio is the container node every process shares
+        if target.startswith(("/dev/accel", "/dev/vfio/")) and \
+                target != "/dev/vfio/vfio":
+            held.add(target)
+    return sorted(held)
+
+
 def describe_devices(devices: Any = None) -> dict:
     """Platform, kind, ids and count of ``devices`` (default: all JAX
-    devices) — initialises the backend."""
+    devices), and the chips' device nodes this process holds —
+    initialises the backend."""
     import jax
 
     devs = list(devices) if devices is not None else jax.devices()
@@ -99,6 +125,7 @@ def describe_devices(devices: Any = None) -> dict:
         "kind": devs[0].device_kind,
         "count": len(devs),
         "ids": [d.id for d in devs],
+        "chip_nodes": held_chip_nodes(),
     }
 
 

@@ -168,6 +168,7 @@ struct Server {
   bool fsync_wal = false;
   bool dirty = false;
   double last_snap = 0;
+  double last_sweep = 0;
 
   // ---- framing ----------------------------------------------------------
   void send_frame(Conn* c, const Val& v) {
@@ -807,6 +808,14 @@ struct Server {
     if (dirty && !persist_path.empty() && now_s() - last_snap > 2.0)
       save_snapshot();
     double now = now_s();
+    // A tick that comes late (the poll wakes every 100 ms) means this
+    // process or its host was frozen: renewals sent meanwhile were read
+    // just above, but a client frozen with us sent none. That deaf time
+    // is not charged to the leases (store/memory.py does the same).
+    double deaf = last_sweep > 0 ? now - last_sweep - 0.1 : 0;
+    last_sweep = now;
+    if (deaf > 0.5)
+      for (auto& kv2 : leases) kv2.second.expires_at += deaf;
     std::vector<int64_t> expired;
     for (auto& kv2 : leases)
       if (kv2.second.expires_at <= now) expired.push_back(kv2.first);

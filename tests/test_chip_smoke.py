@@ -21,7 +21,8 @@ import chip_smoke  # noqa: E402
 TINY = dict(
     EXPECT={
         "platform": "cpu", "attn_pallas_active": False,
-        "matmul_pallas_active": False, "kernels_interpreted": False,
+        "matmul_pallas_active": False, "mosaic_in_step": False,
+        "distinct_chips": False,
     },
     GEOMETRY=dict(
         vocab_size=2048, hidden_size=128, intermediate_size=256,
@@ -35,9 +36,10 @@ TINY = dict(
     LONG_PROMPT_TOKENS=300,
     KERNEL_SPEC=dict(
         D=64, F=128, V=768, H=4, Hk=2, Dh=128, block_size=16, m=8,
-        ctx=[5, 33, 70], prefill=[20, 32], seed=0,
+        m_large=96, ctx=[5, 33, 70], prefill=[20, 32], seed=0,
     ),
     READY_TIMEOUT_S=240.0,
+    REPLICA_PROMPT_TOKENS=(192, 32, 100),
 )
 
 
@@ -59,7 +61,7 @@ def test_rehearsal_serves_and_prints_the_contract_line(monkeypatch, capsys):
     phases = {ln["phase"]: ln for ln in lines[:-1]}
     assert phases["kernels_vs_reference"]["ok"]
     assert phases["kernels_vs_reference"]["interpreted"]  # CPU rehearsal
-    assert len(phases["kernels_vs_reference"]["kernels"]) == 10
+    assert len(phases["kernels_vs_reference"]["kernels"]) == 12
     up = phases["engine_up"]
     assert up["models"] == ["smoke"]
     assert up["device"]["platform"] == "cpu"
@@ -98,3 +100,56 @@ def test_prompt_words_are_exact_in_vocabulary_tokens(n, start):
     ids = [int(w[1:]) for w in text.split()]
     assert len(ids) == n
     assert all(5 <= i < 128256 for i in ids)
+
+
+def test_replicas_rehearsal_at_the_product_lease_defaults(
+    monkeypatch, capsys, tmp_path
+):
+    """store + frontend + four workers started together, nothing about
+    the lease overridden: everybody is still up after the requests and
+    nobody logged a late renewal."""
+    for name, value in TINY.items():
+        monkeypatch.setattr(chip_smoke, name, value)
+    for knob in ("DYN_LEASE_TTL_S", "DYN_LEASE_KEEPALIVE_S"):
+        monkeypatch.delenv(knob, raising=False)
+    chip_smoke.replicas_phase(str(tmp_path))
+    lines = _lines(capsys.readouterr().out)
+    rep = next(ln for ln in lines if ln.get("phase") == "replicas")
+    assert rep["lease_remarks"] == []
+    assert [w["device"]["visible_chips"] for w in rep["workers"]] == [
+        "0", "1", "2", "3"]
+    routes = rep["router_decisions"]
+    assert len(routes) == 12
+    assert len({r["worker"] for r in routes}) >= 2
+    # the three that follow the first shared-prefix request find its blocks
+    assert all(r["overlap_blocks"] > 0 for r in routes[1:4])
+    assert all(r["worker"] == routes[0]["worker"] for r in routes[1:4])
+    stopped = {ln["tag"]: ln for ln in lines if ln.get("phase") == "shutdown"}
+    assert all(stopped[f"worker{i}"]["exit_code"] == 0 for i in range(4))
+    assert stopped["frontend"]["exit_code"] == 0
+
+
+@pytest.mark.parametrize("held,ok", [
+    ([["/dev/vfio/0"], ["/dev/vfio/1"], ["/dev/vfio/2"], ["/dev/vfio/3"]], True),
+    ([["/dev/vfio/0"], ["/dev/vfio/0"], ["/dev/vfio/2"], ["/dev/vfio/3"]], False),
+    ([["/dev/vfio/0"], [], ["/dev/vfio/2"], ["/dev/vfio/3"]], False),
+])
+def test_workers_must_hold_chips_of_their_own(held, ok):
+    devices = [{"chip_nodes": h, "visible_chips": str(i)}
+               for i, h in enumerate(held)]
+    if ok:
+        chip_smoke.check_distinct_chips(devices)
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match="share a chip"):
+            chip_smoke.check_distinct_chips(devices)
+
+
+def test_mosaic_check_reads_the_lowered_step_not_the_platform():
+    """A TPU engine whose step holds no Mosaic kernel fails the check."""
+    report = {"platform": "tpu", "attn_pallas_active": True,
+              "matmul_pallas_active": True, "mosaic_calls_in_step": 0}
+    with pytest.raises(chip_smoke.SmokeFailure, match="mosaic_in_step"):
+        chip_smoke.check_device(report, chip_smoke.ONE_CHIP_CHECKS, "x")
+    chip_smoke.check_device(
+        {**report, "mosaic_calls_in_step": 7}, chip_smoke.ONE_CHIP_CHECKS, "x"
+    )

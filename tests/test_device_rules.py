@@ -65,10 +65,42 @@ def test_dyn_compile_cache_only_turns_the_cache_off(
     """``DYN_COMPILE_CACHE=<dir>`` no longer relocates the cache."""
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
     monkeypatch.setenv("DYN_COMPILE_CACHE", knob)
+    monkeypatch.delenv("JAX_ENABLE_COMPILATION_CACHE", raising=False)
     got = jaxtools.enable_compile_cache()
     assert got == (None if expect_off else "/some/dir")
     assert not [c for c in config_updates
                 if c[0] == "jax_compilation_cache_dir"]
+    # "off" holds although JAX reads JAX_COMPILATION_CACHE_DIR itself:
+    # JAX is told, and so are the children
+    told = ("jax_enable_compilation_cache", False) in config_updates
+    assert told == expect_off
+    assert (os.environ.get("JAX_ENABLE_COMPILATION_CACHE") == "0") == expect_off
+
+
+def test_cache_off_really_writes_nothing(tmp_path):
+    """In a fresh process with ``JAX_COMPILATION_CACHE_DIR`` set, the
+    knob at 0 leaves the directory empty after a compile."""
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from dynamo_tpu.utils.jaxtools import enable_compile_cache\n"
+        "print(enable_compile_cache())\n"
+        "jax.jit(lambda x: x @ x + 1)(jnp.ones((64, 64))).block_until_ready()\n"
+    )
+    for knob, expect_entries in (("0", False), ("", True)):
+        d = tmp_path / f"cache{knob or 'on'}"
+        env = dict(
+            os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+            JAX_COMPILATION_CACHE_DIR=str(d), DYN_COMPILE_CACHE=knob,
+            JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+        )
+        env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+        out = subprocess.run(
+            [sys.executable, "-c", code], env=env, capture_output=True,
+            text=True, timeout=120,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip() == ("None" if knob == "0" else str(d))
+        assert (d.exists() and any(d.iterdir())) == expect_entries
 
 
 def test_one_place_in_the_tree_sets_the_cache_directory():
@@ -213,3 +245,36 @@ def test_bench_without_a_tpu_exits_before_building_the_model():
     assert line["device"] == {"platform": "cpu", "kind": "cpu",
                               "count": line["device"]["count"]}
     assert "engine launching" not in proc.stderr
+
+
+async def test_engine_counts_the_mosaic_kernels_of_its_lowered_step(monkeypatch):
+    """"No kernel ran interpreted" is read off the lowered first step,
+    not re-derived from the platform: with Pallas forced on the CPU the
+    kernels run interpreted, lower to plain HLO, and the count is 0."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import JaxEngine
+    from dynamo_tpu.models.config import ModelConfig
+
+    monkeypatch.setenv("DYN_MATMUL_IMPL", "pallas")
+    mc = ModelConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128,
+    )
+    engine = await JaxEngine.launch(
+        EngineConfig(
+            model_path="", model_name="dev", random_weights=True,
+            quantization="int8", num_blocks=32, block_size=8,
+            max_batch_size=2, max_model_len=64, prefill_chunk_size=32,
+            prewarm=True,
+        ),
+        model_config=mc,
+    )
+    try:
+        dev = engine.device_report
+        assert dev["platform"] == "cpu" and dev["matmul_pallas_active"]
+        assert dev["mosaic_calls_in_step"] == 0
+        assert dev["chip_nodes"] == []  # no TPU device node is held here
+        assert "kernels_interpreted" not in dev
+    finally:
+        await engine.shutdown()

@@ -449,3 +449,34 @@ async def test_native_store_wal_compaction_no_double_delivery(
     finally:
         proc.kill()
         proc.wait()
+
+
+async def test_native_store_does_not_charge_leases_for_its_frozen_time(
+    native_store_binary,
+):
+    """Parity with store/memory.py's sweeper: a store that was frozen
+    (its host stalled) extends the leases by the time it was deaf."""
+    import signal
+
+    from dynamo_tpu.store.client import StoreClient
+
+    proc = subprocess.Popen(
+        [native_store_binary, "--host", "127.0.0.1", "--port", "0"],
+        stdout=subprocess.PIPE,
+    )
+    try:
+        port = int(proc.stdout.readline().split()[1])
+        c = await StoreClient.connect("127.0.0.1", port)
+        lid = await c.lease_grant(1.0)
+        await asyncio.sleep(0.3)  # the sweep has ticked
+        proc.send_signal(signal.SIGSTOP)
+        await asyncio.sleep(2.0)
+        proc.send_signal(signal.SIGCONT)
+        await asyncio.sleep(0.3)  # several sweeps since it woke
+        assert await c.lease_keepalive(lid) is True
+        await asyncio.sleep(1.6)  # nobody renews: it expires on time
+        assert await c.lease_keepalive(lid) is False
+        await c.close()
+    finally:
+        proc.kill()
+        proc.wait()

@@ -55,35 +55,39 @@ def test_decorators_and_graph():
 
 def test_allocator():
     alloc = TpuAllocator(total_chips=4)
-    a = alloc.allocate("w1", {"tpu": 2})
-    assert a.chip_ids == [0, 1]
-    # the visible chips alone do not confine a process: libtpu needs
+    a = alloc.allocate("w1", {"tpu": 1})
+    assert a.chip_ids == [0]
+    # the visible chip alone does not confine a process: libtpu needs
     # the per-process bounds too (sdk/allocator.py)
     assert a.env() == {
-        "TPU_VISIBLE_CHIPS": "0,1",
-        "TPU_CHIPS_PER_PROCESS_BOUNDS": "2,1,1",
+        "TPU_VISIBLE_CHIPS": "0",
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
         "TPU_PROCESS_BOUNDS": "1,1,1",
     }
     b = alloc.allocate("cp", {})
     assert b.env() == {"DYN_JAX_PLATFORM": "cpu"}
-    with pytest.raises(AllocationError):
-        alloc.allocate("w2", {"tpu": 3})  # no such box
-    with pytest.raises(AllocationError):
-        alloc.allocate("w2", {"tpu": 4})  # only 2 free
+    with pytest.raises(AllocationError, match="unverified"):
+        alloc.allocate("w2", {"tpu": 2})  # a part of the host
+    with pytest.raises(AllocationError, match="3 free of 4"):
+        alloc.allocate("w2", {"tpu": 4})  # the whole host, one chip held
     alloc.release("w1")
     assert alloc.free_chips == 4
+    whole = alloc.allocate("tp4", {"tpu": 4})
+    assert whole.chip_ids == [0, 1, 2, 3]
+    assert whole.env() == {}  # nothing to confine: it takes every chip
+    with pytest.raises(AllocationError, match="0 free of 4"):
+        alloc.allocate("w3", {"tpu": 1})
 
 
-def test_allocator_one_chip_workers_get_distinct_aligned_chips():
+def test_allocator_one_chip_workers_get_distinct_chips():
     alloc = TpuAllocator(total_chips=4)
     envs = [alloc.allocate(f"w{i}", {"tpu": 1}).env() for i in range(4)]
     assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
     assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
-    alloc.release("w0")
-    alloc.release("w3")
-    # chips 0 and 3 are free but are not a 2x1 box
-    with pytest.raises(AllocationError, match="aligned"):
-        alloc.allocate("pair", {"tpu": 2})
+    alloc.release("w1")
+    assert alloc.allocate("again", {"tpu": 1}).chip_ids == [1]
+    # a one-chip host has nothing to confine either
+    assert TpuAllocator(total_chips=1).allocate("w", {"tpu": 1}).env() == {}
 
 
 async def test_serve_service_and_dependency_calls():
