@@ -228,15 +228,6 @@ async def _run(
     tokenizer — mask COST is shape-dependent, not content-dependent),
     prewarms the masked variants, and each request decodes through the
     allow-mask on the serial step path (guided's divert discipline)."""
-    if os.environ.get("DYN_STEP_TRACE"):
-        # step-trace forensics print via logging.INFO; the bench is a
-        # bare script, so wire a handler or the trace silently drops
-        import logging
-
-        logging.basicConfig(
-            level=logging.INFO, stream=sys.stderr,
-            format="%(asctime)s %(name)s: %(message)s",
-        )
     import numpy as np
 
     from dynamo_tpu.engine.config import EngineConfig

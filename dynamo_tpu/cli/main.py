@@ -1829,6 +1829,11 @@ def main(argv: Optional[list[str]] = None) -> None:
 
     faults.init_from_env()
     if args.command == "run":
+        # every serving process keeps its newest request spans in memory:
+        # a profiler capture writes them beside its trace
+        from dynamo_tpu.telemetry import get_tracer
+
+        get_tracer().keep_in_memory()
         try:
             asyncio.run(cmd_run(args))
         except KeyboardInterrupt:

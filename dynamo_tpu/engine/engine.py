@@ -22,6 +22,7 @@ import json
 import logging
 import os
 import queue as thread_queue
+import random
 import threading
 import time
 import uuid
@@ -66,11 +67,14 @@ from dynamo_tpu.protocols.common import (
     SamplingOptions,
 )
 from dynamo_tpu.runtime.engine import AsyncEngine, Context, EngineStream
-from dynamo_tpu.telemetry import autopsy, get_tracer
+from dynamo_tpu.telemetry import autopsy, get_tracer, new_trace_id
 from dynamo_tpu.telemetry.debug import (
+    register_count_provider,
     register_debug_provider,
+    unregister_count_provider,
     unregister_debug_provider,
 )
+from dynamo_tpu.telemetry.spans import step_span
 from dynamo_tpu.telemetry.attribution import (
     AttributionLedger,
     BlackBox,
@@ -286,6 +290,8 @@ class JaxEngine:
         # recent sync=False dispatches whose device errors would DEFER
         # to a later synced step (_annotate_deferred_error)
         self._unsynced_steps: list[str] = []
+        # device programs dispatched, by kind (program_counts)
+        self._steps_dispatched: dict[str, int] = {}
         # observability (docs/observability.md): step flight recorder
         # with slow-step watchdog, SLO/goodput tracker, HBM accountant
         slow_ms = config.slow_step_ms
@@ -379,6 +385,7 @@ class JaxEngine:
         # its own registration)
         engine._debug_name = "engine"
         register_debug_provider(engine._debug_name, engine.debug_state)
+        register_count_provider(engine._debug_name, engine.program_counts)
         register_attribution_provider(
             engine._debug_name, engine.attribution_state
         )
@@ -2084,6 +2091,7 @@ class JaxEngine:
         sampling: SamplingBatch,
         sync: bool = True,
         origin: str = "",
+        kind: str = "prefill",
     ):
         """``sync=False`` skips the device->host read of the sampled
         outputs (returns None): a prefill batch with NO last chunks has
@@ -2092,12 +2100,24 @@ class JaxEngine:
         prompt pays it twice for nothing. The dispatch still happens
         (and still broadcasts under multihost); donated caches chain
         the next step regardless."""
-        outs = self._dispatch_device_step(
-            arrays, sampling, origin=origin, defer_sync=not sync
-        )
+        with self._dispatch_span(kind, arrays["tokens"]):
+            outs = self._dispatch_device_step(
+                arrays, sampling, origin=origin, defer_sync=not sync
+            )
         if not sync:
             return None
-        return self._harvest_device_step(outs)
+        with step_span("dyn.step.harvest"):
+            return self._harvest_device_step(outs)
+
+    def _dispatch_span(self, kind: str, tokens):
+        """Count one device program of ``kind`` (program_counts) and
+        mark its dispatch in a live profiler capture; ``tokens`` is the
+        step's token array (host or device), read for its shape only."""
+        self._steps_dispatched[kind] = self._steps_dispatched.get(kind, 0) + 1
+        return step_span(
+            "dyn.step.dispatch", kind=kind, rows=int(tokens.shape[0]),
+            tokens=int(tokens.size),
+        )
 
     # ------------------------------------------------------------------
     # Engine thread loop
@@ -2150,7 +2170,8 @@ class JaxEngine:
             # worker-liveness injection point: `kill` rules here model a
             # hard worker death between steps (one-shot by default)
             faults.fire("worker.liveness")
-            self._drain_incoming()
+            with step_span("dyn.step.plan"):
+                self._drain_incoming()
             if self._draining:
                 # graceful drain: hand off eligible in-flight streams at
                 # this step boundary (every generated token has already
@@ -2194,7 +2215,8 @@ class JaxEngine:
                 # and break the attribution timeline for the same reason
                 self.overlap.note_idle()
                 self.attribution.note_idle()
-                self._wake.wait(timeout=0.05)
+                with step_span("dyn.step.wait"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
             try:
@@ -2424,19 +2446,6 @@ class JaxEngine:
                 break
         return n
 
-    _trace_enabled = bool(os.environ.get("DYN_STEP_TRACE"))
-
-    def _trace(self, event: str, **fields) -> None:
-        """Step tracing (DYN_STEP_TRACE=1): one log line per engine
-        step with kind, wall time, and batch geometry — the profiling
-        surface for serving-stall forensics (reference analogue: the
-        runtime's tracing spans, SURVEY.md §5)."""
-        if self._trace_enabled:
-            log.info(
-                "step %s %s", event,
-                " ".join(f"{k}={v}" for k, v in fields.items()),
-            )
-
     # -- step flight recording (telemetry/recorder.py) ---------------------
     _step_counter = 0
     _last_preemptions = 0
@@ -2618,7 +2627,8 @@ class JaxEngine:
         # clear BEFORE plan(): a failure inside planning must not be
         # attributed to the previous step's (healthy) requests
         self._last_plan = None
-        plan = sched.plan()
+        with step_span("dyn.step.plan"):
+            plan = sched.plan()
         self._last_plan = plan  # step-failure attribution (quarantine)
         plan_ms = round((time.monotonic() - t_plan) * 1e3, 3)
         # phase stamps from an earlier, never-recorded dispatch (e.g. a
@@ -2627,23 +2637,17 @@ class JaxEngine:
         self._last_phases = {}
         # per-step load gauges: two locked float stores per step, noise
         # next to a device dispatch
-        ENGINE_BATCH_OCCUPANCY.set(
-            sched.num_running / max(1, self.config.max_batch_size)
-        )
-        ENGINE_QUEUE_DEPTH.set(sched.num_waiting)
+        with step_span("dyn.step.record"):
+            ENGINE_BATCH_OCCUPANCY.set(
+                sched.num_running / max(1, self.config.max_batch_size)
+            )
+            ENGINE_QUEUE_DEPTH.set(sched.num_waiting)
         if plan.kind == "idle":
             # blocking sleep is deliberate: _one_step executes on the
             # dedicated "jax-engine" thread, never on the event loop
-            time.sleep(0.001)
+            with step_span("dyn.step.wait"):
+                time.sleep(0.001)
             return
-        if self._trace_enabled:
-            self._trace(
-                "plan", kind=plan.kind,
-                prefill=len(plan.prefill_batch),
-                decode=len(plan.decode_seqs),
-                waiting=len(sched.waiting),
-                plan_ms=round((time.monotonic() - t_plan) * 1e3, 1),
-            )
         if plan.kind == "mixed":
             if self._mixed_step_fn is not None:
                 t0 = time.monotonic()
@@ -2652,9 +2656,6 @@ class JaxEngine:
                 )
                 ENGINE_STEP_SECONDS.labels("mixed").observe(
                     time.monotonic() - t0
-                )
-                self._trace(
-                    "mixed", ms=round((time.monotonic() - t0) * 1e3, 1)
                 )
                 return
             plan.kind = "prefill"  # no fused window: prefill this step
@@ -2666,7 +2667,6 @@ class JaxEngine:
             and plan.decode_seqs
             and not self._spec_divert(plan.decode_seqs)
         ):
-            t0 = time.monotonic()
             if self._overlap_ok() and not self._overlap_divert(
                 plan.decode_seqs
             ):
@@ -2682,10 +2682,6 @@ class JaxEngine:
                 # step bodies (_run_spec_step / _finish_spec_record) —
                 # one pipeline call drains many steps, so observing the
                 # whole drain here would poison the spec p99
-                self._trace(
-                    "spec", b=len(plan.decode_seqs),
-                    ms=round((time.monotonic() - t0) * 1e3, 1),
-                )
                 return
             # no drafter had a proposal for any row: fall through to the
             # plain 1-token decode step — the [B, K+1] verify rectangle
@@ -2711,12 +2707,7 @@ class JaxEngine:
             # dispatch N+1 before harvesting N so the TPU never idles
             # for the host's plan+unpack time. --no-overlap restores
             # the serial loop below.
-            t0 = time.monotonic()
             self._decode_pipeline(plan.decode_seqs, plan_ms=plan_ms)
-            self._trace(
-                "decode_pipeline", b=len(plan.decode_seqs),
-                ms=round((time.monotonic() - t0) * 1e3, 1),
-            )
             return
         if (
             plan.kind == "prefill"
@@ -2735,41 +2726,34 @@ class JaxEngine:
             ENGINE_STEP_SECONDS.labels("prefill").observe(
                 time.monotonic() - t0
             )
-            self._trace(
-                "prefill_graduating", rows=len(plan.prefill_batch),
-                ms=round((time.monotonic() - t0) * 1e3, 1),
-            )
             return
-        if plan.kind == "prefill":
-            works = plan.prefill_batch
-            assert works
-            arrays = sched.build_prefill_batch_arrays(works)
-            seqs = [w.seq for w in works]
-        else:
-            seqs = plan.decode_seqs
-            if not seqs:
-                return
-            arrays = sched.build_decode_arrays(seqs)
+        if plan.kind != "prefill" and not plan.decode_seqs:
+            return
+        with step_span("dyn.step.pack"):
+            if plan.kind == "prefill":
+                works = plan.prefill_batch
+                assert works
+                arrays = sched.build_prefill_batch_arrays(works)
+                seqs = [w.seq for w in works]
+            else:
+                seqs = plan.decode_seqs
+                arrays = sched.build_decode_arrays(seqs)
 
-        B = arrays["tokens"].shape[0]
-        sampling = self._batch_sampling(seqs, B)
-        gmask = self._guided_allow_mask(seqs, B)
-        if gmask is not None:
-            # guided rows constrain the sampled token (prefill's first
-            # token and every serial decode step); selects the masked
-            # jit variant (prewarmed under config.prewarm_guided)
-            sampling.arrays["allow_mask"] = gmask
+            B = arrays["tokens"].shape[0]
+            sampling = self._batch_sampling(seqs, B)
+            gmask = self._guided_allow_mask(seqs, B)
+            if gmask is not None:
+                # guided rows constrain the sampled token (prefill's
+                # first token and every serial decode step); selects the
+                # masked jit variant (prewarmed under
+                # config.prewarm_guided)
+                sampling.arrays["allow_mask"] = gmask
 
         if plan.kind == "decode" and self._multi_step_fn is not None:
             t0 = time.monotonic()
             self._window_pipeline([], seqs)
             ENGINE_STEP_SECONDS.labels("decode").observe(
                 time.monotonic() - t0
-            )
-            self._trace(
-                "window_seq",
-                ms=round((time.monotonic() - t0) * 1e3, 1),
-                b=len(seqs),
             )
             return
 
@@ -2782,6 +2766,7 @@ class JaxEngine:
             origin="prefill:" + ",".join(
                 w.seq.request_id for w in plan.prefill_batch
             ) if plan.kind == "prefill" else "",
+            kind=plan.kind,
         )
         if s_out is not None:
             next_tokens, logprobs = s_out[0], s_out[1]
@@ -2789,44 +2774,40 @@ class JaxEngine:
         else:
             next_tokens = logprobs = tops = None
         dt = time.monotonic() - t_step
-        ENGINE_STEP_SECONDS.labels(plan.kind).observe(dt)
-        self._record_step(
-            plan.kind, dt,
-            batch=len(seqs),
-            prefill_rows=len(plan.prefill_batch),
-            tokens=(
-                sum(1 for w in plan.prefill_batch if w.is_last_chunk)
-                if plan.kind == "prefill" else len(seqs)
-            ),
-            plan_ms=plan_ms,
-            synced=need_sync,
-        )
-        self._trace(
-            "dispatch_" + plan.kind,
-            shape=arrays["tokens"].shape,
-            ms=round(dt * 1e3, 1),
-            sync=need_sync,
-        )
+        with step_span("dyn.step.record"):
+            ENGINE_STEP_SECONDS.labels(plan.kind).observe(dt)
+            self._record_step(
+                plan.kind, dt,
+                batch=len(seqs),
+                prefill_rows=len(plan.prefill_batch),
+                tokens=(
+                    sum(1 for w in plan.prefill_batch if w.is_last_chunk)
+                    if plan.kind == "prefill" else len(seqs)
+                ),
+                plan_ms=plan_ms,
+                synced=need_sync,
+            )
 
         def top_row(i):
             return (tops[0][i], tops[1][i]) if tops is not None else None
 
-        if plan.kind == "prefill":
-            for i, work in enumerate(plan.prefill_batch):
-                sched.complete_prefill_chunk(work)
-                if work.is_last_chunk:
+        with step_span("dyn.step.emit"):
+            if plan.kind == "prefill":
+                for i, work in enumerate(plan.prefill_batch):
+                    sched.complete_prefill_chunk(work)
+                    if work.is_last_chunk:
+                        self._emit_token(
+                            work.seq, int(next_tokens[i]),
+                            float(logprobs[i]), top=top_row(i),
+                        )
+            else:
+                for i, seq in enumerate(seqs):
+                    if seq.state != SeqState.RUNNING:
+                        continue
                     self._emit_token(
-                        work.seq, int(next_tokens[i]), float(logprobs[i]),
+                        seq, int(next_tokens[i]), float(logprobs[i]),
                         top=top_row(i),
                     )
-        else:
-            for i, seq in enumerate(seqs):
-                if seq.state != SeqState.RUNNING:
-                    continue
-                self._emit_token(
-                    seq, int(next_tokens[i]), float(logprobs[i]),
-                    top=top_row(i),
-                )
 
     # ------------------------------------------------------------------
     # Speculative decoding (dynamo_tpu/spec; docs/speculative_decoding.md)
@@ -3503,10 +3484,11 @@ class JaxEngine:
 
         def dispatch(seqs_, arrays, sampling, p_ms: float) -> dict:
             t0 = time.monotonic()
-            outs = self._dispatch_device_step(
-                arrays, sampling, origin="decode-pipeline"
-            )
-            packed = self._pack_pair_fn(outs[0], outs[1])
+            with self._dispatch_span("decode", arrays["tokens"]):
+                outs = self._dispatch_device_step(
+                    arrays, sampling, origin="decode-pipeline"
+                )
+                packed = self._pack_pair_fn(outs[0], outs[1])
             return {
                 "packed": packed,
                 "toks": outs[0],  # device column the next step chains off
@@ -3530,22 +3512,24 @@ class JaxEngine:
             # step bit-identically (KV slots rewritten with same values)
             faults.fire("engine.step")
             newest = pending[-1]
-            self._drain_incoming_only()
-            if sched.waiting or sched.prefilling:
-                return False  # drain: the serial planner admits/prefills
-            t_plan = time.monotonic()
-            nxt = sched.plan_pipelined_decode(newest["seqs"], lag)
+            with step_span("dyn.step.plan"):
+                self._drain_incoming_only()
+                if sched.waiting or sched.prefilling:
+                    return False  # drain: the serial planner admits/prefills
+                t_plan = time.monotonic()
+                nxt = sched.plan_pipelined_decode(newest["seqs"], lag)
             if nxt is None:
                 return False
-            arrays = nxt["arrays"]
-            arrays["tokens"] = self._chain_next_fn(
-                newest["toks"], nxt["src_idx"]
-            )
-            sampling = self._batch_sampling(
-                nxt["seqs"],
-                arrays["context_lens"].shape[0],
-                offset=nxt["offsets"],
-            )
+            with step_span("dyn.step.pack"):
+                arrays = nxt["arrays"]
+                arrays["tokens"] = self._chain_next_fn(
+                    newest["toks"], nxt["src_idx"]
+                )
+                sampling = self._batch_sampling(
+                    nxt["seqs"],
+                    arrays["context_lens"].shape[0],
+                    offset=nxt["offsets"],
+                )
             e = dispatch(
                 nxt["seqs"], arrays, sampling,
                 round((time.monotonic() - t_plan) * 1e3, 3),
@@ -3556,51 +3540,52 @@ class JaxEngine:
 
         def harvest(e, depth: int) -> bool:
             t0 = time.monotonic()
-            packed_h = host_value(e["packed"])
+            with step_span("dyn.step.harvest"):
+                packed_h = host_value(e["packed"])
             self.overlap.note_complete()
             self._unsynced_steps.clear()
             sync_ms = round((time.monotonic() - t0) * 1e3, 3)
             B = e["b"]
-            toks = packed_h[:B].astype(np.int32)
-            lps = packed_h[B : 2 * B]
             finished = False
-            for i, seq in enumerate(e["seqs"]):
-                if seq.state != SeqState.RUNNING:
-                    continue
-                if _dead(seq):
-                    # late-detected stop: DISCARD the in-flight token —
-                    # nothing appended means nothing emitted and nothing
-                    # the prefix cache could ever content-address
-                    finished = True
-                    continue
-                self._emit_token(seq, int(toks[i]), float(lps[i]))
-                if seq.state != SeqState.RUNNING:
-                    finished = True
-            _lag_sub(lag, e)
+            with step_span("dyn.step.emit"):
+                toks = packed_h[:B].astype(np.int32)
+                lps = packed_h[B : 2 * B]
+                for i, seq in enumerate(e["seqs"]):
+                    if seq.state != SeqState.RUNNING:
+                        continue
+                    if _dead(seq):
+                        # late-detected stop: DISCARD the in-flight token
+                        # — nothing appended means nothing emitted and
+                        # nothing the prefix cache could ever
+                        # content-address
+                        finished = True
+                        continue
+                    self._emit_token(seq, int(toks[i]), float(lps[i]))
+                    if seq.state != SeqState.RUNNING:
+                        finished = True
+                _lag_sub(lag, e)
             dt = time.monotonic() - e["t_disp"]
-            ENGINE_STEP_SECONDS.labels("decode").observe(dt)
-            self._record_step(
-                "decode", dt,
-                batch=len(e["seqs"]),
-                tokens=len(e["seqs"]),
-                overlapped=True,
-                use_phases=False,  # per-entry stamps below
-                plan_ms=e["plan_ms"],
-                sync_ms=sync_ms,
-                pipeline_depth=depth,
-                # host time this step ran UNDER (planning/dispatching
-                # N+1, emitting N-1) — the overlapped span
-                overlap_ms=round((t0 - e["t_disp"]) * 1e3, 3),
-                **e["phases"],
-            )
-            self._trace(
-                "pipe_decode", b=len(e["seqs"]), depth=depth,
-                ms=round(dt * 1e3, 1), sync_ms=sync_ms,
-            )
+            with step_span("dyn.step.record"):
+                ENGINE_STEP_SECONDS.labels("decode").observe(dt)
+                self._record_step(
+                    "decode", dt,
+                    batch=len(e["seqs"]),
+                    tokens=len(e["seqs"]),
+                    overlapped=True,
+                    use_phases=False,  # per-entry stamps below
+                    plan_ms=e["plan_ms"],
+                    sync_ms=sync_ms,
+                    pipeline_depth=depth,
+                    # host time this step ran UNDER (planning/dispatching
+                    # N+1, emitting N-1) — the overlapped span
+                    overlap_ms=round((t0 - e["t_disp"]) * 1e3, 3),
+                    **e["phases"],
+                )
             return finished
 
-        arrays = sched.build_decode_arrays(seqs)
-        sampling = self._batch_sampling(seqs, arrays["tokens"].shape[0])
+        with step_span("dyn.step.pack"):
+            arrays = sched.build_decode_arrays(seqs)
+            sampling = self._batch_sampling(seqs, arrays["tokens"].shape[0])
         entry = dispatch(seqs, arrays, sampling, plan_ms)
         _lag_add(lag, entry)
         pending = deque([entry])
@@ -4016,7 +4001,8 @@ class JaxEngine:
 
         # dispatch the first window
         if works:
-            p_arrays = sched.build_prefill_batch_arrays(works)
+            with step_span("dyn.step.pack"):
+                p_arrays = sched.build_prefill_batch_arrays(works)
             # Multimodal chunks, top-logprobs AND penalty/bias batches
             # take a dedicated prefill step instead of the mixed
             # rectangle: embedding injection doesn't ride the fixed
@@ -4027,9 +4013,10 @@ class JaxEngine:
             # whole step). Decode follows on the next
             # plan.
             if "extra_embeds" in p_arrays or penalties_in(works, seqs):
-                sampling = self._batch_sampling(
-                    [w.seq for w in works], p_arrays["tokens"].shape[0]
-                )
+                with step_span("dyn.step.pack"):
+                    sampling = self._batch_sampling(
+                        [w.seq for w in works], p_arrays["tokens"].shape[0]
+                    )
                 s_out = self._run_device_step(
                     p_arrays, sampling,
                     sync=any(w.is_last_chunk for w in works),
@@ -4037,18 +4024,19 @@ class JaxEngine:
                         w.seq.request_id for w in works
                     ),
                 )
-                for i, work in enumerate(works):
-                    sched.complete_prefill_chunk(work)
-                    if work.is_last_chunk:
-                        top = (
-                            (s_out[2][i], s_out[3][i])
-                            if len(s_out) > 2
-                            else None
-                        )
-                        self._emit_token(
-                            work.seq, int(s_out[0][i]), float(s_out[1][i]),
-                            top=top,
-                        )
+                with step_span("dyn.step.emit"):
+                    for i, work in enumerate(works):
+                        sched.complete_prefill_chunk(work)
+                        if work.is_last_chunk:
+                            top = (
+                                (s_out[2][i], s_out[3][i])
+                                if len(s_out) > 2
+                                else None
+                            )
+                            self._emit_token(
+                                work.seq, int(s_out[0][i]),
+                                float(s_out[1][i]), top=top,
+                            )
                 return
             if not seqs:
                 # prefill-only first entry (overlapped cohort
@@ -4057,49 +4045,58 @@ class JaxEngine:
                 # tokens on device into the first decode window, so the
                 # prefill->decode boundary costs no host round trip
                 assert all(w.is_last_chunk for w in works)
-                sampling_p = self._batch_sampling(
-                    [w.seq for w in works], p_arrays["tokens"].shape[0]
-                )
-                outs = self._dispatch_device_step(
-                    p_arrays, sampling_p,
-                    origin="prefill:" + ",".join(
-                        w.seq.request_id for w in works
-                    ),
-                )
-                out = (
-                    "prefill",
-                    self._pack_pair_fn(outs[0], outs[1]),
-                    outs[0],
-                    p_arrays["tokens"].shape[0],
-                )
+                with step_span("dyn.step.pack"):
+                    sampling_p = self._batch_sampling(
+                        [w.seq for w in works], p_arrays["tokens"].shape[0]
+                    )
+                with self._dispatch_span("prefill", p_arrays["tokens"]):
+                    outs = self._dispatch_device_step(
+                        p_arrays, sampling_p,
+                        origin="prefill:" + ",".join(
+                            w.seq.request_id for w in works
+                        ),
+                    )
+                    out = (
+                        "prefill",
+                        self._pack_pair_fn(outs[0], outs[1]),
+                        outs[0],
+                        p_arrays["tokens"].shape[0],
+                    )
                 d_arrays = None
             else:
-                d_arrays = sched.build_decode_arrays(seqs)
-                p_rows = (rect or (self.config.mixed_prefill_rows, 0))[0]
-                sampling_p = self._batch_sampling(
-                    [w.seq for w in works], p_rows
-                )
-                sampling_d = self._batch_sampling(
-                    seqs, d_arrays["tokens"].shape[0]
-                )
+                with step_span("dyn.step.pack"):
+                    d_arrays = sched.build_decode_arrays(seqs)
+                    p_rows = (rect or (self.config.mixed_prefill_rows, 0))[0]
+                    sampling_p = self._batch_sampling(
+                        [w.seq for w in works], p_rows
+                    )
+                    sampling_d = self._batch_sampling(
+                        seqs, d_arrays["tokens"].shape[0]
+                    )
                 pipelining = pipelining and not (
                     sampling_p.has_penalties or sampling_d.has_penalties
                     or sampling_p.has_toplp or sampling_d.has_toplp
                     or sampling_p.has_bias or sampling_d.has_bias
                 )
-                out = ("mixed",) + self._dispatch_mixed(
-                    works, seqs, p_arrays, d_arrays, sampling_p, sampling_d,
-                    rect=rect,
-                )
+                with self._dispatch_span("mixed", d_arrays["tokens"]):
+                    out = ("mixed",) + self._dispatch_mixed(
+                        works, seqs, p_arrays, d_arrays, sampling_p,
+                        sampling_d, rect=rect,
+                    )
         else:
-            d_arrays = sched.build_decode_arrays(seqs)
-            sampling_d = self._batch_sampling(seqs, d_arrays["tokens"].shape[0])
+            with step_span("dyn.step.pack"):
+                d_arrays = sched.build_decode_arrays(seqs)
+                sampling_d = self._batch_sampling(
+                    seqs, d_arrays["tokens"].shape[0]
+                )
             pipelining = pipelining and not (
                 sampling_d.has_penalties or sampling_d.has_toplp
                 or sampling_d.has_bias
             )
-            out = ("pure",) + self._dispatch_multi_step(d_arrays, sampling_d) \
-                + (d_arrays["tokens"].shape[0],)
+            with self._dispatch_span("window", d_arrays["tokens"]):
+                out = ("pure",) + self._dispatch_multi_step(
+                    d_arrays, sampling_d
+                ) + (d_arrays["tokens"].shape[0],)
         vmap0 = (
             {id(s): int(d_arrays["valid_steps"][i])
              for i, s in enumerate(seqs)}
@@ -4112,31 +4109,36 @@ class JaxEngine:
 
         def harvest_entry(e) -> None:
             t0 = time.monotonic()
-            if e["kind"] == "prefill":
-                # cohort-graduation entry: one packed [2P] transfer
-                # carrying first tokens + logprobs; the decode window
-                # chained off them is already in flight behind it
-                ph = host_value(e["packed"])
-                P = e["p_rows"]
-                p_next_h = ph[:P].astype(np.int32)
-                p_lp_h = ph[P : 2 * P]
-                for i, work in enumerate(e["works"]):
-                    sched.complete_prefill_chunk(work)
-                    if work.is_last_chunk:
-                        self._emit_token(
-                            work.seq, int(p_next_h[i]), float(p_lp_h[i])
-                        )
-            elif e["kind"] == "mixed":
-                self._emit_mixed(
-                    e["works"], e["seqs"], host_value(e["flat"]), e["b"],
-                    P=e["p_rows"],
+            with step_span("dyn.step.harvest"):
+                host = host_value(
+                    e["packed"] if e["kind"] == "prefill" else e["flat"]
                 )
-            else:
-                tlp = self._wants_toplp(e["seqs"])
-                win = self._unpack_window(host_value(e["flat"]), tlp)
-                for i, seq in enumerate(e["seqs"]):
-                    tops = (win[2][i], win[3][i]) if tlp else None
-                    self._emit_window(seq, win[0][i], win[1][i], tops=tops)
+            with step_span("dyn.step.emit"):
+                if e["kind"] == "prefill":
+                    # cohort-graduation entry: one packed [2P] transfer
+                    # carrying first tokens + logprobs; the decode window
+                    # chained off them is already in flight behind it
+                    P = e["p_rows"]
+                    p_next_h = host[:P].astype(np.int32)
+                    p_lp_h = host[P : 2 * P]
+                    for i, work in enumerate(e["works"]):
+                        sched.complete_prefill_chunk(work)
+                        if work.is_last_chunk:
+                            self._emit_token(
+                                work.seq, int(p_next_h[i]), float(p_lp_h[i])
+                            )
+                elif e["kind"] == "mixed":
+                    self._emit_mixed(
+                        e["works"], e["seqs"], host, e["b"], P=e["p_rows"],
+                    )
+                else:
+                    tlp = self._wants_toplp(e["seqs"])
+                    win = self._unpack_window(host, tlp)
+                    for i, seq in enumerate(e["seqs"]):
+                        tops = (win[2][i], win[3][i]) if tlp else None
+                        self._emit_window(
+                            seq, win[0][i], win[1][i], tops=tops
+                        )
             self.overlap.note_complete()
             # window sync succeeded: earlier async dispatches are
             # known-good (in-order execution) — retire deferred-error
@@ -4147,45 +4149,42 @@ class JaxEngine:
             # one flight-recorder entry per WINDOW (the serving-path
             # unit of work): duration is the host-side sync+emit wait —
             # the dispatch overlapped earlier windows by design
-            self._record_step(
-                "window_" + e["kind"], win_s,
-                batch=len(e["seqs"]),
-                prefill_rows=len(e["works"]),
-                tokens=sum(e["vmap"].values()),
-                overlapped=True,
-                pipeline_depth=len(pending),
-                use_phases=False,  # dispatched via the window fns, not
-                # _run_device_step — its phase stamps belong elsewhere
-                # overlap phase stamps (telemetry/overlap.py): the span
-                # this window ran under other host work, and the device
-                # idle gap that preceded its dispatch
-                overlap_ms=round((t0 - e["t_disp"]) * 1e3, 3),
-                idle_gap_ms=e["idle_gap_ms"],
-            )
-            self._trace(
-                "window", kind=e["kind"], b=len(e["seqs"]),
-                p=len(e["works"]), wait=len(sched.waiting),
-                pref=len(sched.prefilling), run=len(sched.running),
-                depth=len(pending),
-                ms=round(win_s * 1e3, 1),
-            )
+            with step_span("dyn.step.record"):
+                self._record_step(
+                    "window_" + e["kind"], win_s,
+                    batch=len(e["seqs"]),
+                    prefill_rows=len(e["works"]),
+                    tokens=sum(e["vmap"].values()),
+                    overlapped=True,
+                    pipeline_depth=len(pending),
+                    use_phases=False,  # dispatched via the window fns,
+                    # not _run_device_step — its phase stamps belong
+                    # elsewhere. Overlap phase stamps
+                    # (telemetry/overlap.py): the span this window ran
+                    # under other host work, and the device idle gap that
+                    # preceded its dispatch
+                    overlap_ms=round((t0 - e["t_disp"]) * 1e3, 3),
+                    idle_gap_ms=e["idle_gap_ms"],
+                )
 
         def try_extend() -> bool:
             """Plan + dispatch one more window chained off the newest
             in-flight one. False = the pipeline can't grow further."""
             newest = pending[-1]
-            self._drain_incoming_only()
-            nxt = sched.plan_pipelined_mixed(
-                newest["seqs"], newest["works"], lag,
-                # a prefill-only entry's token vector is the prefill
-                # rows alone — graduated row r chains from index r
-                grad_base=0 if newest["kind"] == "prefill" else None,
-            )
+            with step_span("dyn.step.plan"):
+                self._drain_incoming_only()
+                nxt = sched.plan_pipelined_mixed(
+                    newest["seqs"], newest["works"], lag,
+                    # a prefill-only entry's token vector is the prefill
+                    # rows alone — graduated row r chains from index r
+                    grad_base=0 if newest["kind"] == "prefill" else None,
+                )
             if nxt is None or penalties_in(nxt["works2"], nxt["seqs"]):
                 return False
             p2 = None
             if nxt["works2"]:
-                p2 = sched.build_prefill_batch_arrays(nxt["works2"])
+                with step_span("dyn.step.pack"):
+                    p2 = sched.build_prefill_batch_arrays(nxt["works2"])
                 if "extra_embeds" in p2:
                     return False  # multimodal never rides the pipeline
             if self._mh_broadcast is not None:
@@ -4198,33 +4197,39 @@ class JaxEngine:
                 self._mh_broadcast.announce_chain(
                     nxt["src_idx"], newest["kind"] == "mixed"
                 )
-            if newest["kind"] == "prefill":
-                chained = self._chain_next_fn(
-                    newest["p_next"], nxt["src_idx"]
+            with step_span("dyn.step.pack"):
+                if newest["kind"] == "prefill":
+                    chained = self._chain_next_fn(
+                        newest["p_next"], nxt["src_idx"]
+                    )
+                elif newest["kind"] == "mixed":
+                    chained = self._chain_fn(
+                        newest["last"], newest["p_next"], nxt["src_idx"]
+                    )
+                else:
+                    chained = self._chain_pure_fn(
+                        newest["last"], nxt["src_idx"]
+                    )
+                s_d2 = self._batch_sampling(
+                    nxt["seqs"],
+                    nxt["arrays"]["tokens"].shape[0],
+                    offset=nxt["offsets"],
                 )
-            elif newest["kind"] == "mixed":
-                chained = self._chain_fn(
-                    newest["last"], newest["p_next"], nxt["src_idx"]
-                )
-            else:
-                chained = self._chain_pure_fn(newest["last"], nxt["src_idx"])
-            s_d2 = self._batch_sampling(
-                nxt["seqs"],
-                nxt["arrays"]["tokens"].shape[0],
-                offset=nxt["offsets"],
-            )
+                if p2 is not None:
+                    s_p2 = self._batch_sampling(
+                        [w.seq for w in nxt["works2"]], nxt["rect"][0]
+                    )
             if p2 is not None:
-                s_p2 = self._batch_sampling(
-                    [w.seq for w in nxt["works2"]], nxt["rect"][0]
-                )
-                out = ("mixed",) + self._dispatch_mixed(
-                    nxt["works2"], nxt["seqs"], p2, nxt["arrays"],
-                    s_p2, s_d2, tokens_dev=chained, rect=nxt["rect"],
-                )
+                with self._dispatch_span("mixed", nxt["arrays"]["tokens"]):
+                    out = ("mixed",) + self._dispatch_mixed(
+                        nxt["works2"], nxt["seqs"], p2, nxt["arrays"],
+                        s_p2, s_d2, tokens_dev=chained, rect=nxt["rect"],
+                    )
             else:
-                out = ("pure",) + self._dispatch_multi_step(
-                    nxt["arrays"], s_d2, tokens_dev=chained
-                ) + (nxt["arrays"]["tokens"].shape[0],)
+                with self._dispatch_span("window", nxt["arrays"]["tokens"]):
+                    out = ("pure",) + self._dispatch_multi_step(
+                        nxt["arrays"], s_d2, tokens_dev=chained
+                    ) + (nxt["arrays"]["tokens"].shape[0],)
             e = make_entry(out, nxt["works2"], nxt["seqs"], nxt["vmap"])
             _lag_add(lag, e)
             pending.append(e)
@@ -4504,10 +4509,10 @@ class JaxEngine:
             log.exception("autopsy segment publish failed")
 
     def _emit_lifecycle_spans(self, seq: Sequence, reason: FinishReason) -> None:
-        """Record the engine's per-request spans at finish time. Span
-        boundaries come from the scheduler's monotonic stamps, anchored
-        to the submit instant's wall clock so cross-process nesting
-        holds. No-op (two attribute reads) when tracing is disabled."""
+        """Record the engine's per-request spans at finish time, from
+        the scheduler's monotonic stamps as they are: queue_wait, prefill
+        and decode tile submit -> now. No-op (two attribute reads) when
+        tracing is disabled."""
         tracer = get_tracer()
         if not tracer.enabled or not seq.t_submit:
             return
@@ -4518,38 +4523,39 @@ class JaxEngine:
             # three spans stay correlated (three independent record()
             # calls would each sample separately and root a separate
             # trace)
-            import random
-
-            from dynamo_tpu.telemetry import new_trace_id
-
             if tracer.sample < 1.0 and random.random() >= tracer.sample:
                 return
             parent = {"trace_id": new_trace_id(), "span_id": None}
-
-        def wall(mono: float) -> float:
-            return seq.t_submit_wall + (mono - seq.t_submit)
-
-        now = time.monotonic()
-        attrs = {"service": "engine"}
-        if seq.t_admit:
-            tracer.record(
-                "engine.queue_wait", start=seq.t_submit_wall,
-                duration_s=seq.t_admit - seq.t_submit, parent=parent,
-                attrs=attrs,
+        if not seq.t_admit:
+            return
+        tracer.record(
+            "engine.queue_wait", start_mono=seq.t_submit,
+            duration_s=seq.t_admit - seq.t_submit, parent=parent,
+            attrs={"service": "engine",
+                   "waiting": seq.waiting_at_intake},
+        )
+        if not seq.t_prefill_done:
+            return
+        tracer.record(
+            "engine.prefill", start_mono=seq.t_admit,
+            duration_s=seq.t_prefill_done - seq.t_admit, parent=parent,
+            attrs={"service": "engine",
+                   "prompt_tokens": len(seq.request.token_ids),
+                   "cached_tokens": seq.num_cached_prompt,
+                   "chunks": seq.prefill_chunks},
+        )
+        decode = {"service": "engine", "tokens": seq.generated,
+                  "finish_reason": str(reason.value)}
+        if seq.t_first_token:
+            # the server's own TTFT, beside the client's
+            decode["ttft_ms"] = round(
+                (seq.t_first_token - seq.t_submit) * 1e3, 3
             )
-        if seq.t_admit and seq.t_prefill_done:
-            tracer.record(
-                "engine.prefill", start=wall(seq.t_admit),
-                duration_s=seq.t_prefill_done - seq.t_admit, parent=parent,
-                attrs={**attrs, "prompt_tokens": len(seq.request.token_ids),
-                       "cached_tokens": seq.num_cached_prompt},
-            )
-            tracer.record(
-                "engine.decode", start=wall(seq.t_prefill_done),
-                duration_s=now - seq.t_prefill_done, parent=parent,
-                attrs={**attrs, "tokens": seq.generated,
-                       "finish_reason": str(reason.value)},
-            )
+        tracer.record(
+            "engine.decode", start_mono=seq.t_prefill_done,
+            duration_s=time.monotonic() - seq.t_prefill_done, parent=parent,
+            attrs=decode,
+        )
 
     def _annotate_deferred_error(self, exc: BaseException) -> None:
         """A device error from an earlier ``sync=False`` prefill dispatch
@@ -4811,7 +4817,6 @@ class JaxEngine:
         # engine.{queue_wait,prefill,decode} spans (cheap plain fields
         # when tracing is off)
         seq.t_submit = time.monotonic()
-        seq.t_submit_wall = time.time()
         seq.trace = context.trace_context()
         if context.deadline is not None:
             # same-process monotonic instant: the scheduler reaps the
@@ -4852,6 +4857,19 @@ class JaxEngine:
             ),
             top_loss_bucket=attr["top_loss_bucket"],
         )
+
+    def program_counts(self) -> dict:
+        """Cumulative counts a profiler capture reads at its two edges
+        (telemetry/debug.py ``program_spans.json``)."""
+        sched = self.scheduler
+        out: dict = {"steps": dict(self._steps_dispatched)}
+        if sched is not None:
+            out.update(
+                prompt_tokens=sched.prompt_tokens_admitted,
+                cached_prompt_tokens=sched.prompt_tokens_cached,
+                preemptions=sched.preemptions,
+            )
+        return out
 
     def attribution_state(self) -> dict:
         """Provider behind ``/debug/attribution``: the ledger window +
@@ -5024,6 +5042,7 @@ class JaxEngine:
         self._wake.set()
         if self._debug_name is not None:
             unregister_debug_provider(self._debug_name, self.debug_state)
+            unregister_count_provider(self._debug_name, self.program_counts)
             unregister_attribution_provider(
                 self._debug_name, self.attribution_state
             )

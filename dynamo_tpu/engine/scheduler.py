@@ -88,14 +88,15 @@ class Sequence:
     # COMMIT — staged speculative drafts are unwound before verified
     # tokens re-append, so the automaton only ever sees committed tokens
     guided_state: Optional[Any] = None
-    # request-lifecycle stamps (telemetry): monotonic except the wall
-    # anchor; the engine emits queue-wait/prefill/decode spans from
-    # these at finish time (engine.py _emit_finish)
+    # request-lifecycle stamps (telemetry), all monotonic; the engine
+    # emits queue-wait/prefill/decode spans from these at finish time
+    # (engine.py _emit_finish)
     t_submit: float = 0.0  # engine.submit() (monotonic)
-    t_submit_wall: float = 0.0  # same instant, wall clock
     t_admit: float = 0.0  # first admission into prefilling
     t_prefill_done: float = 0.0  # last prompt chunk computed
     t_first_token: float = 0.0  # first generated token appended (TTFT)
+    waiting_at_intake: int = 0  # requests queued ahead when this one arrived
+    prefill_chunks: int = 0  # prefill programs this prompt rode
     # propagated trace context ({"trace_id", "span_id"}) or None
     trace: Optional[dict] = None
     # the CALLER's request id (Context.id — the frontend's autopsy key),
@@ -229,6 +230,10 @@ class Scheduler:
         # prefix-cache stats (one query per admitted request)
         self.prefix_queries = 0
         self.prefix_hits = 0
+        # the same at token level: prompt tokens admitted, and those of
+        # them the cache already held (program_spans.json counts)
+        self.prompt_tokens_admitted = 0
+        self.prompt_tokens_cached = 0
         # recompute-preemption count (observability: healthy serving
         # should sit at ~0 — see _growth_reserve)
         self.preemptions = 0
@@ -237,6 +242,7 @@ class Scheduler:
     def add_request(self, seq: Sequence) -> None:
         seq.arrival = self._arrival
         self._arrival += 1
+        seq.waiting_at_intake = len(self.waiting)
         self.waiting.append(seq)
 
     @property
@@ -505,6 +511,8 @@ class Scheduler:
             self.prefix_queries += 1
             if cached > 0:
                 self.prefix_hits += 1
+            self.prompt_tokens_admitted += seq.total_len
+            self.prompt_tokens_cached += seq.num_cached_prompt
 
     def _plan_prefill_batch(
         self,
@@ -569,6 +577,7 @@ class Scheduler:
     def complete_prefill_chunk(self, work: PrefillWork) -> None:
         seq = work.seq
         seq.num_computed = work.start_pos + len(work.tokens)
+        seq.prefill_chunks += 1
         self._commit_full_blocks(seq)
         if work.is_last_chunk:
             self.prefilling.remove(seq)

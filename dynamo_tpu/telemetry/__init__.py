@@ -4,9 +4,12 @@ introspection.
 Four pieces (docs/observability.md is the operator-facing guide):
 
 - **Spans** (spans.py): ``get_tracer().span("name", parent=ctx)`` with
-  trace-context propagation over the existing transport. Enabled by
-  ``DYN_TRACE_FILE`` (JSONL); ``dynamo-tpu trace export`` renders
-  Perfetto/chrome://tracing flame graphs (export.py).
+  trace-context propagation over the existing transport. Sinks:
+  ``DYN_TRACE_FILE`` (JSONL; ``dynamo-tpu trace export`` renders
+  Perfetto/chrome://tracing flame graphs, export.py) and the in-memory
+  buffer of every serving process, written beside a profiler capture
+  (``program_spans.json``, debug.py). ``step_span`` marks the engine's
+  step phases on the profiler's own clock.
 - **Metrics** (metrics.py): one process registry of labeled counters/
   gauges/histograms with Prometheus text exposition and cardinality
   guard rails; the serving stack's catalog lives in instruments.py.
@@ -33,7 +36,10 @@ from dynamo_tpu.telemetry.debug import (  # noqa: F401
     capture_profile,
     collect_debug_state,
     debug_provider_names,
+    profile_blocking,
+    register_count_provider,
     register_debug_provider,
+    unregister_count_provider,
     unregister_debug_provider,
 )
 from dynamo_tpu.telemetry.attribution import (  # noqa: F401
@@ -60,10 +66,12 @@ from dynamo_tpu.telemetry.spans import (  # noqa: F401
     NULL_SPAN,
     JsonlSpanExporter,
     Span,
+    SpanBuffer,
     Tracer,
     get_tracer,
     new_span_id,
     new_trace_id,
     propagation_context,
     reset_tracer,
+    step_span,
 )

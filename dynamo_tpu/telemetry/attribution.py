@@ -586,16 +586,12 @@ class BlackBox:
 
     def _capture_profile(self, out_dir: str) -> None:
         """Blocking jax.profiler capture — opt-in and short; a failure
-        degrades to a bundle without the profile."""
-        try:
-            import jax
+        (a ``/debug/profile`` capture holding the one profiler among
+        them) degrades to a bundle without the profile."""
+        from dynamo_tpu.telemetry.debug import profile_blocking
 
-            os.makedirs(out_dir, exist_ok=True)
-            jax.profiler.start_trace(out_dir)
-            try:
-                time.sleep(self.profile_ms / 1e3)
-            finally:
-                jax.profiler.stop_trace()
+        try:
+            profile_blocking(self.profile_ms, out_dir)
         except Exception:
             log.exception("black-box profiler capture failed")
 

@@ -13,18 +13,35 @@ exactly when things are broken).
 ``jax.profiler`` capture written where TensorBoard/Perfetto can load it
 (the profiler emits ``plugins/profile/*/trace.json.gz`` under the
 output dir — load it at https://ui.perfetto.dev). One capture at a
-time per process; concurrent requests get a busy error.
+time per process, whoever asks (``profile_blocking`` is the one function
+that starts and stops the profiler; the black box's capture goes
+through it too); a second request gets a busy error. The profiler runs
+in a worker thread: ``stop_trace`` serialises the whole trace, seconds
+during which the event loop must keep serving.
+
+Beside each trace a capture writes ``program_spans.json``: the request
+spans the process holds in memory (telemetry/spans.py ``SpanBuffer``),
+the two clocks at ``start_trace``'s return and ``stop_trace``'s call, and
+the program's cumulative counts at those two instants (``program counts``
+providers: the engine's steps dispatched by kind, prompt tokens admitted
+and served from cache, preemptions). A process that took a capture
+writes the file again when it exits cleanly, so the copy a reader finds
+after shutdown covers every request the process finished.
 """
 
 from __future__ import annotations
 
 import asyncio
+import atexit
+import json
 import logging
 import os
 import tempfile
 import threading
 import time
 from typing import Callable, Optional
+
+from dynamo_tpu.telemetry import spans
 
 log = logging.getLogger("dynamo_tpu.telemetry.debug")
 
@@ -87,12 +104,18 @@ class ProviderRegistry:
 
 
 _DEBUG_PROVIDERS = ProviderRegistry("debug")
+# cumulative counts read at a capture's two edges (program_spans.json)
+_COUNT_PROVIDERS = ProviderRegistry("program counts")
 
 # one jax.profiler capture at a time (the profiler itself is global)
 _profile_lock = threading.Lock()
 _profile_seq = 0
+# the newest capture's program_spans.json: path, and what was read at
+# the capture's edges — rewritten with the spans finished since, at exit
+_last_capture: Optional[dict] = None
 
 MAX_PROFILE_MS = 30_000
+PROGRAM_SPANS_FILE = "program_spans.json"
 
 
 def register_debug_provider(name: str, fn: Callable[[], dict]) -> None:
@@ -113,11 +136,53 @@ def collect_debug_state() -> dict:
     return _DEBUG_PROVIDERS.collect()
 
 
-async def capture_profile(ms: int, out_dir: str = "") -> dict:
-    """Run ``jax.profiler`` for ``ms`` milliseconds; returns
-    ``{"trace_dir", "duration_ms"}`` (raises RuntimeError when a capture
-    is already running or the profiler is unavailable)."""
-    global _profile_seq
+def register_count_provider(name: str, fn: Callable[[], dict]) -> None:
+    _COUNT_PROVIDERS.register(name, fn)
+
+
+def unregister_count_provider(
+    name: str, fn: Optional[Callable[[], dict]] = None
+) -> None:
+    _COUNT_PROVIDERS.unregister(name, fn)
+
+
+def _edge() -> dict:
+    """Both clocks and the program's cumulative counts, now."""
+    return {"monotonic_ns": time.monotonic_ns(), "time_ns": time.time_ns(),
+            "counts": _COUNT_PROVIDERS.collect()}
+
+
+def write_program_spans(written: str) -> Optional[str]:
+    """(Re)write the newest capture's ``program_spans.json`` (atomic
+    replace) with the spans in memory now; the path, or None when this
+    process took no capture."""
+    cap = _last_capture
+    if cap is None:
+        return None
+    buffer = spans.get_tracer().buffer
+    kept, dropped = buffer.snapshot() if buffer is not None else ([], 0)
+    doc = {"written": written, "pid": os.getpid(), "spans": kept,
+           "dropped": dropped, "start": cap["start"], "stop": cap["stop"]}
+    tmp = cap["path"] + ".tmp"
+    with open(tmp, "w") as f:
+        json.dump(doc, f, default=str)
+    os.replace(tmp, cap["path"])
+    return cap["path"]
+
+
+def _write_program_spans_at_exit() -> None:
+    try:
+        write_program_spans("shutdown")
+    except OSError:  # the directory may be gone; exiting anyway
+        log.debug("program_spans.json not rewritten at exit", exc_info=True)
+
+
+def profile_blocking(ms: int, out_dir: str = "") -> dict:
+    """One profiler session, blocking: ``start_trace``, ``ms``
+    milliseconds, ``stop_trace``, ``program_spans.json``. Call from a
+    thread that may block for seconds. Raises RuntimeError when a
+    capture is already running."""
+    global _profile_seq, _last_capture
     ms = max(1, min(int(ms), MAX_PROFILE_MS))
     if not _profile_lock.acquire(blocking=False):
         raise RuntimeError("a profile capture is already running")
@@ -132,10 +197,26 @@ async def capture_profile(ms: int, out_dir: str = "") -> dict:
         os.makedirs(d, exist_ok=True)
         jax.profiler.start_trace(d)
         try:
-            await asyncio.sleep(ms / 1000.0)
+            started = _edge()
+            spans.set_capture_live(True)
+            time.sleep(ms / 1000.0)
         finally:
+            spans.set_capture_live(False)
+            stopped = _edge()
             jax.profiler.stop_trace()
+        if _last_capture is None:
+            atexit.register(_write_program_spans_at_exit)
+        _last_capture = {"path": os.path.join(d, PROGRAM_SPANS_FILE),
+                         "start": started, "stop": stopped}
+        write_program_spans("capture_end")
         log.info("profiler capture (%d ms) -> %s", ms, d)
         return {"trace_dir": d, "duration_ms": ms}
     finally:
         _profile_lock.release()
+
+
+async def capture_profile(ms: int, out_dir: str = "") -> dict:
+    """Run ``jax.profiler`` for ``ms`` milliseconds off the event loop;
+    returns ``{"trace_dir", "duration_ms"}`` (raises RuntimeError when a
+    capture is already running or the profiler is unavailable)."""
+    return await asyncio.to_thread(profile_blocking, ms, out_dir)

@@ -16,12 +16,23 @@ Design constraints (ISSUE 2 acceptance: bench throughput within noise):
 - **Thread-safe export.** The engine step thread and the asyncio loop
   both finish spans; exporters serialize behind one lock.
 
-Timing model: ``start`` is wall-clock (``time.time()``) so spans from
-different processes on one machine order/nest correctly; ``duration_s``
-is measured on the monotonic clock so it never goes negative under NTP
-slew. ``Tracer.record()`` builds a span from explicit timestamps for
-code that only learns span boundaries after the fact (the engine emits
-queue-wait/prefill/decode spans at finish time from scheduler stamps).
+Timing model: every span carries TWO starts taken at the same instant.
+``start`` is wall-clock (``time.time()``) so spans from different
+processes on one machine order/nest correctly; ``start_mono_ns`` is
+``time.monotonic_ns()`` (CLOCK_MONOTONIC, one per host), the clock a load
+generator's window and the profiler capture's edges are stamped on
+(``program_spans.json``, telemetry/debug.py). ``duration_s`` is measured
+on the monotonic clock so it never goes negative under NTP slew.
+``Tracer.record()`` builds a span from explicit timestamps for code that
+only learns span boundaries after the fact (the engine emits
+queue-wait/prefill/decode spans at finish time from the scheduler's
+monotonic stamps, passed as they are).
+
+Sinks, all behind ``Tracer._export``: the JSONL file (``DYN_TRACE_FILE``)
+and the in-memory ``SpanBuffer`` every serving process keeps
+(``Tracer.keep_in_memory``; cli ``run``), which a profiler capture writes
+beside its trace. Per-STEP phases are not spans of this stream: they go
+to the profiler only, through ``step_span`` (bottom of this file).
 
 Env knobs:
   DYN_TRACE_FILE    append finished spans as JSONL here (enables tracing)
@@ -32,6 +43,7 @@ Env knobs:
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -39,6 +51,7 @@ import random
 import threading
 import time
 import uuid
+from collections import deque
 from typing import Any, Optional
 
 log = logging.getLogger("dynamo_tpu.telemetry")
@@ -59,7 +72,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "start",
-        "duration_s", "attrs", "_t0", "_tracer", "_ended",
+        "start_mono_ns", "duration_s", "attrs", "_tracer", "_ended",
     )
 
     def __init__(
@@ -76,7 +89,7 @@ class Span:
         self.span_id = new_span_id()
         self.parent_id = parent_id
         self.start = time.time()
-        self._t0 = time.monotonic()
+        self.start_mono_ns = time.monotonic_ns()
         self.duration_s: Optional[float] = None
         self.attrs: dict = dict(attrs) if attrs else {}
         self._ended = False
@@ -89,7 +102,7 @@ class Span:
         if self._ended:
             return
         self._ended = True
-        self.duration_s = time.monotonic() - self._t0
+        self.duration_s = (time.monotonic_ns() - self.start_mono_ns) / 1e9
         self._tracer._export(self)
 
     # -- propagation -------------------------------------------------------
@@ -103,6 +116,7 @@ class Span:
             "trace_id": self.trace_id,
             "span_id": self.span_id,
             "start": self.start,
+            "start_mono_ns": self.start_mono_ns,
             "duration_s": self.duration_s,
         }
         if self.parent_id:
@@ -179,6 +193,31 @@ class JsonlSpanExporter:
                 self._fh = None
 
 
+class SpanBuffer:
+    """The in-memory sink: the newest ``capacity`` finished spans, and a
+    count of those that fell off the far end. What a profiler capture
+    writes beside its trace (telemetry/debug.py)."""
+
+    CAPACITY = 16_384
+
+    def __init__(self, capacity: int = CAPACITY):
+        self._spans: deque = deque(maxlen=capacity)
+        self._lock = threading.Lock()
+        self.dropped = 0
+
+    def export(self, span: Span) -> None:
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
+            self._spans.append(span)
+
+    def snapshot(self) -> tuple[list[dict], int]:
+        """(the buffered spans as dicts, oldest first; spans dropped)."""
+        with self._lock:
+            spans, dropped = list(self._spans), self.dropped
+        return [s.to_dict() for s in spans], dropped
+
+
 class Tracer:
     """Process-local span factory + exporter fan-out.
 
@@ -197,10 +236,19 @@ class Tracer:
             except ValueError:
                 sample = 1.0
         self.sample = min(1.0, max(0.0, sample))
+        self.buffer: Optional[SpanBuffer] = None
 
     @property
     def enabled(self) -> bool:
         return bool(self._exporters)
+
+    def keep_in_memory(self) -> SpanBuffer:
+        """Attach the in-memory sink (once; later calls return it)."""
+        with self._lock:
+            if self.buffer is None:
+                self.buffer = SpanBuffer()
+                self._exporters.append(self.buffer)
+            return self.buffer
 
     def add_exporter(self, exporter: Any) -> None:
         with self._lock:
@@ -243,14 +291,18 @@ class Tracer:
     def record(
         self,
         name: str,
-        start: float,
-        duration_s: float,
+        start: Optional[float] = None,
+        duration_s: float = 0.0,
         parent: Any = None,
         attrs: Optional[dict] = None,
+        start_mono: Optional[float] = None,
     ) -> Optional[str]:
-        """Record a span whose boundaries are already known (explicit
-        wall-clock start + duration). Returns its span_id, or None when
-        tracing is disabled/unsampled."""
+        """Record a span whose boundaries are already known: a start on
+        either clock (``start_mono``: ``time.monotonic()`` seconds, the
+        stamp engine code holds, kept as given; ``start``: wall clock)
+        and a duration. The other clock's start is the same instant,
+        carried over by the two clocks' present offset. Returns its
+        span_id, or None when tracing is disabled/unsampled."""
         if not self._exporters:
             return None
         ctx = _as_trace_context(parent)
@@ -264,8 +316,17 @@ class Tracer:
         span.trace_id = ctx["trace_id"] if ctx else new_trace_id()
         span.span_id = new_span_id()
         span.parent_id = ctx.get("span_id") if ctx else None
+        if start_mono is not None:
+            span.start_mono_ns = int(start_mono * 1e9)
+            if start is None:
+                start = time.time() - (time.monotonic() - start_mono)
+        elif start is not None:
+            span.start_mono_ns = time.monotonic_ns() - int(
+                (time.time() - start) * 1e9
+            )
+        else:
+            raise ValueError("record() needs start or start_mono")
         span.start = start
-        span._t0 = 0.0
         span.duration_s = max(0.0, duration_s)
         span.attrs = dict(attrs) if attrs else {}
         span._ended = True
@@ -364,3 +425,36 @@ def propagation_context(span: Any, inbound: Any = None) -> Optional[dict]:
     if get_tracer().enabled:
         return {"sampled": False}
     return None
+
+
+# -- step phases on the profiler's clock ------------------------------------
+# The engine's step loops mark their phases (``dyn.step.plan`` / ``pack`` /
+# ``dispatch`` / ``harvest`` / ``emit`` / ``record`` / ``wait``) with
+# ``step_span``. A phase is per STEP, not per request, so it never enters
+# the span stream above: it is a ``jax.profiler.TraceAnnotation`` and
+# lands in a capture's ``.xplane.pb`` on the thread that made it, on the
+# clock of the device's own lines. telemetry/debug.py turns the switch
+# around each capture; with no capture live a phase costs one global read.
+_NO_PHASE = contextlib.nullcontext()
+_capture_live = False
+_annotation: Any = None
+
+
+def set_capture_live(live: bool) -> None:
+    """Called by the one function that starts and stops the profiler
+    (telemetry/debug.py ``profile_blocking``)."""
+    global _capture_live, _annotation
+    if live and _annotation is None:
+        # lazily: a frontend-only process never imports JAX for this
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    _capture_live = live
+
+
+def step_span(name: str, **attrs: Any):
+    """Context manager for one phase of an engine step; ``attrs`` are
+    scalars shown beside the event in the trace viewer."""
+    if not _capture_live:
+        return _NO_PHASE
+    return _annotation(name, **attrs)
