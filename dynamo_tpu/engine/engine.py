@@ -931,7 +931,9 @@ class JaxEngine:
             shapes.append((m, D, V, "lm_head"))
         from dynamo_tpu.ops import qmatmul
 
-        qmatmul.ensure_tuned(shapes, verify=verify)
+        qmatmul.ensure_tuned(
+            shapes, verify=verify, layers=mc.num_hidden_layers
+        )
 
     def _prewarm(self) -> None:
         """Compile every serving-path shape variant NOW, before the

@@ -295,7 +295,9 @@ def _qmm_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def mm(p: Params, name: str, x: jax.Array) -> jax.Array:
+def mm(
+    p: Params, name: str, x: jax.Array, layer: Optional[jax.Array] = None
+) -> jax.Array:
     """x @ p[name], transparently handling int8 weight-only quantization
     (models/quant.py). Reference epilogue: a mixed-dtype dot (bf16
     activations × int8 weight, f32 accumulation) keeps HBM reads
@@ -303,14 +305,23 @@ def mm(p: Params, name: str, x: jax.Array) -> jax.Array:
     then the per-output-channel scale applies to the f32 product before
     casting back. Under DYN_MATMUL_IMPL=pallas the fused dequant kernel
     (ops/qmatmul.py) does the same math with the upcast in-register,
-    which is what actually reaches int8-byte-bound weight reads."""
+    which is what actually reaches int8-byte-bound weight reads.
+
+    ``layer``: the scan's layer index, used where ``p[name]`` is the
+    whole stacked ``[L, K, N]`` array — the kernel then reads its layer
+    in place (a ``[K, N]`` weight has no layers and ignores it).
+    Slicing the stack here instead would put a copy of the matrix in
+    front of every call (ops/qmatmul.py ``_qmm_call``); the reference
+    dot below never sees a stacked weight, XLA fuses the scan's slice
+    into it."""
     w = p[name]
     if w.dtype == jnp.int8:
         if pallas_matmul_active():
             from dynamo_tpu.ops.qmatmul import qmm
 
             return qmm(
-                x, w, p[name + "_scale"], interpret=_qmm_interpret()
+                x, w, p[name + "_scale"], interpret=_qmm_interpret(),
+                layer=layer,
             )
         y = jax.lax.dot_general(
             x, w, (((x.ndim - 1,), (0,)), ((), ())),
@@ -537,7 +548,8 @@ def fused_mlp_ok(cfg: ModelConfig, lp: Params) -> bool:
 
 
 def post_attn_mlp(
-    cfg: ModelConfig, lp: Params, x: jax.Array, a: jax.Array
+    cfg: ModelConfig, lp: Params, x: jax.Array, a: jax.Array,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Everything after attention: output projection + MLP/MoE residual
     — ONE copy shared by every attention variant AND the bench's
@@ -550,29 +562,40 @@ def post_attn_mlp(
     add in-epilogue, ONE gate/up pass with SiLU·mul in-kernel (the
     [.., F] intermediates never hit HBM), and w_down with the second
     residual add in-epilogue — the rounding points match the reference
-    composition exactly (ops/qmatmul.py)."""
+    composition exactly (ops/qmatmul.py).
+
+    ``layer``: the scan's layer index. In ``forward`` the int8 matrices
+    of ``lp`` are the whole stacked ``[L, K, N]`` arrays and the kernels
+    read layer ``layer`` out of them (see :func:`mm`); callers that
+    hold one layer's ``[K, N]`` slices (bench.py --phases, the pipeline
+    stage loop) pass none."""
     if fused_mlp_ok(cfg, lp):
         from dynamo_tpu.ops.qmatmul import qmm, qmm_gate_up
 
         interp = _qmm_interpret()
-        x = qmm(a, lp["wo"], lp["wo_scale"], residual=x, interpret=interp)
+        x = qmm(
+            a, lp["wo"], lp["wo_scale"], residual=x, interpret=interp,
+            layer=layer,
+        )
         h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_bias_one)
         hh = qmm_gate_up(
             h, lp["w_gate"], lp["w_gate_scale"],
             lp["w_up"], lp["w_up_scale"],
-            act=cfg.hidden_act, interpret=interp,
+            act=cfg.hidden_act, interpret=interp, layer=layer,
         )
         return qmm(
             hh, lp["w_down"], lp["w_down_scale"], residual=x,
-            interpret=interp,
+            interpret=interp, layer=layer,
         )
-    x = x + mm(lp, "wo", a).astype(x.dtype)
+    x = x + mm(lp, "wo", a, layer).astype(x.dtype)
     h = rmsnorm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_bias_one)
     if cfg.is_moe:
         x = x + _moe_mlp(cfg, lp, h).astype(x.dtype)
     else:
         mlp_out = mm(
-            lp, "w_down", mlp_act(cfg, mm(lp, "w_gate", h)) * mm(lp, "w_up", h)
+            lp, "w_down",
+            mlp_act(cfg, mm(lp, "w_gate", h, layer)) * mm(lp, "w_up", h, layer),
+            layer,
         )
         x = x + mlp_out.astype(x.dtype)
     return x
@@ -591,15 +614,19 @@ def make_layer_parts(
       qkv(lp, x)                 -> (q, k, v) roped, [B, T, H*, Dh]
       attend_mlp(lp, x, q, kcl, vcl) -> new x (reads the layer cache
                                     AFTER the caller wrote k/v into it)
+
+    ``layer`` (qkv, attend_mlp) / ``layer_idx`` (attend_mlp_stacked) is
+    the scan's layer index for the weights of ``lp`` that are still
+    stacked ``[L, K, N]`` arrays (see :func:`mm`).
     """
     H, Hk, Dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
 
-    def qkv(lp, x):
+    def qkv(lp, x, layer=None):
         B, T = x.shape[0], x.shape[1]
         h = rmsnorm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_bias_one)
-        q = mm(lp, "wq", h)
-        k = mm(lp, "wk", h)
-        v = mm(lp, "wv", h)
+        q = mm(lp, "wq", h, layer)
+        k = mm(lp, "wk", h, layer)
+        v = mm(lp, "wv", h, layer)
         if cfg.attention_bias:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
         q = q.reshape(B, T, H, Dh)
@@ -692,9 +719,9 @@ def make_layer_parts(
             args += (ksc, vsc)
         return kern(*args)  # [B, T, H, Dh]
 
-    def _post_attn(lp, x, attn):
+    def _post_attn(lp, x, attn, layer=None):
         B, T = x.shape[0], x.shape[1]
-        return post_attn_mlp(cfg, lp, x, attn.reshape(B, T, H * Dh))
+        return post_attn_mlp(cfg, lp, x, attn.reshape(B, T, H * Dh), layer)
 
     def _expand1(cache_l):
         """Per-layer cache -> 1-layer stack (free expand-dims), for
@@ -703,7 +730,7 @@ def make_layer_parts(
             return (cache_l[0][None], cache_l[1][None])
         return cache_l[None]
 
-    def attend_mlp(lp, x, q, k_cache_l, v_cache_l):
+    def attend_mlp(lp, x, q, k_cache_l, v_cache_l, layer=None):
         T = x.shape[1]
         if T == 1 and _use_pallas_decode():
             # per-layer cache: run as a 1-layer stack (free expand-dims)
@@ -719,7 +746,7 @@ def make_layer_parts(
                 q, k_cache_l, v_cache_l, block_tables, positions,
                 context_lens, block_size, cfg.sliding_window,
             )
-        return _post_attn(lp, x, attn)
+        return _post_attn(lp, x, attn, layer)
 
     def attend_mlp_stacked(lp, x, q, k_cache, v_cache, layer_idx):
         """attend_mlp over layer ``layer_idx`` of the FULL stacked cache.
@@ -740,7 +767,7 @@ def make_layer_parts(
                 if T == 1
                 else _pallas_prefill_attn(q, (k_cache, v_cache, layer_idx))
             )
-            return _post_attn(lp, x, attn)
+            return _post_attn(lp, x, attn, layer_idx)
         def slice_layer(cache):
             if kv_cache_is_quantized(cache):
                 return tuple(
@@ -751,7 +778,9 @@ def make_layer_parts(
                 cache, layer_idx, 0, keepdims=False
             )
 
-        return attend_mlp(lp, x, q, slice_layer(k_cache), slice_layer(v_cache))
+        return attend_mlp(
+            lp, x, q, slice_layer(k_cache), slice_layer(v_cache), layer_idx
+        )
 
     return qkv, attend_mlp, attend_mlp_stacked
 
@@ -907,10 +936,34 @@ def forward(
             scales = scales.at[i, n_idx, :, off_idx].set(sc)
         return (vals, scales)
 
+    # The int8 weight matrices do NOT ride the scan's xs on the Pallas
+    # matmul path, for the cache's reason above: xs hands body a
+    # [K, N] slice of each stacked [L, K, N] array, XLA cannot fuse a
+    # producer slice into the kernels' custom calls, so it copied every
+    # matrix out before every matmul — the weights crossed HBM twice a
+    # step (measured on v5e, PERF.md PR 26: the copies were 45% of
+    # device time at 8 decode rows). body closes over the whole arrays
+    # (loop invariants, like the block tables) and the kernels read
+    # layer i in place (ops/qmatmul.py _qmm_call). Their scales go the
+    # same way; norms, biases, MoE experts and everything on the
+    # reference path stay in xs, where XLA fuses the slice into its
+    # own dot.
+    stacked = {}
+    if pallas_matmul_active():
+        stacked = {
+            n: w for n, w in layer_params.items()
+            if w.dtype == jnp.int8 and w.ndim == 3
+        }
+        stacked.update({
+            n + "_scale": layer_params[n + "_scale"] for n in list(stacked)
+        })
+    scanned = {n: w for n, w in layer_params.items() if n not in stacked}
+
     def body(carry, inp):
         x, kc, vc = carry
         lp, i = inp
-        q, k, v = qkv(lp, x)
+        lp = {**lp, **stacked}
+        q, k, v = qkv(lp, x, i)
         kc = write_kv(kc, k.reshape(B * T, Hk, Dh), i)
         vc = write_kv(vc, v.reshape(B * T, Hk, Dh), i)
         # attention reads the layer THROUGH the stacked cache (no layer
@@ -920,7 +973,7 @@ def forward(
 
     (x, new_k, new_v), _ = jax.lax.scan(
         body, (x, k_cache, v_cache),
-        (layer_params, jnp.arange(cfg.num_hidden_layers)),
+        (scanned, jnp.arange(cfg.num_hidden_layers)),
     )
 
     x = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps, cfg.norm_bias_one)

@@ -33,6 +33,21 @@ output rounds to the activation dtype exactly like the reference
 the output dtype so both impls round at the same points. Remaining
 differences vs the reference are K-tile accumulation ORDER only.
 
+Weights are addressed as STACKED arrays: the operand is the model's
+whole ``[L, K, N]`` parameter plus a layer index that rides as a
+scalar-prefetch argument and is read by the weight (and scale)
+BlockSpec's index map — the pattern of ``_decode_kernel_stacked``
+(ops/paged_attention.py), for the same reason. XLA cannot fuse a
+producer slice into a custom call: when the layer scan handed these
+kernels a ``[K, N]`` slice of its ``xs``, XLA copied the slice out
+first (``dynamic-slice_bitcast_fusion.*_s8_K_N``, one per weight per
+layer per step, at ~92% of HBM read speed) and the kernel then read
+the copy, so every weight byte crossed HBM twice in time — 45% of
+device time at 8 decode rows, more than the kernels themselves (v5e,
+PERF.md PR 26). A plain ``[K, N]`` weight (LM head, the pipeline stage
+loop, selfcheck) is the same call with L = 1 and layer 0 — one body,
+one pallas_call, no second path.
+
 Grid = (M-tiles, N-tiles, K-tiles), K innermost: the f32 accumulator
 lives in VMEM scratch across K steps and every weight byte is read
 exactly once per M-tile. Tile sizes come from a small autotune table
@@ -99,14 +114,53 @@ def _largest_divisor(n: int, candidates: tuple[int, ...]) -> int:
     return n
 
 
+# Decode row buckets (<= 64 rows) are weight-byte-bound: the kernel is
+# a DMA stream of int8 tiles out of HBM, and a grid step's fixed cost
+# (~0.3 us on v5e) is as long as the DMA of a 256 KB tile. Measured
+# there at 8 rows (PERF.md PR 26): 256 KB tiles stream at 50-53% of
+# HBM speed, >= 1 MB tiles at 76-79%, flat beyond; at equal bytes a
+# wider bn beats a deeper bk (longer contiguous runs in the row-major
+# weight). So: (bn, bk) of at most DECODE_TILE weight bytes, bn first,
+# up to DECODE_BN_MAX.
+DECODE_ROWS_MAX = 64
+DECODE_TILE = 1 << 20
+DECODE_BN_MAX = 2048
+
+
+def _lane_divisors(n: int, cap: int) -> list[int]:
+    """Multiples of 128 dividing n, largest first, none above cap; n
+    itself when there is none (a full dim is always a legal block dim)."""
+    return [
+        d for d in range(min(cap, n) // 128 * 128, 0, -128) if n % d == 0
+    ] or [n]
+
+
+def _decode_weight_tile(K: int, N: int) -> tuple[int, int]:
+    """(bn, bk): the widest bn whose deepest fitting bk fills at least
+    half of DECODE_TILE; the largest tile there is when none does."""
+    tiles = []
+    bks = _lane_divisors(K, K)
+    for bn in _lane_divisors(N, DECODE_BN_MAX):
+        # the deepest bk this bn leaves room for (the shallowest if none)
+        bk = next((b for b in bks if bn * b <= DECODE_TILE), bks[-1])
+        if 2 * bn * bk >= DECODE_TILE:
+            return bn, bk
+        tiles.append((bn, bk))
+    return max(tiles, key=lambda t: t[0] * t[1])
+
+
 def default_tiles(mb: int, K: int, N: int, kind: str) -> tuple[int, int, int]:
     """Heuristic (bm, bn, bk). Rationale: bm covers the whole decode
-    batch in one tile (M is tiny next to K/N); bk ~512 keeps the x tile
-    and accumulator small while amortizing the K-loop; bn ~512-1024
-    makes the int8 weight tile the dominant VMEM tenant (that's the
-    stream we must keep wide). All non-full tiles are multiples of 128
-    so both the int8 sublane rule (32) and the lane rule (128) hold."""
+    batch in one tile (M is tiny next to K/N); at prefill rows bk ~512
+    keeps the x tile and accumulator small while amortizing the K-loop
+    and bn ~512-1024 makes the int8 weight tile the dominant VMEM
+    tenant (that's the stream we must keep wide); at decode rows the
+    weight tile is sized for the HBM stream (``_decode_weight_tile``).
+    All non-full tiles are multiples of 128 so both the int8 sublane
+    rule (32) and the lane rule (128) hold."""
     bm = min(mb, 256)
+    if mb <= DECODE_ROWS_MAX:
+        return (bm, *_decode_weight_tile(K, N))
     bk = _largest_divisor(K, (512, 256, 128))
     if kind == "lm_head":
         # vocab is huge and M tiny: widen N so the weight stream (the
@@ -244,14 +298,24 @@ def _candidate_tiles(mb: int, K: int, N: int, kind: str):
 def _kind_fn(kind: str, w, s, res, tiles):
     """The EXACT kernel variant the serving path dispatches for this
     kind — the residual epilogue streams an extra [bm, bn] input per
-    tile, a different traffic profile than the plain kernel."""
+    tile, a different traffic profile than the plain kernel; a stacked
+    ``[L, K, N]`` weight (what the layer scan hands a layer's matmuls)
+    is read at its last layer."""
+    layer = w.shape[0] - 1 if w.ndim == 3 else None
     if kind == "gate_up":
-        return lambda a: qmm_gate_up(a, w, s, w, s, tiles=tiles)
+        return lambda a: qmm_gate_up(a, w, s, w, s, tiles=tiles, layer=layer)
     if kind == "residual":
-        return lambda a: qmm(a, w, s, residual=res, tiles=tiles)
+        return lambda a: qmm(a, w, s, residual=res, tiles=tiles, layer=layer)
     if kind == "lm_head":
         return lambda a: qmm_lm_head(a, w, s, tiles=tiles)
-    return lambda a: qmm(a, w, s, tiles=tiles)
+    return lambda a: qmm(a, w, s, tiles=tiles, layer=layer)
+
+
+def _stack_shape(kind: str, layers: int) -> tuple[int, ...]:
+    """Leading dims of the weight the serving path hands this kind: the
+    layer scan's matmuls get the model's stacked parameters, the head a
+    plain matrix."""
+    return () if kind == "lm_head" else (layers,)
 
 
 def autotune(
@@ -270,8 +334,11 @@ def autotune(
     mb = m_bucket(m)
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (mb, K), jnp.float32).astype(dtype)
-    w = jax.random.randint(key, (K, N), -127, 128, jnp.int8)
-    s = jnp.full((N,), 0.01, jnp.float32)
+    # two layers, the second one read: the stacked form without the
+    # memory of a whole model's worth of this matrix
+    lead = _stack_shape(kind, 2)
+    w = jax.random.randint(key, (*lead, K, N), -127, 128, jnp.int8)
+    s = jnp.full((*lead, N), 0.01, jnp.float32)
     best, best_t = None, float("inf")
     res = jnp.zeros((mb, N), dtype)
     refused = 0
@@ -308,23 +375,26 @@ def autotune(
 
 
 def verify_compiles(
-    m: int, K: int, N: int, kind: str, dtype=jnp.bfloat16
+    m: int, K: int, N: int, kind: str, dtype=jnp.bfloat16, layers: int = 1
 ) -> None:
     """Compile (never run) the kernel for this shape with the tiling
-    that WILL be used; raises what the compiler raises. The engine
-    calls this at start-up when no prewarm will compile the step
-    functions, so a refused tiling fails there and not at the first
-    request."""
+    that WILL be used, in the form that will be served: a layer's
+    matmuls over ``[layers, K, N]`` stacked weights, the head over
+    ``[K, N]``; raises what the compiler raises.
+    The engine calls this at start-up when no prewarm will compile the
+    step functions, so a refused tiling fails there and not at the
+    first request."""
     mb = m_bucket(m)
     sds = jax.ShapeDtypeStruct
+    lead = _stack_shape(kind, layers)
 
     # weights ride as arguments here: shapes only, nothing is allocated
     def call(a, w, s, res):
         return _kind_fn(kind, w, s, res, None)(a)
 
     jax.jit(call).lower(
-        sds((mb, K), dtype), sds((K, N), jnp.int8), sds((N,), jnp.float32),
-        sds((mb, N), dtype),
+        sds((mb, K), dtype), sds((*lead, K, N), jnp.int8),
+        sds((*lead, N), jnp.float32), sds((mb, N), dtype),
     ).compile()
 
 
@@ -354,12 +424,18 @@ def _act(name: str, g: jax.Array, dtype) -> jax.Array:
 
 
 def _qmm_kernel(
+    layer_ref,  # scalar prefetch: [1] int32 — read by the BlockSpecs only
     *refs,
     n_k: int,
     fused: str,  # "" | "residual" | "gate_up"
     act: str,
 ):
     """One (bm, bn) output tile accumulated over the K grid axis.
+
+    The weights are stacked ``[L, K, N]`` with the layer as a
+    scalar-prefetch index that the weight and scale BlockSpecs read
+    (``_qmm_call``); the layer dimension is squeezed, so the body sees
+    the same (bk, bn) and (1, bn) tiles whatever L is.
 
     refs layout by variant:
       plain:    x, w, s, o, acc
@@ -425,8 +501,9 @@ def _qmm_kernel(
 
 def _qmm_call(
     x2: jax.Array,  # [M, K] float activations (bf16/f32)
-    weights: list[jax.Array],  # one [K, N] int8, or two for gate_up
-    scales: list[jax.Array],  # matching [N] f32 per-channel scales
+    weights: list[jax.Array],  # one [L, K, N] / [K, N] int8, two for gate_up
+    scales: list[jax.Array],  # matching [L, N] / [N] f32 per-channel scales
+    layer: Optional[jax.Array],  # scalar int32 (may be traced) into L
     residual2: Optional[jax.Array],  # [M, N] or None
     kind: str,
     fused: str,
@@ -434,10 +511,21 @@ def _qmm_call(
     interpret: bool,
     tiles: Optional[tuple[int, int, int]],
 ) -> jax.Array:
+    """THE pallas_call of the family. The weight and scale BlockSpecs
+    read the layer from a scalar-prefetch ref, so only the tiles the
+    grid visits move, once, straight out of the stacked parameters (why:
+    the module docstring). A 2-D ``[K, N]`` weight is the same call with
+    L = 1 and layer 0 — a leading unit dimension is a free reshape."""
     M, K = x2.shape
-    N = weights[0].shape[1]
-    for w in weights:
-        assert w.dtype == jnp.int8 and w.shape == (K, N)
+    if weights[0].ndim == 2:  # one layer, read at 0, whatever was passed
+        weights = [w[None] for w in weights]
+        scales = [s[None] for s in scales]
+        layer = 0
+    assert layer is not None, "a stacked weight needs its layer index"
+    L, _, N = weights[0].shape
+    for w, s in zip(weights, scales):
+        assert w.dtype == jnp.int8 and w.shape == (L, K, N), (w.shape, K, N)
+        assert s.shape == (L, N), (s.shape, L, N)
     bm, bn, bk = tiles if tiles is not None else tile_config(M, K, N, kind)
     mp = m_bucket(M)
     bm = min(bm, mp)
@@ -455,32 +543,41 @@ def _qmm_call(
             residual2 = jnp.pad(residual2, ((0, mp - M), (0, 0)))
     grid = (mp // bm, N // bn, K // bk)
 
-    in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))]
+    in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k, lyr: (i, k))]
     inputs: list[jax.Array] = [x2]
     for w, s in zip(weights, scales):
-        in_specs.append(pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)))
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
+        in_specs.append(
+            pl.BlockSpec((None, bk, bn), lambda i, j, k, lyr: (lyr[0], k, j))
+        )
+        in_specs.append(
+            pl.BlockSpec((None, 1, bn), lambda i, j, k, lyr: (lyr[0], 0, j))
+        )
         inputs.append(w)
-        inputs.append(s.reshape(1, N).astype(jnp.float32))
+        inputs.append(s.reshape(L, 1, N).astype(jnp.float32))
     if residual2 is not None:
-        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)))
+        in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, k, lyr: (i, j)))
         inputs.append(residual2)
 
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     if fused == "gate_up":
         scratch.append(pltpu.VMEM((bm, bn), jnp.float32))
 
+    # no name= and no jit of its own: the benchmark's readers find the
+    # kernels by the name an unnamed pallas_call gets (PERF.md section 7)
     out = pl.pallas_call(
         functools.partial(
             _qmm_kernel, n_k=grid[2], fused=fused, act=act
         ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # the layer
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, lyr: (i, j)),
+            scratch_shapes=scratch,
+        ),
         out_shape=jax.ShapeDtypeStruct((mp, N), x2.dtype),
-        scratch_shapes=scratch,
         interpret=interpret,
-    )(*inputs)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
     return out[:M] if M != mp else out
 
 
@@ -490,45 +587,56 @@ def _flatten(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
 
 def qmm(
     x: jax.Array,  # [..., K] float activations
-    w: jax.Array,  # [K, N] int8
-    scale: jax.Array,  # [N] f32 per-output-channel dequant scale
+    w: jax.Array,  # [K, N] int8, or the stacked [L, K, N] with ``layer``
+    scale: jax.Array,  # [N] / [L, N] f32 per-output-channel dequant scale
     residual: Optional[jax.Array] = None,  # [..., N] fused epilogue add
     kind: str = "mm",
     interpret: bool = False,
     tiles: Optional[tuple[int, int, int]] = None,
+    layer: Optional[jax.Array] = None,  # scalar int32 index into L
 ) -> jax.Array:
     """y = (x @ w) * scale (+ residual), rounded to x.dtype — the
-    in-kernel-dequant replacement for the reference ``mm`` epilogue."""
+    in-kernel-dequant replacement for the reference ``mm`` epilogue.
+
+    With ``layer`` the weight and scale are the model's whole stacked
+    ``[L, K, N]`` / ``[L, N]`` parameters and the kernel reads layer
+    ``layer`` (a traced scan index is fine) straight out of them: the
+    layer scan must NOT slice ``w[layer]`` first, or XLA stages the
+    slice in a copy of its own before the call (``_qmm_call``). A
+    ``[K, N]`` weight has no layers and ignores ``layer``."""
     x2, lead = _flatten(x)
     r2 = None
     if residual is not None:
         r2, _ = _flatten(residual)
         kind = "residual" if kind == "mm" else kind
     y = _qmm_call(
-        x2, [w], [scale], r2, kind,
+        x2, [w], [scale], layer, r2, kind,
         "residual" if residual is not None else "", "silu", interpret, tiles,
     )
-    return y.reshape(*lead, w.shape[1])
+    return y.reshape(*lead, w.shape[-1])
 
 
 def qmm_gate_up(
     x: jax.Array,  # [..., D]
-    w_gate: jax.Array,  # [D, F] int8
-    gate_scale: jax.Array,  # [F] f32
-    w_up: jax.Array,  # [D, F] int8
-    up_scale: jax.Array,  # [F] f32
+    w_gate: jax.Array,  # [D, F] int8, or stacked [L, D, F] with ``layer``
+    gate_scale: jax.Array,  # [F] / [L, F] f32
+    w_up: jax.Array,  # [D, F] / [L, D, F] int8
+    up_scale: jax.Array,  # [F] / [L, F] f32
     act: str = "silu",
     interpret: bool = False,
     tiles: Optional[tuple[int, int, int]] = None,
+    layer: Optional[jax.Array] = None,  # scalar int32 index into L
 ) -> jax.Array:
     """act(x @ Wg * sg) * (x @ Wu * su) — both MLP weights stream in one
-    kernel pass; the [..., F] gate/up intermediates never touch HBM."""
+    kernel pass; the [..., F] gate/up intermediates never touch HBM.
+    ``layer``: both weights are read out of their stacked arrays, as in
+    :func:`qmm` — the two largest copies of a layer never happen."""
     x2, lead = _flatten(x)
     y = _qmm_call(
-        x2, [w_gate, w_up], [gate_scale, up_scale], None, "gate_up",
+        x2, [w_gate, w_up], [gate_scale, up_scale], layer, None, "gate_up",
         "gate_up", act, interpret, tiles,
     )
-    return y.reshape(*lead, w_gate.shape[1])
+    return y.reshape(*lead, w_gate.shape[-1])
 
 
 def qmm_lm_head(
@@ -544,7 +652,7 @@ def qmm_lm_head(
     sampling, same as the reference path."""
     x2, lead = _flatten(x)
     y = _qmm_call(
-        x2, [w], [scale], None, "lm_head", "", "silu", interpret, tiles
+        x2, [w], [scale], None, None, "lm_head", "", "silu", interpret, tiles
     )
     return y.reshape(*lead, w.shape[1])
 
@@ -553,6 +661,7 @@ def ensure_tuned(
     shapes: list[tuple[int, int, int, str]],
     tune: Optional[bool] = None,
     verify: bool = False,
+    layers: int = 1,
 ) -> None:
     """Engine-prewarm hook: make sure every reachable (M, K, N, kind)
     has a tile config ready before the step functions trace. With
@@ -560,7 +669,8 @@ def ensure_tuned(
     compiles per missing shape — one-time, cached on disk); otherwise
     the heuristic defaults serve, and any previously-tuned entries load
     from the cache. ``verify`` compiles each kernel with its resolved
-    tiling (TPU only) — see :func:`verify_compiles`."""
+    tiling (TPU only) over ``layers`` stacked layers — see
+    :func:`verify_compiles`."""
     if tune is None:
         tune = os.environ.get("DYN_QMATMUL_TUNE") == "1"
     table = _load_table()
@@ -571,4 +681,4 @@ def ensure_tuned(
         else:
             tile_config(m, K, N, kind)  # validates/loads the entry
             if verify and on_tpu:
-                verify_compiles(m, K, N, kind)
+                verify_compiles(m, K, N, kind, layers=layers)

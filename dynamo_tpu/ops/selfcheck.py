@@ -105,15 +105,26 @@ def _matmul_checks(spec: dict, interpret: bool) -> dict:
     H, Hk, Dh, m = spec["H"], spec["Hk"], spec["Dh"], spec["m"]
     key = jax.random.PRNGKey(spec["seed"])
 
-    def weight(i: int, k: int, n: int):
+    # the served form of a layer's matmuls: the kernel reads layer
+    # LAYER of a stacked [LAYERS, K, N] weight through a traced index
+    LAYERS, LAYER = 2, 1
+
+    def weight(i: int, k: int, n: int, stacked: bool = False):
+        lead = (LAYERS,) if stacked else ()
         w = jax.random.randint(
-            jax.random.fold_in(key, i), (k, n), -127, 128, jnp.int8
+            jax.random.fold_in(key, i), (*lead, k, n), -127, 128, jnp.int8
         )
         s = jax.random.uniform(
-            jax.random.fold_in(key, 100 + i), (n,), jnp.float32,
+            jax.random.fold_in(key, 100 + i), (*lead, n), jnp.float32,
             0.5 / (127 * k ** 0.5), 1.5 / (127 * k ** 0.5),
         )
         return w, s
+
+    def layer_of(stacked: bool, *arrays):
+        """(the traced layer index or None, each array's LAYER slice)."""
+        if not stacked:
+            return None, arrays
+        return jnp.int32(LAYER), tuple(a[LAYER] for a in arrays)
 
     def act(i: int, k: int, rows: int = m):
         return jax.random.normal(
@@ -130,47 +141,58 @@ def _matmul_checks(spec: dict, interpret: bool) -> dict:
 
     out: dict = {}
     cases = {
-        "qmm_wq": (D, H * Dh, False, m),
-        "qmm_wkv": (D, Hk * Dh, False, m),
-        "qmm_wo_residual": (H * Dh, D, True, m),
-        "qmm_w_down_residual": (F, D, True, m),
+        "qmm_wq": (D, H * Dh, False, m, False),
+        "qmm_wkv": (D, Hk * Dh, False, m, False),
+        "qmm_wo_residual": (H * Dh, D, True, m, False),
+        "qmm_w_down_residual": (F, D, True, m, False),
         # a prefill rectangle: more than one M tile
-        f"qmm_wq_m{spec['m_large']}": (D, H * Dh, False, spec["m_large"]),
+        f"qmm_wq_m{spec['m_large']}": (D, H * Dh, False, spec["m_large"],
+                                      False),
+        "qmm_wq_stacked": (D, H * Dh, False, m, True),
+        "qmm_w_down_residual_stacked": (F, D, True, m, True),
     }
-    for i, (name, (k, n, residual, rows)) in enumerate(cases.items()):
-        x, (w, s) = act(i, k, rows), weight(i, k, n)
-        product = jax.jit(ref_mm)(x, w, s)
+    for i, (name, (k, n, residual, rows, stacked)) in enumerate(cases.items()):
+        x, (w, s) = act(i, k, rows), weight(i, k, n, stacked)
+        layer, (w_l, s_l) = layer_of(stacked, w, s)
+        product = jax.jit(ref_mm)(x, w_l, s_l)
         if residual:
             r = act(50 + i, n, rows)
             got = jax.jit(
-                lambda x, w, s, r: qmm(x, w, s, residual=r, interpret=interpret)
-            )(x, w, s, r)
+                lambda x, w, s, r, l: qmm(
+                    x, w, s, residual=r, interpret=interpret, layer=l
+                )
+            )(x, w, s, r, layer)
             out[name] = (*_ulps(got, r + product, extra=product),
                          ULP_LIMITS["qmm_residual"])
         else:
             got = jax.jit(
-                lambda x, w, s: qmm(x, w, s, interpret=interpret)
-            )(x, w, s)
+                lambda x, w, s, l: qmm(x, w, s, interpret=interpret, layer=l)
+            )(x, w, s, layer)
             out[name] = (*_ulps(got, product), ULP_LIMITS["qmm"])
 
-    x, (wg, sg), (wu, su) = act(10, D), weight(10, D, F), weight(11, D, F)
-    g, u = jax.jit(ref_mm)(x, wg, sg), jax.jit(ref_mm)(x, wu, su)
     # the gate activations of models.llama._mlp_act on the bf16 gate
-    acts = {
-        "silu": (jax.nn.silu, None),
-        "gelu": (lambda g: jax.nn.gelu(g, approximate=True),
-                 0.5 * g.astype(jnp.float32) * u.astype(jnp.float32)),
-    }
-    for name, (fn, extra) in acts.items():
-        got = jax.jit(
-            lambda x, wg, sg, wu, su: qmm_gate_up(
-                x, wg, sg, wu, su, act=name, interpret=interpret
+    x = act(10, D)
+    for stacked, suffix, names in ((False, "", ("silu", "gelu")),
+                                   (True, "_stacked", ("silu",))):
+        (wg, sg), (wu, su) = weight(10, D, F, stacked), weight(11, D, F, stacked)
+        layer, (wg_l, sg_l, wu_l, su_l) = layer_of(stacked, wg, sg, wu, su)
+        g, u = jax.jit(ref_mm)(x, wg_l, sg_l), jax.jit(ref_mm)(x, wu_l, su_l)
+        acts = {
+            "silu": (jax.nn.silu, None),
+            "gelu": (lambda g: jax.nn.gelu(g, approximate=True),
+                     0.5 * g.astype(jnp.float32) * u.astype(jnp.float32)),
+        }
+        for name in names:
+            fn, extra = acts[name]
+            got = jax.jit(
+                lambda x, wg, sg, wu, su, l: qmm_gate_up(
+                    x, wg, sg, wu, su, act=name, interpret=interpret, layer=l
+                )
+            )(x, wg, sg, wu, su, layer)
+            want = jax.jit(lambda g, u: fn(g) * u)(g, u)
+            out[f"qmm_gate_up_{name}{suffix}"] = (
+                *_ulps(got, want, extra=extra), ULP_LIMITS["gate_up"]
             )
-        )(x, wg, sg, wu, su)
-        want = jax.jit(lambda g, u: fn(g) * u)(g, u)
-        out[f"qmm_gate_up_{name}"] = (
-            *_ulps(got, want, extra=extra), ULP_LIMITS["gate_up"]
-        )
 
     x, (w, s) = act(12, D), weight(12, D, V)
     got = jax.jit(lambda x, w, s: qmm_lm_head(x, w, s, interpret=interpret))(

@@ -101,25 +101,33 @@ def _sds(shape, dtype, sharding):
 # ---------------------------------------------------------------------------
 
 
-def _qmm_case(kind: str, m: int, sh):
-    """(fn, shapes) for one fused-dequant matmul of the decoder layer."""
+def _qmm_case(kind: str, m: int, sh, stacked: bool = False):
+    """(fn, shapes) for one fused-dequant matmul of the decoder layer.
+    ``stacked``: the form llama.forward serves — the whole [L, K, N]
+    parameter and a traced layer index as the LAST argument (None, an
+    empty pytree, for the 2-D form of the pipeline stage loop)."""
+    lead = (L,) if stacked else ()
     x = lambda k: _sds((m, k), jnp.bfloat16, sh)  # noqa: E731
-    w = lambda k, n: _sds((k, n), jnp.int8, sh)  # noqa: E731
-    s = lambda n: _sds((n,), jnp.float32, sh)  # noqa: E731
+    w = lambda k, n: _sds((*lead, k, n), jnp.int8, sh)  # noqa: E731
+    s = lambda n: _sds((*lead, n), jnp.float32, sh)  # noqa: E731
+    layer = _sds((), jnp.int32, sh) if stacked else None
     if kind == "wq":
-        return qmm, (x(D), w(D, H * DH), s(H * DH))
+        return (lambda a, b, c, l: qmm(a, b, c, layer=l),
+                (x(D), w(D, H * DH), s(H * DH), layer))
     if kind == "wkv":
-        return qmm, (x(D), w(D, HK * DH), s(HK * DH))
+        return (lambda a, b, c, l: qmm(a, b, c, layer=l),
+                (x(D), w(D, HK * DH), s(HK * DH), layer))
     if kind in ("wo", "w_down"):
         k = H * DH if kind == "wo" else F
         res = _sds((m, D), jnp.bfloat16, sh)
         return (
-            lambda a, b, c, r: qmm(a, b, c, residual=r),
-            (x(k), w(k, D), s(D), res),
+            lambda a, b, c, r, l: qmm(a, b, c, residual=r, layer=l),
+            (x(k), w(k, D), s(D), res, layer),
         )
     if kind == "gate_up":
-        return qmm_gate_up, (x(D), w(D, F), s(F), w(D, F), s(F))
-    assert kind == "lm_head"
+        return (lambda a, g, gs, u, us, l: qmm_gate_up(a, g, gs, u, us, layer=l),
+                (x(D), w(D, F), s(F), w(D, F), s(F), layer))
+    assert kind == "lm_head" and not stacked
     return qmm_lm_head, (x(D), w(D, V), s(V))
 
 
@@ -159,7 +167,10 @@ def _attn_kernel(prefill: bool, int8: bool):
 
 
 _QMM_CASES = [
-    pytest.param("qmm", kind, m, id=f"qmm-{kind}-M{m}")
+    # "qmm": a [K, N] weight (pipeline stage loop, bench --phases);
+    # "qmm-stacked": layer l of the [L, K, N] parameter, as served
+    pytest.param(family, kind, m, id=f"{family}-{kind}-M{m}")
+    for family in ("qmm", "qmm-stacked")
     for kind in ("wq", "wkv", "wo", "w_down", "gate_up")
     # decode small 4 -> 8 / decode pad 64 / spec-verify 512 / prefill budget 4096
     for m in (8, 64, 512, 4096)
@@ -181,8 +192,10 @@ _ATTN_CASES = [
 def test_kernel_compiles_for_v5e(
     family, variant, size, one_chip, no_compile_cache
 ):
-    if family == "qmm":
-        fn, shapes = _qmm_case(variant, size, one_chip)
+    if family.startswith("qmm"):
+        fn, shapes = _qmm_case(
+            variant, size, one_chip, stacked=family == "qmm-stacked"
+        )
     else:
         prefill = family == "prefill"
         b, t = size if prefill else (size, 1)

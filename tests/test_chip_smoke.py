@@ -61,7 +61,7 @@ def test_rehearsal_serves_and_prints_the_contract_line(monkeypatch, capsys):
     phases = {ln["phase"]: ln for ln in lines[:-1]}
     assert phases["kernels_vs_reference"]["ok"]
     assert phases["kernels_vs_reference"]["interpreted"]  # CPU rehearsal
-    assert len(phases["kernels_vs_reference"]["kernels"]) == 12
+    assert len(phases["kernels_vs_reference"]["kernels"]) == 15  # 3 stacked
     up = phases["engine_up"]
     assert up["models"] == ["smoke"]
     assert up["device"]["platform"] == "cpu"

@@ -206,6 +206,33 @@ def test_default_tiles_always_legal(mb, K, N, kind):
     assert bk == K or bk % 128 == 0
 
 
+@pytest.mark.parametrize(
+    "K,N,kind",
+    [
+        # mistral-7b: wq / wkv / gate+up / w_down / head
+        (4096, 4096, "mm"), (4096, 1024, "mm"), (4096, 14336, "gate_up"),
+        (14336, 4096, "residual"), (4096, 32000, "lm_head"),
+        # qwen2.5-7b: 3584 = 7 * 512, 18944 = 37 * 512, 152064 = 99 * 1536
+        (3584, 3584, "mm"), (3584, 512, "mm"), (3584, 18944, "gate_up"),
+        (18944, 3584, "residual"), (3584, 152064, "lm_head"),
+    ],
+)
+@pytest.mark.parametrize("mb", [8, 32, 64])
+def test_decode_rows_stream_wide_weight_tiles(mb, K, N, kind):
+    """At decode rows the weight tile is sized for the HBM stream (v5e,
+    PERF.md PR 26: 256 KB tiles stream at half of HBM speed, >= 1 MB at
+    76-79%): between half of DECODE_TILE and all of it at the served
+    widths, never past it, and lane-aligned. Prefill rows keep the
+    compute-sized tiles."""
+    bm, bn, bk = default_tiles(mb, K, N, kind)
+    assert bm == mb and N % bn == 0 and K % bk == 0
+    assert bn % 128 == 0 and bk % 128 == 0
+    assert qmatmul.DECODE_TILE // 2 <= bn * bk <= qmatmul.DECODE_TILE
+    assert bn <= qmatmul.DECODE_BN_MAX
+    _, pbn, pbk = default_tiles(256, K, N, kind)
+    assert pbn * pbk <= 1024 * 512 < bn * bk
+
+
 def test_lm_head_tiles_divide_flagship_vocab():
     # 128256 is not divisible by 512; the lm_head candidate ladder must
     # land on a divisor (768), not crash or fall back to full-V tiles
@@ -271,6 +298,86 @@ def test_tuned_entry_used_by_kernel(tune_dir):
         np.asarray(y, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=6e-2,
     )
+
+
+# ---------------------------------------------------------------------------
+# Stacked weights: the kernel reads layer l of [L, K, N] in place
+# ---------------------------------------------------------------------------
+
+# (D, F) at test size with the served models' divisibility: Mistral-7B
+# 4096/14336 over 8 (w_down's K = 1792 = 7 * 256), Qwen2.5-7B 3584/18944
+# over 4 (every K a multiple of 7 * 128 — never a power of two)
+_GEOMETRIES = {"mistral-like": (512, 1792), "qwen-like": (896, 4736)}
+_L = 3
+
+
+def _stacked_case(kind: str, D: int, F: int, dtype):
+    """(call(weights, scales, layer), stacked weights, stacked scales)
+    for one kind; ``layer`` may be an int, a traced scalar, or None with
+    2-D slices."""
+    rng = np.random.default_rng(11)
+    K, N = {"mm": (D, D), "residual": (F, D), "gate_up": (D, F)}[kind]
+    x = jnp.asarray(rng.standard_normal((8, K)), dtype)
+    r = jnp.asarray(rng.standard_normal((8, N)), dtype)
+    ws = [jnp.asarray(rng.integers(-127, 128, (_L, K, N)), jnp.int8)
+          for _ in range(2)]
+    ss = [jnp.asarray(rng.uniform(0.001, 0.02, (_L, N)), jnp.float32)
+          for _ in range(2)]
+
+    def call(w, s, layer):
+        if kind == "gate_up":
+            return qmm_gate_up(
+                x, w[0], s[0], w[1], s[1], interpret=True, layer=layer
+            )
+        res = r if kind == "residual" else None
+        return qmm(x, w[0], s[0], residual=res, interpret=True, layer=layer)
+
+    return call, ws, ss
+
+
+@pytest.mark.parametrize("layer", [0, 1, _L - 1])
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+@pytest.mark.parametrize("kind", ["mm", "residual", "gate_up"])
+def test_stacked_weight_equals_its_layer_slice(kind, geometry, layer):
+    """qmm over the whole [L, K, N] array with a layer index is the 2-D
+    call on w[layer] BIT FOR BIT (same tiles, same accumulation order:
+    only where the weight tile is fetched from differs), in bf16 and
+    f32 — what lets the layer scan stop slicing its weights."""
+    D, F = _GEOMETRIES[geometry]
+    for dtype in (jnp.bfloat16, jnp.float32):
+        call, ws, ss = _stacked_case(kind, D, F, dtype)
+        got = call(ws, ss, jnp.int32(layer))
+        want = call([w[layer] for w in ws], [s[layer] for s in ss], None)
+        assert got.dtype == want.dtype == dtype and got.shape == want.shape
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32)
+        )
+
+
+def test_stacked_layer_index_traced_in_scan():
+    """The layer index is a traced scan counter, as in llama.forward:
+    the stacked arrays are closed over (loop invariants) and every
+    iteration reads its own layer."""
+    D, F = 256, 384
+    call, ws, ss = _stacked_case("gate_up", D, F, jnp.bfloat16)
+
+    def body(carry, i):
+        return carry, call(ws, ss, i)
+
+    _, got = jax.jit(
+        lambda: jax.lax.scan(body, 0, jnp.arange(_L, dtype=jnp.int32))
+    )()
+    for l in range(_L):
+        want = call([w[l] for w in ws], [s[l] for s in ss], None)
+        np.testing.assert_array_equal(
+            np.asarray(got[l], np.float32), np.asarray(want, np.float32)
+        )
+
+
+def test_stacked_weight_rejects_mismatched_scale():
+    x, w, s = _mk(8, 64, 128)
+    with pytest.raises(AssertionError):
+        qmm(x, jnp.stack([w, w]), s, interpret=True, layer=jnp.int32(1))
 
 
 # ---------------------------------------------------------------------------
@@ -381,3 +488,112 @@ def test_engine_greedy_reference_vs_pallas(decode_steps, monkeypatch):
     tol = 2e-2 * float(np.max(np.abs(logits)))
     for tok in (ref[i], pal[i]):
         assert float(np.max(logits) - logits[tok]) <= tol, (i, tok)
+
+
+# ---------------------------------------------------------------------------
+# The weights are never sliced before a kernel (a count over the jaxpr)
+# ---------------------------------------------------------------------------
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for item in (v if isinstance(v, (tuple, list)) else (v,)):
+            inner = getattr(item, "jaxpr", item)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of whatever it nests — down to,
+    not into, the kernels (their bodies read tiles, not HBM arrays)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(eqn):
+                yield from _walk(sub)
+
+
+async def _decode_step_layer_scan(mc):
+    """The layer scan of the engine's own decode step: its equation,
+    traced from ``JaxEngine._step_fn`` over the engine's parameters and
+    caches with the arrays prewarm hands it (4 decode rows)."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import JaxEngine
+    from dynamo_tpu.engine.sampling import SamplingBatch
+    from dynamo_tpu.protocols.common import SamplingOptions
+
+    engine = await JaxEngine.launch(
+        EngineConfig(
+            model_path="", model_name="qmm", random_weights=True,
+            quantization="int8", num_blocks=16, block_size=8,
+            max_batch_size=4, prewarm=False,
+        ),
+        model_config=mc,
+    )
+    try:
+        b, width = 4, 4
+        sampling = SamplingBatch.from_options(
+            [SamplingOptions(use_greedy=True)] * b, [0] * b
+        )
+        jaxpr = jax.make_jaxpr(engine._step_fn)(
+            engine.params, engine.k_cache, engine.v_cache,
+            np.zeros((b, 1), np.int32), np.zeros((b, 1), np.int32),
+            np.zeros((b,), np.int32), np.zeros((b, width), np.int32),
+            np.zeros((b,), np.int32), np.zeros((b,), np.int32),
+            sampling.arrays,
+        )
+    finally:
+        await engine.shutdown()
+    scans = [
+        e for e in _walk(jaxpr.jaxpr)
+        if e.primitive.name == "scan"
+        and e.params["length"] == mc.num_hidden_layers
+    ]
+    assert len(scans) == 1
+    return scans[0]
+
+
+def _int8_matrices(avals):
+    return [a.shape for a in avals if a.dtype == jnp.int8 and a.ndim == 2]
+
+
+@pytest.mark.parametrize("impl", ["pallas", "reference"])
+def test_decode_step_never_slices_a_weight_before_a_kernel(impl, monkeypatch):
+    """On the Pallas path every qmm kernel of the layer body is handed
+    the whole [L, K, N] int8 array (rank 3) and NOTHING in the scan —
+    not its xs, not a dynamic_slice, not an index — yields an int8
+    [K, N]: XLA would materialize such a slice in front of the custom
+    call, one copy per weight per layer per step (PERF.md, PR 26). The
+    reference path keeps scanning its weights: XLA fuses that slice
+    into its own dot."""
+    from dynamo_tpu.models.config import ModelConfig
+
+    mc = ModelConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=256,
+    )
+    monkeypatch.setenv("DYN_MATMUL_IMPL", impl)
+    scan = asyncio.run(_decode_step_layer_scan(mc))
+    body = scan.params["jaxpr"].jaxpr
+    n_fixed = scan.params["num_consts"] + scan.params["num_carry"]
+    xs = _int8_matrices(v.aval for v in body.invars[n_fixed:])
+    if impl == "reference":
+        # wq wk wv wo w_gate w_up w_down, sliced by the scan
+        assert len(xs) == 7, xs
+        return
+    assert xs == []
+    produced = [
+        (e.primitive.name, shapes) for e in _walk(body)
+        if (shapes := _int8_matrices(v.aval for v in e.outvars))
+    ]
+    assert produced == []
+    kernels = [
+        [v.aval.shape for v in e.invars if v.aval.dtype == jnp.int8]
+        for e in _walk(body) if e.primitive.name == "pallas_call"
+    ]
+    kernels = [shapes for shapes in kernels if shapes]  # bf16 KV: qmm only
+    # wq, wk, wv, wo + residual, gate+up (two weights), w_down + residual
+    assert sorted(len(k) for k in kernels) == [1, 1, 1, 1, 1, 2]
+    L = mc.num_hidden_layers
+    assert all(len(s) == 3 and s[0] == L for k in kernels for s in k), kernels
