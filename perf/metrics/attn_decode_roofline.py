@@ -2,10 +2,14 @@
 traced interval. Bytes are the KV pages ACTUALLY read at the batch's
 context lengths (whole 128-token pages of K and V per running row, from
 the ``/debug/state`` samples taken during the capture), per call = per
-layer; time is the trace's total for ``paged_attention_decode*``."""
+layer: the count is multiplied by the calls the trace shows, never by a
+layer count, so it holds for a model in which only some layers attend.
+Time is the trace's total for ``paged_attention_decode*``. Heads and
+head size come from ``geometry`` of the configuration's family module
+(``perf/reference/family.py``)."""
 
 from perf import roofline
-from perf.reference.model import geometry
+from perf.reference.family import family_of
 
 
 def read(run, variant=""):
@@ -16,7 +20,7 @@ def read(run, variant=""):
                if lo - 0.5 <= s["t"] <= hi + 0.5 and s["contexts"]]
     if not ops or not samples:
         return None
-    g = geometry(run.config)
+    g = family_of(run.config).geometry(run.config)
     pk = roofline.peaks(run.device["kind"])
     per_call = [roofline.least_seconds(*roofline.attn_decode_cost(
         ctx, g["H"], g["Hk"], g["Dh"], run.block_size), pk) for ctx in samples]

@@ -30,6 +30,8 @@ import math
 import os
 import sys
 
+from perf.reference.family import family_of
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 POSITIONS = 768           # generated positions a probe aims at, all rows and waves
 REFERENCE_TOKENS = 32768  # padded tokens the reference reads in one run
@@ -145,15 +147,23 @@ def compare(seqs: list[dict], ref_logprobs: list[list[float]]) -> dict:
             "logprob_err_max": max(diffs), "positions": len(diffs)}
 
 
+def chosen_logprobs(logits, chosen):
+    """log-softmax of ``logits [B, P, V]`` at ``chosen [B, P]``."""
+    import jax
+    import jax.numpy as jnp
+
+    lp = jax.nn.log_softmax(jnp.asarray(logits, jnp.float32), axis=-1)
+    return jnp.take_along_axis(lp, jnp.asarray(chosen)[:, :, None], axis=-1)[:, :, 0]
+
+
 def reference_logprobs(cfg: dict, seed: int, seqs: list[dict],
                        precision: str = "f32") -> list[list[float]]:
-    """The reference's logprob of every chosen id (in this process), the
+    """The logprob that the reference of the configuration's family
+    (``family.family_of``) gives every chosen id (in this process), the
     sequences padded to a few lengths and read a few rows at a time."""
     import numpy as np
 
-    from perf.reference import model
-
-    fn = model.logits_fn(cfg, precision)
+    fn = family_of(cfg).logits_fn(cfg, precision)
     P = -(-max(len(s["at"]) for s in seqs) // PER_ROW) * PER_ROW
     out: list = [None] * len(seqs)
     buckets: dict = {}
@@ -173,7 +183,7 @@ def reference_logprobs(cfg: dict, seed: int, seqs: list[dict],
                 lengths[b] = len(s["tokens"])
                 at[b, :len(s["at"])] = s["at"]
                 chosen[b, :len(s["at"])] = s["chosen"]
-            lp = np.asarray(model.chosen_logprobs(
+            lp = np.asarray(chosen_logprobs(
                 fn(seed, tokens, lengths, at), chosen))
             for b, i in enumerate(part):
                 out[i] = lp[b, :len(seqs[i]["at"])].tolist()

@@ -26,7 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perf.reference import check, model  # noqa: E402
+from perf.reference import check  # noqa: E402
+from perf.reference.family import family_of  # noqa: E402
 
 
 def control_answers(cfg: dict, seed: int, precision: str,
@@ -43,14 +44,14 @@ def control_answers(cfg: dict, seed: int, precision: str,
     lengths = np.array([len(j["ids"]) for j in jobs], np.int32)
     for b, j in enumerate(jobs):
         tokens[b, :lengths[b]] = j["ids"]
-    fn = model.logits_fn(cfg, precision)
+    fn = family_of(cfg).logits_fn(cfg, precision)
     chosen = np.zeros((B, n_out), np.int32)
     lps = np.zeros((B, n_out), np.float32)
     for k in range(n_out):
         at = (lengths - 1)[:, None].astype(np.int32)
         logits = fn(seed, tokens, lengths, at)
         pick = np.asarray(logits[:, 0].argmax(-1)).astype(np.int32)
-        lp = np.asarray(model.chosen_logprobs(logits, pick[:, None]))[:, 0]
+        lp = np.asarray(check.chosen_logprobs(logits, pick[:, None]))[:, 0]
         chosen[:, k], lps[:, k] = pick, lp
         tokens[np.arange(B), lengths] = pick
         lengths = lengths + 1

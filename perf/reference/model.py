@@ -1,7 +1,34 @@
-"""The plain reference: the llama-family forward pass in float32.
+"""The plain reference of the llama family (``FAMILIES`` below): the
+forward pass in float32.
 
-Straightforward ``jax.numpy`` at ``jax.default_matmul_precision("highest")``
-— no kernels, no cache, no batching tricks. It takes NOTHING from the
+THE CONTRACT OF A FAMILY MODULE. A configuration's ``model_type`` names
+its family module (``perf/reference/family.py``: the file of that name,
+else the module whose ``FAMILIES`` lists it). Such a module gives:
+
+- ``logits_fn(cfg, precision) -> run``, with ``run(seed, tokens [B, T],
+  lengths [B], at [B, P]) -> logits [B, P, V]`` in float32 under
+  ``jax.default_matmul_precision("highest")``: the logits at the
+  positions ``at`` of each right-padded sequence. ``cfg`` holds the
+  published ``config.json`` keys. The weights are drawn INSIDE from
+  ``seed`` by the recipe the configuration file states; nothing is read
+  from the program;
+- ``PRECISIONS``: ``"f32"`` and at least the control one step below what
+  the configuration states (``"a8"`` here); ``logits_fn`` refuses others;
+- ``geometry(cfg)``: a dict with at least ``D, V, H, Hk, Dh`` (the kernel
+  readers' shapes);
+- optionally ``layer_matmuls(g)``: result width -> the ``qmm_cost``
+  arguments ``(K, weights in the call, residual fused)`` of one layer's
+  matmuls of that width, for ``perf/metrics/qmm_roofline.py`` (which
+  matmuls a layer has is the family's knowledge; without it that reader
+  says nothing);
+- optionally ``FAMILIES``: the ``model_type`` values it covers beside its
+  own file name.
+
+What is the same for every family stays in ``check.py``: the probe, the
+padding and blocking of rows, ``chosen_logprobs``, ``compare``.
+
+THIS MODULE. Straightforward ``jax.numpy`` at
+``jax.default_matmul_precision("highest")`` — no kernels, no cache, no batching tricks. It takes NOTHING from the
 program: the weights are drawn here from the seed by the same recipe the
 program's random initialiser documents (``models/quant.py``
 ``init_params_quantized``: parameter i of ``param_shapes`` order, layer j,
@@ -34,6 +61,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+FAMILIES = ("llama", "mistral", "qwen2")
+PRECISIONS = ("f32", "a8", "f8", "w4")
+
 # models/llama.py param_shapes order: the index is part of the recipe
 PARAM_ORDER = ("embed", "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm",
                "final_norm", "lm_head", "bq", "bk", "bv",
@@ -56,6 +86,18 @@ def geometry(cfg: dict) -> dict:
         window = None
     g["window"] = window
     return g
+
+
+def layer_matmuls(g: dict) -> dict:
+    """Result width -> the ``qmm_cost`` arguments of a layer's matmuls of
+    that width: (K, weights in the call, residual fused)."""
+    D, F, q, kv = g["D"], g["F"], g["H"] * g["Dh"], g["Hk"] * g["Dh"]
+    by_n: dict = {}
+    for n, k, weights, residual in ((q, D, 1, False), (kv, D, 1, False),
+                                    (kv, D, 1, False), (D, q, 1, True),
+                                    (F, D, 2, False), (D, F, 1, True)):
+        by_n.setdefault(n, []).append((k, weights, residual))
+    return by_n
 
 
 def param_index(g: dict) -> dict[str, int]:
@@ -106,6 +148,8 @@ def logits_fn(cfg: dict, precision: str = "f32"):
     [B, P, V]`` at the positions ``at`` of each sequence, float32.
     The whole model in one jitted call: weights are drawn layer by layer
     inside the scan, so at most one layer's float32 weights exist."""
+    if precision not in PRECISIONS:
+        raise ValueError(f"precision {precision!r} is none of {PRECISIONS}")
     g = geometry(cfg)
     idx = param_index(g)
     bits = 4 if precision == "w4" else 8
@@ -173,9 +217,3 @@ def logits_fn(cfg: dict, precision: str = "f32"):
 
     jitted = jax.jit(f)
     return run
-
-
-def chosen_logprobs(logits, chosen):
-    """log-softmax of ``logits [B, P, V]`` at ``chosen [B, P]``."""
-    lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.take_along_axis(lp, chosen[:, :, None], axis=-1)[:, :, 0]

@@ -3,7 +3,9 @@
 The control (the reference one precision step down, in the program's
 place) has to come out as NOT correct; the reference against itself
 reads exactly 0; and the reference's seeded draw is the program's
-documented recipe, value for value."""
+documented recipe, value for value. The reference is the one of the
+configuration's family, found by its ``model_type``: a second family
+enters as a file."""
 
 import json
 import os
@@ -17,6 +19,7 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 sys.path.insert(0, REPO)
 
 from perf.reference import check, control, model  # noqa: E402
+from perf.reference.family import FamilyError, family_of  # noqa: E402
 from perf.server import hf_config  # noqa: E402
 
 TINY_LIMIT = 0.05  # the rehearsal's limit; the program reads 0.006 there
@@ -171,3 +174,98 @@ def test_a_cells_probe_is_its_own_mix_at_the_size_the_window_runs(cell):
     assert [len(j["ids"]) for j in a] == [len(j["ids"]) for j in b]
     assert a[0]["ids"] != b[0]["ids"]
     assert set(check.load_limits(cell["name"])) == {"logprob_err_mean"}
+
+
+TOY = {"vocab_size": 16, "hidden_size": 64}
+
+
+@pytest.mark.parametrize("model_type,stem,source", [
+    ("toyfam", "toyfam", None),
+    ("toy-fam", "toy_fam", None),                      # "-" is read as "_"
+    ("toy3", "some_family", 'FAMILIES = ("toy3", "toy4")'),  # listed, not named
+])
+def test_a_family_that_exists_only_as_a_file_is_found_and_called(
+        family_files, model_type, stem, source):
+    """No file under ``perf/`` changes: the module lives in the test's
+    temporary directory, and the output check and its control both run
+    ITS ``logits_fn``."""
+    where = family_files(stem, source or "")
+    cfg = dict(TOY, model_type=model_type)
+    family = family_of(cfg)
+    assert os.path.dirname(family.__file__) == where
+    assert not os.path.exists(os.path.join(REPO, "perf", "reference", stem + ".py"))
+    jobs = [{"row": 0, "wave": 0, "ids": [3, 4, 5], "out": 4},
+            {"row": 1, "wave": 0, "ids": [9], "out": 4}]
+    answers = control.control_answers(cfg, 7, "a8", jobs)
+    # the toy "a8" puts the id two after the last one first
+    assert [a["chosen"] for a in answers] == [[7, 9, 11, 13], [11, 13, 15, 1]]
+    seqs = check.sequences([dict(a, after=None) for a in answers])
+    ref = check.reference_logprobs(cfg, 7, seqs, "f32")
+    assert [c[0] for c in family.CALLS] == ["a8"] * 4 + ["f32"]
+    assert {c[1] for c in family.CALLS} == {7}
+    # "f32" puts the id one after first: every chosen id lies one below its best
+    for seq, got in zip(seqs, ref):
+        for at, chosen, lp in zip(seq["at"], seq["chosen"], got):
+            logits = -np.abs(np.arange(16.0) - (seq["tokens"][at] + 1) % 16)
+            assert logits[chosen] == -1.0
+            assert lp == pytest.approx(-1.0 - np.log(np.exp(logits).sum()), abs=1e-5)
+    got = control.control_error(cfg, 7, "a8", jobs)
+    assert got["positions"] == 8 and got["logprob_err_mean"] > 0.5
+
+
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    CONFIG_FILES = [c["file"] for c in json.load(_f)["configs"]]
+
+
+@pytest.mark.parametrize("model_type", ["llama", "mistral", "qwen2"])
+def test_the_llama_family_resolves_to_model_py(model_type):
+    assert family_of({"model_type": model_type}) is model
+    assert model_type in model.FAMILIES
+
+
+@pytest.mark.parametrize("path", CONFIG_FILES)
+def test_every_configuration_of_the_benchmark_finds_its_family(path):
+    with open(os.path.join(REPO, path)) as f:
+        cfg = hf_config(json.load(f))
+    assert family_of(cfg) is model
+    assert {"D", "V", "H", "Hk", "Dh"} <= set(family_of(cfg).geometry(cfg))
+
+
+@pytest.mark.parametrize("cfg,says", [
+    ({"model_type": "lfm2_moe"}, ("'lfm2_moe'", "perf/reference/lfm2_moe.py")),
+    ({"model_type": "deepseek-v3"}, ("'deepseek-v3'", "perf/reference/deepseek_v3.py")),
+    ({"hidden_size": 64}, ("no model_type",)),
+    ({"model_type": "check"}, ("perf.reference.check", "logits_fn")),  # a file, no family
+])
+def test_an_unknown_family_is_an_error_that_names_it(cfg, says):
+    """Never another family's equations under this model's name."""
+    with pytest.raises(FamilyError) as err:
+        family_of(cfg)
+    assert all(part in str(err.value) for part in says)
+    with pytest.raises(FamilyError):
+        check.reference_logprobs(dict(cfg, vocab_size=16), 1, [], "f32")
+
+
+def test_two_modules_that_list_one_model_type_are_refused(family_files):
+    family_files("one", 'FAMILIES = ("twice",)')
+    family_files("other", 'FAMILIES = ("twice",)')
+    with pytest.raises(FamilyError, match="more than one"):
+        family_of({"model_type": "twice"})
+
+
+def test_model_py_meets_the_contract_of_a_family_module(cfg):
+    assert {"f32", "a8"} <= set(model.PRECISIONS)
+    g = model.geometry(cfg)
+    assert {"D", "V", "H", "Hk", "Dh"} <= set(g)
+    widths = model.layer_matmuls(g)
+    assert sum(len(v) for v in widths.values()) == 6   # q k v o gate+up down
+    assert widths[g["F"]] == [(g["D"], 2, False)]
+    assert (g["F"], 1, True) in widths[g["D"]]
+    with pytest.raises(ValueError, match="bf16"):
+        model.logits_fn(cfg, "bf16")
+    tokens = np.arange(24, dtype=np.int32).reshape(2, 12)
+    logits = model.logits_fn(cfg, "f32")(
+        5, tokens, np.array([12, 7], np.int32), np.array([[3, 11, 0], [6, 0, 0]], np.int32))
+    assert logits.shape == (2, 3, g["V"]) and logits.dtype == np.float32
+    lp = np.asarray(check.chosen_logprobs(logits, np.zeros((2, 3), np.int32)))
+    assert lp.shape == (2, 3) and (lp < 0).all()

@@ -166,18 +166,28 @@ def test_attention_decode_bytes_are_whole_pages():
     assert ops == 4 * 32 * 128 * (1 + 128 + 129)
 
 
-def test_qmm_roofline_reader_counts_the_weight_slice_copies(recorded):
-    """On the recorded decode step the kernels alone would read 84% of the
-    HBM floor; with the int8 weight-slice copies that feed them (where the
-    HBM read is paid) the weight path reads 47%."""
-    from perf.metrics import qmm_roofline
+def mistral_run(trace, model_type=None):
+    """What ``qmm_roofline`` reads of ``perf/run.py``'s ``Run``."""
+    with open(os.path.join(REPO, "perf", "configs", "mistral-7b.json")) as f:
+        config = json.load(f)
+    if model_type:
+        config["model_type"] = model_type
 
     class Run:
-        trace, notes = recorded, []
+        notes = []
         device = {"kind": "TPU v5 lite"}
-        with open(os.path.join(REPO, "perf", "configs", "mistral-7b.json")) as f:
-            config = json.load(f)
 
+    Run.trace, Run.config = trace, config
+    return Run
+
+
+def test_qmm_roofline_reader_counts_the_weight_slice_copies(recorded):
+    """The recorded decode step dates from before PR 26: the kernels alone
+    would read 84% of the HBM floor; with the int8 weight-slice copies that
+    fed them then (where the HBM read was paid) the weight path reads 47%."""
+    from perf.metrics import qmm_roofline
+
+    Run = mistral_run(recorded)
     share = qmm_roofline.read(Run)
     note = Run.notes[-1]["qmm_roofline"]
     assert note["bound"] == "bytes" and note["row_counts"] == [32]
@@ -186,3 +196,97 @@ def test_qmm_roofline_reader_counts_the_weight_slice_copies(recorded):
         100 * note["least_s"] / (note["kernels_s"] + note["weight_slices_s"]))
     assert 40.0 < share < 55.0
     assert 100 * note["least_s"] / note["kernels_s"] > 80.0
+
+
+HEAD_ROW = {"calls": 1, "total_s": 0.0001876, "median_s": 0.0001876}
+
+
+def with_op(recorded, label):
+    return dict(recorded, ops={**recorded["ops"], label: HEAD_ROW})
+
+
+def test_qmm_roofline_counts_the_head_once_at_its_own_shape(recorded):
+    """The head runs outside the scan as ``step.<n>`` with N = the
+    vocabulary: one ``[8, 4096] x [4096, 32000]`` call, 187.6 us in the
+    PR 26 captures (85% of its HBM floor)."""
+    from perf.metrics import qmm_roofline
+
+    before = mistral_run(recorded)
+    qmm_roofline.read(before)
+    was = before.notes[-1]["qmm_roofline"]
+    assert was["head_calls"] == 0 and was["head_s"] == 0.0
+
+    Run = mistral_run(with_op(recorded, "step.1_bf16_8_32000__custom-call"))
+    share = qmm_roofline.read(Run)
+    note = Run.notes[-1]["qmm_roofline"]
+    ops, byts = roofline.qmm_cost(8, 4096, 32000)
+    assert byts == 4096 * 32000 + 32000 * 4 + 8 * 4096 * 2 + 8 * 32000 * 2
+    least, bound = roofline.least_seconds(ops, byts, roofline.peaks("TPU v5 lite"))
+    assert bound == "bytes" and 84.0 < 100 * least / HEAD_ROW["total_s"] < 86.0
+    assert note["head_calls"] == 1 and note["head_s"] == HEAD_ROW["total_s"]
+    assert note["least_s"] == pytest.approx(was["least_s"] + least, rel=1e-12)
+    assert note["kernels_s"] == pytest.approx(
+        was["kernels_s"] + HEAD_ROW["total_s"], rel=1e-12)
+    assert note["row_counts"] == [8, 32]
+    assert share == pytest.approx(100 * note["least_s"] / (
+        note["kernels_s"] + note["weight_slices_s"]))
+
+
+@pytest.mark.parametrize("label", [
+    "step.3_bf16_8_4096__custom-call",      # a top-level call of another width
+    "step.1_f32_8_32000__custom-call",      # not a bf16 result
+    "jit_step.1_bf16_8_32000__fusion",      # no custom call
+])
+def test_qmm_roofline_leaves_other_top_level_calls_alone(recorded, label):
+    from perf.metrics import qmm_roofline
+
+    plain, other = mistral_run(recorded), mistral_run(with_op(recorded, label))
+    assert qmm_roofline.read(plain) == qmm_roofline.read(other)
+    assert other.notes[-1]["qmm_roofline"]["head_calls"] == 0
+
+
+def test_qmm_roofline_says_nothing_for_a_family_that_lists_no_matmuls(
+        recorded, family_files):
+    """Which matmuls a layer has is the family's knowledge: a family module
+    without ``layer_matmuls`` reads ``None``, never llama's count under
+    another model's name; with its own it reads, by files alone."""
+    from perf.metrics import qmm_roofline
+
+    family_files("silent")
+    Run = mistral_run(recorded, "silent")
+    assert qmm_roofline.read(Run) is None and Run.notes == []
+    family_files("telling", """
+def layer_matmuls(g):
+    return {14336: [(4096, 2, False)], 4096: [(4096, 1, False), (14336, 1, True)],
+            1024: [(4096, 1, False)]}
+""")
+    Run = mistral_run(recorded, "telling")
+    assert 40.0 < qmm_roofline.read(Run) < 60.0
+    # a width the family does not list: nothing, not a guess
+    family_files("narrow", "\ndef layer_matmuls(g):\n    return {4096: [(4096, 1, False)]}\n")
+    assert qmm_roofline.read(mistral_run(recorded, "narrow")) is None
+    with pytest.raises(LookupError, match="'nobody'"):
+        qmm_roofline.read(mistral_run(recorded, "nobody"))
+
+
+def test_attention_decode_reader_takes_its_heads_from_the_family(family_files):
+    """Only ``H, Hk, Dh`` of the family's ``geometry``, times the calls the
+    trace counted: right for a model in which only some layers attend."""
+    from perf.metrics import attn_decode_roofline
+
+    family_files("hybrid")   # the toy family: H 4, Hk 2, Dh 16
+
+    class Run:
+        notes, block_size, trace_span = [], 128, (10.0, 12.0)
+        device = {"kind": "TPU v5 lite"}
+        config = {"model_type": "hybrid", "hidden_size": 64, "vocab_size": 16}
+        samples = [{"t": 11.0, "contexts": [100, 300]}]
+        trace = {"ops": {"paged_attention_decode_stacked.6_bf16_2_4_16__custom-call":
+                         {"calls": 6, "total_s": 6e-6}}}
+
+    share = attn_decode_roofline.read(Run)
+    least, _ = roofline.least_seconds(
+        *roofline.attn_decode_cost([100, 300], 4, 2, 16, 128),
+        roofline.peaks("TPU v5 lite"))
+    assert share == pytest.approx(100 * 6 * least / 6e-6)
+    assert Run.notes[-1]["attn_decode_roofline"]["calls"] == 6
