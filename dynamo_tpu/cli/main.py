@@ -959,6 +959,7 @@ async def cmd_run(args: Any) -> None:
             from dynamo_tpu.disagg.protocols import DisaggConfig
             from dynamo_tpu.disagg.worker import DisaggDecodeEngine
 
+            jax_engine.refuse_kv_transfer()  # a family may not build it
             engine = await DisaggDecodeEngine.create(
                 jax_engine,
                 drt.store,
@@ -1091,6 +1092,7 @@ async def _run_prefill_worker(args: Any) -> None:
         return
     _, _, jax_engine = await _build_core_engine(args)
     assert jax_engine is not None
+    jax_engine.refuse_kv_transfer()  # a family may not build it
     drt = await DistributedRuntime.create(config=_runtime_config(args))
     drt.runtime.install_signal_handlers()
     print(f"prefill worker consuming {ns}_prefill_queue", flush=True)

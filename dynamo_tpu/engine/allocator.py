@@ -33,6 +33,39 @@ class NoBlocksError(RuntimeError):
     pass
 
 
+class StateSlots:
+    """Slots of the per-sequence state plane (models with recurrent
+    layers keep a fixed-size state per running sequence beside their
+    paged rows). Slot 0 is the garbage slot of padded rows, like block
+    0. A slot is NOT cleared on release or on acquisition: the model
+    starts a row from a zero state whenever the row starts at position
+    0, whatever its slot holds (models/kimi_linear.py)."""
+
+    def __init__(self, num_slots: int):
+        if num_slots < 2:
+            raise ValueError("need at least 2 state slots (slot 0 is reserved)")
+        self.num_slots = num_slots
+        self._free = list(range(num_slots - 1, 0, -1))
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_used(self) -> int:
+        return self.num_slots - 1 - len(self._free)
+
+    def acquire(self) -> int:
+        if not self._free:
+            raise NoBlocksError("no free state slots")
+        return self._free.pop()
+
+    def release(self, slot: int) -> None:
+        if slot <= 0 or slot >= self.num_slots or slot in self._free:
+            raise ValueError(f"state slot {slot} is not held")
+        self._free.append(slot)
+
+
 class BlockAllocator:
     def __init__(
         self,

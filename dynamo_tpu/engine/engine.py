@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu import faults
-from dynamo_tpu.engine.allocator import BlockAllocator
+from dynamo_tpu.engine.allocator import BlockAllocator, StateSlots
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.kvbm import BlockLayout, KvbmConfig, KvBlockManager
 from dynamo_tpu.ops.block_copy import gather_blocks, scatter_blocks
@@ -51,7 +51,7 @@ from dynamo_tpu.engine.scheduler import (
     Sequence,
     StepPlan,
 )
-from dynamo_tpu.models import ModelConfig
+from dynamo_tpu.models import ModelConfig, family as model_family
 from dynamo_tpu.utils import affinity, compile_fence, transfer_fence
 from dynamo_tpu.utils.bucketing import next_bucket
 from dynamo_tpu.models.llama import (
@@ -292,6 +292,14 @@ class JaxEngine:
         self._unsynced_steps: list[str] = []
         # device programs dispatched, by kind (program_counts)
         self._steps_dispatched: dict[str, int] = {}
+        # models with recurrent state: state slots held, summed over the
+        # programs dispatched, and slots there were (program_counts)
+        self._state_slot_steps = [0, 0]
+        # the family's device-side counts (models/kimi_linear.py
+        # COUNT_NAMES): totals as Python ints, and the device's last
+        # int32 reading (the device wraps, the totals do not)
+        self._family_counts: dict[str, int] = {}
+        self._family_counts_seen: Optional[np.ndarray] = None
         # observability (docs/observability.md): step flight recorder
         # with slow-step watchdog, SLO/goodput tracker, HBM accountant
         slow_ms = config.slow_step_ms
@@ -535,6 +543,16 @@ class JaxEngine:
 
             cache_spec = PP_CACHE_SPEC
 
+        pp_specs_fn = specs_fn
+
+        def specs_fn(mc: ModelConfig):  # noqa: F811 — runs before any weight loads
+            # a family refuses here what it does not build (e.g. tp > 1
+            # or speculation for a model with recurrent state)
+            check = getattr(model_family(mc), "check_engine", None)
+            if check is not None:
+                check(cfg)
+            return pp_specs_fn(mc) if pp_specs_fn is not None else None
+
         from dynamo_tpu.models import loader
 
         self.model_config, self.params = loader.resolve_model(
@@ -584,18 +602,24 @@ class JaxEngine:
             num_blocks = int(
                 multihost_utils.broadcast_one_to_all(np.int32(num_blocks))
             )
-        self.k_cache, self.v_cache = init_cache(
+        stateful = self.model_config.has_recurrent_state
+        cache_kw = {"state_slots": self._state_slot_count} if stateful else {}
+        self.k_cache, self.v_cache = model_family(self.model_config).init_cache(
             self.model_config,
             num_blocks,
             cfg.block_size,
             self.mesh,
             dtype=jnp.dtype(cfg.kv_cache_dtype),
             spec=cache_spec,
+            **cache_kw,
         )
         self.allocator = BlockAllocator(
             num_blocks,
             cfg.block_size,
-            enable_prefix_caching=cfg.enable_prefix_caching,
+            # a cached page prefix is worth nothing without the recurrent
+            # state at its end, and no state is snapshotted at page
+            # boundaries: every admission is a counted miss, recomputed
+            enable_prefix_caching=cfg.enable_prefix_caching and not stateful,
             on_event=self._on_kv_event,
         )
         self.scheduler = Scheduler(
@@ -608,6 +632,8 @@ class JaxEngine:
             max_prefill_tokens=cfg.max_prefill_tokens,
         )
         self.scheduler.decode_lookahead = max(1, cfg.decode_steps)
+        if stateful:
+            self.scheduler.state_slots = StateSlots(self._state_slot_count)
         if cfg.static_shapes:
             # one compiled decode/mixed shape: pad the decode batch to
             # max_batch_size and the table width to the max_model_len
@@ -841,8 +867,11 @@ class JaxEngine:
         # HBM accounting: long-lived allocations once, live stats on
         # refresh (per-step sampled + every /debug/state snapshot)
         self.hbm.set_device(devices[0] if len(devices) else None)
+        self._plane_bytes = (
+            tree_bytes(self.k_cache), tree_bytes(self.v_cache)
+        )
         self.hbm.set_static(
-            tree_bytes(self.params), tree_bytes((self.k_cache, self.v_cache))
+            tree_bytes(self.params), sum(self._plane_bytes)
         )
         self.hbm.refresh()
         from dynamo_tpu.models.llama import (
@@ -879,6 +908,12 @@ class JaxEngine:
             cfg.block_size,
         )
 
+    @property
+    def _state_slot_count(self) -> int:
+        """State-plane slots of a model with recurrent layers: one per
+        sequence that can be admitted, and the garbage slot 0."""
+        return self.config.max_batch_size + 1
+
     def _ensure_qmatmul_tuned(self, verify: bool = False) -> None:
         """Resolve tile configs for every qmatmul shape the step
         functions can reach, BEFORE those functions trace — the tile
@@ -892,6 +927,8 @@ class JaxEngine:
 
         if not pallas_matmul_active() or self.config.quantization != "int8":
             return
+        if self.model_config.has_recurrent_state:
+            return  # that family's shapes take the heuristic tiles
         mc, sched = self.model_config, self.scheduler
         assert mc is not None and sched is not None
         D, F, V = mc.hidden_size, mc.intermediate_size, mc.vocab_size
@@ -946,7 +983,9 @@ class JaxEngine:
         sched = self.scheduler
         assert sched is not None
         t0 = time.monotonic()
-        width = sched.table_width_pad or sched.TABLE_BUCKET
+        width = (
+            sched.table_width_pad or sched.TABLE_BUCKET
+        ) + sched.table_extra
 
         def sampling_for(
             n: int, penalties: bool = False, toplp: bool = False,
@@ -1396,7 +1435,9 @@ class JaxEngine:
                 jax.block_until_ready(self.k_cache)
         if self._spec_step_fn is not None:
             Ssp = self.config.spec_tokens + 1
-            width = sched.table_width_pad or sched.TABLE_BUCKET
+            width = (
+                sched.table_width_pad or sched.TABLE_BUCKET
+            ) + sched.table_extra
             for Bd in decode_buckets:
                 sa = {
                     "tokens": np.zeros((Bd, Ssp), np.int32),
@@ -1526,6 +1567,17 @@ class JaxEngine:
                 2 * mc.num_hidden_layers * self.config.block_size
                 * mc.num_key_value_heads * 4
             )
+        fam = model_family(mc)
+        reserved = 0
+        if mc.has_recurrent_state:
+            # latent pages instead of K and V; the state plane and the
+            # family's own step transients come off the top
+            bytes_per_block_total = fam.page_bytes_per_block(
+                mc, self.config.block_size, itemsize
+            )
+            reserved = fam.state_bytes(
+                mc, self._state_slot_count, itemsize
+            ) + fam.STEP_TRANSIENT_BYTES
         if getattr(devices[0], "platform", "") != "tpu":
             # CPU/virtual test backends: a modest fixed pool (their
             # memory_stats describe host RAM, which would size a
@@ -1537,7 +1589,7 @@ class JaxEngine:
                 f"{devices[0]} reports no memory_stats(); cannot size the "
                 "KV cache from free HBM — set num_blocks explicitly"
             )
-        free = stats["bytes_limit"] - stats["bytes_in_use"]
+        free = stats["bytes_limit"] - stats["bytes_in_use"] - reserved
         # step-transient headroom the cache must leave: a full batched
         # prefill's activations dominate — per token roughly 6 D-wide
         # bf16 tensors (h/q/k/v/attn/out), 3 F-wide (gate/up/act, ×E for
@@ -1658,8 +1710,15 @@ class JaxEngine:
 
         ns_scale = NamedSharding(self.mesh, SCALE_SPEC)
 
+        ns_rep0 = NamedSharding(self.mesh, PSpec())
+
         def pin_caches(k, v):
             def pin(c):
+                if isinstance(c, dict):  # a family's own planes: one device
+                    return {
+                        n: jax.lax.with_sharding_constraint(a, ns_rep0)
+                        for n, a in c.items()
+                    }
                 if isinstance(c, tuple):  # int8 cache: (values, scales)
                     return (
                         jax.lax.with_sharding_constraint(c[0], ns_cache),
@@ -1677,7 +1736,7 @@ class JaxEngine:
             def forward(*a, **kw):  # noqa: F811 — pp-sharded model step
                 return forward_pp(*a, mesh=mesh, **kw)
         else:
-            from dynamo_tpu.models.llama import forward  # noqa: F811
+            forward = model_family(mc).forward  # noqa: F811
 
         def step(
             params,
@@ -2116,6 +2175,10 @@ class JaxEngine:
         mark its dispatch in a live profiler capture; ``tokens`` is the
         step's token array (host or device), read for its shape only."""
         self._steps_dispatched[kind] = self._steps_dispatched.get(kind, 0) + 1
+        slots = self.scheduler.state_slots if self.scheduler else None
+        if slots is not None:
+            self._state_slot_steps[0] += slots.num_used
+            self._state_slot_steps[1] += slots.num_slots - 1
         return step_span(
             "dyn.step.dispatch", kind=kind, rows=int(tokens.shape[0]),
             tokens=int(tokens.size),
@@ -2420,14 +2483,24 @@ class JaxEngine:
         self.kvbm.host.insert_many(seq_hashes, packed)
         return len(seq_hashes)
 
+    def refuse_kv_transfer(self) -> None:
+        if self.model_config is not None and self.model_config.has_recurrent_state:
+            raise NotImplementedError(
+                "KV block export/import (disaggregated transfer, fleet "
+                "fabric) moves K/V pages only: a model with recurrent "
+                "state and latent pages is not supported"
+            )
+
     async def export_kv_blocks(
         self, seq_hashes: list[int]
     ) -> tuple[list[int], np.ndarray]:
+        self.refuse_kv_transfer()
         return await self.acall_on_thread(
             functools.partial(self._export_blocks, seq_hashes)
         )
 
     async def import_kv_blocks(self, seq_hashes: list[int], packed: np.ndarray) -> int:
+        self.refuse_kv_transfer()
         return await self.acall_on_thread(
             functools.partial(self._import_blocks, seq_hashes, packed)
         )
@@ -3809,7 +3882,9 @@ class JaxEngine:
         out["slot_mapping"].reshape(P, T)[:B0, :T0] = arrays[
             "slot_mapping"
         ].reshape(B0, T0)
-        out["block_tables"][:B0, :w0] = arrays["block_tables"]
+        out["block_tables"][:B0] = self.scheduler.widen_tables(
+            arrays["block_tables"], width
+        )
         out["context_lens"][:B0] = arrays["context_lens"]
         out["last_token_idx"][:B0] = arrays["last_token_idx"]
         return out
@@ -3836,10 +3911,9 @@ class JaxEngine:
             d_arrays["block_tables"].shape[1],
         )
         p_pad = self._pad_prefill_rect(p_arrays, P, T, width)
-        if d_arrays["block_tables"].shape[1] < width:
-            dt = np.zeros((d_arrays["block_tables"].shape[0], width), np.int32)
-            dt[:, : d_arrays["block_tables"].shape[1]] = d_arrays["block_tables"]
-            d_arrays["block_tables"] = dt
+        d_arrays["block_tables"] = self.scheduler.widen_tables(
+            d_arrays["block_tables"], width
+        )
         if self._mh_broadcast is not None:
             self._mh_broadcast.announce_mixed(
                 p_pad, sampling_p, d_arrays, sampling_d
@@ -4871,7 +4945,43 @@ class JaxEngine:
                 cached_prompt_tokens=sched.prompt_tokens_cached,
                 preemptions=sched.preemptions,
             )
+            if sched.state_slots is not None:
+                out.update(
+                    state_slot_steps_used=self._state_slot_steps[0],
+                    state_slot_steps_total=self._state_slot_steps[1],
+                    **self._read_family_counts(),
+                )
         return out
+
+    def _read_family_counts(self) -> dict:
+        """The counts a family keeps ON THE DEVICE in its state pytree
+        (``state["counts"]``, int32, cumulative): read on the engine
+        thread — the only one that may touch the donated caches — and
+        added up in Python ints, so the device's wrap-around after 2**32
+        never shows. Nothing new if the engine thread does not answer."""
+        names = getattr(model_family(self.model_config), "COUNT_NAMES", ())
+        if not names or not isinstance(self.v_cache, dict):
+            return {}
+
+        def read() -> np.ndarray:
+            with transfer_fence.allow():
+                return np.asarray(jax.device_get(self.v_cache["counts"]))
+
+        try:
+            if threading.current_thread() is self._thread or not self._running:
+                now = read()
+            else:
+                now = self.call_on_thread(read).result(timeout=5.0)
+        except Exception:
+            return dict(self._family_counts)
+        seen = self._family_counts_seen
+        delta = now.astype(np.uint32) - (
+            np.zeros_like(now) if seen is None else seen
+        ).astype(np.uint32)
+        self._family_counts_seen = now
+        for name, d in zip(names, delta.tolist()):
+            self._family_counts[name] = self._family_counts.get(name, 0) + int(d)
+        return dict(self._family_counts)
 
     def attribution_state(self) -> dict:
         """Provider behind ``/debug/attribution``: the ledger window +
@@ -4950,6 +5060,16 @@ class JaxEngine:
                 # working set — high is GOOD until allocation pressure
                 # starts evicting it)
                 "cached_free_fraction": (cached_free / free) if free else 0.0,
+            }
+        if sched is not None and sched.state_slots is not None:
+            # models with recurrent layers: the per-sequence state plane
+            # beside the pages (which then hold latent rows)
+            slots = sched.state_slots
+            out["state_plane"] = {
+                "total_slots": slots.num_slots - 1,
+                "used_slots": slots.num_used,
+                "bytes": self._plane_bytes[1],
+                "latent_pool_bytes": self._plane_bytes[0],
             }
         out["hbm"] = self.hbm.refresh()
         # the device this engine actually runs on, the kernel impls that

@@ -27,7 +27,11 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from dynamo_tpu.engine.allocator import BlockAllocator, NoBlocksError
+from dynamo_tpu.engine.allocator import (
+    BlockAllocator,
+    NoBlocksError,
+    StateSlots,
+)
 from dynamo_tpu.protocols.common import FinishReason, PreprocessedRequest
 from dynamo_tpu.telemetry import autopsy
 from dynamo_tpu.telemetry.instruments import (
@@ -56,6 +60,8 @@ class Sequence:
     tokens: TokenBlockSequence
     state: SeqState = SeqState.WAITING
     block_table: list[int] = field(default_factory=list)
+    # slot of the state plane while admitted (0 = none; recurrent models)
+    state_slot: int = 0
     num_computed: int = 0  # tokens whose KV is in cache
     num_cached_prompt: int = 0  # prefix-cache hit length (tokens)
     committed_blocks: int = 0  # prefix of block_table already content-addressed
@@ -237,6 +243,11 @@ class Scheduler:
         # recompute-preemption count (observability: healthy serving
         # should sit at ~0 — see _growth_reserve)
         self.preemptions = 0
+        # models with recurrent layers (engine sets it): a slot of the
+        # state plane per admitted sequence, taken at admission and
+        # given back at finish, abort and preemption. Every block table
+        # then carries the row's slot as its LAST column.
+        self.state_slots: Optional[StateSlots] = None
 
     # -- intake -----------------------------------------------------------
     def add_request(self, seq: Sequence) -> None:
@@ -455,6 +466,8 @@ class Scheduler:
             )
             if self.allocator.num_free < free_need + reserve:
                 break  # backpressure: the population's growth comes first
+            if self.state_slots is not None and not self.state_slots.num_free:
+                break  # every state slot is held: wait for a finish
             # admitting this seq adds its own growth to the reserve
             reserve += seq.blocks_needed(
                 seq.total_len + (seq.max_new_tokens or self.decode_lookahead),
@@ -502,6 +515,8 @@ class Scheduler:
                 if seq.t_submit:
                     ENGINE_QUEUE_WAIT.observe(seq.t_admit - seq.t_submit)
             seq.block_table = blocks
+            if self.state_slots is not None:
+                seq.state_slot = self.state_slots.acquire()
             seq.num_cached_prompt = cached * self.block_size
             seq.num_computed = seq.num_cached_prompt
             seq.committed_blocks = cached  # reused blocks are already addressed
@@ -718,7 +733,7 @@ class Scheduler:
             pos = s.total_len - 1 + gl
             positions[i, 0] = pos
             slot_mapping[i] = s.block_table[pos // bs] * bs + pos % bs
-            tables[i, : len(s.block_table)] = s.block_table
+            self._fill_table(tables, i, s)
             ctx[i] = s.total_len + gl
             offsets[i] = gl
             vmap[id(s)] = 1
@@ -891,7 +906,7 @@ class Scheduler:
             # the sampled-but-unapplied tokens occupy slots up to
             # total_len - 1 + lag; the next window starts there
             positions[i, 0] = s.total_len - 1 + gen_after
-            tables[i, : len(s.block_table)] = s.block_table
+            self._fill_table(tables, i, s)
             ctx[i] = s.total_len + gen_after
             v = K
             if s.max_new_tokens is not None:
@@ -973,7 +988,7 @@ class Scheduler:
             arrays["slot_mapping"][i * S + j] = (
                 seq.block_table[pos // bs] * bs + pos % bs
             )
-        arrays["block_tables"][i, : len(seq.block_table)] = seq.block_table
+        self._fill_table(arrays["block_tables"], i, seq)
         arrays["context_lens"][i] = base + 1 + k
         arrays["draft_lens"][i] = k
 
@@ -1138,6 +1153,7 @@ class Scheduler:
         self.running.remove(victim)
         self.allocator.free_sequence(victim.block_table)
         victim.block_table = []
+        self._release_state(victim)
         victim.num_computed = 0
         victim.num_cached_prompt = 0
         victim.committed_blocks = 0
@@ -1198,6 +1214,7 @@ class Scheduler:
         if seq.block_table:
             self.allocator.free_sequence(seq.block_table)
             seq.block_table = []
+        self._release_state(seq)
         if self.on_finish is not None:
             self.on_finish(seq, reason)
 
@@ -1206,17 +1223,48 @@ class Scheduler:
     CHUNK_BUCKETS = [16, 32, 64, 128, 256, 512, 1024, 2048, 4096]
     TABLE_BUCKET = 8  # block-table width rounded to multiples of this
 
+    def _release_state(self, seq: Sequence) -> None:
+        if self.state_slots is not None and seq.state_slot:
+            self.state_slots.release(seq.state_slot)
+            seq.state_slot = 0
+
+    @property
+    def table_extra(self) -> int:
+        """Columns of a block table beyond its pages: the state slot."""
+        return 0 if self.state_slots is None else 1
+
     def _table_width(self, max_blocks: int) -> int:
         """Block-table width for a step: the fixed serving cap when set
         (one compiled shape), bucketed otherwise — growing past the cap
-        degrades to a wider bucket rather than corrupting tables."""
+        degrades to a wider bucket rather than corrupting tables. With
+        state slots, one column more (``_fill_table``)."""
         w = max(
             self.TABLE_BUCKET,
             -(-max_blocks // self.TABLE_BUCKET) * self.TABLE_BUCKET,
         )
         if self.table_width_pad is not None and w <= self.table_width_pad:
-            return self.table_width_pad
-        return w
+            w = self.table_width_pad
+        return w + self.table_extra
+
+    def _fill_table(self, tables: np.ndarray, i: int, seq: Sequence) -> None:
+        """Row ``i``: the sequence's pages, and in the last column its
+        state slot where the model keeps recurrent state."""
+        tables[i, : len(seq.block_table)] = seq.block_table
+        if self.state_slots is not None:
+            tables[i, -1] = seq.state_slot
+
+    def widen_tables(self, tables: np.ndarray, width: int) -> np.ndarray:
+        """``tables`` padded to ``width`` columns (the state-slot column
+        stays the last)."""
+        w0 = tables.shape[1]
+        if w0 >= width:
+            return tables
+        out = np.zeros((tables.shape[0], width), np.int32)
+        pages = w0 - self.table_extra
+        out[:, :pages] = tables[:, :pages]
+        if self.table_extra:
+            out[:, -1] = tables[:, -1]
+        return out
 
     def _decode_batch(self, n: int) -> int:
         if (
@@ -1262,7 +1310,7 @@ class Scheduler:
                 slot_mapping[i * T + j] = (
                     w.seq.block_table[pos // bs] * bs + pos % bs
                 )
-            tables[i, : len(w.seq.block_table)] = w.seq.block_table
+            self._fill_table(tables, i, w.seq)
             ctx[i] = w.start_pos + t
             last_idx[i] = t - 1
             mm = self._mm_chunk_arrays(w.seq, w.start_pos, t, T)
@@ -1333,7 +1381,7 @@ class Scheduler:
             pos = s.total_len - 1
             positions[i, 0] = pos
             slot_mapping[i] = s.block_table[pos // bs] * bs + pos % bs
-            tables[i, : len(s.block_table)] = s.block_table
+            self._fill_table(tables, i, s)
             ctx[i] = s.total_len
             valid_steps[i] = self._seq_lookahead(s)
         return {

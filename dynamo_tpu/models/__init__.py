@@ -9,4 +9,19 @@ the collectives.
 
 from dynamo_tpu.models.config import ModelConfig
 
-__all__ = ["ModelConfig"]
+
+def family(cfg: ModelConfig):
+    """The module that holds a configuration's parameters, cache and
+    step: ``models/kimi_linear.py`` for ``model_type`` ``kimi_linear``,
+    ``models/llama.py`` for every other. Both give ``param_shapes``,
+    ``param_specs``, ``init_params``, ``init_cache`` and ``forward``."""
+    if cfg.model_type == "kimi_linear":
+        from dynamo_tpu.models import kimi_linear
+
+        return kimi_linear
+    from dynamo_tpu.models import llama
+
+    return llama
+
+
+__all__ = ["ModelConfig", "family"]

@@ -43,6 +43,32 @@ class ModelConfig:
     # token id the processor substitutes per image patch slot (LLaVA's
     # image_token_index); None = resolve via the tokenizer
     image_token_index: Optional[int] = None
+    # kimi_linear (models/kimi_linear.py): layers of several kinds. The
+    # 1-based layer lists of linear_attn_config say which mixer a layer
+    # has (gated delta rule with per-sequence state, or latent attention
+    # over paged rows); first_k_dense_replace says which feed-forward.
+    linear_attn_config: Optional[dict] = None
+    first_k_dense_replace: int = 0
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_use_nope: bool = False
+    # expert layer: num_experts is how many THIS process holds; the
+    # router scores num_experts * expert_shards and this process holds
+    # the expert_shard_index-th run of them (1 / 0 = all of them)
+    num_experts: int = 0
+    num_experts_per_token: int = 0
+    num_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    moe_router_activation_func: str = "softmax"
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 1.0
+    num_expert_group: int = 1
+    topk_group: int = 1
+    expert_shards: int = 1
+    expert_shard_index: int = 0
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -51,6 +77,12 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_local_experts > 0
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Layers that keep a fixed-size state per sequence beside the
+        paged rows (the engine gives every running sequence a slot)."""
+        return self.model_type == "kimi_linear"
 
     @property
     def eos_token_ids(self) -> list[int]:
@@ -115,4 +147,7 @@ class ModelConfig:
             kwargs["sliding_window"] = None
         elif raw.get("use_sliding_window") is False:
             kwargs["sliding_window"] = None
+        # kimi_linear states its longest context as model_max_length
+        if "max_position_embeddings" not in raw and "model_max_length" in raw:
+            kwargs["max_position_embeddings"] = int(raw["model_max_length"])
         return cls(**kwargs)

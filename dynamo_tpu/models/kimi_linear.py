@@ -1,0 +1,805 @@
+"""Kimi-Linear: a decoder whose layers are of several kinds, on the
+engine's normal step.
+
+Mixers (``linear_attn_config``: 1-based layer lists):
+
+- KDA, a gated delta rule with a per-channel decay. Each sequence keeps
+  a FIXED-SIZE state per layer — a ``[H, d, d]`` float32 matrix per head
+  and the last ``kernel - 1`` inputs of a short causal convolution — in
+  the state plane, at the slot the scheduler gave the sequence.
+- MLA, latent attention without rotary embedding. The paged cache holds
+  one ``kv_lora_rank + qk_rope_head_dim`` wide row a token a layer (the
+  normalised latent and the shared key part); queries absorb the latent's
+  up-projection, so attention runs over the latent rows themselves.
+
+Feed-forward: a dense SiLU-gated MLP in the first
+``first_k_dense_replace`` layers, then an expert layer — sigmoid router
+over ALL experts, top-k by score plus selection bias, weights from the
+scores, renormalised and scaled — that is told which experts it holds
+(``num_experts`` of them, the ``expert_shard_index``-th of
+``expert_shards`` runs) and computes their part plus the shared expert.
+
+How this differs from ``models/llama.py`` for the engine:
+
+- the two cache pytrees the step threads through are ``pages``
+  (``{"latent": [Lm, slots, C padded to whole 128-lane tiles]}``) and
+  ``state`` (``{"kda": [Lk, S, H, d, d] f32, "conv": [Lk, S, (kernel-1) *
+  3*H*d] f32, "counts": int32 [3]}``) instead of K and V;
+- a row's table is its pages THEN its state slot: the scheduler appends
+  the slot as the table's last column (``Scheduler.state_slots``), so no
+  step function grows an argument. Slot 0, like page 0, is the garbage
+  slot of padded rows;
+- a row that starts at position 0 starts from a zero state, whatever its
+  slot held: a reused slot needs no clearing, and a preempted sequence
+  recomputed from its tokens is exact. Right padding (tokens at or past
+  ``context_lens``) never enters the state or the convolution tail.
+
+Layers are unrolled in Python (nine kinds-of-layer do not share a scan
+body); every int8 matrix is read in place out of its stacked array by
+the ``qmm`` kernels where its shape allows (a multiple of 128 both ways),
+and through the plain mixed dot where not (``mla_wkva``: 576 outputs,
+``kda_wb``: 32).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from dynamo_tpu.models import llama
+from dynamo_tpu.models.config import ModelConfig
+
+Params = dict[str, Any]
+
+LOW_RANK = 128       # decay and output-gate bottleneck (not in config.json)
+KDA_CHUNK = 64       # tokens per block of the chunked recurrence
+MLA_QUERY_TOKENS = 512  # query tokens whose scores exist at once (prefill)
+# at most this many tokens go through every held expert at once; more are
+# sorted by expert and go through the grouped matmul
+MOE_DENSE_TOKENS = 64
+# what a step holds beside weights, pages and state, at the published
+# widths: a prefill tile's attention scores (512 tokens x 32 heads x the
+# table's 4k rows, float32, a few copies), the decay blocks of a KDA
+# chunk, the grouped matmuls' sorted rows — the engine leaves this free
+# when it sizes the pages
+STEP_TRANSIENT_BYTES = 4 << 30
+COUNT_NAMES = ("moe_layer_calls", "moe_local_assignments", "moe_experts_touched")
+
+
+class Geometry:
+    """The sizes of one configuration, worked out once."""
+
+    def __init__(self, cfg: ModelConfig):
+        la = cfg.linear_attn_config or {}
+        self.L = cfg.num_hidden_layers
+        self.D = cfg.hidden_size
+        self.V = cfg.vocab_size
+        self.kda_layers = [i - 1 for i in la.get("kda_layers", [])]
+        self.mla_layers = [i - 1 for i in la.get("full_attn_layers", [])]
+        if sorted(self.kda_layers + self.mla_layers) != list(range(self.L)):
+            raise ValueError(
+                "linear_attn_config must give every one of the "
+                f"{self.L} layers one mixer: kda_layers "
+                f"{la.get('kda_layers')}, full_attn_layers "
+                f"{la.get('full_attn_layers')}"
+            )
+        self.Hl = la.get("num_heads", cfg.num_attention_heads)
+        self.dl = la.get("head_dim", cfg.head_dim)
+        self.kernel = la.get("short_conv_kernel_size", 4)
+        self.HD = self.Hl * self.dl
+        self.H = cfg.num_attention_heads
+        self.nope = cfg.qk_nope_head_dim
+        self.rope = cfg.qk_rope_head_dim
+        self.vd = cfg.v_head_dim
+        self.rank = cfg.kv_lora_rank
+        self.C = self.rank + self.rope          # one cached latent row
+        # as stored: padded to whole 128-lane tiles. A 576-wide minor
+        # dimension makes the chip's default layout put the SLOTS minor
+        # instead, and every step then copies the whole plane into the
+        # row-major layout the step wants and back (measured: 11 ms of a
+        # 29 ms decode step, PERF.md PR 29)
+        self.Cpad = -(-self.C // 128) * 128
+        self.F = cfg.intermediate_size
+        self.Fe = cfg.moe_intermediate_size
+        self.E = cfg.num_experts                # held here
+        self.E_all = cfg.num_experts * cfg.expert_shards
+        self.e0 = cfg.expert_shard_index * cfg.num_experts
+        self.dense_layers = list(range(min(cfg.first_k_dense_replace, self.L)))
+        self.moe_layers = [i for i in range(self.L) if i not in self.dense_layers]
+        if cfg.q_lora_rank is not None:
+            raise ValueError("kimi_linear with q_lora_rank is not built")
+        if cfg.num_expert_group != 1 or cfg.topk_group != 1:
+            raise ValueError("kimi_linear expert groups other than 1 are not built")
+        if cfg.moe_router_activation_func != "sigmoid":
+            raise ValueError(
+                f"kimi_linear router {cfg.moe_router_activation_func!r} is "
+                "not built (sigmoid only)"
+            )
+        if self.moe_layers and cfg.num_shared_experts != 1:
+            raise ValueError("kimi_linear is built for exactly 1 shared expert")
+
+    def kind_index(self, layer: int) -> tuple[str, int, str, int]:
+        """(mixer kind, index in its stack, ffn kind, index in its stack)."""
+        if layer in self.kda_layers:
+            mixer = ("kda", self.kda_layers.index(layer))
+        else:
+            mixer = ("mla", self.mla_layers.index(layer))
+        if layer in self.dense_layers:
+            ffn = ("dense", self.dense_layers.index(layer))
+        else:
+            ffn = ("moe", self.moe_layers.index(layer))
+        return (*mixer, *ffn)
+
+
+# ---------------------------------------------------------------------------
+# Parameters. The ORDER of param_shapes is part of the seeded recipe.
+# ---------------------------------------------------------------------------
+
+# name -> axis the int8 scales reduce over (weight-only int8, as
+# models/quant.py: per output channel; embedding rows per row)
+QUANT_AXIS = {
+    "embed": -1, "lm_head": -2,
+    "kda_wq": -2, "kda_wk": -2, "kda_wv": -2, "kda_wfa": -2, "kda_wfb": -2,
+    "kda_wb": -2, "kda_wga": -2, "kda_wgb": -2, "kda_wo": -2,
+    "mla_wq": -2, "mla_wkva": -2, "mla_wkvb": -2, "mla_wo": -2,
+    "w_gate": -2, "w_up": -2, "w_down": -2,
+    "ws_gate": -2, "ws_up": -2, "ws_down": -2,
+    "we_gate": -2, "we_up": -2, "we_down": -2,
+}
+
+
+_GLOBAL = ("embed", "final_norm", "lm_head")   # every other is a stack
+
+
+def param_shapes(cfg: ModelConfig) -> dict[str, tuple[tuple[int, ...], Any]]:
+    """name -> (shape, dtype); layer parameters are stacked per KIND."""
+    g = Geometry(cfg)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    Lk, Lm = len(g.kda_layers), len(g.mla_layers)
+    Ld, Le = len(g.dense_layers), len(g.moe_layers)
+    D, HD, R, K1 = g.D, g.HD, LOW_RANK, g.kernel
+    shapes: dict = {
+        "embed": ((g.V, D), bf16),
+        "final_norm": ((D,), f32),
+        "lm_head": ((D, g.V), bf16),
+        "attn_norm": ((g.L, D), f32),
+        "mlp_norm": ((g.L, D), f32),
+    }
+    if Lk:
+        shapes.update({
+            "kda_wq": ((Lk, D, HD), bf16),
+            "kda_wk": ((Lk, D, HD), bf16),
+            "kda_wv": ((Lk, D, HD), bf16),
+            "kda_conv": ((Lk, K1, 3 * HD), f32),   # q | k | v channels
+            "kda_wfa": ((Lk, D, R), bf16),
+            "kda_wfb": ((Lk, R, HD), bf16),
+            "kda_A_log": ((Lk, g.Hl), f32),
+            "kda_dt_bias": ((Lk, HD), f32),
+            "kda_wb": ((Lk, D, g.Hl), bf16),
+            "kda_wga": ((Lk, D, R), bf16),
+            "kda_wgb": ((Lk, R, HD), bf16),
+            "kda_onorm": ((Lk, g.dl), f32),
+            "kda_wo": ((Lk, HD, D), bf16),
+        })
+    if Lm:
+        shapes.update({
+            "mla_wq": ((Lm, D, g.H * (g.nope + g.rope)), bf16),
+            "mla_wkva": ((Lm, D, g.C), bf16),
+            "mla_kvnorm": ((Lm, g.rank), f32),
+            "mla_wkvb": ((Lm, g.rank, g.H * (g.nope + g.vd)), bf16),
+            "mla_wo": ((Lm, g.H * g.vd, D), bf16),
+        })
+    if Ld:
+        shapes.update({
+            "w_gate": ((Ld, D, g.F), bf16),
+            "w_up": ((Ld, D, g.F), bf16),
+            "w_down": ((Ld, g.F, D), bf16),
+        })
+    if Le:
+        shapes.update({
+            "router": ((Le, D, g.E_all), f32),
+            "router_bias": ((Le, g.E_all), f32),
+            "ws_gate": ((Le, D, g.Fe), bf16),
+            "ws_up": ((Le, D, g.Fe), bf16),
+            "ws_down": ((Le, g.Fe, D), bf16),
+            "we_gate": ((Le, g.E, D, g.Fe), bf16),
+            "we_up": ((Le, g.E, D, g.Fe), bf16),
+            "we_down": ((Le, g.E, g.Fe, D), bf16),
+        })
+    return shapes
+
+
+def param_specs(cfg: ModelConfig) -> dict[str, P]:
+    """One device holds everything (check_engine refuses tp/ep/pp > 1)."""
+    return {name: P() for name in param_shapes(cfg)}
+
+
+def _draw_one(name: str, key, shape: tuple[int, ...]):
+    """One leading slice of parameter ``name`` in float32 — the recipe:
+    norms 1; selection bias 0; ``A_log = log(U(1, 16))``; ``dt_bias`` the
+    inverse softplus of a log-uniform step in [1e-3, 1e-1]; everything
+    else ``normal / sqrt(fan_in)`` (fan_in: the second-to-last axis)."""
+    if name.endswith("norm"):
+        return jnp.ones(shape, jnp.float32)
+    if name == "router_bias":
+        return jnp.zeros(shape, jnp.float32)
+    if name == "kda_A_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if name == "kda_dt_bias":
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(max(1, fan_in))
+
+
+def _quantize(arr, axis: int):
+    amax = jnp.max(jnp.abs(arr), axis=axis, keepdims=True)
+    scale = jnp.maximum(amax, 1e-12) / 127.0
+    q = jnp.clip(jnp.round(arr / scale), -127, 127).astype(jnp.int8)
+    return q, jnp.squeeze(scale, axis=axis)
+
+
+def _init(cfg: ModelConfig, seed: int, mesh, quantize: bool, dtype) -> Params:
+    """The seeded draw: parameter ``i`` of ``param_shapes`` order has key
+    ``fold_in(PRNGKey(seed), i)``; a stacked parameter draws layer ``j`` of
+    its stack from ``fold_in(., j)`` and, where it holds experts, expert
+    ``e`` from ``fold_in(., e)`` again (``models/quant.py``
+    ``init_params_quantized``'s order, extended). One slice at a time on
+    the device, so the float32 transient is one layer's."""
+    root = jax.random.PRNGKey(seed)
+    params: Params = {}
+
+    def put(arr):
+        if mesh is not None:
+            arr = jax.device_put(arr, NamedSharding(mesh, P()))
+        return arr
+
+    for i, (name, (shape, want)) in enumerate(param_shapes(cfg).items()):
+        key = jax.random.fold_in(root, i)
+        axis = QUANT_AXIS.get(name) if quantize else None
+        out_dtype = want if dtype is None or want == jnp.float32 else dtype
+
+        def leaf(k, shp, name=name, axis=axis, out_dtype=out_dtype):
+            arr = _draw_one(name, k, shp)
+            if axis is not None:
+                return _quantize(arr, axis)
+            return arr.astype(out_dtype), None
+
+        if name in _GLOBAL:
+            q, s = jax.jit(lambda k, shp=shape: leaf(k, shp))(key)
+        else:
+            if len(shape) == 4:      # [layers, experts, ., .]
+                one = jax.jit(lambda k, shp=shape[2:], n=shape[1]: jax.vmap(
+                    lambda e: leaf(jax.random.fold_in(k, e), shp)
+                )(jnp.arange(n)))
+            else:
+                one = jax.jit(lambda k, shp=shape[1:]: leaf(k, shp))
+            parts = [one(jax.random.fold_in(key, j)) for j in range(shape[0])]
+            q = jnp.stack([p[0] for p in parts])
+            s = jnp.stack([p[1] for p in parts]) if axis is not None else None
+        params[name] = put(q)
+        if s is not None:
+            params[name + "_scale"] = put(s)
+    return params
+
+
+def init_params(cfg: ModelConfig, seed: int = 0, mesh: Optional[Mesh] = None,
+                specs: Optional[dict] = None, dtype=None) -> Params:
+    """The seeded draw, unquantized. ``dtype`` overrides bfloat16 for the
+    matrices (float32 in tests, so the program meets its reference to
+    rounding)."""
+    return _init(cfg, seed, mesh, False, dtype)
+
+
+def init_params_quantized(cfg: ModelConfig, seed: int = 0,
+                          mesh: Optional[Mesh] = None,
+                          specs: Optional[dict] = None) -> Params:
+    """The seeded draw as served: every matrix weight-only int8 with a
+    float32 scale per output channel, made and quantized on the device."""
+    return _init(cfg, seed, mesh, True, None)
+
+
+# ---------------------------------------------------------------------------
+# The cache: latent pages, and the per-sequence state plane
+# ---------------------------------------------------------------------------
+
+
+def cache_shapes(cfg: ModelConfig, num_blocks: int, block_size: int,
+                 state_slots: int) -> tuple[dict, dict]:
+    g = Geometry(cfg)
+    Lk, Lm = len(g.kda_layers), len(g.mla_layers)
+    pages = {"latent": (max(1, Lm), num_blocks * block_size, g.Cpad)}
+    state = {
+        "kda": (max(1, Lk), state_slots, g.Hl, g.dl, g.dl),
+        # a slot's tail rows side by side: the minor dimension stays whole
+        # lane tiles (a [., 3, 3HD] plane is copied at the step's edges too)
+        "conv": (max(1, Lk), state_slots, (g.kernel - 1) * 3 * g.HD),
+    }
+    return pages, state
+
+
+def page_bytes_per_block(cfg: ModelConfig, block_size: int, itemsize: int) -> int:
+    """Bytes one block of pages takes over all layers (the engine sizes
+    the pool with it)."""
+    g = Geometry(cfg)
+    return max(1, len(g.mla_layers)) * block_size * g.Cpad * itemsize
+
+
+def state_bytes(cfg: ModelConfig, state_slots: int, itemsize: int) -> int:
+    g = Geometry(cfg)
+    per_slot = g.Hl * g.dl * g.dl * 4 + (g.kernel - 1) * 3 * g.HD * 4
+    return max(1, len(g.kda_layers)) * state_slots * per_slot
+
+
+def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
+               mesh: Optional[Mesh] = None, dtype=jnp.bfloat16,
+               spec: Optional[P] = None, state_slots: int = 2):
+    """(pages, state), zeroed. ``state_slots`` counts the garbage slot 0."""
+    if jnp.dtype(dtype) == jnp.int8:
+        raise ValueError("kimi_linear has no int8 latent cache")
+    sh = NamedSharding(mesh, P()) if mesh is not None else None
+    pshape, sshape = cache_shapes(cfg, num_blocks, block_size, state_slots)
+    pages = {"latent": jnp.zeros(pshape["latent"], dtype, device=sh)}
+    state = {
+        "kda": jnp.zeros(sshape["kda"], jnp.float32, device=sh),
+        # float32 like the matmul results the convolution reads: a token
+        # sees the same inputs whether they came from the tail or the chunk
+        "conv": jnp.zeros(sshape["conv"], jnp.float32, device=sh),
+        # cumulative, on the device, read at a profiler capture's edges
+        # (engine.program_counts): expert-layer calls, assignments of real
+        # tokens to held experts, held experts touched (COUNT_NAMES)
+        "counts": jnp.zeros((len(COUNT_NAMES),), jnp.int32, device=sh),
+    }
+    return pages, state
+
+
+def check_engine(config) -> None:
+    """What is not built for this family is refused when the engine
+    starts, never served wrong."""
+    refused = {
+        "tensor_parallel_size > 1": config.tensor_parallel_size > 1,
+        "expert_parallel_size > 1": config.expert_parallel_size > 1,
+        "pipeline_parallel_size > 1": config.pipeline_parallel_size > 1,
+        "data_parallel_size > 1": config.data_parallel_size > 1,
+        "num_nodes > 1": config.num_nodes > 1,
+        "spec_decode (a rejected draft cannot be taken out of the "
+        "recurrent state)": bool(config.spec_decode),
+        "host_kv_blocks > 0 (KVBM offload moves K/V pages only)":
+            config.host_kv_blocks > 0,
+        "kv_cache_dtype int8": jnp.dtype(config.kv_cache_dtype) == jnp.int8,
+    }
+    bad = [what for what, hit in refused.items() if hit]
+    if bad:
+        raise ValueError(
+            "model_type kimi_linear (recurrent state + latent pages) does "
+            "not support: " + "; ".join(bad)
+        )
+
+
+# ---------------------------------------------------------------------------
+# Building blocks
+# ---------------------------------------------------------------------------
+
+
+def kernels_active() -> bool:
+    """The family's own Pallas kernels run where the attention kernels
+    do: on a TPU, one device (``llama.pallas_attention_active``)."""
+    return llama.pallas_attention_active()
+
+
+# A matmul's RESULT keeps the float32 of its accumulator (its operands
+# are the activation dtype): what reads it — a nonlinearity, the float32
+# residual stream — rounds once, when it next becomes a matmul's operand,
+# and not a second time in between.
+MM_OUT = jnp.float32
+
+
+def _mm(p: Params, name: str, x: jax.Array, idx: int) -> jax.Array:
+    """x @ p[name][idx] for a stacked weight, in ``MM_OUT``: int8 through
+    the ``qmm`` kernel (reads layer ``idx`` in place) where the shape is
+    a multiple of 128 both ways, else the mixed-dtype dot; float weights
+    plainly."""
+    w = p[name]
+    out = MM_OUT or x.dtype
+    if w.dtype != jnp.int8:
+        return _einsum_f32("...k,kn->...n", x, w[idx].astype(x.dtype)).astype(out)
+    K, N = w.shape[-2:]
+    if llama.pallas_matmul_active() and K % 128 == 0 and N % 128 == 0:
+        from dynamo_tpu.ops.qmatmul import qmm
+
+        return qmm(x, w, p[name + "_scale"], interpret=llama._qmm_interpret(),
+                   layer=jnp.int32(idx), out_dtype=out)
+    y = jax.lax.dot_general(
+        x, w[idx], (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return (y * p[name + "_scale"][idx]).astype(out)
+
+
+def _weight(p: Params, name: str, idx: int, dtype) -> jax.Array:
+    """Layer ``idx`` of a stacked weight, dequantized."""
+    w = p[name][idx]
+    if w.dtype == jnp.int8:
+        return (w.astype(jnp.float32) * p[name + "_scale"][idx]).astype(dtype)
+    return w.astype(dtype)
+
+
+def _einsum_f32(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """einsum with float32 accumulation and result. XLA:CPU has no
+    bf16 x bf16 -> f32 matmul for every shape: there the operands are
+    upcast first, which is exact."""
+    if jax.default_backend() == "cpu":
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+
+
+def _gated_mlp(p: Params, names: tuple[str, str, str], h: jax.Array,
+               idx: int) -> jax.Array:
+    gate, up, down = names
+    mid = jax.nn.silu(_mm(p, gate, h, idx)) * _mm(p, up, h, idx)
+    return _mm(p, down, mid.astype(h.dtype), idx)
+
+
+def kda_decay_log(p: Params, f: jax.Array, idx: int, g: Geometry) -> jax.Array:
+    """log a_t = -exp(A_log_h) * softplus(f_t + dt_bias): [..., H, d], <= 0."""
+    f = f.astype(jnp.float32) + p["kda_dt_bias"][idx]
+    f = f.reshape(*f.shape[:-1], g.Hl, g.dl)
+    return -jnp.exp(p["kda_A_log"][idx])[:, None] * jax.nn.softplus(f)
+
+
+def kda_decode(q, k, v, glog, beta, S):
+    """One recurrent update a row. q, k, v, glog [B, H, d]; beta [B, H];
+    S [B, H, d(key), d(value)] float32. Returns (o [B, H, d], S')."""
+    with jax.named_scope("kda_decode"), jax.default_matmul_precision("highest"):
+        S = jnp.exp(glog)[..., None] * S
+        u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", S, k))
+        S = S + k[..., :, None] * u[..., None, :]
+        return jnp.einsum("bhkv,bhk->bhv", S, q), S
+
+
+def kda_chunk_for(rows: int, T: int) -> int:
+    """Tokens per block: the [rows, C, C, H, d] decay block is what a
+    chunk holds at once, so more rows take shorter blocks."""
+    C = KDA_CHUNK
+    while C > 16 and rows * C * C > 8 * KDA_CHUNK * KDA_CHUNK:
+        C //= 2
+    return min(C, T)
+
+
+def kda_chunked(q, k, v, glog, beta, S, chunk: Optional[int] = None):
+    """The same recurrence over T tokens, ``chunk`` at a time. q, k, v,
+    glog [B, T, H, d] float32; beta [B, T, H]; S [B, H, d, d].
+
+    With G_t the decay summed from the chunk's start (so every exponent
+    below is of G_t - G_j <= 0, t >= j: nothing overflows), the delta
+    rule's corrections u_t solve a unit lower-triangular system:
+      u_t + beta_t sum_{j<t} A_tj u_j = beta_t (v_t - S0^T (k_t e^{G_t})),
+      A_tj = sum_c k_t[c] k_j[c] e^{G_t[c] - G_j[c]};
+      o_t = S0^T (q_t e^{G_t}) + sum_{j<=t} B_tj u_j,  B as A with q_t;
+      S' = e^{G_C} S0 + sum_j (k_j e^{G_C - G_j}) u_j^T.
+    A token with beta 0 and glog 0 (padding) changes nothing."""
+    B, T, H, d = q.shape
+    C = min(chunk or kda_chunk_for(B, T), T)
+    assert T % C == 0, (T, C)
+
+    def blocks(x):
+        return jnp.moveaxis(x.reshape(B, T // C, C, *x.shape[2:]), 1, 0)
+
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+
+    def body(S, blk):
+        qc, kc, vc, gc, bc = blk                      # [B, C, H, .]
+        G = jnp.cumsum(gc, axis=1)                    # [B, C, H, d]
+        diff = G[:, :, None] - G[:, None, :]          # [B, C(t), C(j), H, d]
+        decay = jnp.exp(jnp.where(tri[None, :, :, None, None], diff, -jnp.inf))
+        kk = kc[:, :, None] * kc[:, None, :] * decay
+        A = jnp.where(strict[None, :, :, None], kk.sum(-1), 0.0)   # [B,C,C,H]
+        Bm = (qc[:, :, None] * kc[:, None, :] * decay).sum(-1)     # [B,C,C,H]
+        eG = jnp.exp(G)
+        rhs = bc[..., None] * (vc - jnp.einsum("bhkv,bthk->bthv", S, kc * eG))
+        M = jnp.eye(C)[None, :, :, None] + bc[:, :, None, :] * A
+        u = jax.scipy.linalg.solve_triangular(
+            jnp.moveaxis(M, 3, 1), jnp.moveaxis(rhs, 2, 1), lower=True,
+            unit_diagonal=True)                        # [B, H, C, d]
+        o = jnp.einsum("bhkv,bthk->bthv", S, qc * eG) + jnp.einsum(
+            "btjh,bhjv->bthv", Bm, u)
+        last = G[:, -1]                                # [B, H, d]
+        S = jnp.exp(last)[..., None] * S + jnp.einsum(
+            "bjhk,bhjv->bhkv", kc * jnp.exp(last[:, None] - G), u)
+        return S, o
+
+    with jax.named_scope("kda_chunked"), jax.default_matmul_precision("highest"):
+        S, o = jax.lax.scan(body, S, tuple(map(blocks, (q, k, v, glog, beta))))
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, H, d), S
+
+
+def moe_routing(cfg: ModelConfig, p: Params, x: jax.Array, idx: int):
+    """x [N, D] -> (weights [N, k] float32, expert ids [N, k]) over ALL
+    experts: scores sigmoid, chosen by score + selection bias, weighted
+    by the scores themselves, renormalised over the chosen, scaled."""
+    with jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(x.astype(jnp.float32) @ p["router"][idx])
+    _, topi = jax.lax.top_k(s + p["router_bias"][idx], cfg.num_experts_per_token)
+    w = jnp.take_along_axis(s, topi, axis=-1)
+    if cfg.moe_renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling_factor, topi
+
+
+def _expert_weights(p: Params, name: str, idx: int, dtype):
+    w = p[name][idx]
+    scale = p[name + "_scale"][idx] if w.dtype == jnp.int8 else None
+    return w.astype(dtype), scale
+
+
+def moe_local_dense(p: Params, x: jax.Array, combine: jax.Array, idx: int):
+    """Every held expert over every token, weighted by ``combine`` [N, E]
+    (0 where a token did not choose the expert): the form for a few rows,
+    where each expert's weights cross HBM once whatever was chosen."""
+    def edot(eq, a, name):
+        w, scale = _expert_weights(p, name, idx, a.dtype)
+        y = _einsum_f32(eq, a, w)
+        return y if scale is None else y * scale[:, None, :]
+
+    with jax.named_scope("moe_experts"):
+        gate = edot("nd,edf->enf", x, "we_gate")
+        up = edot("nd,edf->enf", x, "we_up")
+        mid = (jax.nn.silu(gate) * up).astype(x.dtype)
+        return jnp.einsum("end,ne->nd", edot("enf,efd->end", mid, "we_down"),
+                          combine)
+
+
+def moe_local_grouped(p: Params, x: jax.Array, w: jax.Array, local_e: jax.Array,
+                      idx: int, E: int):
+    """Assignments sorted by held expert, then grouped matmuls
+    (``ragged_dot``) over each expert's run of rows: work and weight
+    reads follow the rows assigned. ``local_e`` [N, k]: the held expert's
+    index, or E for an assignment another shard holds (sorted last, in no
+    group, its weight already 0)."""
+    N, k = local_e.shape
+    flat = local_e.reshape(-1)
+    order = jnp.argsort(flat)
+    sorted_e = flat[order]
+    xs = jnp.take(x, order // k, axis=0)
+    sizes = jnp.bincount(sorted_e, length=E + 1)[:E].astype(jnp.int32)
+    held = sorted_e < E
+    cpu = jax.default_backend() == "cpu"
+
+    def gdot(a, name):
+        # the upcast copy of the layer's experts (XLA does not fuse it
+        # into the grouped matmul) must not be made before its rows
+        # exist: tied to them, one layer's copies live at a time — left
+        # free, the compiler hoists every layer's to the step's start
+        # (8 GB at the published widths, refused by the chip's compiler)
+        stack, a = jax.lax.optimization_barrier((p[name], a))
+        w8 = stack[idx]
+        scale = p[name + "_scale"][idx] if w8.dtype == jnp.int8 else None
+        wt = w8.astype(jnp.float32 if cpu else a.dtype)
+        y = jax.lax.ragged_dot(a.astype(wt.dtype), wt, sizes,
+                               preferred_element_type=jnp.float32)
+        if scale is not None:
+            y = y * jnp.take(scale, jnp.minimum(sorted_e, E - 1), axis=0)
+        return y
+
+    with jax.named_scope("moe_experts"):
+        mid = jax.nn.silu(gdot(xs, "we_gate")) * gdot(xs, "we_up")
+        out = jnp.where(held[:, None], gdot(mid.astype(x.dtype), "we_down"), 0)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
+    out = jnp.take(out, inv, axis=0).reshape(N, k, -1)
+    return jnp.sum(out * w[..., None], axis=1)
+
+
+def moe_ffn(cfg: ModelConfig, g: Geometry, p: Params, h: jax.Array,
+            idx: int, valid: Optional[jax.Array] = None,
+            h_route: Optional[jax.Array] = None):
+    """This process's part of the expert layer: its own experts' share of
+    the routed sum (what other shards' experts add is theirs to compute)
+    plus the shared expert. Returns (out, counts int32 [3]): this call,
+    the assignments of real tokens (``valid`` [B, T]; padding is not
+    traffic) to held experts, and the held experts they touched.
+    ``h_route``: the same hidden state before it was rounded to the
+    activation dtype — the router reads that one (a choice among 256
+    near-equal scores turns on the last bits)."""
+    B, T, D = h.shape
+    x = h.reshape(B * T, D)
+    w, topi = moe_routing(
+        cfg, p, x if h_route is None else h_route.reshape(B * T, D), idx)
+    local = (topi >= g.e0) & (topi < g.e0 + g.E)
+    w = jnp.where(local, w, 0.0)
+    local_e = jnp.where(local, topi - g.e0, g.E)
+    real = local if valid is None else local & valid.reshape(B * T, 1)
+    touched = jnp.zeros((g.E + 1,), jnp.int32).at[
+        jnp.where(real, local_e, g.E)].max(1)[: g.E]
+    counts = jnp.stack([jnp.int32(1), jnp.sum(real, dtype=jnp.int32),
+                        jnp.sum(touched, dtype=jnp.int32)])
+    if B * T <= MOE_DENSE_TOKENS:
+        combine = jnp.zeros((B * T, g.E + 1), jnp.float32).at[
+            jnp.arange(B * T)[:, None], local_e].add(w)[:, : g.E]
+        routed = moe_local_dense(p, x, combine, idx)
+    else:
+        routed = moe_local_grouped(p, x, w, local_e, idx, g.E)
+    shared = _gated_mlp(p, ("ws_gate", "ws_up", "ws_down"), h, idx)
+    return routed.reshape(B, T, D) + shared.astype(jnp.float32), counts
+
+
+# ---------------------------------------------------------------------------
+# The step
+# ---------------------------------------------------------------------------
+
+
+def forward(
+    cfg: ModelConfig,
+    params: Params,
+    pages: dict,              # {"latent": [Lm, slots, Cpad]}
+    state: dict,              # {"kda": [Lk, S, H, d, d], "conv": [Lk, S, 3 * 3HD], "counts": [3]}
+    tokens: jax.Array,        # [B, T]
+    positions: jax.Array,     # [B, T] (padded: 0)
+    slot_mapping: jax.Array,  # [B*T] flat page slots (padded: 0)
+    block_tables: jax.Array,  # [B, pages + 1]: the LAST column is the state slot
+    context_lens: jax.Array,  # [B] valid tokens incl. the new ones
+    last_token_idx: jax.Array,
+    block_size: int,
+    extra_embeds: Optional[jax.Array] = None,
+    embeds_mask: Optional[jax.Array] = None,
+    logits_all: bool = False,
+):
+    """One model step: (logits [B, V], pages, state). Same contract as
+    ``models/llama.py`` ``forward``; the engine threads ``pages`` and
+    ``state`` where it threads K and V."""
+    if extra_embeds is not None or logits_all:
+        raise NotImplementedError(
+            "kimi_linear: no injected embeddings, no all-position logits")
+    g = Geometry(cfg)
+    B, T = tokens.shape
+    eps = cfg.rms_norm_eps
+    tables, sslot = block_tables[:, :-1], block_tables[:, -1]
+    start = positions[:, 0]
+    n_valid = jnp.clip(context_lens - start, 0, T)            # [B]
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]         # [B, T]
+    fresh = start == 0                                        # zero state in
+    latent, kda_plane, conv_plane = pages["latent"], state["kda"], state["conv"]
+    counts = state["counts"]
+    # the residual stream is float32 (the layers add small terms to a
+    # large sum: rounding it to bf16 at every add is the first thing that
+    # turns a near-tie of the router over); the layers read it rounded to
+    # the activation dtype, as the matmul kernels take it
+    x = llama.embed_lookup(params, tokens)
+    act = x.dtype
+    x = x.astype(jnp.float32)
+
+    def kda_mixer(h, ki, kda_plane, conv_plane):
+        qkv = jnp.concatenate(
+            [_mm(params, n, h, ki) for n in ("kda_wq", "kda_wk", "kda_wv")], -1)
+        tail = jnp.where(fresh[:, None, None], 0, conv_plane[ki, sslot].reshape(
+            B, g.kernel - 1, 3 * g.HD))
+        full = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)  # float32
+        K1 = g.kernel
+        cw = params["kda_conv"][ki]                            # [K1, 3HD]
+        y = sum(full[:, i:i + T].astype(jnp.float32) * cw[i] for i in range(K1))
+        # the last K1-1 VALID inputs: input j sits at full[j + K1 - 1]
+        rows = n_valid[:, None] + jnp.arange(K1 - 1)[None, :]
+        new_tail = jnp.take_along_axis(full, rows[:, :, None], axis=1)
+        q, k, v = (a.reshape(B, T, g.Hl, g.dl)
+                   for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
+            * g.dl ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+        glog = kda_decay_log(params, _mm(
+            params, "kda_wfb", _mm(params, "kda_wfa", h, ki).astype(act), ki), ki, g)
+        beta = jax.nn.sigmoid(_mm(params, "kda_wb", h, ki).astype(jnp.float32))
+        glog = jnp.where(valid[:, :, None, None], glog, 0.0)
+        beta = jnp.where(valid[:, :, None], beta, 0.0)
+        if T == 1 and kernels_active():
+            # in place on the plane: no gather before, no scatter after
+            from dynamo_tpu.ops.kda import kda_decode_update
+
+            o, kda_plane = kda_decode_update(
+                kda_plane, jnp.int32(ki), sslot, fresh, q[:, 0], k[:, 0],
+                v[:, 0], glog[:, 0], beta[:, 0],
+                interpret=jax.default_backend() != "tpu")
+            o = o[:, None]
+        else:
+            S = jnp.where(fresh[:, None, None, None], 0.0, kda_plane[ki, sslot])
+            if T == 1:
+                o, S = kda_decode(
+                    q[:, 0], k[:, 0], v[:, 0], glog[:, 0], beta[:, 0], S)
+                o = o[:, None]
+            else:
+                o, S = kda_chunked(q, k, v, glog, beta, S)
+            kda_plane = kda_plane.at[ki, sslot].set(S)
+        conv_plane = conv_plane.at[ki, sslot].set(
+            new_tail.reshape(B, -1).astype(conv_plane.dtype))
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) \
+            * params["kda_onorm"][ki]
+        gate = _mm(params, "kda_wgb", _mm(params, "kda_wga", h, ki).astype(act), ki)
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32).reshape(o.shape))
+        out = _mm(params, "kda_wo", o.reshape(B, T, g.HD).astype(h.dtype), ki)
+        return out, kda_plane, conv_plane
+
+    def mla_mixer(h, mi, latent):
+        q = _mm(params, "mla_wq", h, mi).astype(act).reshape(
+            B, T, g.H, g.nope + g.rope)
+        kv = _mm(params, "mla_wkva", h, mi)                    # [B, T, C]
+        c = llama.rmsnorm(kv[..., : g.rank], params["mla_kvnorm"][mi], eps)
+        lane_pad = jnp.zeros((B, T, g.Cpad - g.C), c.dtype)
+        row = jnp.concatenate([c, kv[..., g.rank:].astype(c.dtype), lane_pad], -1)
+        latent = latent.at[mi, slot_mapping].set(
+            row.reshape(B * T, g.Cpad).astype(latent.dtype))
+        wkvb = _weight(params, "mla_wkvb", mi, h.dtype).reshape(
+            g.rank, g.H, g.nope + g.vd)
+        # queries absorb the key up-projection: attention runs over the
+        # cached rows [c | k_r] themselves, one shared 576-wide "head"
+        q_lat = jnp.concatenate([
+            jnp.einsum("bthn,chn->bthc", q[..., : g.nope], wkvb[..., : g.nope]),
+            q[..., g.nope:],
+            jnp.zeros((B, T, g.H, g.Cpad - g.C), q.dtype)], axis=-1)  # [B, T, H, Cpad]
+        if T == 1 and kernels_active():
+            # flash decode over the row's own pages, each read once
+            from dynamo_tpu.ops.mla import mla_decode_attention
+
+            o_lat = mla_decode_attention(
+                (q_lat[:, 0].astype(jnp.float32)
+                 / math.sqrt(g.nope + g.rope)).astype(h.dtype),
+                latent, jnp.int32(mi), tables, context_lens,
+                block_size=block_size, rank=g.rank,
+                interpret=jax.default_backend() != "tpu")[:, None]
+            o = jnp.einsum("bthc,chv->bthv", o_lat, wkvb[..., g.nope:])
+            return (_mm(params, "mla_wo",
+                        o.reshape(B, T, g.H * g.vd).astype(act), mi), latent)
+        S = tables.shape[1] * block_size
+        slot_ids = (tables[:, :, None] * block_size
+                    + jnp.arange(block_size, dtype=tables.dtype)).reshape(B, S)
+        rows = latent[mi, slot_ids].astype(h.dtype)            # [B, S, Cpad]
+        key_pos = jnp.arange(S, dtype=jnp.int32)[None, None, None, :]
+        scale = 1.0 / math.sqrt(g.nope + g.rope)
+
+        def attend(q_blk, pos_blk):                            # [B, t, H, C]
+            s = _einsum_f32("bthc,bsc->bhts", q_blk, rows) * scale
+            mask = (key_pos <= pos_blk[:, None, :, None]) & (
+                key_pos < context_lens[:, None, None, None])
+            pr = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+            return jnp.einsum("bhts,bsc->bthc", pr.astype(h.dtype),
+                              rows[..., : g.rank])
+
+        with jax.named_scope("mla_attend"):
+            tq = max(1, min(T, MLA_QUERY_TOKENS // B))
+            if tq >= T:
+                o_lat = attend(q_lat, positions)
+            else:
+                qb = jnp.moveaxis(q_lat.reshape(B, T // tq, tq, g.H, g.Cpad), 1, 0)
+                pb = jnp.moveaxis(positions.reshape(B, T // tq, tq), 1, 0)
+                o_lat = jnp.moveaxis(jax.lax.map(
+                    lambda a: attend(*a), (qb, pb)), 0, 1
+                ).reshape(B, T, g.H, g.rank)
+        o = jnp.einsum("bthc,chv->bthv", o_lat.astype(act), wkvb[..., g.nope:])
+        return (_mm(params, "mla_wo", o.reshape(B, T, g.H * g.vd).astype(act), mi),
+                latent)
+
+    for layer in range(g.L):
+        mixer, mi, ffn, fi = g.kind_index(layer)
+        h = llama.rmsnorm(x, params["attn_norm"][layer], eps).astype(act)
+        if mixer == "kda":
+            out, kda_plane, conv_plane = kda_mixer(h, mi, kda_plane, conv_plane)
+        else:
+            out, latent = mla_mixer(h, mi, latent)
+        x = x + out.astype(jnp.float32)
+        h32 = llama.rmsnorm(x, params["mlp_norm"][layer], eps)
+        h = h32.astype(act)
+        if ffn == "dense":
+            out = _gated_mlp(params, ("w_gate", "w_up", "w_down"), h, fi)
+        else:
+            out, seen = moe_ffn(cfg, g, params, h, fi, valid, h32)
+            counts = counts + seen
+        x = x + out.astype(jnp.float32)
+
+    x = llama.rmsnorm(x, params["final_norm"], eps).astype(act)
+    x_last = jnp.take_along_axis(
+        x, last_token_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    return (llama.lm_head(params, x_last), {"latent": latent},
+            {"kda": kda_plane, "conv": conv_plane, "counts": counts})

@@ -86,6 +86,7 @@ def resolve_model(
     pp-sharded layer stacks) and may validate/raise before any weight
     loads. ``quantize="int8"`` applies weight-only int8 at load
     (models/quant.py) regardless of source."""
+    from dynamo_tpu.models import family
     from dynamo_tpu.models.llama import init_params
 
     if quantize not in (None, "int8"):
@@ -113,6 +114,18 @@ def resolve_model(
             else:
                 model_config = ModelConfig.from_dir(model_path)
         specs = specs_fn(model_config) if specs_fn is not None else None
+        fam = family(model_config)
+        if hasattr(fam, "init_params_quantized"):
+            # a family with its own parameters draws them itself
+            if not random_weights:
+                raise NotImplementedError(
+                    f"model_type {model_config.model_type!r}: no checkpoint "
+                    "loader yet (random_weights only)"
+                )
+            log.warning("initializing RANDOM weights (%s)", quantize or "bf16")
+            init = fam.init_params_quantized if quantize == "int8" \
+                else fam.init_params
+            return model_config, init(model_config, seed, mesh, specs)
         if not random_weights and reader is not None:
             from dynamo_tpu.gguf import load_params_from_gguf
 

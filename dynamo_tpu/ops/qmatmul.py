@@ -510,6 +510,7 @@ def _qmm_call(
     act: str,
     interpret: bool,
     tiles: Optional[tuple[int, int, int]],
+    out_dtype=None,
 ) -> jax.Array:
     """THE pallas_call of the family. The weight and scale BlockSpecs
     read the layer from a scalar-prefetch ref, so only the tiles the
@@ -575,7 +576,7 @@ def _qmm_call(
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, lyr: (i, j)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((mp, N), x2.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp, N), out_dtype or x2.dtype),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
     return out[:M] if M != mp else out
@@ -594,6 +595,7 @@ def qmm(
     interpret: bool = False,
     tiles: Optional[tuple[int, int, int]] = None,
     layer: Optional[jax.Array] = None,  # scalar int32 index into L
+    out_dtype=None,  # None = x.dtype; float32 keeps the accumulator's bits
 ) -> jax.Array:
     """y = (x @ w) * scale (+ residual), rounded to x.dtype — the
     in-kernel-dequant replacement for the reference ``mm`` epilogue.
@@ -612,6 +614,7 @@ def qmm(
     y = _qmm_call(
         x2, [w], [scale], layer, r2, kind,
         "residual" if residual is not None else "", "silu", interpret, tiles,
+        out_dtype,
     )
     return y.reshape(*lead, w.shape[-1])
 

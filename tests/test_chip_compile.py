@@ -229,3 +229,51 @@ def test_tp4_attention_compiles_for_v5e(
     # attention is local per KV-head shard: no collective may appear
     for op in ("all-reduce", "all-gather", "all-to-all", "collective-permute"):
         assert f" {op}(" not in text and f" {op}-start(" not in text, op
+
+
+# ---------------------------------------------------------------------------
+# One chip: the kimi_linear family's own kernels at its published widths
+# (32 KDA heads of 128, 7 KDA layers over 65 state slots; 576-wide latent
+# rows, rank 512, 2 MLA layers; hidden 2304) at the decode buckets 4 / 64
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_kda_decode_update_compiles_for_v5e(rows, one_chip, no_compile_cache):
+    from dynamo_tpu.ops.kda import kda_decode_update
+
+    vec = _sds((rows, 32, 128), jnp.float32, one_chip)
+    ids = _sds((rows,), jnp.int32, one_chip)
+    text = _compile_text(
+        kda_decode_update, _sds((7, 65, 32, 128, 128), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip), ids, ids, vec, vec, vec, vec,
+        _sds((rows, 32), jnp.float32, one_chip))
+    assert "tpu_custom_call" in text and "kda_decode_update" in text
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_mla_decode_attention_compiles_for_v5e(rows, one_chip, no_compile_cache):
+    from dynamo_tpu.ops.mla import mla_decode_attention
+
+    text = _compile_text(
+        functools.partial(mla_decode_attention, block_size=BS, rank=512),
+        _sds((rows, 32, 576), jnp.bfloat16, one_chip),
+        _sds((2, NUM_BLOCKS * BS, 576), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((rows, TABLE_W), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text and "mla_decode_attention" in text
+
+
+@pytest.mark.parametrize("k,n", [(2304, 4096), (2304, 128), (128, 4096),
+                                 (4096, 2304), (2304, 6144), (512, 8192)])
+@pytest.mark.parametrize("rows", [64, 4096])
+def test_qmm_with_a_float32_result_compiles_for_v5e(
+    rows, k, n, one_chip, no_compile_cache
+):
+    """The family keeps a matmul's accumulator bits (``out_dtype``) at
+    widths the llama shapes do not have."""
+    text = _compile_text(
+        lambda a, b, c, l: qmm(a, b, c, layer=l, out_dtype=jnp.float32),
+        _sds((rows, k), jnp.bfloat16, one_chip), _sds((7, k, n), jnp.int8, one_chip),
+        _sds((7, n), jnp.float32, one_chip), _sds((), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text
