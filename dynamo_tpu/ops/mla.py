@@ -1,6 +1,6 @@
 """Latent-attention decode over the paged latent cache, as one Pallas
-flash-decode kernel (the pattern of ops/paged_attention.py
-``_decode_kernel_stacked``).
+flash-decode kernel over a (row, table column) grid, a page a step: what
+ops/paged_attention.py's decode kernel was before PR 30 (PERF.md §7).
 
 The cache holds ONE row a token a layer: ``[c | k_r]``, the normalised
 latent (``rank`` values) and the shared, unrotated key part (``rope``

@@ -131,14 +131,17 @@ def _qmm_case(kind: str, m: int, sh, stacked: bool = False):
     return qmm_lm_head, (x(D), w(D, V), s(V))
 
 
-def _attn_shapes(prefill: bool, b: int, t: int, int8: bool, shard):
+def _attn_shapes(prefill: bool, b: int, t: int, int8: bool, shard,
+                 heads: tuple[int, int] = (H, HK)):
     """Argument shapes of the stacked attention kernels, in the
     kernels' own order. ``shard(spec)`` maps a PartitionSpec to the
-    sharding each argument carries (one chip: the same for all)."""
+    sharding each argument carries (one chip: the same for all);
+    ``heads`` = (query heads, KV heads)."""
+    h, hk = heads
     cdt = jnp.int8 if int8 else jnp.bfloat16
-    qshape = (b, t, H, DH) if prefill else (b, H, DH)
+    qshape = (b, t, h, DH) if prefill else (b, h, DH)
     qspec = P(None, None, "tp", None) if prefill else P(None, "tp", None)
-    cache = _sds((L, NUM_BLOCKS * BS, HK, DH), cdt, shard(llama.CACHE_SPEC))
+    cache = _sds((L, NUM_BLOCKS * BS, hk, DH), cdt, shard(llama.CACHE_SPEC))
     out = [
         _sds(qshape, jnp.bfloat16, shard(qspec)),
         cache, cache,
@@ -149,7 +152,7 @@ def _attn_shapes(prefill: bool, b: int, t: int, int8: bool, shard):
         out.append(_sds((b,), jnp.int32, shard(P())))  # start_pos
     out.append(_sds((b,), jnp.int32, shard(P())))  # context_lens
     if int8:
-        scale = _sds((L, NUM_BLOCKS, HK, BS), jnp.float32,
+        scale = _sds((L, NUM_BLOCKS, hk, BS), jnp.float32,
                      shard(llama.SCALE_SPEC))
         out += [scale, scale]
     return out
@@ -179,9 +182,18 @@ _QMM_CASES = [
     pytest.param("qmm", "lm_head", m, id=f"qmm-lm_head-M{m}")
     for m in (8, 64, 512)
 ]
+# (query heads, KV heads) of the decode cases: Llama / Mistral 32/8
+# (G 4) and Qwen2.5-7B 28/4 (G 7: no sublane multiple)
+_DECODE_HEADS = {"decode": (H, HK), "decode-qwen": (28, 4)}
 _ATTN_CASES = [
     pytest.param("decode", cache, b, id=f"attn-decode-{cache}-B{b}")
     for cache in ("bf16", "int8") for b in (4, 64)
+] + [
+    # the rows the benchmark's cells decode at (chat 8, sessions 32,
+    # decode-heavy 64), table width 40, at both served geometries
+    pytest.param(family, cache, b, id=f"attn-{family}-{cache}-B{b}")
+    for family, rows in (("decode", (8, 32)), ("decode-qwen", (8, 32, 64)))
+    for cache in ("bf16", "int8") for b in rows
 ] + [
     pytest.param("prefill", cache, bt, id=f"attn-prefill-{cache}-{bt[0]}x{bt[1]}")
     for cache in ("bf16", "int8") for bt in ((1, 1024), (32, 128))
@@ -201,7 +213,10 @@ def test_kernel_compiles_for_v5e(
         b, t = size if prefill else (size, 1)
         int8 = variant == "int8"
         fn = _attn_kernel(prefill, int8)
-        shapes = _attn_shapes(prefill, b, t, int8, lambda spec: one_chip)
+        shapes = _attn_shapes(
+            prefill, b, t, int8, lambda spec: one_chip,
+            heads=_DECODE_HEADS.get(family, (H, HK)),
+        )
     assert "tpu_custom_call" in _compile_text(fn, *shapes)
 
 

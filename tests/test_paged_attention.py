@@ -143,28 +143,142 @@ def test_prefill_kernel_multi_tile():
     )
 
 
+def _quantized(k, bs):
+    """Float [S, Hk, Dh] -> (int8 values, scales [N, Hk, bs]): the
+    int8 cache as ops/kv_quant.py stores it."""
+    from tests.test_kv_quant import _quantize_layer, _scales_to_layout
+
+    q8, sc = _quantize_layer(k)
+    return q8, jnp.asarray(_scales_to_layout(sc, bs))
+
+
 @pytest.mark.parametrize(
-    "B,H,Hk,ctx_lens",
+    "B,H,Hk,ctx_lens,bs,pages,cache",
     [
-        (2, 4, 2, [7, 29]),  # GQA, ragged contexts
-        (1, 4, 4, [16]),  # MHA, exactly block-aligned
-        (3, 8, 1, [1, 33, 5]),  # MQA, ctx=1 edge
-        (2, 4, 2, [40, 0]),  # padded row (ctx=0)
+        # pages None: a compute block sized from the geometry
+        (2, 4, 2, [7, 29], 16, None, "f32"),  # GQA, ragged contexts
+        (1, 4, 4, [16], 16, None, "f32"),  # MHA, exactly block-aligned
+        (3, 8, 1, [1, 33, 5], 16, None, "f32"),  # MQA, ctx=1 edge
+        (2, 4, 2, [40, 0], 16, None, "f32"),  # padded row (ctx=0)
+        # a block of `pages` pages: contexts of 1, exactly one block,
+        # one key short of it and one key past it (a second block that
+        # holds a single key), two blocks and a key
+        (5, 4, 2, [1, 32, 31, 33, 65], 16, 2, "f32"),
+        # rows of context 0 between live rows: no block runs for them,
+        # and the live row behind one starts its own first block
+        (6, 4, 2, [40, 0, 7, 0, 0, 65], 16, 2, "f32"),
+        (3, 4, 2, [0, 0, 50], 16, 2, "f32"),  # dead rows first
+        (3, 4, 2, [100, 3, 64], 16, 1, "f32"),  # one page a block
+        (2, 4, 2, [100, 64], 16, 3, "f32"),  # blocks that split unevenly
+        # the served head geometries at the served page size
+        (3, 32, 8, [1, 256, 257], 128, 2, "f32"),  # Llama / Mistral
+        (3, 28, 4, [255, 0, 300], 128, 2, "f32"),  # Qwen2.5: G = 7
+        # a tp=4 shard of each: 2 and 1 KV heads
+        (2, 8, 2, [33, 200], 128, 2, "f32"),
+        (2, 7, 1, [129, 64], 128, 2, "f32"),
+        # int8 cache: scales spread over the (token, head) columns
+        (3, 8, 4, [23, 37, 0], 16, 2, "int8"),
+        (2, 32, 8, [130, 256], 128, 2, "int8"),
+        (2, 28, 4, [257, 90], 128, 2, "int8"),
+        (2, 8, 2, [40, 129], 128, None, "int8"),
+        (2, 7, 1, [300, 5], 128, 2, "int8"),
     ],
 )
-def test_decode_kernel_matches_reference(B, H, Hk, ctx_lens):
-    Dh, bs, num_blocks = 128, 16, 16
+def test_decode_kernel_matches_reference(B, H, Hk, ctx_lens, bs, pages, cache):
+    Dh = 128
+    num_blocks = sum(-(-c // bs) for c in ctx_lens) + 2
     q, k, v, tables, ctx = _setup(B, H, Hk, Dh, num_blocks, bs, ctx_lens)
-    out = paged_attention_decode(q, k, v, tables, ctx, bs, interpret=True)
+    scales = {}
+    if cache == "int8":
+        (k, ks), (v, vs) = _quantized(k, bs), _quantized(v, bs)
+        scales = dict(k_scale=ks, v_scale=vs)
+    out = paged_attention_decode(
+        q, k, v, tables, ctx, bs, interpret=True, pages_per_block=pages,
+        **scales,
+    )
     # reference wants [B, T, H, Dh] and per-token positions
     positions = jnp.maximum(ctx - 1, 0)[:, None]  # decode: last position
     ref = paged_attention_reference(
-        q[:, None], k, v, tables, positions, ctx, bs
+        q[:, None],
+        (k, scales["k_scale"]) if scales else k,
+        (v, scales["v_scale"]) if scales else v,
+        tables, positions, ctx, bs,
     )[:, 0]
     valid = np.asarray(ctx) > 0
+    tol = 5e-2 if scales else 2e-2
     np.testing.assert_allclose(
-        np.asarray(out)[valid], np.asarray(ref)[valid], rtol=2e-2, atol=2e-2
+        np.asarray(out)[valid], np.asarray(ref)[valid], rtol=tol, atol=tol
     )
+    # a row of context 0 attends nothing: zeros, never NaN
+    assert not np.asarray(out)[~valid].any()
+
+
+@pytest.mark.parametrize("fill", ["nan", "out_of_range"])
+def test_decode_kernel_never_reads_dead_table_columns(fill):
+    """Table columns past a row's last live page (and before its
+    window's first) are never dereferenced: NaN-filled pages there, or
+    page ids far outside the pool, leave the result as it was."""
+    Dh, bs, H, Hk, P = 128, 16, 4, 2, 2
+    ctx_lens = [37, 0, 16, 70]
+    window = 24
+    num_blocks = 24
+    q, k, v, tables, ctx = _setup(4, H, Hk, Dh, num_blocks, bs, ctx_lens)
+    W = 12
+    clean = np.zeros((4, W), np.int32)
+    clean[:, : tables.shape[1]] = np.asarray(tables)
+    want = paged_attention_decode(
+        q, k, v, jnp.asarray(clean), ctx, bs, sliding_window=window,
+        interpret=True, pages_per_block=P,
+    )
+    dirty = clean.copy()
+    nan_page = num_blocks - 1  # no row's live page
+    k = k.at[nan_page * bs:].set(jnp.nan)
+    v = v.at[nan_page * bs:].set(jnp.nan)
+    for b, c in enumerate(ctx_lens):
+        lo = max(c - window, 0)
+        live = range(lo // bs, -(-c // bs)) if c else range(0)
+        for j in range(W):
+            if j not in live:
+                dirty[b, j] = nan_page if fill == "nan" else 2**30 + j
+    got = paged_attention_decode(
+        q, k, v, jnp.asarray(dirty), ctx, bs, sliding_window=window,
+        interpret=True, pages_per_block=P,
+    )
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_decode_kernel_grid_has_no_table_axis():
+    """The lowered pallas_call's grid is the rows alone, whatever the
+    block table's width: no step is spent on a dead column."""
+    from dynamo_tpu.ops.paged_attention import paged_attention_decode_stacked
+
+    def grids(W):
+        B, H, Hk, Dh, bs = 4, 8, 2, 128, 16
+        cache = jax.ShapeDtypeStruct((2, 64 * bs, Hk, Dh), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(
+            lambda q, kc, vc, lyr, t, c: paged_attention_decode_stacked(
+                q, kc, vc, lyr, t, c, block_size=bs, interpret=True
+            )
+        )(
+            jax.ShapeDtypeStruct((B, H, Dh), jnp.bfloat16), cache, cache,
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((B, W), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+        )
+        found = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append(tuple(eqn.params["grid_mapping"].grid))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jaxpr.jaxpr)
+        return found
+
+    assert grids(8) == grids(40) == [(4,)]
 
 
 def test_decode_kernel_bf16():
@@ -183,17 +297,23 @@ def test_decode_kernel_bf16():
     )
 
 
-@pytest.mark.parametrize("window,ctx_lens", [
-    (8, [7, 29]),     # window < block_size
-    (16, [40, 33]),   # window == block_size
-    (24, [50, 3]),    # window spans pages; one ctx inside window
+@pytest.mark.parametrize("window,ctx_lens,pages", [
+    (8, [7, 29], None),     # window < block_size
+    (16, [40, 33], None),   # window == block_size
+    (24, [50, 3], None),    # window spans pages; one ctx inside window
+    # the window opens inside the context, on a page that starts no
+    # block of the table: the walk begins at the window's first page
+    (40, [100, 37], 2),
+    (33, [97, 64], 1),
+    (64, [129, 70], 3),
 ])
-def test_decode_kernel_sliding_window(window, ctx_lens):
+def test_decode_kernel_sliding_window(window, ctx_lens, pages):
     Dh, bs, num_blocks = 128, 16, 16
     B, H, Hk = 2, 4, 2
     q, k, v, tables, ctx = _setup(B, H, Hk, Dh, num_blocks, bs, ctx_lens)
     out = paged_attention_decode(
-        q, k, v, tables, ctx, bs, sliding_window=window, interpret=True
+        q, k, v, tables, ctx, bs, sliding_window=window, interpret=True,
+        pages_per_block=pages,
     )
     positions = jnp.maximum(ctx - 1, 0)[:, None]
     ref = paged_attention_reference(
