@@ -65,7 +65,7 @@ EXPECT = {
     # each confined worker holds a chip of its own (--chips 4)
     "distinct_chips": True,
 }
-# Llama-3.1-8B / DeepSeek-R1-Distill-Llama-8B (bench.py _build_config)
+# Llama-3.1-8B / DeepSeek-R1-Distill-Llama-8B
 GEOMETRY = dict(
     vocab_size=128256, hidden_size=4096, intermediate_size=14336,
     num_hidden_layers=32, num_attention_heads=32, num_key_value_heads=8,

@@ -3,9 +3,8 @@ an SSE writer loop.
 
 The frontend's chunk path (http/service.py ``_stream_sse``) runs once
 per delta for EVERY open stream on ONE event loop — at the fan-out
-ceiling (``bench.py --fanout``) a microsecond of per-chunk work is
-multiplied by thousands of streams times hundreds of chunks, and a
-MILLISECOND of synchronous work is a loop stall every stream observes
+ceiling a microsecond of per-chunk work is multiplied by thousands of
+streams times hundreds of chunks, and a MILLISECOND of synchronous work is a loop stall every stream observes
 (telemetry/hostplane.py measures exactly this). Three families of work
 do not belong inside the chunk loop:
 

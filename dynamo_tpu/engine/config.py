@@ -107,11 +107,11 @@ class EngineConfig:
     # decode-occupancy ceiling for the wide rectangle (None = no
     # ceiling, the default): the wide and narrow rectangles have the
     # SAME padded token budget, so when at most wide_rows prompts are
-    # prefilling the wide swap costs decode nothing at any occupancy —
-    # measured at ISL-3000 c=16: 123.6 -> 138.2 out tok/s, p50 TTFT
-    # 17.7 -> 10.8 s when the old ceiling of 4 was lifted. The real
-    # guards are the prefilling-count (<= wide_rows) and backlog
-    # (> narrow len) conditions in scheduler._mixed_rect.
+    # prefilling the wide swap costs decode nothing at any occupancy
+    # (which is why the old ceiling of 4 was lifted; not measured on
+    # the attached chip). The real guards are the prefilling-count
+    # (<= wide_rows) and backlog (> narrow len) conditions in
+    # scheduler._mixed_rect.
     mixed_wide_max_running: Optional[int] = None
     # speculative decoding (dynamo_tpu/spec; needs decode_steps == 1 —
     # fused windows and speculation are competing multi-token-per-
@@ -137,8 +137,9 @@ class EngineConfig:
     # overlap on or off (the compute is the same program over the same
     # values; only the host's position in the timeline moves).
     # False (--no-overlap) restores the fully serial
-    # plan -> dispatch -> sync -> emit loop — the escape hatch and the
-    # A/B baseline (bench.py --overlap).
+    # plan -> dispatch -> sync -> emit loop — the escape hatch, and
+    # the side tests/test_overlap.py compares dispatch and sync counts
+    # against.
     overlap: bool = True
     # explicit MID decode bucket override (None = auto: pad/2 when the
     # pad is >= 64). Deployments whose steady population sits well

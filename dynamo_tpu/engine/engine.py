@@ -268,14 +268,14 @@ class JaxEngine:
         # degradation.py): flipped from the asyncio thread, read by the
         # engine thread each step — a plain bool attr is race-free here
         self.spec_suspended = False
-        self.spec_proposed_total = 0  # bench/introspection counters
+        self.spec_proposed_total = 0  # introspection counters
         self.spec_accepted_total = 0
         # overlapped spec pipeline accounting (docs/speculative_decoding.md):
         # wall seconds of host drafting hidden under device execution
         # (optimistic pre-drafts) vs exposed on the dispatch critical
         # path (first-step drafts + harvest-time repairs), and how often
         # the pre-draft's predicted tail matched the realized one.
-        # Engine-thread writes; bench//debug/state read advisorily.
+        # Engine-thread writes; /debug/state reads advisorily.
         self.spec_draft_hidden_s_total = 0.0
         self.spec_draft_exposed_s_total = 0.0
         self.spec_predraft_hits = 0
@@ -324,8 +324,8 @@ class JaxEngine:
         )
         # overlapped decode pipeline (docs/performance.md): device
         # idle-gap accounting feeding the flight recorder's
-        # idle_gap_ms stamps, /debug/state "overlap", and bench.py's
-        # device_idle_frac. Engine-thread only.
+        # idle_gap_ms stamps and /debug/state "overlap". Engine-thread
+        # only.
         self.overlap = OverlapTracker()
         self.slo = SloTracker(
             SloConfig(ttft_ms=config.slo_ttft_ms, itl_ms=config.slo_itl_ms)
@@ -368,7 +368,7 @@ class JaxEngine:
         remote_kv_objects=None,
     ) -> "JaxEngine":
         """``model_config`` injection skips reading config.json from
-        model_path (benchmarks / synthetic model shapes).
+        model_path (synthetic model shapes).
         ``remote_kv_objects``: a kvbm SyncObjectStore backing the G4
         remote tier when config.remote_kv_bucket is set."""
         engine = cls(config)
@@ -566,8 +566,8 @@ class JaxEngine:
         )
         self.eos_token_ids = self.model_config.eos_token_ids
         # install the attribution ledger's byte model: geometry + quant
-        # + kv dtype are now final, so the live roofline denominator is
-        # computed from the same formula bench.py prints (roofline.py)
+        # + kv dtype are now final, so the live roofline denominator
+        # (roofline.py) can be computed
         from dynamo_tpu.telemetry.roofline import build_roofline
 
         self.attribution.configure(build_roofline(
@@ -859,7 +859,8 @@ class JaxEngine:
             prewarm = jax.default_backend() == "tpu"
         # without a prewarm nothing compiles before the first request:
         # have the qmatmul tilings verified by the compiler now instead
-        self._ensure_qmatmul_tuned(verify=not prewarm)
+        if not prewarm:
+            self._verify_qmatmul_compiles()
         self._build_step_fn()
         self._gate_kv_offload()
         if prewarm:
@@ -914,21 +915,26 @@ class JaxEngine:
         sequence that can be admitted, and the garbage slot 0."""
         return self.config.max_batch_size + 1
 
-    def _ensure_qmatmul_tuned(self, verify: bool = False) -> None:
-        """Resolve tile configs for every qmatmul shape the step
-        functions can reach, BEFORE those functions trace — the tile
-        choice is a trace-time constant, so a tuned entry landing after
-        tracing would never be used. Reads the on-disk tune table;
-        with DYN_QMATMUL_TUNE=1 on TPU, missing shapes are measured and
-        persisted here (one-time cost, then cached). The step-shape
-        prewarm below then compiles the kernels as part of the jitted
-        steps — no separate kernel warmup is needed."""
+    def _verify_qmatmul_compiles(self) -> None:
+        """Hand the chip's compiler every qmatmul shape the step
+        functions can reach, with the tiling ``default_tiles`` gives it
+        (nothing runs, nothing is allocated), so that a tiling the
+        compiler refuses fails at start-up and not at the first
+        request. Only called when no prewarm will compile the step
+        functions themselves. The recurrent-state family is not
+        covered: its unrolled layers call ``qmm`` per weight with a
+        float32 result (models/kimi_linear.py ``_mm``), which this
+        llama-family shape list does not describe; its kernels at
+        published widths are compiled by tests/test_chip_compile.py."""
         from dynamo_tpu.models.llama import pallas_matmul_active
 
-        if not pallas_matmul_active() or self.config.quantization != "int8":
+        if (
+            jax.default_backend() != "tpu"
+            or not pallas_matmul_active()
+            or self.config.quantization != "int8"
+            or self.model_config.has_recurrent_state
+        ):
             return
-        if self.model_config.has_recurrent_state:
-            return  # that family's shapes take the heuristic tiles
         mc, sched = self.model_config, self.scheduler
         assert mc is not None and sched is not None
         D, F, V = mc.hidden_size, mc.intermediate_size, mc.vocab_size
@@ -966,11 +972,10 @@ class JaxEngine:
             lm_ms |= {b * (self.config.spec_tokens + 1) for b in decode_buckets}
         for m in sorted(lm_ms):
             shapes.append((m, D, V, "lm_head"))
-        from dynamo_tpu.ops import qmatmul
+        from dynamo_tpu.ops.qmatmul import verify_compiles
 
-        qmatmul.ensure_tuned(
-            shapes, verify=verify, layers=mc.num_hidden_layers
-        )
+        for m, K, N, kind in shapes:
+            verify_compiles(m, K, N, kind, layers=mc.num_hidden_layers)
 
     def _prewarm(self) -> None:
         """Compile every serving-path shape variant NOW, before the
@@ -5197,7 +5202,7 @@ class JaxEngine:
         if self._thread is None or not self._thread.is_alive():
             # give the device memory back NOW, not whenever the last
             # reference to this engine dies: a second engine in the same
-            # process (bench.py A/Bs, tests) must be able to allocate
+            # process (A/B tests) must be able to allocate
             # its weights and cache on the same chip. The cache is this
             # engine's alone and is deleted; the weights may be shared
             # with the caller, so only the reference is dropped.

@@ -51,10 +51,6 @@ COUNT_W = 4096
 # (OpenAI caps top_logprobs at 20)
 TOPLP_N = 20
 
-# Back-compat aliases (tests/benchmarks referenced the bucket lists)
-BIAS_BUCKETS = [BIAS_W]
-COUNT_BUCKETS = [COUNT_W]
-
 
 @dataclass
 class SamplingBatch:

@@ -535,6 +535,7 @@ class HttpService:
                     400, f"invalid request: {exc}", model, endpoint, rid
                 )
             except Exception as exc:
+                ctx.kill()  # whatever failed, nothing keeps generating for it
                 log.exception("engine failure for %s", model)
                 return self._error(
                     500, f"engine error: {exc}", model, endpoint, rid
@@ -570,11 +571,14 @@ class HttpService:
         if rid:
             headers[REQUEST_ID_HEADER] = rid
         resp = web.StreamResponse(status=200, headers=headers)
-        await resp.prepare(request)
-        self.hostplane.mark_stream(rid)
         first = True
         status = "200"
         try:
+            # inside the try: a client that left while its first chunk
+            # was primed makes prepare() raise on the closing transport,
+            # and the generation it leaves behind is killed like any other
+            await resp.prepare(request)
+            self.hostplane.mark_stream(rid)
             async for chunk in stream:
                 if first:
                     HTTP_TTFT.labels(model).observe(time.monotonic() - start)

@@ -146,10 +146,8 @@ class KvScheduler:
         # optimistic in-flight accounting: published metrics lag by a
         # publish interval, so a BURST of concurrent no-overlap requests
         # would all see identical zero-load snapshots and (modulo the
-        # random tie-break) pile onto few workers — measured as a 1.7x
-        # first-turn TTFT p50 penalty vs round-robin on a 6-user burst
-        # (benchmarks/router_ab_bench.py). Every schedule() charges its
-        # decision as one waiting request, and the charge expires as
+        # random tie-break) pile onto few workers. Every schedule()
+        # charges its decision as one waiting request, and the charge expires as
         # soon as the worker publishes a metrics snapshot NEWER than
         # the dispatch (the snapshot then reflects the request itself —
         # keeping the charge would double-count it for the whole

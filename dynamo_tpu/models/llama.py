@@ -552,9 +552,7 @@ def post_attn_mlp(
     layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Everything after attention: output projection + MLP/MoE residual
-    — ONE copy shared by every attention variant AND the bench's
-    per-phase microbenches (bench.py --phases), so the measured matmul
-    composition can never drift from the served one. ``a`` is the
+    — ONE copy shared by every attention variant. ``a`` is the
     flattened attention output [B, T, H*Dh].
 
     Under the Pallas matmul impl (int8 weights) the decode hot path
@@ -567,8 +565,8 @@ def post_attn_mlp(
     ``layer``: the scan's layer index. In ``forward`` the int8 matrices
     of ``lp`` are the whole stacked ``[L, K, N]`` arrays and the kernels
     read layer ``layer`` out of them (see :func:`mm`); callers that
-    hold one layer's ``[K, N]`` slices (bench.py --phases, the pipeline
-    stage loop) pass none."""
+    hold one layer's ``[K, N]`` slices (the pipeline stage loop) pass
+    none."""
     if fused_mlp_ok(cfg, lp):
         from dynamo_tpu.ops.qmatmul import qmm, qmm_gate_up
 
@@ -989,7 +987,7 @@ def forward(
 
 def lm_head(p: Params, x: jax.Array) -> jax.Array:
     """Final-hidden → f32 logits. Int8 tables under the Pallas impl go
-    through the vocab-tiled kernel variant (its own tune key — at
+    through the vocab-tiled kernel variant (its own tile rule — at
     V=128256 the LM head is the single largest weight read of a decode
     step); either path rounds through the activation dtype before the
     f32 upcast, so the logits grid is identical."""

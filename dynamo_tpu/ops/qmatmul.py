@@ -24,7 +24,7 @@ Kernel family (one body, flag-specialized like ops/paged_attention.py):
                         never hit HBM);
 - ``qmm_lm_head``    — the vocab-tiled variant: at V=128256 the LM head
                         is the single largest weight read of a decode
-                        step, so N-tiling + a dedicated tune key matter.
+                        step, so it gets N-tiles of its own.
 
 Numerics contract (tests/test_qmatmul.py): int8→bf16 upcast is exact,
 products accumulate in f32, the dequant scale applies in f32, and the
@@ -50,29 +50,21 @@ one pallas_call, no second path.
 
 Grid = (M-tiles, N-tiles, K-tiles), K innermost: the f32 accumulator
 lives in VMEM scratch across K steps and every weight byte is read
-exactly once per M-tile. Tile sizes come from a small autotune table
-keyed on (M-bucket, K, N, kind) with an on-disk JSON cache in the style
-of analysis/cache.py (atomic writes, every failure degrades to the
-heuristic default); ``DYN_QMATMUL_TUNE=1`` measures candidates on real
-hardware at engine prewarm and persists the winners.
+exactly once per M-tile. Tile sizes come from ``default_tiles``, a
+function of (M-bucket, K, N, kind) alone: it reads no file and no
+environment, so one commit runs one tiling wherever it is checked out.
 
 Dispatch lives in ``models.llama.matmul_impl`` (DYN_MATMUL_IMPL =
 auto|reference|pallas, mirroring DYN_ATTN_IMPL); off-TPU the kernels
 run interpreted so tier-1 exercises them on CPU. Multi-device meshes
 keep the reference path: the contraction axis of ``wo``/``w_down`` is
 tp-sharded, and a shard_mapped qmatmul would need its own psum story —
-single-chip decode (the headline bench) is where the weight-bound win
-lives.
+single-chip decode is where the weight-bound win lives.
 """
 
 from __future__ import annotations
 
 import functools
-import json
-import logging
-import os
-import tempfile
-from pathlib import Path
 from typing import Optional
 
 import jax
@@ -80,9 +72,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-log = logging.getLogger(__name__)
-
-# M (token-rows) buckets the tune table is keyed on; the wrapper pads
+# M (token-rows) buckets the tiles are chosen for; the wrapper pads
 # every call up to its bucket (padded rows compute zeros and are sliced
 # off), so one compiled kernel serves each bucket like the engine's
 # batch buckets do.
@@ -101,7 +91,7 @@ def m_bucket(m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Tile selection: heuristic defaults + on-disk autotune table
+# Tile selection
 # ---------------------------------------------------------------------------
 
 
@@ -177,14 +167,8 @@ def default_tiles(mb: int, K: int, N: int, kind: str) -> tuple[int, int, int]:
 
 
 def _valid_tiles(tiles, mb: int, K: int, N: int) -> bool:
-    """A tune-table entry is only trusted if it still describes a legal
-    blocking — corrupt or stale entries degrade to the default."""
-    if not (
-        isinstance(tiles, (list, tuple))
-        and len(tiles) == 3
-        and all(isinstance(t, int) and t > 0 for t in tiles)
-    ):
-        return False
+    """Whether (bm, bn, bk) is a legal blocking of the padded problem:
+    what an explicit ``tiles=`` argument is held to."""
     bm, bn, bk = tiles
     if mb % bm or N % bn or K % bk:
         return False
@@ -196,103 +180,6 @@ def _valid_tiles(tiles, mb: int, K: int, N: int) -> bool:
     if bm != mb and bm % 8:
         return False
     return True
-
-
-def _tune_path() -> Optional[Path]:
-    env = os.environ.get("DYN_QMATMUL_TUNE_DIR")
-    if env:
-        return Path(env) / "tune.json"
-    try:
-        from dynamo_tpu.analysis.config import find_pyproject
-
-        pyproject = find_pyproject(Path(__file__).resolve())
-        if pyproject is not None:
-            return pyproject.parent / ".dynamo_qmatmul" / "tune.json"
-    except Exception:
-        pass
-    return None
-
-
-_table: Optional[dict] = None
-
-
-def _load_table() -> dict:
-    """Entries: {"kind:mb:K:N": [bm, bn, bk]}. Any failure — missing
-    file, bad JSON, wrong schema — degrades to an empty table; the
-    kernel must never be wrong or crash because of the cache."""
-    global _table
-    if _table is None:
-        _table = {}
-        path = _tune_path()
-        if path is not None:
-            try:
-                data = json.loads(path.read_text())
-                if isinstance(data, dict) and data.get("version") == 1:
-                    entries = data.get("entries")
-                    if isinstance(entries, dict):
-                        _table = entries
-            except (OSError, ValueError):
-                _table = {}
-    return _table
-
-
-def _save_table() -> None:
-    path = _tune_path()
-    if path is None or _table is None:
-        return
-    try:
-        path.parent.mkdir(parents=True, exist_ok=True)
-        fd, tmp = tempfile.mkstemp(dir=str(path.parent), suffix=".tmp")
-        with os.fdopen(fd, "w") as f:
-            f.write(json.dumps({"version": 1, "entries": _table}))
-        os.replace(tmp, path)
-    except OSError:
-        pass  # a table that can't persist is just an unwarmed table
-
-
-def _reset_table_for_tests() -> None:
-    global _table
-    _table = None
-
-
-def tune_key(m: int, K: int, N: int, kind: str) -> str:
-    return f"{kind}:{m_bucket(m)}:{K}:{N}"
-
-
-def tile_config(m: int, K: int, N: int, kind: str) -> tuple[int, int, int]:
-    """(bm, bn, bk) for this shape: the tuned entry when one exists and
-    still validates, the heuristic default otherwise."""
-    mb = m_bucket(m)
-    entry = _load_table().get(tune_key(m, K, N, kind))
-    if entry is not None and _valid_tiles(entry, mb, K, N):
-        return tuple(entry)
-    return default_tiles(mb, K, N, kind)
-
-
-def record_tiles(
-    m: int, K: int, N: int, kind: str, tiles: tuple[int, int, int]
-) -> None:
-    table = _load_table()
-    table[tune_key(m, K, N, kind)] = list(tiles)
-    _save_table()
-
-
-def _candidate_tiles(mb: int, K: int, N: int, kind: str):
-    """Small candidate grid around the default (autotune is a table fill,
-    not a search problem — a handful of compiles per shape)."""
-    seen = set()
-    bms = {min(mb, 128), min(mb, 256), min(mb, 512)}
-    bns = {
-        _largest_divisor(N, (c,)) for c in (256, 384, 512, 768, 1024)
-    } | {default_tiles(mb, K, N, kind)[1]}
-    bks = {_largest_divisor(K, (c,)) for c in (128, 256, 512, 1024)}
-    for bm in sorted(bms):
-        for bn in sorted(bns):
-            for bk in sorted(bks):
-                t = (bm, bn, bk)
-                if t not in seen and _valid_tiles(list(t), mb, K, N):
-                    seen.add(t)
-                    yield t
 
 
 def _kind_fn(kind: str, w, s, res, tiles):
@@ -311,69 +198,6 @@ def _kind_fn(kind: str, w, s, res, tiles):
     return lambda a: qmm(a, w, s, tiles=tiles, layer=layer)
 
 
-def _stack_shape(kind: str, layers: int) -> tuple[int, ...]:
-    """Leading dims of the weight the serving path hands this kind: the
-    layer scan's matmuls get the model's stacked parameters, the head a
-    plain matrix."""
-    return () if kind == "lm_head" else (layers,)
-
-
-def autotune(
-    m: int, K: int, N: int, kind: str, dtype=jnp.bfloat16, repeats: int = 3
-) -> tuple[int, int, int]:
-    """Measure candidate tilings on the real device and persist the
-    winner. TPU only — interpret-mode timings would tune for the
-    emulator; off-TPU this returns the default untouched. A candidate
-    the compiler refuses is counted and logged, never silently dropped;
-    when every candidate is refused this raises (the kernel cannot
-    serve this shape at all)."""
-    import time
-
-    if jax.default_backend() != "tpu":
-        return tile_config(m, K, N, kind)
-    mb = m_bucket(m)
-    key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (mb, K), jnp.float32).astype(dtype)
-    # two layers, the second one read: the stacked form without the
-    # memory of a whole model's worth of this matrix
-    lead = _stack_shape(kind, 2)
-    w = jax.random.randint(key, (*lead, K, N), -127, 128, jnp.int8)
-    s = jnp.full((*lead, N), 0.01, jnp.float32)
-    best, best_t = None, float("inf")
-    res = jnp.zeros((mb, N), dtype)
-    refused = 0
-    for tiles in _candidate_tiles(mb, K, N, kind):
-        fn = jax.jit(_kind_fn(kind, w, s, res, tiles))
-        try:
-            jax.block_until_ready(fn(x))  # compile
-        except Exception as e:  # Mosaic/XLA refusals share no base type
-            refused += 1
-            log.warning(
-                "qmatmul autotune %s: tiles %s refused by the compiler: %s",
-                tune_key(m, K, N, kind), tiles, str(e).splitlines()[0][:200],
-            )
-            continue
-        t0 = time.monotonic()
-        for _ in range(repeats):
-            out = fn(x)
-        jax.block_until_ready(out)
-        dt = (time.monotonic() - t0) / repeats
-        if dt < best_t:
-            best, best_t = tiles, dt
-    if best is None:
-        raise RuntimeError(
-            f"qmatmul autotune {tune_key(m, K, N, kind)}: the compiler "
-            f"refused all {refused} candidate tilings"
-        )
-    if refused:
-        log.warning(
-            "qmatmul autotune %s: %d candidate tilings refused, winner %s",
-            tune_key(m, K, N, kind), refused, best,
-        )
-    record_tiles(m, K, N, kind, best)
-    return best
-
-
 def verify_compiles(
     m: int, K: int, N: int, kind: str, dtype=jnp.bfloat16, layers: int = 1
 ) -> None:
@@ -386,7 +210,9 @@ def verify_compiles(
     first request."""
     mb = m_bucket(m)
     sds = jax.ShapeDtypeStruct
-    lead = _stack_shape(kind, layers)
+    # the layer scan's matmuls get the model's stacked parameters, the
+    # head a plain matrix
+    lead = () if kind == "lm_head" else (layers,)
 
     # weights ride as arguments here: shapes only, nothing is allocated
     def call(a, w, s, res):
@@ -527,13 +353,15 @@ def _qmm_call(
     for w, s in zip(weights, scales):
         assert w.dtype == jnp.int8 and w.shape == (L, K, N), (w.shape, K, N)
         assert s.shape == (L, N), (s.shape, L, N)
-    bm, bn, bk = tiles if tiles is not None else tile_config(M, K, N, kind)
     mp = m_bucket(M)
+    bm, bn, bk = (
+        tiles if tiles is not None else default_tiles(mp, K, N, kind)
+    )
     bm = min(bm, mp)
-    # explicit `tiles` bypasses _valid_tiles — a non-dividing blocking
-    # would silently leave output columns unwritten (grid floor-division
-    # drops the remainder), so fail loudly instead
-    if mp % bm or N % bn or K % bk:
+    # a non-dividing blocking would silently leave output columns
+    # unwritten (grid floor-division drops the remainder), so an
+    # explicit `tiles` that is not legal fails loudly instead
+    if not _valid_tiles((bm, bn, bk), mp, K, N):
         raise ValueError(
             f"tiles (bm={bm}, bn={bn}, bk={bk}) must divide the padded "
             f"problem (M={mp}, N={N}, K={K})"
@@ -649,7 +477,7 @@ def qmm_lm_head(
     interpret: bool = False,
     tiles: Optional[tuple[int, int, int]] = None,
 ) -> jax.Array:
-    """The vocab-tiled LM-head qmm (its own tune key: at V=128256 this
+    """The vocab-tiled LM-head qmm (its own tile rule: at V=128256 this
     is the single largest weight read per decode step). Output rounds
     to x.dtype exactly like ``mm`` — the caller upcasts to f32 for
     sampling, same as the reference path."""
@@ -658,30 +486,3 @@ def qmm_lm_head(
         x2, [w], [scale], None, None, "lm_head", "", "silu", interpret, tiles
     )
     return y.reshape(*lead, w.shape[1])
-
-
-def ensure_tuned(
-    shapes: list[tuple[int, int, int, str]],
-    tune: Optional[bool] = None,
-    verify: bool = False,
-    layers: int = 1,
-) -> None:
-    """Engine-prewarm hook: make sure every reachable (M, K, N, kind)
-    has a tile config ready before the step functions trace. With
-    DYN_QMATMUL_TUNE=1 on TPU this measures and persists winners (a few
-    compiles per missing shape — one-time, cached on disk); otherwise
-    the heuristic defaults serve, and any previously-tuned entries load
-    from the cache. ``verify`` compiles each kernel with its resolved
-    tiling (TPU only) over ``layers`` stacked layers — see
-    :func:`verify_compiles`."""
-    if tune is None:
-        tune = os.environ.get("DYN_QMATMUL_TUNE") == "1"
-    table = _load_table()
-    on_tpu = jax.default_backend() == "tpu"
-    for m, K, N, kind in shapes:
-        if tune and tune_key(m, K, N, kind) not in table:
-            autotune(m, K, N, kind)
-        else:
-            tile_config(m, K, N, kind)  # validates/loads the entry
-            if verify and on_tpu:
-                verify_compiles(m, K, N, kind, layers=layers)

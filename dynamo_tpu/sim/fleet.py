@@ -740,7 +740,7 @@ class FleetSim:
             ),
             # of OFFERED load: shed, frontend-failed, and killed
             # requests all count as misses, so a policy cannot score
-            # 1.0 by rejecting the traffic (the bench headline)
+            # 1.0 by rejecting the traffic
             "slo_attainment_offered": (
                 self.met / self.arrived if self.arrived else 1.0
             ),
@@ -756,7 +756,7 @@ class FleetSim:
             "degradation_level": self.degradation_level,
             "decode_workers_final": len(self.workers),
             "prefill_servers_final": self.prefill_servers,
-            # fleet KV fabric A/B surface (bench.py --kvfleet headline):
+            # fleet KV fabric A/B surface (tests/test_kv_fabric.py):
             # prefilled_tokens is the recompute bill — with the fabric
             # on, every fleet hit moves its shared head from this figure
             # into fleet_fetched_tokens

@@ -1,17 +1,15 @@
 """Continuous decode perf attribution: where every wall second went.
 
-ROADMAP item 2's question — "the headline sits at ~0.37 of roofline;
-where does the other 60% go?" — had to be answered offline, by reading
-raw flight-recorder phase stamps or running ``bench.py --phases``. This
-module makes the answer a *live time series*: an always-on per-step
+"Where does the time a decode step spends beyond its byte-bound floor
+go?" had to be answered offline, by reading raw flight-recorder phase
+stamps. This module makes the answer a *live time series*: an always-on per-step
 ledger (``AttributionLedger``) decomposes the engine's decode timeline
 into named loss buckets, rolls them into windowed gauges
 (``dynamo_step_time_frac{component}``, ``dynamo_roofline_frac``,
 ``dynamo_tokens_lost_per_s{component}``), and a black-box recorder
 (``BlackBox``) bundles full forensic state into one timestamped dump
 dir when an anomaly trips — so a roofline regression is caught, named,
-and preserved while it happens instead of reconstructed from a bench
-round a week later.
+and preserved while it happens instead of reconstructed a week later.
 
 ## The decomposition
 
@@ -41,12 +39,11 @@ stamps the flight recorder already carries plus the roofline byte model
   by the byte prior.
 
 ``roofline_frac`` is achieved tok/s over the byte-bound ceiling at the
-live geometry — the same formula ``bench.py`` prints as
-``vs_baseline`` (telemetry/roofline.py keeps them one implementation).
+live geometry (telemetry/roofline.py).
 ``tokens_lost_per_s{component}`` distributes the gap to the ceiling
 over the loss buckets proportionally to their *excess* time (host
 buckets count whole; device buckets count time beyond their byte-bound
-ideal), so "the other 60%" is a first-class per-component series.
+ideal), so the gap to the ceiling is a first-class per-component series.
 
 ## Threading
 
@@ -318,8 +315,7 @@ class AttributionLedger:
             # ceiling (= ideal/span). The roofline is a decode
             # ceiling, so prefill intervals must not dilute the frac —
             # a traffic-mix shift toward long prompts is not a decode
-            # regression (and bench vs_baseline, measured over a
-            # decode-dominated window, stays comparable).
+            # regression.
             out["decode_tok_s"] = round(dec_tokens / dec_span, 3)
             out["roofline_frac"] = round(ideal / dec_span, 6)
             # loss attribution: host buckets lose their whole span,

@@ -31,10 +31,7 @@ metrics service) via the same :class:`ProviderRegistry` machinery as
 - the ``/debug/hostplane`` provider registry
   (``register_hostplane_provider`` / ``collect_hostplane``).
 
-``bench.py --fanout`` drives a synthetic engine through the real
-HttpService and reads this module's surface to report the frontend's
-requests/sec and stream fan-out ceilings (docs/observability.md "Host
-data plane").
+Operator view: docs/observability.md "Host data plane".
 """
 
 from __future__ import annotations
@@ -242,14 +239,6 @@ class LoopLagMonitor:
             with contextlib.suppress(asyncio.CancelledError):
                 await self._task
             self._task = None
-
-    def reset_window(self) -> None:
-        """Drop the lag window (beats/stalls keep counting): the
-        fan-out bench calls this between rungs so each rung's p99 is
-        its own, not the ladder's history."""
-        with self._lock:
-            self._window.clear()
-            self._summary = {"p50_ms": 0.0, "p99_ms": 0.0, "max_ms": 0.0}
 
     def snapshot(self) -> dict:
         self._refresh_gauges()

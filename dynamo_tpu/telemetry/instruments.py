@@ -342,8 +342,7 @@ STEP_TIME_FRAC = REGISTRY.gauge(
 ROOFLINE_FRAC = REGISTRY.gauge(
     "dynamo_roofline_frac",
     "Achieved decode tok/s over the kv_dtype-aware byte-bound roofline "
-    "at the live geometry (telemetry/roofline.py — the same formula as "
-    "bench.py's headline vs_baseline)",
+    "at the live geometry (telemetry/roofline.py)",
 )
 TOKENS_LOST_PER_S = REGISTRY.gauge(
     "dynamo_tokens_lost_per_s",

@@ -20,7 +20,7 @@ and the roofline gap only closes if we can *measure* when it is not.
   failure, and must not be billed as device idleness.
 
 All methods are engine-thread only (mirrors ``_last_phases``); readers
-(``/debug/state``, bench) take an advisory ``stats()`` snapshot.
+(``/debug/state``) take an advisory ``stats()`` snapshot.
 
 The numbers are a **host-observable lower bound**: a step's true device
 completion is only witnessed at its harvest, so idleness hidden behind

@@ -1,13 +1,12 @@
-"""The decode roofline byte-budget model — ONE formula for bench and serving.
+"""The decode roofline byte-budget model behind the serving process's
+own gauges.
 
-``bench.py``'s headline ``vs_baseline`` has always been *achieved tok/s
-over the HBM byte-bound roofline*; the attribution ledger
-(telemetry/attribution.py) publishes the same ratio live as
-``dynamo_roofline_frac``. Both MUST compute the denominator from the
-same model or the two numbers drift and "the bench says 0.37 but the
-server says 0.45" becomes an argument instead of a measurement — so the
-math lives here and both import it (docs/performance.md documents the
-byte table this module implements).
+The attribution ledger (telemetry/attribution.py) publishes *achieved
+decode tok/s over the HBM byte-bound roofline* live as
+``dynamo_roofline_frac`` and splits measured device time by this
+module's per-phase byte prior. The benchmark does not read it:
+``perf/roofline.py`` counts a kernel's operations and bytes against a
+device trace (PERF.md section 3).
 
 The model (kv_dtype- and quant-aware):
 
@@ -21,8 +20,7 @@ The model (kv_dtype- and quant-aware):
   tok/s = ``batch / (step_bytes / HBM_BW_BYTES)``.
 - ``phase_ideal_bytes`` splits the same budget into the four decode
   phases (attention / MLP+projections / LM head / sampling) — the cost
-  prior ``bench.py --phases`` reports per phase and the attribution
-  ledger uses to split measured device time.
+  prior the attribution ledger uses to split measured device time.
 """
 
 from __future__ import annotations
@@ -156,12 +154,10 @@ class RooflineModel:
     """The scalars the attribution ledger needs per step, derived once
     at engine init so the hot path never touches the model config:
     ``ideal_step_s(batch, context_tokens)`` (the roofline denominator —
-    param_bytes parity with the bench formula, embedding included) and
-    the device-phase split prior. ``mlp_bytes`` is the LAYER matmul
-    weights only — the same set ``phase_ideal_bytes`` bills to ``mlp``
-    (the embedding gather reads B rows, not the table, so it belongs in
-    neither phase) — so the ledger's device split and ``bench.py
-    --phases`` decompose against the identical prior."""
+    ``param_bytes``, embedding included) and the device-phase split
+    prior. ``mlp_bytes`` is the LAYER matmul weights only — the same
+    set ``phase_ideal_bytes`` bills to ``mlp`` (the embedding gather
+    reads B rows, not the table, so it belongs in neither phase)."""
 
     param_bytes: float
     kv_bytes_per_token: float

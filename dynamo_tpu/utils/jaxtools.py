@@ -8,10 +8,10 @@ CPU when it cannot initialise one. ``DYN_JAX_PLATFORM`` (e.g. "cpu")
 pins the platform for dev runs and control-plane children;
 ``DYN_JAX_CPU_DEVICES`` asks the CPU backend for that many virtual
 devices (sharding rehearsals). ``describe_devices`` is what the engine
-logs and what ``chip_smoke.py`` / ``bench.py`` check, so a run that
-fell back to the CPU says so.
+logs and what ``chip_smoke.py`` checks, so a run that fell back to the
+CPU says so.
 
-Compile cache — ONE rule for the engine, the benchmarks, the smoke and
+Compile cache — ONE rule for the engine, the benchmark, the smoke and
 the tests: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
 itself and no code sets another directory; where it is not, the cache
 is ``<checkout>/.jax_cache`` (fixed: the path is part of the cache

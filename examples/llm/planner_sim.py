@@ -11,9 +11,7 @@ worker absorbs load), and every tick is appended to a JSONL trace:
 
     python -m examples.llm.planner_sim --out planner_trace.jsonl
 
-A recorded trace ships at examples/llm/planner_trace.jsonl; live-load
-equivalents drive `benchmarks/load_gen.py --rate-mode sin` at a real
-frontend instead.
+A recorded trace ships at examples/llm/planner_trace.jsonl.
 """
 
 from __future__ import annotations

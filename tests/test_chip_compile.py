@@ -15,7 +15,7 @@ in a module-scoped, non-autouse fixture (never at import time, in a
 
 Shapes are the Llama-3.1-8B widths the repo serves (D=4096, F=14336,
 32/8 heads of 128, V=128256) at the token-row counts the static-shape
-scheduler produces by default (engine.py ``_ensure_qmatmul_tuned``):
+scheduler produces by default (engine.py ``_verify_qmatmul_compiles``):
 decode buckets 4/32/64, prefill rectangles up to 4096 tokens, and the
 spec-verify rectangle 64 x 5 -> M bucket 512.
 """

@@ -105,11 +105,11 @@ def test_cache_off_really_writes_nothing(tmp_path):
 
 def test_one_place_in_the_tree_sets_the_cache_directory():
     hits = []
-    for root in ("dynamo_tpu", "benchmarks", "tests", "examples"):
+    for root in ("dynamo_tpu", "perf", "tests", "examples"):
         for dirpath, _, files in os.walk(os.path.join(REPO, root)):
             hits += [os.path.join(dirpath, f) for f in files
                      if f.endswith(".py")]
-    hits += [os.path.join(REPO, f) for f in ("bench.py", "chip_smoke.py")]
+    hits.append(os.path.join(REPO, "chip_smoke.py"))
     setters = [
         os.path.relpath(p, REPO) for p in hits
         if re.search(r'update\(\s*"jax_compilation_cache_dir"',
@@ -201,50 +201,6 @@ def test_cpu_fallback_warns_once_unless_cpu_was_asked_for(monkeypatch, caplog):
         assert not jaxtools.warn_if_cpu_fallback(log, "engine 'x'")  # once
     assert len(caplog.records) == 1
     assert "CPU backend" in caplog.records[0].getMessage()
-
-
-def test_autotune_counts_refusals_and_fails_when_nothing_compiles(
-    monkeypatch, caplog, tmp_path
-):
-    from dynamo_tpu.ops import qmatmul
-
-    monkeypatch.setenv("DYN_QMATMUL_TUNE_DIR", str(tmp_path))
-    qmatmul._reset_table_for_tests()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    refused_bn = {256}
-
-    def fake_kind_fn(kind, w, s, res, tiles):
-        def fn(a):
-            if tiles[1] in refused_bn:
-                raise ValueError("Mosaic failed to compile TPU kernel")
-            return a
-        return fn
-
-    monkeypatch.setattr(qmatmul, "_kind_fn", fake_kind_fn)
-    with caplog.at_level(logging.WARNING, logger=qmatmul.log.name):
-        best = qmatmul.autotune(64, 512, 768, "mm")
-    assert best[1] not in refused_bn
-    assert any("refused by the compiler" in r.getMessage()
-               for r in caplog.records)
-    refused_bn.update({384, 768})
-    with pytest.raises(RuntimeError, match="refused all"):
-        qmatmul.autotune(64, 512, 768, "residual")
-    qmatmul._reset_table_for_tests()
-
-
-def test_bench_without_a_tpu_exits_before_building_the_model():
-    env = {k: v for k, v in os.environ.items() if k != "DYN_BENCH_PLATFORM"}
-    env["JAX_PLATFORMS"] = "cpu"  # what JAX falls back to without a chip
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert proc.returncode == 2
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["ok"] is False
-    assert line["device"] == {"platform": "cpu", "kind": "cpu",
-                              "count": line["device"]["count"]}
-    assert "engine launching" not in proc.stderr
 
 
 async def test_engine_counts_the_mosaic_kernels_of_its_lowered_step(monkeypatch):

@@ -4,7 +4,7 @@ flag and Client filtering, the KV scheduler's drain-aware scoring, the
 engine's migrate-eligibility mirror, the fabric's hot-prefix handoff,
 the DrainCoordinator state machine, the worker-control subject
 round-trip, the planner's rolling_restart, and the sim's kill-vs-drain
-A/B that bench.py --chaos gates. The live SIGTERM-mid-stream proof is
+A/B. The live SIGTERM-mid-stream proof is
 tests/test_cli_drain_e2e.py; the fault-point seams are covered in
 tests/test_faults.py."""
 
@@ -587,7 +587,7 @@ async def test_rolling_restart_empty_fleet_is_a_noop():
 
 
 # ---------------------------------------------------------------------------
-# Simulator: drain modeling + the kill-vs-drain A/B bench.py gates
+# Simulator: drain modeling + the kill-vs-drain A/B
 # ---------------------------------------------------------------------------
 
 
@@ -630,9 +630,9 @@ def test_sim_drain_migrates_inflight_and_conserves_requests():
 
 
 def test_sim_kill_vs_drain_ab_is_deterministic_and_shallower():
-    """The bench.py --chaos acceptance gate, run at the bench's exact
-    seeds/config: the drain's SLO-attainment dip must be STRICTLY
-    shallower than the kill's, and replays bit-identical."""
+    """The acceptance A/B on one seeded trace and fault plan: the
+    drain's SLO-attainment dip must be STRICTLY shallower than the
+    kill's, and replays bit-identical."""
     kill = _ab_run("worker.liveness")
     drain = _ab_run("worker.drain")
     assert _ab_run("worker.drain") == drain  # bit-identical replay

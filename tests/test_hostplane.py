@@ -1,13 +1,11 @@
 """Host data-plane observability (ISSUE 17): the event-loop lag
 monitor, the per-stream host-cost ledger, the /debug/hostplane
-surface, the fan-out bench gate, and the `top` host columns —
+surface, and the `top` host columns —
 docs/observability.md "Host data plane"."""
 
 import asyncio
 import json
 import os
-import subprocess
-import sys
 import time
 from typing import Any, AsyncIterator
 
@@ -35,7 +33,6 @@ from dynamo_tpu.telemetry.recorder import FlightRecorder
 from tests.prom_parser import parse as prom_parse
 
 MODEL_DIR = os.path.join(os.path.dirname(__file__), "data", "tiny_llama_model")
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -74,16 +71,13 @@ def test_note_lag_window_and_percentiles():
     assert snap["stalls"] == 0 and snap["running"] is False
 
 
-def test_note_lag_negative_clamped_and_reset_window():
+def test_note_lag_negative_clamped():
     mon = LoopLagMonitor(interval_s=0.01, clock=FakeClock())
     mon.note_lag(-0.5)  # clock jitter must not mint negative lag
     assert mon.snapshot()["lag"]["max_ms"] == 0.0
     mon.note_lag(0.02)
-    assert mon.snapshot()["lag"]["max_ms"] == 20.0
-    mon.reset_window()
     snap = mon.snapshot()
-    # beats keep counting; the window (and its summary) start over
-    assert snap["beats"] == 2 and snap["lag"]["max_ms"] == 0.0
+    assert snap["beats"] == 2 and snap["lag"]["max_ms"] == 20.0
 
 
 def test_stall_fires_exactly_one_bundle_per_holdoff(tmp_path):
@@ -474,81 +468,6 @@ async def test_tool_parser_stamp_rides_note_stage():
         r for r in LEDGER.snapshot(recent=64)["recent"] if r["rid"] == rid
     )
     assert "tool_parser" in row["stages_ms"]
-
-
-# ---------------------------------------------------------------------------
-# fan-out bench: pure compare logic + a smoke run of the real ladder
-# ---------------------------------------------------------------------------
-def test_fanout_compare_verdicts():
-    import bench
-
-    base = {"rps": 1000.0, "streams": 1000, "noise_frac": 0.2}
-    ok = bench._fanout_compare({"rps": 900.0, "streams": 900}, base)
-    assert ok["regressed"] is False
-    assert ok["floor_rps"] == 800.0 and ok["floor_streams"] == 800
-    # either headline under its floor regresses
-    assert bench._fanout_compare(
-        {"rps": 700.0, "streams": 900}, base
-    )["regressed"] is True
-    assert bench._fanout_compare(
-        {"rps": 900.0, "streams": 700}, base
-    )["regressed"] is True
-    # noise_frac defaults wide (0.5) when the profile omits it
-    loose = bench._fanout_compare(
-        {"rps": 501.0, "streams": 501}, {"rps": 1000.0, "streams": 1000}
-    )
-    assert loose["noise_frac"] == 0.5 and loose["regressed"] is False
-
-
-def test_fanout_bench_smoke(tmp_path):
-    """One tiny rung per ladder through the REAL server + client path;
-    gated against a permissive temp baseline so the smoke asserts the
-    machinery, not this box's throughput."""
-    baseline = tmp_path / "baseline.json"
-    baseline.write_text(json.dumps({
-        "profiles": {
-            "cpu-fanout-quick": {"rps": 0.1, "streams": 1, "noise_frac": 0.5}
-        }
-    }))
-    report = tmp_path / "report.json"
-    env = dict(
-        os.environ,
-        DYN_BENCH_FANOUT_SMOKE="1",
-        DYN_BENCH_FANOUT_CHUNKS="2",
-        DYN_BENCH_FANOUT_INTERVAL_S="0.01",
-        DYN_SENTINEL_REPORT=str(report),
-        JAX_PLATFORMS="cpu",
-    )
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--fanout", "--quick",
-         "--baseline", str(baseline)],
-        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=300,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip()]
-    by_metric = {l["metric"]: l for l in lines}
-    rps = by_metric["frontend_fanout_rps"]
-    streams = by_metric["frontend_fanout_streams"]
-    assert rps["value"] > 0 and rps["vs_baseline"] > 0
-    assert streams["value"] == 8  # the smoke rung completed clean
-    cfg = rps["config"]
-    assert cfg["profile"] == "cpu-fanout-quick"
-    assert cfg["rps_rungs"] and cfg["stream_rungs"]
-    assert cfg["stream_rungs"][0]["failures"] == 0
-    assert cfg["regressed"] is False
-    # the CI artifact mirrors both headline lines
-    rep = json.loads(report.read_text())
-    assert rep["rps"]["metric"] == "frontend_fanout_rps"
-    assert rep["streams"]["value"] == 8
-
-
-def test_committed_fanout_baselines_present():
-    with open(os.path.join(REPO_ROOT, "BENCH_BASELINE.json")) as f:
-        profiles = json.load(f)["profiles"]
-    for key in ("cpu-fanout-quick", "cpu-fanout-full"):
-        prof = profiles[key]
-        assert prof["rps"] > 0 and prof["streams"] > 0
-        assert 0.0 < prof["noise_frac"] < 1.0
 
 
 # ---------------------------------------------------------------------------

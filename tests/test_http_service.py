@@ -26,6 +26,7 @@ class CounterEngine(AsyncEngine):
     async def _gen(self, request: Any, ctx: Context) -> AsyncIterator[Any]:
         self.requests += 1
         self.produced = 0
+        self.ctx = ctx
         assert isinstance(request, ChatCompletionRequest)
         gen = ChatDeltaGenerator(model=request.model)
         for i in range(self.n):
@@ -151,6 +152,41 @@ async def test_client_disconnect_cancels_engine():
         assert n < 1000, "engine was not interrupted"
         await asyncio.sleep(0.3)
         assert engine.produced == n, "engine kept producing after disconnect"
+    finally:
+        await service.stop()
+
+
+async def test_disconnect_before_the_sse_headers_kills_the_generation(monkeypatch):
+    """A client that leaves while its first chunk is being primed makes
+    the response's headers fail on the closing transport. Seen on the
+    chip: the handler answered 500 to nobody and the sequence ran on to
+    max_tokens. The generation has to be killed like any other whose
+    client went away."""
+    from aiohttp import web
+
+    async def closing(self):
+        if self.content_type == "text/event-stream":
+            raise ConnectionResetError("Cannot write to closing transport")
+        return await real(self)
+
+    real = web.StreamResponse._write_headers
+    monkeypatch.setattr(web.StreamResponse, "_write_headers", closing)
+    engine = CounterEngine(n=1000, delay=0.01)
+    service, base = await _start_service(engine)
+    try:
+        async with aiohttp.ClientSession() as s:
+            payload = {
+                "model": "foo",
+                "messages": [{"role": "user", "content": "x"}],
+                "stream": True,
+            }
+            try:
+                async with s.post(f"{base}/v1/chat/completions", json=payload) as r:
+                    await r.read()
+            except aiohttp.ClientError:
+                pass  # no headers ever arrive: the client's side of it
+        assert engine.requests == 1
+        assert engine.ctx.is_killed
     finally:
         await service.stop()
 

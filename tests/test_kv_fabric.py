@@ -5,7 +5,7 @@ lifecycle on a virtual clock, never-dangling catalog invariants across
 failed fetches, two-worker onboarding (in-process peer plane, then the
 real store wire plane over loopback sockets), the router's discounted
 fleet scoring (incl. the resume-racing-a-demotion regression), the
-remote-bridge timeout surfacing, and the simulator A/B the bench gates.
+remote-bridge timeout surfacing, and the simulator's fabric off / on A/B.
 """
 
 import asyncio
@@ -637,7 +637,7 @@ def test_catalog_timeout_degrades_not_raises_into_routing():
 
 
 # ---------------------------------------------------------------------------
-# Simulator A/B + the bench gate's compare function
+# Simulator A/B
 # ---------------------------------------------------------------------------
 
 
@@ -672,31 +672,6 @@ def test_sim_fabric_ab_fewer_reprefill_tokens():
 def test_sim_fabric_ab_deterministic():
     a, b = _sim_ab(duration=60.0), _sim_ab(duration=60.0)
     assert a == b
-
-
-def test_kvfleet_compare_gate_directions():
-    import bench
-
-    base = {"hit_rate": 0.6, "avoided_frac": 0.3, "noise_frac": 0.25}
-    ok = bench._kvfleet_compare(
-        {"hit_rate": 0.55, "avoided_frac": 0.28}, base
-    )
-    assert not ok["regressed"]
-    # either headline under its floor regresses
-    assert bench._kvfleet_compare(
-        {"hit_rate": 0.4, "avoided_frac": 0.28}, base
-    )["regressed"]
-    assert bench._kvfleet_compare(
-        {"hit_rate": 0.55, "avoided_frac": 0.1}, base
-    )["regressed"]
-    # the A/B invariant is unconditional: zero hits / no win always gates
-    wide = {"hit_rate": 0.001, "avoided_frac": 0.001, "noise_frac": 1.0}
-    assert bench._kvfleet_compare(
-        {"hit_rate": 0.0, "avoided_frac": 0.5}, wide
-    )["regressed"]
-    assert bench._kvfleet_compare(
-        {"hit_rate": 0.5, "avoided_frac": 0.0}, wide
-    )["regressed"]
 
 
 def test_fabric_debug_stanza_registered():

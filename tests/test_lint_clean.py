@@ -22,13 +22,12 @@ from dynamo_tpu.analysis.cache import LintCache
 
 REPO = Path(__file__).resolve().parents[1]
 
-# the self-clean contract extends beyond the package: the benchmark
-# driver and the test-infrastructure helpers run the same async/engine
-# machinery, so a blocking call or hidden sync there skews the numbers
-# the package's own rules protect (fixture data under tests/data stays
-# out — violating fixtures exist to violate)
+# the self-clean contract extends beyond the package: the
+# test-infrastructure helpers run the same async/engine machinery, so a
+# blocking call or hidden sync there hides what the package's own rules
+# protect (fixture data under tests/data stays out — violating fixtures
+# exist to violate)
 EXTRA_CLEAN_PATHS = [
-    str(REPO / "bench.py"),
     str(REPO / "tests" / "cli_harness.py"),
     str(REPO / "tests" / "prom_parser.py"),
     str(REPO / "tests" / "sdk_graph.py"),
@@ -50,7 +49,7 @@ def test_repo_is_lint_clean():
 
 
 @pytest.mark.pre_merge
-def test_bench_and_test_helpers_are_lint_clean():
+def test_test_helpers_are_lint_clean():
     # a separate lint_paths call (not config `include`): these files
     # live outside the package root, and folding them into the main
     # walk would change the whole-program pass's module universe (and
@@ -62,7 +61,7 @@ def test_bench_and_test_helpers_are_lint_clean():
     findings = lint_paths(EXTRA_CLEAN_PATHS, config=cfg, cache=cache)
     live = unsuppressed(findings)
     assert live == [], (
-        "unsuppressed dynalint findings in bench.py / tests helpers:\n"
+        "unsuppressed dynalint findings in the tests' helpers:\n"
         + format_text(findings)
     )
 

@@ -801,92 +801,6 @@ async def test_e2e_stall_fires_exactly_one_blackbox(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# bench sentinel comparison logic (bench.py --sentinel)
-# ---------------------------------------------------------------------------
-def test_sentinel_flags_inflated_baseline_and_names_bucket():
-    """The acceptance case: a baseline 20% above the measured headline
-    exits nonzero (noise band 15%) and names the losing bucket."""
-    import bench
-
-    measured = {
-        "tok_s": 1000.0,
-        "roofline_frac": 0.30,
-        "step_time_frac": {"plan": 0.30, "mlp": 0.50, "sync": 0.20},
-    }
-    base = {
-        "tok_s": 1250.0,  # measured is 20% below
-        "noise_frac": 0.15,
-        "roofline_frac": 0.375,
-        "step_time_frac": {"plan": 0.10, "mlp": 0.65, "sync": 0.25},
-        "bucket_noise_abs": 0.05,
-    }
-    v = bench._sentinel_compare(measured, base)
-    assert v["regressed"] is True
-    assert v["losing_bucket"] == "plan"  # +0.20 of step time
-    assert v["bucket_deltas"]["plan"] == pytest.approx(0.20)
-    assert v["floor_tok_s"] == pytest.approx(1062.5)
-
-
-def test_sentinel_passes_inside_noise_band():
-    import bench
-
-    measured = {"tok_s": 980.0, "roofline_frac": 0.3,
-                "step_time_frac": {"plan": 0.1}}
-    base = {"tok_s": 1000.0, "noise_frac": 0.15,
-            "step_time_frac": {"plan": 0.12}, "bucket_noise_abs": 0.05}
-    v = bench._sentinel_compare(measured, base)
-    assert v["regressed"] is False
-    assert v["losing_bucket"] == ""
-
-
-def test_sentinel_uniform_slowdown_does_not_blame_a_shrinking_bucket():
-    """A global slowdown moves every bucket frac slightly negative or
-    not at all; the fallback must say 'uniform', not name the
-    least-shrunk bucket as the culprit."""
-    import bench
-
-    measured = {"tok_s": 500.0,
-                "step_time_frac": {"plan": 0.09, "mlp": 0.61}}
-    base = {"tok_s": 1000.0, "noise_frac": 0.15,
-            "step_time_frac": {"plan": 0.10, "mlp": 0.62},
-            "bucket_noise_abs": 0.05}
-    v = bench._sentinel_compare(measured, base)
-    assert v["regressed"] is True
-    assert v["losing_bucket"] == "uniform"
-
-
-def test_sentinel_profile_keys_split_platform_and_tier():
-    import bench
-
-    wl = {"model_name": "tiny"}
-    assert bench._sentinel_profile_key(True, wl, True) == "cpu-tiny-quick"
-    assert bench._sentinel_profile_key(False, wl, False) == "tpu-tiny-full"
-    # the DYN_BENCH_SPEC=0 escape hatch runs a different step program
-    # (fused windows vs the spec pipeline) — its baseline must not
-    # share a key with the spec headline's
-    assert (
-        bench._sentinel_profile_key(True, wl, True, spec=False)
-        == "cpu-tiny-quick-nospec"
-    )
-
-
-def test_committed_baseline_has_the_ci_profile():
-    """CI runs `--sentinel --quick` on CPU against the committed file —
-    the profile it compares against must exist with explicit bands."""
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        "BENCH_BASELINE.json")
-    data = json.load(open(path))
-    prof = data["profiles"]["cpu-tiny-quick"]
-    assert prof["tok_s"] > 0
-    assert 0 < prof["noise_frac"] < 1
-    assert 0 < prof["bucket_noise_abs"] < 1
-    assert set(prof["step_time_frac"]) <= {
-        "queue_wait", "plan", "dispatch", "sync", "idle_gap",
-        "attention", "mlp", "lm_head", "sampling",
-    }
-
-
-# ---------------------------------------------------------------------------
 # top: ROOF%/LOSS columns, --watch-roofline, tok/s absence marker
 # ---------------------------------------------------------------------------
 async def test_top_roofline_column_and_watch_sort():

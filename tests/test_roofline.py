@@ -1,13 +1,12 @@
-"""telemetry/roofline.py: the ONE roofline formula bench.py and the
-attribution ledger share, pinned to the 8B int8 numbers documented in
-docs/performance.md (the byte table and the ~5.4k → ~5.9k tok/s
-bf16→int8 KV headline move)."""
+"""telemetry/roofline.py: the roofline formula behind the attribution
+ledger's device-split prior and `dynamo_roofline_fraction`, pinned at
+the 8B int8 geometry (bytes a decode step must read, from shapes and
+the datasheet bandwidth: arithmetic, not a measurement)."""
 
 import pytest
 
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.telemetry.roofline import (
-    HBM_BW_BYTES,
     RooflineModel,
     build_roofline,
     kv_bytes_per_token,
@@ -19,8 +18,7 @@ from dynamo_tpu.telemetry.roofline import (
 
 
 def _mc_8b() -> ModelConfig:
-    # DeepSeek-R1-Distill-Llama-8B geometry (BASELINE.md config 1) —
-    # the bench.py headline shape
+    # DeepSeek-R1-Distill-Llama-8B geometry (BASELINE.md config 1)
     return ModelConfig(
         vocab_size=128256, hidden_size=4096, intermediate_size=14336,
         num_hidden_layers=32, num_attention_heads=32,
@@ -28,13 +26,13 @@ def _mc_8b() -> ModelConfig:
     )
 
 
-# headline workload: batch 64, isl 128 / osl 128 -> avg ctx 192
+# the pinned workload: batch 64, isl 128 / osl 128 -> avg ctx 192
 B, AVG_CTX = 64, 192
 
 
 def test_8b_int8_param_bytes_pin():
-    # int8 weights ≈ 8.03 GB (fits a 16 GB v5e chip with KV headroom;
-    # docs/performance.md: MLP+projections ~6.98 GB + 2·V·D ~1.05 GB)
+    # int8 weights ≈ 8.03 GB (fits a 16 GB v5e chip with KV headroom:
+    # MLP+projections ~6.98 GB + 2·V·D ~1.05 GB)
     assert param_bytes(_mc_8b(), "int8") == pytest.approx(8.03e9, rel=0.01)
     assert param_bytes(_mc_8b(), None) == 2 * param_bytes(_mc_8b(), "int8")
 
@@ -50,9 +48,8 @@ def test_8b_kv_bytes_per_token_pin():
 
 def test_8b_headline_roofline_pins():
     mc = _mc_8b()
-    # the numbers every BENCH_r* vs_baseline was computed against:
-    # bf16 KV -> ~5437 tok/s (ROADMAP item 2's denominator), int8 KV ->
-    # ~5916 (docs/performance.md "the target moves from ~5.4k to ~5.9k")
+    # the HBM ceiling at the pinned workload: bf16 KV -> ~5437 tok/s,
+    # int8 KV -> ~5916 (half the KV bytes a step)
     assert roofline_tok_s(mc, B, AVG_CTX, "int8", "bfloat16") == pytest.approx(
         5437.0, abs=1.0
     )
@@ -62,7 +59,7 @@ def test_8b_headline_roofline_pins():
 
 
 def test_8b_phase_byte_table_pins():
-    # the docs/performance.md byte table at the headline config
+    # the per-phase byte table at the pinned workload
     ph = phase_ideal_bytes(_mc_8b(), B, AVG_CTX, "int8", "int8")
     assert ph["mlp"] == pytest.approx(6.98e9, rel=0.01)
     assert ph["attention"] == pytest.approx(0.83e9, rel=0.01)
@@ -79,24 +76,11 @@ def test_8b_phase_byte_table_pins():
     )
 
 
-def test_bench_imports_the_same_formulas():
-    """bench.py must not grow a private copy again: its helpers ARE the
-    shared ones."""
-    import bench
-
-    mc = _mc_8b()
-    assert bench._param_bytes(mc, "int8") == param_bytes(mc, "int8")
-    assert bench._kv_bytes_per_token(mc, "int8") == kv_bytes_per_token(
-        mc, "int8"
-    )
-    assert bench.HBM_BW_BYTES == HBM_BW_BYTES
-
-
 def test_roofline_model_matches_free_functions():
     mc = _mc_8b()
     rm = build_roofline(mc, "int8", "int8")
     assert isinstance(rm, RooflineModel)
-    # ideal_step_s at the headline geometry reproduces the tok/s pin
+    # ideal_step_s at the pinned geometry reproduces the tok/s pin
     # (the model adds the [B, V] sampling read — sub-0.5% at 8B)
     ideal = rm.ideal_step_s(B, B * AVG_CTX)
     assert B / ideal == pytest.approx(
@@ -109,9 +93,9 @@ def test_roofline_model_matches_free_functions():
 
 
 def test_roofline_model_phase_prior_matches_phase_table():
-    """The ledger's device-split prior and bench --phases must
-    decompose against the IDENTICAL byte table (the embedding gather
-    belongs to neither: it reads B rows, not the table)."""
+    """The ledger's device-split prior decomposes against the byte
+    table `phase_ideal_bytes` gives (the embedding gather belongs to
+    no phase: it reads B rows, not the table)."""
     mc = _mc_8b()
     rm = build_roofline(mc, "int8", "int8")
     ph = phase_ideal_bytes(mc, B, AVG_CTX, "int8", "int8")
