@@ -295,6 +295,9 @@ class JaxEngine:
         # models with recurrent state: state slots held, summed over the
         # programs dispatched, and slots there were (program_counts)
         self._state_slot_steps = [0, 0]
+        # single-step decode dispatches of _decode_pipeline, and those
+        # of them issued with a step still in flight (program_counts)
+        self._decode_dispatches = [0, 0]
         # the family's device-side counts (models/kimi_linear.py
         # COUNT_NAMES): totals as Python ints, and the device's last
         # int32 reading (the device wraps, the totals do not)
@@ -3311,7 +3314,8 @@ class JaxEngine:
           condition using the EXACT emitted counts, reserves blocks for
           the in-flight tokens (up to K+1 per row) plus the next draft
           run with rollback on ``NoBlocksError``, and never
-          preempts/admits — any irregularity (new arrivals, opt-outs,
+          preempts/admits — any irregularity (arrivals the planner could
+          admit: ``Scheduler.admission_work``; opt-outs,
           cancellation, deadline, block pressure, zero proposals)
           flushes back to the serial planner, the same divert
           discipline as ``_overlap_divert``;
@@ -3430,8 +3434,7 @@ class JaxEngine:
             SPEC_STEP_SECONDS.labels("draft").observe(repair_s)
             self.spec_draft_exposed_s_total += repair_s
             flush = (
-                bool(sched.waiting)
-                or bool(sched.prefilling)
+                sched.admission_work()
                 or not self._running
                 # a drain must reach the serial loop's migrate sweep:
                 # the pipeline would otherwise hold its streams until
@@ -3543,7 +3546,12 @@ class JaxEngine:
           appended, never emitted, never content-addressed — and the
           pipeline flushes so ``plan()`` reaps with nothing in flight;
         - the pipeline NEVER preempts and never admits: block pressure
-          or new arrivals drain it back to the serial planner.
+          drains it back to the serial planner, and so does a waiting
+          queue — but only while that planner could act on it
+          (``Scheduler.admission_work``). A queue whose head the last
+          ``_admit`` could not place moves at the next finish, which
+          flushes the pipeline anyway: a saturated server keeps
+          pipelining.
 
         Greedy output is bit-identical to the serial loop (same step
         program over the same values); sampled output draws the
@@ -3564,6 +3572,7 @@ class JaxEngine:
 
         def dispatch(seqs_, arrays, sampling, p_ms: float) -> dict:
             t0 = time.monotonic()
+            self._decode_dispatches[0] += 1
             with self._dispatch_span("decode", arrays["tokens"]):
                 outs = self._dispatch_device_step(
                     arrays, sampling, origin="decode-pipeline"
@@ -3594,7 +3603,7 @@ class JaxEngine:
             newest = pending[-1]
             with step_span("dyn.step.plan"):
                 self._drain_incoming_only()
-                if sched.waiting or sched.prefilling:
+                if sched.admission_work():
                     return False  # drain: the serial planner admits/prefills
                 t_plan = time.monotonic()
                 nxt = sched.plan_pipelined_decode(newest["seqs"], lag)
@@ -3614,6 +3623,7 @@ class JaxEngine:
                 nxt["seqs"], arrays, sampling,
                 round((time.monotonic() - t_plan) * 1e3, 3),
             )
+            self._decode_dispatches[1] += 1
             _lag_add(lag, e)
             pending.append(e)
             return True
@@ -4949,6 +4959,8 @@ class JaxEngine:
                 prompt_tokens=sched.prompt_tokens_admitted,
                 cached_prompt_tokens=sched.prompt_tokens_cached,
                 preemptions=sched.preemptions,
+                decode_dispatches=self._decode_dispatches[0],
+                decode_dispatches_chained=self._decode_dispatches[1],
             )
             if sched.state_slots is not None:
                 out.update(
@@ -5093,6 +5105,10 @@ class JaxEngine:
         out["overlap"] = {
             "enabled": self.config.overlap,
             **self.overlap.stats(),
+            # the decode pipeline's dispatches, and those chained onto a
+            # step in flight: the share says how often it overlaps
+            "decode_dispatches": self._decode_dispatches[0],
+            "decode_dispatches_chained": self._decode_dispatches[1],
         }
         # serve-phase compile fence (DYN_COMPILE_FENCE): mode + lifetime
         # escalation count, so `top`//debug/state show whether a fenced

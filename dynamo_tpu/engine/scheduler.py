@@ -248,6 +248,13 @@ class Scheduler:
         # given back at finish, abort and preemption. Every block table
         # then carries the row's slot as its LAST column.
         self.state_slots: Optional[StateSlots] = None
+        # the head of ``waiting`` that the last _admit could not place
+        # (growth reserve, max_batch_size, state slots, no blocks). It
+        # stays unplaceable until something is freed, so finish() and
+        # _preempt() forget it; a new head (the old one reaped, an
+        # arrival into an empty queue, a preempted victim put first) is
+        # simply not this one. Read by admission_work().
+        self._blocked_head: Optional[Sequence] = None
 
     # -- intake -----------------------------------------------------------
     def add_request(self, seq: Sequence) -> None:
@@ -267,6 +274,32 @@ class Scheduler:
     @property
     def has_work(self) -> bool:
         return bool(self.waiting or self.prefilling or self.running)
+
+    def admission_work(self) -> bool:
+        """Is there admission or prefill work the serial planner could
+        do now? The overlapped decode pipelines (engine._decode_pipeline
+        and _spec_pipeline) never admit, and drain back to plan() when
+        this says yes — not merely when somebody waits: _admit looks at
+        waiting[0] only, and a head it could not place stays unplaceable
+        while the pipeline runs, because nothing is freed inside it
+        (every finish, late stop or cancellation flushes it first) and
+        decode growth only takes pages. So a saturated server, whose
+        queue only a finish can move, keeps pipelining; arrivals queue
+        behind the blocked head and change nothing. A cancelled or
+        expired waiting request still counts as work: plan() reaps it
+        within a step."""
+        if self.prefilling:
+            return True
+        if not self.waiting:
+            return False
+        if self.waiting[0] is not self._blocked_head:
+            return True
+        now = time.monotonic()
+        return any(
+            (seq.is_cancelled and seq.is_cancelled())
+            or (bool(seq.deadline) and now >= seq.deadline)
+            for seq in self.waiting
+        )
 
     # -- planning ---------------------------------------------------------
     def plan(self) -> StepPlan:
@@ -528,6 +561,9 @@ class Scheduler:
                 self.prefix_hits += 1
             self.prompt_tokens_admitted += seq.total_len
             self.prompt_tokens_cached += seq.num_cached_prompt
+        # the loop ends with a request still waiting only where it could
+        # not place the head (the while's batch cap, or one of the breaks)
+        self._blocked_head = self.waiting[0] if self.waiting else None
 
     def _plan_prefill_batch(
         self,
@@ -1148,6 +1184,7 @@ class Scheduler:
 
     def _preempt(self, victim: Sequence) -> None:
         self.preemptions += 1
+        self._blocked_head = None  # pages, a row and a slot come free
         ENGINE_PREEMPTIONS.inc()
         log.warning("preempting %s (recompute)", victim.request_id)
         self.running.remove(victim)
@@ -1191,6 +1228,14 @@ class Scheduler:
         )
         for i in range(seq.committed_blocks, n_complete_computed):
             self.allocator.commit_block(seq.block_table[i], hashes[i])
+            if (
+                self._blocked_head is not None
+                and hashes[i] in self._blocked_head.tokens.sequence_hashes()
+            ):
+                # a page of the blocked head's own prompt is now held
+                # by a running row: admitting it costs one page less
+                # (allocator.free_need), so it has to be tried again
+                self._blocked_head = None
         seq.committed_blocks = max(seq.committed_blocks, n_complete_computed)
 
     def should_finish(self, seq: Sequence) -> Optional[FinishReason]:
@@ -1209,6 +1254,7 @@ class Scheduler:
             return
         seq.state = SeqState.FINISHED
         seq.finish_reason = reason
+        self._blocked_head = None  # pages, a row and a slot come free
         if seq in self.running:
             self.running.remove(seq)
         if seq.block_table:

@@ -170,6 +170,102 @@ def test_scheduler_preemption_frees_blocks():
     assert b.block_table == []  # its blocks were freed
 
 
+def _blocked_scheduler(cause):
+    """One running sequence ``a`` and a request ``b`` that _admit cannot
+    place, for ``cause``; returns (sched, a, b)."""
+    from dynamo_tpu.engine.allocator import StateSlots
+
+    # 9 usable pages of 4 tokens; a: 8-token prompt, 24 more to come
+    # (8 pages at its end), so its reserve is 6 of the 7 pages free
+    alloc = BlockAllocator(10, 4)
+    sched = Scheduler(alloc, 4, max_batch_size=1 if cause == "batch" else 4)
+    if cause == "slots":
+        sched.state_slots = StateSlots(2)  # one usable slot
+    a = _mk_seq(list(range(8)), max_tokens=24 if cause == "reserve" else 4,
+                request_id="a")
+    sched.add_request(a)
+    plan = sched.plan()
+    sched.complete_prefill_chunk(plan.prefill)
+    assert sched.running == [a] and not sched.admission_work()
+    b = _mk_seq(list(range(100, 108)), max_tokens=4, request_id="b")
+    sched.add_request(b)
+    # an arrival into an EMPTY queue has not been tried: work
+    assert sched.admission_work()
+    assert sched.plan().kind == "decode"
+    assert list(sched.waiting) == [b] and sched.preemptions == 0
+    return sched, a, b
+
+
+@pytest.mark.parametrize("cause", ["reserve", "batch", "slots"])
+def test_admission_work_forgets_blocked_head_on_every_release(cause):
+    """admission_work(): a head that _admit could not place is no work
+    for the serial planner — whatever blocked it — until something is
+    freed; arrivals BEHIND it change nothing; a finish makes it work
+    again and the head is admitted first (FIFO)."""
+    sched, a, b = _blocked_scheduler(cause)
+    assert not sched.admission_work()
+    c = _mk_seq(list(range(200, 208)), max_tokens=4, request_id="c")
+    sched.add_request(c)  # queues behind the blocked head
+    assert not sched.admission_work()
+    # decode growth takes pages from pool and reserve alike: still blocked
+    for _ in range(3):
+        sched.append_token(a, 1)
+        assert sched.plan().kind == "decode"
+        assert not sched.admission_work()
+    sched.running.remove(a)
+    sched.finish(a, FinishReason.LENGTH)
+    assert sched.admission_work()
+    plan = sched.plan()
+    assert plan.kind == "prefill" and plan.prefill.seq is b
+    assert sched.preemptions == 0
+
+
+@pytest.mark.parametrize("event", ["cancel_head", "cancel_behind", "deadline",
+                                   "preempt", "head_shares_page"])
+def test_admission_work_sees_what_can_move_a_blocked_queue(event):
+    """The events other than a finish after which plan() has something
+    to do for a blocked queue: a waiting request cancelled or past its
+    deadline (reaped within a step), a preemption (the victim is the new
+    head), and a running row committing a page of the head's own prompt
+    (allocator.free_need falls by one)."""
+    import time
+
+    sched, a, b = _blocked_scheduler("reserve")
+    c = _mk_seq(list(range(200, 208)), max_tokens=4, request_id="c")
+    sched.add_request(c)
+    assert not sched.admission_work()
+    if event == "cancel_head":
+        b.is_cancelled = lambda: True
+    elif event == "cancel_behind":
+        c.is_cancelled = lambda: True
+    elif event == "deadline":
+        b.deadline = time.monotonic() - 1.0
+    elif event == "preempt":
+        sched._preempt(a)
+        assert sched.waiting[0] is a
+    elif event == "head_shares_page":
+        # b's prompt is a's prompt and the 4 tokens a generates next
+        sched.waiting.clear()
+        b = _mk_seq(list(range(8)) + [1, 1, 1, 1, 9], max_tokens=4,
+                    request_id="b2")
+        sched.add_request(b)
+        sched.plan()
+        assert list(sched.waiting) == [b] and not sched.admission_work()
+        for _ in range(4):
+            sched.append_token(a, 1)
+            assert not sched.admission_work()  # page 3 of a is not full yet
+        sched.append_token(a, 1)  # ...now it is computed, and committed
+    assert sched.admission_work()
+    sched.plan()
+    if event in ("cancel_head", "deadline"):
+        # reaped; c is the head now, tried, and blocked in its turn
+        assert b.state.value == "finished" and list(sched.waiting) == [c]
+        assert not sched.admission_work()
+    elif event == "cancel_behind":
+        assert c.state.value == "finished" and list(sched.waiting) == [b]
+        assert not sched.admission_work()
+
+
 # ---------------------------------------------------------------------------
 # Model correctness: incremental == one-shot
 # ---------------------------------------------------------------------------

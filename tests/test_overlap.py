@@ -301,3 +301,200 @@ async def test_overlap_records_phase_stamps():
         assert dbg["steps_dispatched"] > 0
     finally:
         await eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# A waiting queue that only a finish can move (Scheduler.admission_work)
+# ---------------------------------------------------------------------------
+
+# r0 and r1 run long; r2 and r3 cannot be placed until one of them ends
+_LONG = [list(range(1, 12)), list(range(30, 41))]
+_BLOCKED = [list(range(60, 80)), list(range(100, 120))]
+
+
+def _spy(eng) -> dict:
+    """Engine-thread stamps, counted in device dispatches: when each
+    request entered ``waiting``, was admitted, and finished."""
+    sched = eng.scheduler
+    log = {"intake": {}, "admit": {}, "finish": {}}
+    add, admit, fin = sched.add_request, sched._admit, sched.on_finish
+
+    def add_request(seq):
+        log["intake"].setdefault(seq.request_id, eng.overlap.steps_dispatched)
+        add(seq)
+
+    def _admit():
+        before = {id(s) for s in sched.prefilling}
+        admit()
+        for s in sched.prefilling:
+            if id(s) not in before:
+                log["admit"][s.request_id] = eng.overlap.steps_dispatched
+
+    def on_finish(seq, reason):
+        log["finish"][seq.request_id] = (eng.overlap.steps_dispatched, reason)
+        fin(seq, reason)
+
+    sched.add_request, sched._admit, sched.on_finish = add_request, _admit, on_finish
+    return log
+
+
+async def _launch_blocking(cause: str, overlap: bool):
+    """An engine in which two long requests leave no room for a third,
+    for ``cause``: the growth reserve (27 pages: the two need 24 at
+    their ends, a 20-token prompt 3 more than the 2 left over), the
+    batch rows, or the state slots (kimi tiny; the test holds one of
+    three slots, so the rows are not what runs out)."""
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    if cause == "slots":
+        from tests.test_kimi_linear_engine import launch
+
+        eng, _ = await launch(max_batch_size=3, overlap=overlap)
+        await eng.acall_on_thread(eng.scheduler.state_slots.acquire)
+        return eng
+    kw = dict(num_blocks=27) if cause == "reserve" else dict(max_batch_size=2)
+    return await JaxEngine.launch(_engine_config(overlap=overlap, **kw))
+
+
+async def _run_blocking(cause: str, overlap: bool, watch=None):
+    """r0..r3 through ``_launch_blocking``'s engine; ``watch(eng)`` runs
+    beside them. Returns (tokens of each stream, stamps, the engine's
+    counts)."""
+    eng = await _launch_blocking(cause, overlap)
+    try:
+        log = _spy(eng)
+        budgets = [64, 96, 8, 8]
+        streams = asyncio.gather(*[
+            _generate(eng, p, max_tokens=n, request_id=f"r{i}")
+            for i, (p, n) in enumerate(zip(_LONG + _BLOCKED, budgets))
+        ])
+        if watch is not None:
+            await watch(eng)
+        outs = await streams
+        counts = dict(eng.program_counts(), **{
+            "overlap": eng.debug_state()["overlap"],
+            "kv_preemptions": eng.scheduler.preemptions,
+        })
+        return [o[0] for o in outs], log, counts
+    finally:
+        await eng.shutdown()
+
+
+@pytest.mark.parametrize("cause", ["reserve", "batch", "slots"])
+async def test_blocked_queue_keeps_chaining_and_moves_at_first_finish(cause):
+    """Whatever keeps the head of ``waiting`` out (reserve, batch rows,
+    state slots), the pipeline keeps chaining while it waits; the first
+    finish admits it, in FIFO order and with no preemption; and every
+    stream's tokens are the serial engine's."""
+
+    async def chained_while_waiting(eng):
+        # all four were submitted before the first decode step, so every
+        # chained dispatch so far was issued with r2 and r3 waiting
+        await eng.wait_for_state(
+            lambda e: len(e.scheduler.waiting) == 2
+            and e._decode_dispatches[1] >= 5
+        )
+
+    over, log, counts = await _run_blocking(cause, True, chained_while_waiting)
+    serial, serial_log, serial_counts = await _run_blocking(cause, False)
+    assert over == serial
+    assert [len(o) for o in over] == [64, 96, 8, 8]
+    for lg, c in ((log, counts), (serial_log, serial_counts)):
+        assert list(lg["admit"]) == ["r0", "r1", "r2", "r3"]  # FIFO
+        # r2 waited, and went in at r0's finish: no dispatch in between
+        assert lg["intake"]["r2"] < lg["admit"]["r2"] == lg["finish"]["r0"][0]
+        finishes = {step for step, _ in lg["finish"].values()}
+        assert lg["admit"]["r3"] in finishes
+        assert c["kv_preemptions"] == 0 and c["preemptions"] == 0
+    d, chained = counts["decode_dispatches"], counts["decode_dispatches_chained"]
+    assert counts["overlap"]["decode_dispatches"] == d
+    assert counts["overlap"]["decode_dispatches_chained"] == chained
+    # an unchained dispatch is one entry into the pipeline: after an
+    # admission's prefill or a finish, never once per step
+    assert 0 < d - chained <= 10 and d > 90
+    assert serial_counts["decode_dispatches"] == 0
+
+
+@pytest.mark.parametrize("how", ["cancelled", "deadline"])
+async def test_blocked_request_leaves_the_queue_within_a_step(how):
+    """A waiting request that is cancelled, or whose deadline passes,
+    while the pipeline runs over a blocked queue is reaped before another
+    step is dispatched — as when every waiting request drained it."""
+    import time
+
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    # 30 pages: the two long ones need 28 at their ends, and hold 4
+    eng = await JaxEngine.launch(_engine_config(overlap=True, num_blocks=31))
+    try:
+        log = _spy(eng)
+        streams = asyncio.gather(*[
+            _generate(eng, p, max_tokens=n, request_id=f"r{i}")
+            for i, (p, n) in enumerate(zip(_LONG + _BLOCKED, [100, 100, 8, 8]))
+        ])
+        await eng.wait_for_state(
+            lambda e: len(e.scheduler.waiting) == 2
+            and e._decode_dispatches[1] >= 2
+        )
+        head, behind = list(eng.scheduler.waiting)
+        at = eng.overlap.steps_dispatched + 4
+        victim = head if how == "cancelled" else behind
+
+        def trigger() -> bool:
+            # the engine thread asks this of the head at every step: the
+            # event lands exactly when ``at`` programs have been dispatched
+            if eng.overlap.steps_dispatched < at:
+                return False
+            if how == "deadline":
+                behind.deadline = time.monotonic() - 1.0
+                return False
+            return True
+
+        head.is_cancelled = trigger
+        await eng.wait_for_state(lambda e: victim.request_id in log["finish"])
+        step, reason = log["finish"][victim.request_id]
+        assert step == at
+        assert reason == (FinishReason.CANCELLED if how == "cancelled"
+                          else FinishReason.TIMEOUT)
+        assert victim not in eng.scheduler.waiting
+        assert "r0" not in log["finish"] and "r1" not in log["finish"]
+        chained = eng._decode_dispatches[1]
+        # the queue is blocked again behind its new head: chaining goes on
+        await eng.wait_for_state(
+            lambda e: len(e.scheduler.waiting) == 1
+            and e._decode_dispatches[1] >= chained + 5
+        )
+        head.is_cancelled = lambda: False
+        outs = await streams
+        assert [len(o[0]) for o in outs] == [
+            100, 100, *((0, 8) if how == "cancelled" else (8, 0))]
+        assert eng.scheduler.preemptions == 0
+    finally:
+        await eng.shutdown()
+
+
+async def test_arrival_into_an_empty_queue_drains_the_pipeline():
+    """Nobody has tried to place a request that arrives into an empty
+    queue: the pipeline drains for it before the next dispatch, and it is
+    admitted at once — exactly as before the blocked-queue rule."""
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    async def run(overlap):
+        eng = await JaxEngine.launch(_engine_config(overlap=overlap))
+        try:
+            log = _spy(eng)
+            first = asyncio.ensure_future(
+                _generate(eng, _LONG[0], max_tokens=80, request_id="r0"))
+            await eng.wait_for_state(
+                lambda e: e.overlap.steps_dispatched >= 8
+                and (not overlap or e._decode_dispatches[1] >= 5))
+            late = await _generate(eng, _BLOCKED[0], max_tokens=8,
+                                   request_id="r1")
+            return (await first)[0], late[0], log
+        finally:
+            await eng.shutdown()
+
+    over0, over1, log = await run(True)
+    serial0, serial1, _ = await run(False)
+    assert (over0, over1) == (serial0, serial1)
+    assert log["admit"]["r1"] == log["intake"]["r1"] < log["finish"]["r0"][0]
