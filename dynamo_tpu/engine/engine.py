@@ -298,8 +298,8 @@ class JaxEngine:
         # single-step decode dispatches of _decode_pipeline, and those
         # of them issued with a step still in flight (program_counts)
         self._decode_dispatches = [0, 0]
-        # the family's device-side counts (models/kimi_linear.py
-        # COUNT_NAMES): totals as Python ints, and the device's last
+        # the family's device-side counts (its module's COUNT_NAMES,
+        # models/__init__.py): totals as Python ints, and the device's last
         # int32 reading (the device wraps, the totals do not)
         self._family_counts: dict[str, int] = {}
         self._family_counts_seen: Optional[np.ndarray] = None
@@ -1578,8 +1578,9 @@ class JaxEngine:
         fam = model_family(mc)
         reserved = 0
         if mc.has_recurrent_state:
-            # latent pages instead of K and V; the state plane and the
-            # family's own step transients come off the top
+            # the family's own pages (latent rows, or the K and V of its
+            # attention layers alone); the state plane and the family's
+            # own step transients come off the top
             bytes_per_block_total = fam.page_bytes_per_block(
                 mc, self.config.block_size, itemsize
             )
@@ -2496,7 +2497,7 @@ class JaxEngine:
             raise NotImplementedError(
                 "KV block export/import (disaggregated transfer, fleet "
                 "fabric) moves K/V pages only: a model with recurrent "
-                "state and latent pages is not supported"
+                "state beside its pages is not supported"
             )
 
     async def export_kv_blocks(
@@ -5080,13 +5081,14 @@ class JaxEngine:
             }
         if sched is not None and sched.state_slots is not None:
             # models with recurrent layers: the per-sequence state plane
-            # beside the pages (which then hold latent rows)
+            # beside the pages (the family's own: latent rows, or the
+            # K and V of its attention layers)
             slots = sched.state_slots
             out["state_plane"] = {
                 "total_slots": slots.num_slots - 1,
                 "used_slots": slots.num_used,
                 "bytes": self._plane_bytes[1],
-                "latent_pool_bytes": self._plane_bytes[0],
+                "page_pool_bytes": self._plane_bytes[0],
             }
         out["hbm"] = self.hbm.refresh()
         # the device this engine actually runs on, the kernel impls that

@@ -5,23 +5,82 @@ TRT-LLM, reference: SURVEY.md §1 L3); dynamo-tpu's flagship engine is
 native: functional JAX models (params as pytrees), lax.scan over layers for
 fast compiles, paged KV cache, and named-axis shardings so pjit/XLA place
 the collectives.
+
+THE CONTRACT OF A FAMILY MODULE (what ``family`` returns; the engine and
+the loader reach a model through nothing else):
+
+- ``param_shapes(cfg)``, ``param_specs(cfg)``, ``init_params(cfg, seed,
+  mesh, specs)``: the parameter pytree and its shardings;
+- ``init_cache(cfg, num_blocks, block_size, mesh, dtype, spec[,
+  state_slots])``: the two cache pytrees the step threads through and
+  donates (K and V pages; or a family's pages and its state plane);
+- ``forward(cfg, params, cache_a, cache_b, tokens, positions,
+  slot_mapping, block_tables, context_lens, last_token_idx, block_size,
+  ...) -> (logits, cache_a, cache_b)``: one model step;
+- optionally ``FAMILIES``: the ``model_type`` values it serves beside its
+  own file name.
+
+A family that draws its own parameters and keeps a fixed-size state per
+sequence beside the paged rows says so and gives the engine what it
+sizes and checks with (``models/kimi_linear.py``, ``models/qwen3_next.py``):
+
+- ``init_params_quantized(cfg, seed, mesh, specs)`` (the loader then
+  takes random weights only);
+- ``RECURRENT_STATE = True``: every admitted sequence gets a slot of the
+  state plane, the table's last column; prefix reuse is off;
+- ``page_bytes_per_block(cfg, block_size, itemsize)``,
+  ``state_bytes(cfg, state_slots, itemsize)``, ``STEP_TRANSIENT_BYTES``:
+  what a block of pages, the state plane and a step's temporaries take;
+- ``check_engine(engine_config)``: raises for what it does not build;
+- ``COUNT_NAMES``: the cumulative int32 counts it keeps on the device in
+  ``cache_b["counts"]`` (``engine.program_counts``).
 """
 
+import functools
+import importlib
+import importlib.util
+import pkgutil
+
 from dynamo_tpu.models.config import ModelConfig
+
+REQUIRED = ("param_shapes", "param_specs", "init_params", "init_cache", "forward")
+
+
+def _is_family(module) -> bool:
+    return all(hasattr(module, name) for name in REQUIRED)
 
 
 def family(cfg: ModelConfig):
     """The module that holds a configuration's parameters, cache and
-    step: ``models/kimi_linear.py`` for ``model_type`` ``kimi_linear``,
-    ``models/llama.py`` for every other. Both give ``param_shapes``,
-    ``param_specs``, ``init_params``, ``init_cache`` and ``forward``."""
-    if cfg.model_type == "kimi_linear":
-        from dynamo_tpu.models import kimi_linear
+    step: ``models/<model_type>.py`` (``-`` read as ``_``) where that
+    file is a family module, else the one whose ``FAMILIES`` lists the
+    ``model_type`` (``models/llama.py``: the dense decoders), else an
+    error that names what exists. The contract is this module's
+    docstring."""
+    return _family_of(str(cfg.model_type))
 
-        return kimi_linear
+
+@functools.lru_cache(maxsize=None)
+def _family_of(model_type: str):
+    stem = model_type.replace("-", "_")
+    if stem.isidentifier() and importlib.util.find_spec(f"{__name__}.{stem}"):
+        module = importlib.import_module(f"{__name__}.{stem}")
+        if _is_family(module):
+            return module
     from dynamo_tpu.models import llama
 
-    return llama
+    if model_type in llama.FAMILIES:
+        return llama
+    served = {}
+    for info in pkgutil.iter_modules(__path__):
+        module = importlib.import_module(f"{__name__}.{info.name}")
+        if _is_family(module):
+            served[info.name] = sorted(getattr(module, "FAMILIES", ()))
+    raise LookupError(
+        f"no model family for model_type {model_type!r}: no family module "
+        f"dynamo_tpu/models/{stem}.py, and no FAMILIES lists it "
+        f"(family modules and what they list: {served})"
+    )
 
 
 __all__ = ["ModelConfig", "family"]

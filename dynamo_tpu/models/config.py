@@ -69,6 +69,21 @@ class ModelConfig:
     topk_group: int = 1
     expert_shards: int = 1
     expert_shard_index: int = 0
+    # qwen3_next (models/qwen3_next.py): every full_attention_interval-th
+    # layer is gated GQA attention with a rotary part of each head, the
+    # others Gated DeltaNet (linear_*); every layer has softmax-routed
+    # experts (num_experts_per_tok of them) and a gated shared expert
+    full_attention_interval: int = 0
+    partial_rotary_factor: float = 1.0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    shared_expert_intermediate_size: int = 0
+    norm_topk_prob: bool = True
+    decoder_sparse_step: int = 1
+    mlp_only_layers: list = field(default_factory=list)
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -81,8 +96,11 @@ class ModelConfig:
     @property
     def has_recurrent_state(self) -> bool:
         """Layers that keep a fixed-size state per sequence beside the
-        paged rows (the engine gives every running sequence a slot)."""
-        return self.model_type == "kimi_linear"
+        paged rows (the engine gives every running sequence a slot): the
+        configuration's family module says so (``RECURRENT_STATE``)."""
+        from dynamo_tpu.models import family
+
+        return bool(getattr(family(self), "RECURRENT_STATE", False))
 
     @property
     def eos_token_ids(self) -> list[int]:

@@ -1,0 +1,383 @@
+"""What the hybrid families share (``models/kimi_linear.py``,
+``models/qwen3_next.py``): decoders whose layers are unrolled by kind,
+with a per-sequence recurrent state beside the paged rows and an expert
+layer that holds a share of the experts.
+
+- the seeded draw and its weight-only int8 form (``init``, ``quantize``,
+  the draws of the delta rule's decay parameters);
+- a stacked weight's matmul with a float32 result (``mm``, ``weight``,
+  ``einsum_f32``, ``gated_mlp``);
+- the gated delta rule over a head's ``[d_k, d_v]`` float32 state, a
+  token at a time (``delta_decode``) and a chunk at a time
+  (``delta_chunked``), with the log-decay a vector over the key
+  channels (KDA) or one scalar a head (Gated DeltaNet: minor dimension 1);
+- the held experts' part of an expert layer once the family's router has
+  chosen (``moe_local``: every held expert over a few rows, or the rows
+  sorted by expert through ``ragged_dot``), with its on-device counts;
+- what no such family builds yet, refused at start-up (``check_engine``).
+
+A family keeps what is its own: the layer plan, the mixers, the router,
+``Geometry``, the cache shapes.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Callable, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from dynamo_tpu.models import llama
+
+Params = dict[str, Any]
+
+DELTA_CHUNK = 64     # tokens per block of the chunked recurrence
+GLOBAL = ("embed", "final_norm", "lm_head")   # every other parameter is a stack
+MOE_COUNT_NAMES = ("moe_layer_calls", "moe_local_assignments",
+                   "moe_experts_touched")
+
+
+# ---------------------------------------------------------------------------
+# The seeded draw
+# ---------------------------------------------------------------------------
+
+
+def draw_normal(key, shape: tuple[int, ...]):
+    """``normal / sqrt(fan_in)`` (fan_in: the second-to-last axis)."""
+    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(max(1, fan_in))
+
+
+def draw_A_log(key, shape: tuple[int, ...]):
+    """``A_log = log(U(1, 16))``."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def draw_dt_bias(key, shape: tuple[int, ...]):
+    """The inverse softplus of a log-uniform step in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def quantize(arr, axis: int):
+    amax = jnp.max(jnp.abs(arr), axis=axis, keepdims=True)
+    scale = jnp.maximum(amax, 1e-12) / 127.0
+    q = jnp.clip(jnp.round(arr / scale), -127, 127).astype(jnp.int8)
+    return q, jnp.squeeze(scale, axis=axis)
+
+
+def init(shapes: dict, draw_one: Callable, quant_axis: dict, seed: int, mesh,
+         quantized: bool, dtype) -> Params:
+    """The seeded draw: parameter ``i`` of ``shapes`` (a family's
+    ``param_shapes``, whose ORDER is part of the recipe) has key
+    ``fold_in(PRNGKey(seed), i)``; a stacked parameter draws layer ``j`` of
+    its stack from ``fold_in(., j)`` and, where it holds experts, expert
+    ``e`` from ``fold_in(., e)`` again (``models/quant.py``
+    ``init_params_quantized``'s order, extended). ``draw_one(name, key,
+    shape)`` is the family's rule for one leading slice in float32;
+    ``quant_axis`` names the axis the int8 scales reduce over. One slice
+    at a time on the device, so the float32 transient is one layer's."""
+    root = jax.random.PRNGKey(seed)
+    params: Params = {}
+
+    def put(arr):
+        if mesh is not None:
+            arr = jax.device_put(arr, NamedSharding(mesh, P()))
+        return arr
+
+    for i, (name, (shape, want)) in enumerate(shapes.items()):
+        key = jax.random.fold_in(root, i)
+        axis = quant_axis.get(name) if quantized else None
+        out_dtype = want if dtype is None or want == jnp.float32 else dtype
+
+        def leaf(k, shp, name=name, axis=axis, out_dtype=out_dtype):
+            arr = draw_one(name, k, shp)
+            if axis is not None:
+                return quantize(arr, axis)
+            return arr.astype(out_dtype), None
+
+        if name in GLOBAL:
+            q, s = jax.jit(lambda k, shp=shape: leaf(k, shp))(key)
+        else:
+            if len(shape) == 4:      # [layers, experts, ., .]
+                one = jax.jit(lambda k, shp=shape[2:], n=shape[1]: jax.vmap(
+                    lambda e: leaf(jax.random.fold_in(k, e), shp)
+                )(jnp.arange(n)))
+            else:
+                one = jax.jit(lambda k, shp=shape[1:]: leaf(k, shp))
+            parts = [one(jax.random.fold_in(key, j)) for j in range(shape[0])]
+            q = jnp.stack([p[0] for p in parts])
+            s = jnp.stack([p[1] for p in parts]) if axis is not None else None
+        params[name] = put(q)
+        if s is not None:
+            params[name + "_scale"] = put(s)
+    return params
+
+
+def check_engine(config, what: str) -> None:
+    """What is not built for a family with recurrent state is refused
+    when the engine starts, never served wrong. (A checkpoint is refused
+    by ``models/loader.py``, block export and import by
+    ``engine.refuse_kv_transfer``, injected embeddings by ``forward``.)"""
+    refused = {
+        "tensor_parallel_size > 1": config.tensor_parallel_size > 1,
+        "expert_parallel_size > 1": config.expert_parallel_size > 1,
+        "pipeline_parallel_size > 1": config.pipeline_parallel_size > 1,
+        "data_parallel_size > 1": config.data_parallel_size > 1,
+        "num_nodes > 1": config.num_nodes > 1,
+        "spec_decode (a rejected draft cannot be taken out of the "
+        "recurrent state)": bool(config.spec_decode),
+        "host_kv_blocks > 0 (KVBM offload moves K/V pages only)":
+            config.host_kv_blocks > 0,
+        "kv_cache_dtype int8": jnp.dtype(config.kv_cache_dtype) == jnp.int8,
+    }
+    bad = [name for name, hit in refused.items() if hit]
+    if bad:
+        raise ValueError(f"{what} does not support: " + "; ".join(bad))
+
+
+# ---------------------------------------------------------------------------
+# Matmuls of a stacked weight
+# ---------------------------------------------------------------------------
+
+
+def kernels_active() -> bool:
+    """The families' own Pallas kernels run where the attention kernels
+    do: on a TPU, one device (``llama.pallas_attention_active``)."""
+    return llama.pallas_attention_active()
+
+
+# A matmul's RESULT keeps the float32 of its accumulator (its operands
+# are the activation dtype): what reads it — a nonlinearity, the float32
+# residual stream — rounds once, when it next becomes a matmul's operand,
+# and not a second time in between.
+MM_OUT = jnp.float32
+
+
+def mm(p: Params, name: str, x: jax.Array, idx: int) -> jax.Array:
+    """x @ p[name][idx] for a stacked weight, in ``MM_OUT``: int8 through
+    the ``qmm`` kernel (reads layer ``idx`` in place) where the shape is
+    a multiple of 128 both ways, else the mixed-dtype dot; float weights
+    plainly."""
+    w = p[name]
+    out = MM_OUT or x.dtype
+    if w.dtype != jnp.int8:
+        return einsum_f32("...k,kn->...n", x, w[idx].astype(x.dtype)).astype(out)
+    K, N = w.shape[-2:]
+    if llama.pallas_matmul_active() and K % 128 == 0 and N % 128 == 0:
+        from dynamo_tpu.ops.qmatmul import qmm
+
+        return qmm(x, w, p[name + "_scale"], interpret=llama._qmm_interpret(),
+                   layer=jnp.int32(idx), out_dtype=out)
+    y = jax.lax.dot_general(
+        x, w[idx], (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return (y * p[name + "_scale"][idx]).astype(out)
+
+
+def weight(p: Params, name: str, idx: int, dtype) -> jax.Array:
+    """Layer ``idx`` of a stacked weight, dequantized."""
+    w = p[name][idx]
+    if w.dtype == jnp.int8:
+        return (w.astype(jnp.float32) * p[name + "_scale"][idx]).astype(dtype)
+    return w.astype(dtype)
+
+
+def einsum_f32(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """einsum with float32 accumulation and result. XLA:CPU has no
+    bf16 x bf16 -> f32 matmul for every shape: there the operands are
+    upcast first, which is exact."""
+    if jax.default_backend() == "cpu":
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+
+
+def gated_mlp(p: Params, names: tuple[str, str, str], h: jax.Array,
+              idx: int) -> jax.Array:
+    gate, up, down = names
+    mid = jax.nn.silu(mm(p, gate, h, idx)) * mm(p, up, h, idx)
+    return mm(p, down, mid.astype(h.dtype), idx)
+
+
+# ---------------------------------------------------------------------------
+# The gated delta rule
+# ---------------------------------------------------------------------------
+
+
+def delta_decode(q, k, v, glog, beta, S):
+    """One recurrent update a row. q, k, v [B, H, d]; glog [B, H, d] or
+    [B, H, 1]; beta [B, H]; S [B, H, d(key), d(value)] float32. Returns
+    (o [B, H, d], S')."""
+    with jax.named_scope("delta_decode"), jax.default_matmul_precision("highest"):
+        S = jnp.exp(glog)[..., None] * S
+        u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", S, k))
+        S = S + k[..., :, None] * u[..., None, :]
+        return jnp.einsum("bhkv,bhk->bhv", S, q), S
+
+
+def delta_chunk_for(rows: int, T: int) -> int:
+    """Tokens per block: the [rows, C, C, H, d] decay block is what a
+    chunk holds at once, so more rows take shorter blocks."""
+    C = DELTA_CHUNK
+    while C > 16 and rows * C * C > 8 * DELTA_CHUNK * DELTA_CHUNK:
+        C //= 2
+    return min(C, T)
+
+
+def delta_chunked(q, k, v, glog, beta, S, chunk: Optional[int] = None):
+    """The same recurrence over T tokens, ``chunk`` at a time. q, k, v
+    [B, T, H, d] float32; glog [B, T, H, d], or [B, T, H, 1] where the
+    decay is one scalar a head; beta [B, T, H]; S [B, H, d, d].
+
+    With G_t the decay summed from the chunk's start (so every exponent
+    below is of G_t - G_j <= 0, t >= j: nothing overflows), the delta
+    rule's corrections u_t solve a unit lower-triangular system:
+      u_t + beta_t sum_{j<t} A_tj u_j = beta_t (v_t - S0^T (k_t e^{G_t})),
+      A_tj = sum_c k_t[c] k_j[c] e^{G_t[c] - G_j[c]};
+      o_t = S0^T (q_t e^{G_t}) + sum_{j<=t} B_tj u_j,  B as A with q_t;
+      S' = e^{G_C} S0 + sum_j (k_j e^{G_C - G_j}) u_j^T.
+    A scalar decay leaves the sum over c: A and B are then plain matrix
+    products times e^{G_t - G_j}, and no [C, C, H, d] block exists.
+    A token with beta 0 and glog 0 (padding) changes nothing."""
+    B, T, H, d = q.shape
+    C = min(chunk or delta_chunk_for(B, T), T)
+    assert T % C == 0, (T, C)
+    per_head = glog.shape[-1] == 1
+
+    def blocks(x):
+        return jnp.moveaxis(x.reshape(B, T // C, C, *x.shape[2:]), 1, 0)
+
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+
+    def body(S, blk):
+        qc, kc, vc, gc, bc = blk                      # [B, C, H, .]
+        G = jnp.cumsum(gc, axis=1)                    # [B, C, H, d | 1]
+        diff = G[:, :, None] - G[:, None, :]          # [B, C(t), C(j), H, d | 1]
+        decay = jnp.exp(jnp.where(tri[None, :, :, None, None], diff, -jnp.inf))
+        if per_head:
+            kk = jnp.einsum("bthk,bjhk->btjh", kc, kc) * decay[..., 0]
+            Bm = jnp.einsum("bthk,bjhk->btjh", qc, kc) * decay[..., 0]
+            A = jnp.where(strict[None, :, :, None], kk, 0.0)       # [B,C,C,H]
+        else:
+            kk = kc[:, :, None] * kc[:, None, :] * decay
+            A = jnp.where(strict[None, :, :, None], kk.sum(-1), 0.0)   # [B,C,C,H]
+            Bm = (qc[:, :, None] * kc[:, None, :] * decay).sum(-1)     # [B,C,C,H]
+        eG = jnp.exp(G)
+        rhs = bc[..., None] * (vc - jnp.einsum("bhkv,bthk->bthv", S, kc * eG))
+        M = jnp.eye(C)[None, :, :, None] + bc[:, :, None, :] * A
+        u = jax.scipy.linalg.solve_triangular(
+            jnp.moveaxis(M, 3, 1), jnp.moveaxis(rhs, 2, 1), lower=True,
+            unit_diagonal=True)                        # [B, H, C, d]
+        o = jnp.einsum("bhkv,bthk->bthv", S, qc * eG) + jnp.einsum(
+            "btjh,bhjv->bthv", Bm, u)
+        last = G[:, -1]                                # [B, H, d | 1]
+        S = jnp.exp(last)[..., None] * S + jnp.einsum(
+            "bjhk,bhjv->bhkv", kc * jnp.exp(last[:, None] - G), u)
+        return S, o
+
+    with jax.named_scope("delta_chunked"), jax.default_matmul_precision("highest"):
+        S, o = jax.lax.scan(body, S, tuple(map(blocks, (q, k, v, glog, beta))))
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, H, d), S
+
+
+# ---------------------------------------------------------------------------
+# The held experts
+# ---------------------------------------------------------------------------
+
+
+def _expert_weights(p: Params, name: str, idx: int, dtype):
+    w = p[name][idx]
+    scale = p[name + "_scale"][idx] if w.dtype == jnp.int8 else None
+    return w.astype(dtype), scale
+
+
+def moe_local_dense(p: Params, x: jax.Array, combine: jax.Array, idx: int):
+    """Every held expert over every token, weighted by ``combine`` [N, E]
+    (0 where a token did not choose the expert): the form for a few rows,
+    where each expert's weights cross HBM once whatever was chosen."""
+    def edot(eq, a, name):
+        w, scale = _expert_weights(p, name, idx, a.dtype)
+        y = einsum_f32(eq, a, w)
+        return y if scale is None else y * scale[:, None, :]
+
+    with jax.named_scope("moe_experts"):
+        gate = edot("nd,edf->enf", x, "we_gate")
+        up = edot("nd,edf->enf", x, "we_up")
+        mid = (jax.nn.silu(gate) * up).astype(x.dtype)
+        return jnp.einsum("end,ne->nd", edot("enf,efd->end", mid, "we_down"),
+                          combine)
+
+
+def moe_local_grouped(p: Params, x: jax.Array, w: jax.Array, local_e: jax.Array,
+                      idx: int, E: int):
+    """Assignments sorted by held expert, then grouped matmuls
+    (``ragged_dot``) over each expert's run of rows: work and weight
+    reads follow the rows assigned. ``local_e`` [N, k]: the held expert's
+    index, or E for an assignment another shard holds (sorted last, in no
+    group, its weight already 0)."""
+    N, k = local_e.shape
+    flat = local_e.reshape(-1)
+    order = jnp.argsort(flat)
+    sorted_e = flat[order]
+    xs = jnp.take(x, order // k, axis=0)
+    sizes = jnp.bincount(sorted_e, length=E + 1)[:E].astype(jnp.int32)
+    held = sorted_e < E
+    cpu = jax.default_backend() == "cpu"
+
+    def gdot(a, name):
+        # the upcast copy of the layer's experts (XLA does not fuse it
+        # into the grouped matmul) must not be made before its rows
+        # exist: tied to them, one layer's copies live at a time — left
+        # free, the compiler hoists every layer's to the step's start
+        # (8 GB at the published widths, refused by the chip's compiler)
+        stack, a = jax.lax.optimization_barrier((p[name], a))
+        w8 = stack[idx]
+        scale = p[name + "_scale"][idx] if w8.dtype == jnp.int8 else None
+        wt = w8.astype(jnp.float32 if cpu else a.dtype)
+        y = jax.lax.ragged_dot(a.astype(wt.dtype), wt, sizes,
+                               preferred_element_type=jnp.float32)
+        if scale is not None:
+            y = y * jnp.take(scale, jnp.minimum(sorted_e, E - 1), axis=0)
+        return y
+
+    with jax.named_scope("moe_experts"):
+        mid = jax.nn.silu(gdot(xs, "we_gate")) * gdot(xs, "we_up")
+        out = jnp.where(held[:, None], gdot(mid.astype(x.dtype), "we_down"), 0)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
+    out = jnp.take(out, inv, axis=0).reshape(N, k, -1)
+    return jnp.sum(out * w[..., None], axis=1)
+
+
+def moe_local(p: Params, x: jax.Array, w: jax.Array, topi: jax.Array, idx: int,
+              e0: int, E: int, dense_tokens: int,
+              valid: Optional[jax.Array] = None):
+    """This process's experts' share of the routed sum. x [N, D]; ``w``,
+    ``topi`` [N, k]: the router's weights and choices over ALL experts;
+    this process holds experts ``e0 ... e0 + E - 1`` (what other shards'
+    experts add is theirs to compute). Up to ``dense_tokens`` rows go
+    through every held expert, more are sorted by expert. Returns
+    (routed [N, D] float32, counts int32 [3] in ``MOE_COUNT_NAMES``'
+    order): this call, the assignments of real tokens (``valid`` [N];
+    padding is not traffic) to held experts, and the held experts they
+    touched."""
+    N = x.shape[0]
+    local = (topi >= e0) & (topi < e0 + E)
+    w = jnp.where(local, w, 0.0)
+    local_e = jnp.where(local, topi - e0, E)
+    real = local if valid is None else local & valid.reshape(N, 1)
+    touched = jnp.zeros((E + 1,), jnp.int32).at[
+        jnp.where(real, local_e, E)].max(1)[:E]
+    counts = jnp.stack([jnp.int32(1), jnp.sum(real, dtype=jnp.int32),
+                        jnp.sum(touched, dtype=jnp.int32)])
+    if N <= dense_tokens:
+        combine = jnp.zeros((N, E + 1), jnp.float32).at[
+            jnp.arange(N)[:, None], local_e].add(w)[:, :E]
+        routed = moe_local_dense(p, x, combine, idx)
+    else:
+        routed = moe_local_grouped(p, x, w, local_e, idx, E)
+    return routed, counts

@@ -50,13 +50,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.models import llama
+from dynamo_tpu.models import hybrid, llama
 from dynamo_tpu.models.config import ModelConfig
 
 Params = dict[str, Any]
 
 LOW_RANK = 128       # decay and output-gate bottleneck (not in config.json)
-KDA_CHUNK = 64       # tokens per block of the chunked recurrence
 MLA_QUERY_TOKENS = 512  # query tokens whose scores exist at once (prefill)
 # at most this many tokens go through every held expert at once; more are
 # sorted by expert and go through the grouped matmul
@@ -67,7 +66,8 @@ MOE_DENSE_TOKENS = 64
 # chunk, the grouped matmuls' sorted rows — the engine leaves this free
 # when it sizes the pages
 STEP_TRANSIENT_BYTES = 4 << 30
-COUNT_NAMES = ("moe_layer_calls", "moe_local_assignments", "moe_experts_touched")
+COUNT_NAMES = hybrid.MOE_COUNT_NAMES
+RECURRENT_STATE = True   # every admitted sequence holds a state slot
 
 
 class Geometry:
@@ -152,9 +152,6 @@ QUANT_AXIS = {
 }
 
 
-_GLOBAL = ("embed", "final_norm", "lm_head")   # every other is a stack
-
-
 def param_shapes(cfg: ModelConfig) -> dict[str, tuple[tuple[int, ...], Any]]:
     """name -> (shape, dtype); layer parameters are stacked per KIND."""
     g = Geometry(cfg)
@@ -228,64 +225,17 @@ def _draw_one(name: str, key, shape: tuple[int, ...]):
     if name == "router_bias":
         return jnp.zeros(shape, jnp.float32)
     if name == "kda_A_log":
-        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+        return hybrid.draw_A_log(key, shape)
     if name == "kda_dt_bias":
-        dt = jnp.exp(jax.random.uniform(
-            key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
-        return dt + jnp.log(-jnp.expm1(-dt))
-    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-    return jax.random.normal(key, shape, jnp.float32) / math.sqrt(max(1, fan_in))
-
-
-def _quantize(arr, axis: int):
-    amax = jnp.max(jnp.abs(arr), axis=axis, keepdims=True)
-    scale = jnp.maximum(amax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(arr / scale), -127, 127).astype(jnp.int8)
-    return q, jnp.squeeze(scale, axis=axis)
+        return hybrid.draw_dt_bias(key, shape)
+    return hybrid.draw_normal(key, shape)
 
 
 def _init(cfg: ModelConfig, seed: int, mesh, quantize: bool, dtype) -> Params:
-    """The seeded draw: parameter ``i`` of ``param_shapes`` order has key
-    ``fold_in(PRNGKey(seed), i)``; a stacked parameter draws layer ``j`` of
-    its stack from ``fold_in(., j)`` and, where it holds experts, expert
-    ``e`` from ``fold_in(., e)`` again (``models/quant.py``
-    ``init_params_quantized``'s order, extended). One slice at a time on
-    the device, so the float32 transient is one layer's."""
-    root = jax.random.PRNGKey(seed)
-    params: Params = {}
-
-    def put(arr):
-        if mesh is not None:
-            arr = jax.device_put(arr, NamedSharding(mesh, P()))
-        return arr
-
-    for i, (name, (shape, want)) in enumerate(param_shapes(cfg).items()):
-        key = jax.random.fold_in(root, i)
-        axis = QUANT_AXIS.get(name) if quantize else None
-        out_dtype = want if dtype is None or want == jnp.float32 else dtype
-
-        def leaf(k, shp, name=name, axis=axis, out_dtype=out_dtype):
-            arr = _draw_one(name, k, shp)
-            if axis is not None:
-                return _quantize(arr, axis)
-            return arr.astype(out_dtype), None
-
-        if name in _GLOBAL:
-            q, s = jax.jit(lambda k, shp=shape: leaf(k, shp))(key)
-        else:
-            if len(shape) == 4:      # [layers, experts, ., .]
-                one = jax.jit(lambda k, shp=shape[2:], n=shape[1]: jax.vmap(
-                    lambda e: leaf(jax.random.fold_in(k, e), shp)
-                )(jnp.arange(n)))
-            else:
-                one = jax.jit(lambda k, shp=shape[1:]: leaf(k, shp))
-            parts = [one(jax.random.fold_in(key, j)) for j in range(shape[0])]
-            q = jnp.stack([p[0] for p in parts])
-            s = jnp.stack([p[1] for p in parts]) if axis is not None else None
-        params[name] = put(q)
-        if s is not None:
-            params[name + "_scale"] = put(s)
-    return params
+    """The seeded draw (``hybrid.init``: parameter ``i`` of
+    ``param_shapes`` order, layer ``j``, expert ``e``)."""
+    return hybrid.init(param_shapes(cfg), _draw_one, QUANT_AXIS, seed, mesh,
+                       quantize, dtype)
 
 
 def init_params(cfg: ModelConfig, seed: int = 0, mesh: Optional[Mesh] = None,
@@ -361,24 +311,9 @@ def init_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 def check_engine(config) -> None:
     """What is not built for this family is refused when the engine
     starts, never served wrong."""
-    refused = {
-        "tensor_parallel_size > 1": config.tensor_parallel_size > 1,
-        "expert_parallel_size > 1": config.expert_parallel_size > 1,
-        "pipeline_parallel_size > 1": config.pipeline_parallel_size > 1,
-        "data_parallel_size > 1": config.data_parallel_size > 1,
-        "num_nodes > 1": config.num_nodes > 1,
-        "spec_decode (a rejected draft cannot be taken out of the "
-        "recurrent state)": bool(config.spec_decode),
-        "host_kv_blocks > 0 (KVBM offload moves K/V pages only)":
-            config.host_kv_blocks > 0,
-        "kv_cache_dtype int8": jnp.dtype(config.kv_cache_dtype) == jnp.int8,
-    }
-    bad = [what for what, hit in refused.items() if hit]
-    if bad:
-        raise ValueError(
-            "model_type kimi_linear (recurrent state + latent pages) does "
-            "not support: " + "; ".join(bad)
-        )
+    hybrid.check_engine(
+        config, "model_type kimi_linear (recurrent state + latent pages)")
+
 
 
 # ---------------------------------------------------------------------------
@@ -386,63 +321,13 @@ def check_engine(config) -> None:
 # ---------------------------------------------------------------------------
 
 
-def kernels_active() -> bool:
-    """The family's own Pallas kernels run where the attention kernels
-    do: on a TPU, one device (``llama.pallas_attention_active``)."""
-    return llama.pallas_attention_active()
-
-
-# A matmul's RESULT keeps the float32 of its accumulator (its operands
-# are the activation dtype): what reads it — a nonlinearity, the float32
-# residual stream — rounds once, when it next becomes a matmul's operand,
-# and not a second time in between.
-MM_OUT = jnp.float32
-
-
-def _mm(p: Params, name: str, x: jax.Array, idx: int) -> jax.Array:
-    """x @ p[name][idx] for a stacked weight, in ``MM_OUT``: int8 through
-    the ``qmm`` kernel (reads layer ``idx`` in place) where the shape is
-    a multiple of 128 both ways, else the mixed-dtype dot; float weights
-    plainly."""
-    w = p[name]
-    out = MM_OUT or x.dtype
-    if w.dtype != jnp.int8:
-        return _einsum_f32("...k,kn->...n", x, w[idx].astype(x.dtype)).astype(out)
-    K, N = w.shape[-2:]
-    if llama.pallas_matmul_active() and K % 128 == 0 and N % 128 == 0:
-        from dynamo_tpu.ops.qmatmul import qmm
-
-        return qmm(x, w, p[name + "_scale"], interpret=llama._qmm_interpret(),
-                   layer=jnp.int32(idx), out_dtype=out)
-    y = jax.lax.dot_general(
-        x, w[idx], (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return (y * p[name + "_scale"][idx]).astype(out)
-
-
-def _weight(p: Params, name: str, idx: int, dtype) -> jax.Array:
-    """Layer ``idx`` of a stacked weight, dequantized."""
-    w = p[name][idx]
-    if w.dtype == jnp.int8:
-        return (w.astype(jnp.float32) * p[name + "_scale"][idx]).astype(dtype)
-    return w.astype(dtype)
-
-
-def _einsum_f32(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
-    """einsum with float32 accumulation and result. XLA:CPU has no
-    bf16 x bf16 -> f32 matmul for every shape: there the operands are
-    upcast first, which is exact."""
-    if jax.default_backend() == "cpu":
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
-
-
-def _gated_mlp(p: Params, names: tuple[str, str, str], h: jax.Array,
-               idx: int) -> jax.Array:
-    gate, up, down = names
-    mid = jax.nn.silu(_mm(p, gate, h, idx)) * _mm(p, up, h, idx)
-    return _mm(p, down, mid.astype(h.dtype), idx)
+# shared with the other hybrid family (models/hybrid.py)
+kernels_active = hybrid.kernels_active
+_mm, _weight, _einsum_f32 = hybrid.mm, hybrid.weight, hybrid.einsum_f32
+_gated_mlp = hybrid.gated_mlp
+kda_decode, kda_chunked = hybrid.delta_decode, hybrid.delta_chunked
+kda_chunk_for = hybrid.delta_chunk_for
+moe_local_dense, moe_local_grouped = hybrid.moe_local_dense, hybrid.moe_local_grouped
 
 
 def kda_decay_log(p: Params, f: jax.Array, idx: int, g: Geometry) -> jax.Array:
@@ -450,73 +335,6 @@ def kda_decay_log(p: Params, f: jax.Array, idx: int, g: Geometry) -> jax.Array:
     f = f.astype(jnp.float32) + p["kda_dt_bias"][idx]
     f = f.reshape(*f.shape[:-1], g.Hl, g.dl)
     return -jnp.exp(p["kda_A_log"][idx])[:, None] * jax.nn.softplus(f)
-
-
-def kda_decode(q, k, v, glog, beta, S):
-    """One recurrent update a row. q, k, v, glog [B, H, d]; beta [B, H];
-    S [B, H, d(key), d(value)] float32. Returns (o [B, H, d], S')."""
-    with jax.named_scope("kda_decode"), jax.default_matmul_precision("highest"):
-        S = jnp.exp(glog)[..., None] * S
-        u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", S, k))
-        S = S + k[..., :, None] * u[..., None, :]
-        return jnp.einsum("bhkv,bhk->bhv", S, q), S
-
-
-def kda_chunk_for(rows: int, T: int) -> int:
-    """Tokens per block: the [rows, C, C, H, d] decay block is what a
-    chunk holds at once, so more rows take shorter blocks."""
-    C = KDA_CHUNK
-    while C > 16 and rows * C * C > 8 * KDA_CHUNK * KDA_CHUNK:
-        C //= 2
-    return min(C, T)
-
-
-def kda_chunked(q, k, v, glog, beta, S, chunk: Optional[int] = None):
-    """The same recurrence over T tokens, ``chunk`` at a time. q, k, v,
-    glog [B, T, H, d] float32; beta [B, T, H]; S [B, H, d, d].
-
-    With G_t the decay summed from the chunk's start (so every exponent
-    below is of G_t - G_j <= 0, t >= j: nothing overflows), the delta
-    rule's corrections u_t solve a unit lower-triangular system:
-      u_t + beta_t sum_{j<t} A_tj u_j = beta_t (v_t - S0^T (k_t e^{G_t})),
-      A_tj = sum_c k_t[c] k_j[c] e^{G_t[c] - G_j[c]};
-      o_t = S0^T (q_t e^{G_t}) + sum_{j<=t} B_tj u_j,  B as A with q_t;
-      S' = e^{G_C} S0 + sum_j (k_j e^{G_C - G_j}) u_j^T.
-    A token with beta 0 and glog 0 (padding) changes nothing."""
-    B, T, H, d = q.shape
-    C = min(chunk or kda_chunk_for(B, T), T)
-    assert T % C == 0, (T, C)
-
-    def blocks(x):
-        return jnp.moveaxis(x.reshape(B, T // C, C, *x.shape[2:]), 1, 0)
-
-    tri = jnp.tril(jnp.ones((C, C), bool))
-    strict = jnp.tril(jnp.ones((C, C), bool), -1)
-
-    def body(S, blk):
-        qc, kc, vc, gc, bc = blk                      # [B, C, H, .]
-        G = jnp.cumsum(gc, axis=1)                    # [B, C, H, d]
-        diff = G[:, :, None] - G[:, None, :]          # [B, C(t), C(j), H, d]
-        decay = jnp.exp(jnp.where(tri[None, :, :, None, None], diff, -jnp.inf))
-        kk = kc[:, :, None] * kc[:, None, :] * decay
-        A = jnp.where(strict[None, :, :, None], kk.sum(-1), 0.0)   # [B,C,C,H]
-        Bm = (qc[:, :, None] * kc[:, None, :] * decay).sum(-1)     # [B,C,C,H]
-        eG = jnp.exp(G)
-        rhs = bc[..., None] * (vc - jnp.einsum("bhkv,bthk->bthv", S, kc * eG))
-        M = jnp.eye(C)[None, :, :, None] + bc[:, :, None, :] * A
-        u = jax.scipy.linalg.solve_triangular(
-            jnp.moveaxis(M, 3, 1), jnp.moveaxis(rhs, 2, 1), lower=True,
-            unit_diagonal=True)                        # [B, H, C, d]
-        o = jnp.einsum("bhkv,bthk->bthv", S, qc * eG) + jnp.einsum(
-            "btjh,bhjv->bthv", Bm, u)
-        last = G[:, -1]                                # [B, H, d]
-        S = jnp.exp(last)[..., None] * S + jnp.einsum(
-            "bjhk,bhjv->bhkv", kc * jnp.exp(last[:, None] - G), u)
-        return S, o
-
-    with jax.named_scope("kda_chunked"), jax.default_matmul_precision("highest"):
-        S, o = jax.lax.scan(body, S, tuple(map(blocks, (q, k, v, glog, beta))))
-    return jnp.moveaxis(o, 0, 1).reshape(B, T, H, d), S
 
 
 def moe_routing(cfg: ModelConfig, p: Params, x: jax.Array, idx: int):
@@ -530,69 +348,6 @@ def moe_routing(cfg: ModelConfig, p: Params, x: jax.Array, idx: int):
     if cfg.moe_renormalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return w * cfg.routed_scaling_factor, topi
-
-
-def _expert_weights(p: Params, name: str, idx: int, dtype):
-    w = p[name][idx]
-    scale = p[name + "_scale"][idx] if w.dtype == jnp.int8 else None
-    return w.astype(dtype), scale
-
-
-def moe_local_dense(p: Params, x: jax.Array, combine: jax.Array, idx: int):
-    """Every held expert over every token, weighted by ``combine`` [N, E]
-    (0 where a token did not choose the expert): the form for a few rows,
-    where each expert's weights cross HBM once whatever was chosen."""
-    def edot(eq, a, name):
-        w, scale = _expert_weights(p, name, idx, a.dtype)
-        y = _einsum_f32(eq, a, w)
-        return y if scale is None else y * scale[:, None, :]
-
-    with jax.named_scope("moe_experts"):
-        gate = edot("nd,edf->enf", x, "we_gate")
-        up = edot("nd,edf->enf", x, "we_up")
-        mid = (jax.nn.silu(gate) * up).astype(x.dtype)
-        return jnp.einsum("end,ne->nd", edot("enf,efd->end", mid, "we_down"),
-                          combine)
-
-
-def moe_local_grouped(p: Params, x: jax.Array, w: jax.Array, local_e: jax.Array,
-                      idx: int, E: int):
-    """Assignments sorted by held expert, then grouped matmuls
-    (``ragged_dot``) over each expert's run of rows: work and weight
-    reads follow the rows assigned. ``local_e`` [N, k]: the held expert's
-    index, or E for an assignment another shard holds (sorted last, in no
-    group, its weight already 0)."""
-    N, k = local_e.shape
-    flat = local_e.reshape(-1)
-    order = jnp.argsort(flat)
-    sorted_e = flat[order]
-    xs = jnp.take(x, order // k, axis=0)
-    sizes = jnp.bincount(sorted_e, length=E + 1)[:E].astype(jnp.int32)
-    held = sorted_e < E
-    cpu = jax.default_backend() == "cpu"
-
-    def gdot(a, name):
-        # the upcast copy of the layer's experts (XLA does not fuse it
-        # into the grouped matmul) must not be made before its rows
-        # exist: tied to them, one layer's copies live at a time — left
-        # free, the compiler hoists every layer's to the step's start
-        # (8 GB at the published widths, refused by the chip's compiler)
-        stack, a = jax.lax.optimization_barrier((p[name], a))
-        w8 = stack[idx]
-        scale = p[name + "_scale"][idx] if w8.dtype == jnp.int8 else None
-        wt = w8.astype(jnp.float32 if cpu else a.dtype)
-        y = jax.lax.ragged_dot(a.astype(wt.dtype), wt, sizes,
-                               preferred_element_type=jnp.float32)
-        if scale is not None:
-            y = y * jnp.take(scale, jnp.minimum(sorted_e, E - 1), axis=0)
-        return y
-
-    with jax.named_scope("moe_experts"):
-        mid = jax.nn.silu(gdot(xs, "we_gate")) * gdot(xs, "we_up")
-        out = jnp.where(held[:, None], gdot(mid.astype(x.dtype), "we_down"), 0)
-    inv = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
-    out = jnp.take(out, inv, axis=0).reshape(N, k, -1)
-    return jnp.sum(out * w[..., None], axis=1)
 
 
 def moe_ffn(cfg: ModelConfig, g: Geometry, p: Params, h: jax.Array,
@@ -610,20 +365,8 @@ def moe_ffn(cfg: ModelConfig, g: Geometry, p: Params, h: jax.Array,
     x = h.reshape(B * T, D)
     w, topi = moe_routing(
         cfg, p, x if h_route is None else h_route.reshape(B * T, D), idx)
-    local = (topi >= g.e0) & (topi < g.e0 + g.E)
-    w = jnp.where(local, w, 0.0)
-    local_e = jnp.where(local, topi - g.e0, g.E)
-    real = local if valid is None else local & valid.reshape(B * T, 1)
-    touched = jnp.zeros((g.E + 1,), jnp.int32).at[
-        jnp.where(real, local_e, g.E)].max(1)[: g.E]
-    counts = jnp.stack([jnp.int32(1), jnp.sum(real, dtype=jnp.int32),
-                        jnp.sum(touched, dtype=jnp.int32)])
-    if B * T <= MOE_DENSE_TOKENS:
-        combine = jnp.zeros((B * T, g.E + 1), jnp.float32).at[
-            jnp.arange(B * T)[:, None], local_e].add(w)[:, : g.E]
-        routed = moe_local_dense(p, x, combine, idx)
-    else:
-        routed = moe_local_grouped(p, x, w, local_e, idx, g.E)
+    routed, counts = hybrid.moe_local(
+        p, x, w, topi, idx, g.e0, g.E, MOE_DENSE_TOKENS, valid)
     shared = _gated_mlp(p, ("ws_gate", "ws_up", "ws_down"), h, idx)
     return routed.reshape(B, T, D) + shared.astype(jnp.float32), counts
 

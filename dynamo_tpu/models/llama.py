@@ -39,6 +39,11 @@ from dynamo_tpu.models.config import ModelConfig
 
 Params = dict[str, Any]
 
+# the model_type values this module serves beside its own name
+# (models/__init__.py family): dense GQA decoders, Mixtral-routed MLPs,
+# gemma norms, a vision tower in front
+FAMILIES = ("llama", "mistral", "mixtral", "qwen2", "gemma", "llava")
+
 
 # ---------------------------------------------------------------------------
 # Parameter init / sharding specs

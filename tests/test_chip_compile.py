@@ -292,3 +292,84 @@ def test_qmm_with_a_float32_result_compiles_for_v5e(
         _sds((rows, k), jnp.bfloat16, one_chip), _sds((7, k, n), jnp.int8, one_chip),
         _sds((7, n), jnp.float32, one_chip), _sds((), jnp.int32, one_chip))
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# One chip: the qwen3_next family at its published widths — the shared
+# paged-attention kernels at 16 / 2 heads of 256 over pages stored as
+# their (token, head) rows, the delta-rule update on a [6, 65, 32, 128,
+# 128] plane, the float32-result qmm at its widths (hidden 2048)
+# ---------------------------------------------------------------------------
+
+QN_H, QN_HK, QN_DH, QN_PAGES = 16, 2, 256, 1024
+
+
+@pytest.mark.parametrize("rows", [8, 32, 64])
+def test_decode_attention_at_head_256_reads_the_stored_rows_in_place(
+    rows, one_chip, no_compile_cache
+):
+    """The pool ``[2, slots * Hk, 256]`` reaches the decode kernel's page
+    view ``[bs * Hk, 256]`` as a bitcast: a ``[2, slots, 2, 256]`` array
+    gets a 2-row tile here and the view a copy of the whole pool a call
+    (PERF.md, PR 33)."""
+    slots = QN_PAGES * BS
+    pool = _sds((2, slots * QN_HK, QN_DH), jnp.bfloat16, one_chip)
+
+    def decode(q, k, v, layer, tables, ctx):
+        shape4 = (2, slots, QN_HK, QN_DH)
+        return paged_attention_decode_stacked(
+            q, k.reshape(shape4), v.reshape(shape4), layer, tables, ctx,
+            block_size=BS)
+
+    text = _compile_text(
+        decode, _sds((rows, QN_H, QN_DH), jnp.bfloat16, one_chip), pool, pool,
+        _sds((), jnp.int32, one_chip), _sds((rows, TABLE_W), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text
+    pool_ops = [ln for ln in text.splitlines()
+                if f"bf16[2,{QN_PAGES}," in ln or f"bf16[2,{slots}," in ln]
+    assert pool_ops and all(
+        " bitcast(" in ln or " parameter(" in ln or "custom-call(" in ln
+        or "ENTRY" in ln or "HloModule" in ln for ln in pool_ops), pool_ops[:3]
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 1024), (4, 256)])
+def test_prefill_attention_at_head_256_compiles_for_v5e(
+    rows, tokens, one_chip, no_compile_cache
+):
+    """The rows' own pages, gathered: a cache of ``rows x 40`` pages."""
+    own = _sds((1, rows * TABLE_W * BS, QN_HK, QN_DH), jnp.bfloat16, one_chip)
+    text = _compile_text(
+        functools.partial(paged_attention_prefill_stacked, block_size=BS),
+        _sds((rows, tokens, QN_H, QN_DH), jnp.bfloat16, one_chip), own, own,
+        _sds((), jnp.int32, one_chip), _sds((rows, TABLE_W), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip), _sds((rows,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_the_delta_rule_update_compiles_on_the_qwen3_next_plane(
+    rows, one_chip, no_compile_cache
+):
+    from dynamo_tpu.ops.kda import kda_decode_update
+
+    vec = _sds((rows, 32, 128), jnp.float32, one_chip)
+    ids = _sds((rows,), jnp.int32, one_chip)
+    text = _compile_text(
+        kda_decode_update, _sds((6, 65, 32, 128, 128), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip), ids, ids, vec, vec, vec, vec,
+        _sds((rows, 32), jnp.float32, one_chip))
+    assert "tpu_custom_call" in text and "kda_decode_update" in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 12288), (2048, 8192), (2048, 512),
+                                 (4096, 2048), (512, 2048)])
+@pytest.mark.parametrize("rows", [64, 1024])
+def test_qmm_at_the_qwen3_next_widths_compiles_for_v5e(
+    rows, k, n, one_chip, no_compile_cache
+):
+    text = _compile_text(
+        lambda a, b, c, l: qmm(a, b, c, layer=l, out_dtype=jnp.float32),
+        _sds((rows, k), jnp.bfloat16, one_chip), _sds((6, k, n), jnp.int8, one_chip),
+        _sds((6, n), jnp.float32, one_chip), _sds((), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text
