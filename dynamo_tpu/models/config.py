@@ -84,6 +84,31 @@ class ModelConfig:
     norm_topk_prob: bool = True
     decoder_sparse_step: int = 1
     mlp_only_layers: list = field(default_factory=list)
+    # nemotron_h (models/nemotron_h.py): hybrid_override_pattern gives
+    # every layer ONE part by a letter — M a Mamba-2 mixer (mamba_*,
+    # ssm_state_size, n_groups B/C groups, conv_kernel taps, chunk_size
+    # tokens a block of the chunked scan), * attention, E sigmoid-routed
+    # relu^2 experts (n_routed_experts held here, as num_experts above)
+    # plus a shared one, - a relu^2 MLP
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    use_conv_bias: bool = True
+    use_bias: bool = False
+    mamba_proj_bias: bool = False
+    mlp_bias: bool = False
+    n_routed_experts: int = 0
+    n_shared_experts: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    n_group: int = 1
+    mlp_hidden_act: str = "relu2"
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 0.0001
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -101,6 +126,18 @@ class ModelConfig:
         from dynamo_tpu.models import family
 
         return bool(getattr(family(self), "RECURRENT_STATE", False))
+
+    def layer_letters(self) -> list[str]:
+        """``hybrid_override_pattern`` as one letter a layer (``M``,
+        ``*``, ``E``, ``-``), ``num_hidden_layers`` of them."""
+        letters = list(self.hybrid_override_pattern)
+        bad = sorted(set(letters) - set("M*E-"))
+        if bad or len(letters) != self.num_hidden_layers:
+            raise ValueError(
+                f"hybrid_override_pattern {self.hybrid_override_pattern!r} "
+                f"must give each of the {self.num_hidden_layers} layers one "
+                f"of M, *, E, -" + (f" (found {bad})" if bad else ""))
+        return letters
 
     @property
     def eos_token_ids(self) -> list[int]:
@@ -168,4 +205,10 @@ class ModelConfig:
         # kimi_linear states its longest context as model_max_length
         if "max_position_embeddings" not in raw and "model_max_length" in raw:
             kwargs["max_position_embeddings"] = int(raw["model_max_length"])
+        # nemotron_h names the RMSNorm epsilon norm_eps / layer_norm_epsilon
+        if "rms_norm_eps" not in raw:
+            for key in ("norm_eps", "layer_norm_epsilon"):
+                if key in raw:
+                    kwargs["rms_norm_eps"] = float(raw[key])
+                    break
         return cls(**kwargs)

@@ -14,6 +14,9 @@ layer that holds a share of the experts.
 - the held experts' part of an expert layer once the family's router has
   chosen (``moe_local``: every held expert over a few rows, or the rows
   sorted by expert through ``ragged_dot``), with its on-device counts;
+  what an expert IS — the stacks that read the rows, the activation that
+  joins them, the stack that writes back — is the family's to say
+  (``ExpertForm``: ``GATED_SILU`` three matrices, ``RELU2`` two);
 - what no such family builds yet, refused at start-up (``check_engine``).
 
 A family keeps what is its own: the layer plan, the mixers, the router,
@@ -23,7 +26,7 @@ A family keeps what is its own: the layer plan, the mixers, the router,
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,10 +58,14 @@ def draw_A_log(key, shape: tuple[int, ...]):
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
 
 
-def draw_dt_bias(key, shape: tuple[int, ...]):
-    """The inverse softplus of a log-uniform step in [1e-3, 1e-1]."""
+def draw_dt_bias(key, shape: tuple[int, ...], lo: float = 1e-3,
+                 hi: float = 1e-1, floor: float = 0.0):
+    """The inverse softplus of a log-uniform step in ``[lo, hi]``, held
+    at or above ``floor``."""
     dt = jnp.exp(jax.random.uniform(
-        key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        key, shape, jnp.float32, math.log(lo), math.log(hi)))
+    if floor > 0.0:
+        dt = jnp.maximum(dt, floor)
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
@@ -203,6 +210,17 @@ def gated_mlp(p: Params, names: tuple[str, str, str], h: jax.Array,
     return mm(p, down, mid.astype(h.dtype), idx)
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+def relu2_mlp(p: Params, names: tuple[str, str], h: jax.Array,
+              idx: int) -> jax.Array:
+    """``relu(h U)^2 D``: the ungated two-matrix feed-forward."""
+    up, down = names
+    return mm(p, down, relu2(mm(p, up, h, idx)).astype(h.dtype), idx)
+
+
 # ---------------------------------------------------------------------------
 # The gated delta rule
 # ---------------------------------------------------------------------------
@@ -290,13 +308,29 @@ def delta_chunked(q, k, v, glog, beta, S, chunk: Optional[int] = None):
 # ---------------------------------------------------------------------------
 
 
+class ExpertForm(NamedTuple):
+    """What one routed expert computes, by the names of its ``[layers,
+    experts, ., .]`` stacks: ``mid(project) @ down``, where
+    ``project(name)`` is the rows times stack ``name`` (one of ``reads``)."""
+    reads: tuple[str, ...]
+    down: str
+    mid: Callable[[Callable[[str], jax.Array]], jax.Array]
+
+
+GATED_SILU = ExpertForm(
+    ("we_gate", "we_up"), "we_down",
+    lambda project: jax.nn.silu(project("we_gate")) * project("we_up"))
+RELU2 = ExpertForm(("we_up",), "we_down", lambda project: relu2(project("we_up")))
+
+
 def _expert_weights(p: Params, name: str, idx: int, dtype):
     w = p[name][idx]
     scale = p[name + "_scale"][idx] if w.dtype == jnp.int8 else None
     return w.astype(dtype), scale
 
 
-def moe_local_dense(p: Params, x: jax.Array, combine: jax.Array, idx: int):
+def moe_local_dense(p: Params, x: jax.Array, combine: jax.Array, idx: int,
+                    form: ExpertForm = GATED_SILU):
     """Every held expert over every token, weighted by ``combine`` [N, E]
     (0 where a token did not choose the expert): the form for a few rows,
     where each expert's weights cross HBM once whatever was chosen."""
@@ -306,15 +340,14 @@ def moe_local_dense(p: Params, x: jax.Array, combine: jax.Array, idx: int):
         return y if scale is None else y * scale[:, None, :]
 
     with jax.named_scope("moe_experts"):
-        gate = edot("nd,edf->enf", x, "we_gate")
-        up = edot("nd,edf->enf", x, "we_up")
-        mid = (jax.nn.silu(gate) * up).astype(x.dtype)
-        return jnp.einsum("end,ne->nd", edot("enf,efd->end", mid, "we_down"),
+        rows = {name: edot("nd,edf->enf", x, name) for name in form.reads}
+        mid = form.mid(rows.__getitem__).astype(x.dtype)
+        return jnp.einsum("end,ne->nd", edot("enf,efd->end", mid, form.down),
                           combine)
 
 
 def moe_local_grouped(p: Params, x: jax.Array, w: jax.Array, local_e: jax.Array,
-                      idx: int, E: int):
+                      idx: int, E: int, form: ExpertForm = GATED_SILU):
     """Assignments sorted by held expert, then grouped matmuls
     (``ragged_dot``) over each expert's run of rows: work and weight
     reads follow the rows assigned. ``local_e`` [N, k]: the held expert's
@@ -346,8 +379,8 @@ def moe_local_grouped(p: Params, x: jax.Array, w: jax.Array, local_e: jax.Array,
         return y
 
     with jax.named_scope("moe_experts"):
-        mid = jax.nn.silu(gdot(xs, "we_gate")) * gdot(xs, "we_up")
-        out = jnp.where(held[:, None], gdot(mid.astype(x.dtype), "we_down"), 0)
+        mid = form.mid(lambda name: gdot(xs, name))
+        out = jnp.where(held[:, None], gdot(mid.astype(x.dtype), form.down), 0)
     inv = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
     out = jnp.take(out, inv, axis=0).reshape(N, k, -1)
     return jnp.sum(out * w[..., None], axis=1)
@@ -355,7 +388,8 @@ def moe_local_grouped(p: Params, x: jax.Array, w: jax.Array, local_e: jax.Array,
 
 def moe_local(p: Params, x: jax.Array, w: jax.Array, topi: jax.Array, idx: int,
               e0: int, E: int, dense_tokens: int,
-              valid: Optional[jax.Array] = None):
+              valid: Optional[jax.Array] = None,
+              form: ExpertForm = GATED_SILU):
     """This process's experts' share of the routed sum. x [N, D]; ``w``,
     ``topi`` [N, k]: the router's weights and choices over ALL experts;
     this process holds experts ``e0 ... e0 + E - 1`` (what other shards'
@@ -377,7 +411,7 @@ def moe_local(p: Params, x: jax.Array, w: jax.Array, topi: jax.Array, idx: int,
     if N <= dense_tokens:
         combine = jnp.zeros((N, E + 1), jnp.float32).at[
             jnp.arange(N)[:, None], local_e].add(w)[:, :E]
-        routed = moe_local_dense(p, x, combine, idx)
+        routed = moe_local_dense(p, x, combine, idx, form)
     else:
-        routed = moe_local_grouped(p, x, w, local_e, idx, E)
+        routed = moe_local_grouped(p, x, w, local_e, idx, E, form)
     return routed, counts

@@ -68,6 +68,15 @@ _STALE_TEST = "test_every_configuration_of_the_benchmark_finds_its_family"
 # held to its own cap in its family's file (test_perf_qwen3_next.py).
 _STALE_PROBE_TEST = "test_a_cells_probe_is_its_own_mix_at_the_size_the_window_runs"
 _CHAT_PROMPT_CAP = 3072
+# a fourth: ``test_perf_qwen3_next.test_the_cell_is_listed_where_its_readers_read``
+# asserts that qwen3-next's cell is the ONLY entry of ``moe_touched_share``'s and
+# ``state_slots_used_share.open``'s ``workloads``; a second hybrid cell that those
+# readers read (PR 37) is appended there, as BENCHMARK.json's rules ask. The test
+# is skipped; ``test_perf_nemotron_h.test_the_qwen3_next_cell_keeps_its_listing``
+# holds every other assertion it made, by name and order (so that the next
+# appended cell needs no skip), and those two lists as [that cell, PR 37's, ...].
+_STALE_LISTING_TEST = ("test_perf_qwen3_next.py",
+                       "test_the_cell_is_listed_where_its_readers_read")
 
 
 def _stale_reason(item) -> str | None:
@@ -75,6 +84,9 @@ def _stale_reason(item) -> str | None:
     import json
 
     name = getattr(item, "originalname", None)
+    if (os.path.basename(str(item.fspath)), name) == _STALE_LISTING_TEST:
+        return ("asserts that this cell alone is on two readers' lists; a "
+                "second hybrid cell is listed there too")
     if name not in (_STALE_TEST, _STALE_PROBE_TEST):
         return None
     from perf import server

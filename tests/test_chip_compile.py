@@ -373,3 +373,115 @@ def test_qmm_at_the_qwen3_next_widths_compiles_for_v5e(
         _sds((rows, k), jnp.bfloat16, one_chip), _sds((6, k, n), jnp.int8, one_chip),
         _sds((6, n), jnp.float32, one_chip), _sds((), jnp.int32, one_chip))
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# One chip: the nemotron_h family at its published widths — the Mamba-2
+# update on a [6, 65, 64, 64, 128] plane (a NON-square state a head, B and C
+# a group of 8 heads), the shared decode attention at 32 / 2 heads of 128
+# over pages stored as rows, and the whole decode step of the benchmark's
+# 13-layer stage with a bound on what it holds beside its arguments
+# ---------------------------------------------------------------------------
+
+NH_HM, NH_P, NH_N, NH_G = 64, 64, 128, 8
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_ssm_decode_update_compiles_for_v5e(rows, one_chip, no_compile_cache):
+    from dynamo_tpu.ops.ssm import ssm_decode_update
+
+    ids = _sds((rows,), jnp.int32, one_chip)
+    head = _sds((rows, NH_HM), jnp.float32, one_chip)
+    group = _sds((rows, NH_G, NH_N), jnp.float32, one_chip)
+    text = _compile_text(
+        ssm_decode_update,
+        _sds((6, 65, NH_HM, NH_P, NH_N), jnp.float32, one_chip),
+        _sds((), jnp.int32, one_chip), ids, ids,
+        _sds((rows, NH_HM, NH_P), jnp.float32, one_chip), head, head, group, group)
+    assert "tpu_custom_call" in text and "ssm_decode_update" in text
+
+
+def _nemotron_stage():
+    import json
+    import os
+
+    from dynamo_tpu.models import ModelConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs", "nemotron-3-nano-30b.json")) as f:
+        raw = json.load(f)
+    return ModelConfig.from_dict(raw), raw["serving"]["engine"]["num_blocks"]
+
+
+def _compiled_nemotron_step(rows, T, one_chip, monkeypatch):
+    """The served step (int8 weights, bf16 pages, the pool the configuration
+    pins, 65 state slots) of ``rows`` x ``T`` tokens, for the described chip."""
+    from dynamo_tpu.models import hybrid, nemotron_h as nh
+
+    cfg, num_blocks = _nemotron_stage()
+    monkeypatch.setattr(hybrid, "kernels_active", lambda: True)
+    monkeypatch.setattr(llama, "pallas_matmul_active", lambda: True)
+    monkeypatch.setattr(llama, "_qmm_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds_tree(shapes, dtype_of):
+        return {n: _sds(s, dtype_of(n), one_chip) for n, s in shapes.items()}
+
+    params = {}
+    for name, (shape, dtype) in nh.param_shapes(cfg).items():
+        if name in nh.QUANT_AXIS:
+            params[name] = _sds(shape, jnp.int8, one_chip)
+            axis = nh.QUANT_AXIS[name] % len(shape)
+            params[name + "_scale"] = _sds(
+                shape[:axis] + shape[axis + 1:], jnp.float32, one_chip)
+        else:
+            params[name] = _sds(shape, jnp.float32, one_chip)
+    pshape, sshape = nh.cache_shapes(cfg, num_blocks, BS, 65)
+    pages = sds_tree(pshape, lambda n: jnp.bfloat16)
+    state = sds_tree(sshape, lambda n: jnp.float32)
+    state["counts"] = _sds((len(nh.COUNT_NAMES),), jnp.int32, one_chip)
+    ids = _sds((rows,), jnp.int32, one_chip)
+    grid = _sds((rows, T), jnp.int32, one_chip)
+
+    def step(params, pages, state, tokens, positions, slots, tables, ctx, last):
+        return nh.forward(cfg, params, pages, state, tokens, positions, slots,
+                          tables, ctx, last, BS)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pages, state, grid, grid, _sds((rows * T,), jnp.int32, one_chip),
+        _sds((rows, TABLE_W + 1), jnp.int32, one_chip), ids, ids).compile()
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_the_nemotron_h_decode_step_compiles_for_v5e_within_its_transients(
+    rows, one_chip, no_compile_cache, monkeypatch
+):
+    """The served decode step for the described chip: the Mamba-2 kernel and
+    the decode attention are Mosaic calls, and beside its arguments the step
+    holds well under ``STEP_TRANSIENT_BYTES`` — a layout copy of the 2 GB
+    pool or of the 0.85 GB plane at the program's edge would show here
+    (PR 33 found a 1.6 GB pool copy this way)."""
+    from dynamo_tpu.models import nemotron_h as nh
+
+    compiled = _compiled_nemotron_step(rows, 1, one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("ssm_decode_update") >= 6
+    assert "paged_attention_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < nh.STEP_TRANSIENT_BYTES // 2
+
+
+def test_the_nemotron_h_largest_prefill_step_compiles_for_v5e_within_its_transients(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """``max_prefill_tokens`` 4 096 as 4 rows of a whole 1 024-token chunk:
+    every held expert over 8 blocks of 512 tokens, no sorted rows (so no
+    ``ragged-dot`` and no copy of a layer's 128 experts), and the step's
+    temporaries inside what the family reserves for them."""
+    from dynamo_tpu.models import nemotron_h as nh
+
+    compiled = _compiled_nemotron_step(4, 1024, one_chip, monkeypatch)
+    assert "ragged-dot" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < nh.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
