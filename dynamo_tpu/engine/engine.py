@@ -50,6 +50,8 @@ from dynamo_tpu.engine.scheduler import (
     SeqState,
     Sequence,
     StepPlan,
+    mixed_rect_of,
+    prefill_rectangles,
 )
 from dynamo_tpu.models import ModelConfig, family as model_family
 from dynamo_tpu.utils import affinity, compile_fence, transfer_fence
@@ -298,6 +300,9 @@ class JaxEngine:
         # single-step decode dispatches of _decode_pipeline, and those
         # of them issued with a step still in flight (program_counts)
         self._decode_dispatches = [0, 0]
+        # prefill dispatches: the prompt tokens their chunks held, and
+        # rows x tokens of the rectangles they ran (program_counts)
+        self._prefill_tokens = [0, 0]
         # the family's device-side counts (its module's COUNT_NAMES,
         # models/__init__.py): totals as Python ints, and the device's last
         # int32 reading (the device wraps, the totals do not)
@@ -697,74 +702,66 @@ class JaxEngine:
                 -(-blocks_cap // Scheduler.TABLE_BUCKET)
                 * Scheduler.TABLE_BUCKET,
             )
-            # prefill-batch shapes (each bucket is a multi-minute AOT
-            # prewarm): a single-row shape so a lone prompt on an idle
-            # engine doesn't pay 8× padded compute (prefill is
-            # compute-bound, unlike decode), the mixed rectangle's row
-            # count, the full-burst width, AND the budget-filling width
-            # (max_prefill_tokens / smallest chunk): without it, a
-            # burst wider than the mixed rows has no bucket between
-            # rows and the full pad, so batched prefill degrades to
-            # rows-sized steps — measured at B=64 as staggered prefill
-            # waves that desynchronize decode for the population's
-            # lifetime (windows run 16-40 wide at full-window cost,
-            # 924 vs 1505 tok/s)
-            sched.prefill_chunk_buckets = [128, 256, 1024, 4096]
-            budget_rows = max(
-                1,
+            # prefill shapes: every (rows, tokens) rectangle is a
+            # whole-model program compiled at start-up, so the set is
+            # few (scheduler.prefill_rectangles says which; STATIC_* why),
+            # and it is THE set: the planner, the array builder and
+            # every prewarm loop read sched.prefill_rects and nothing
+            # else. Row counts: a single row, so that a lone prompt on
+            # an idle engine does not pay 8x padded compute (prefill is
+            # compute-bound, unlike decode); the mixed rectangle's rows;
+            # the budget-filling width (max_prefill_tokens / shortest
+            # chunk) — without it a burst wider than the mixed rows
+            # prefills in rows-sized waves that desynchronise decode for
+            # the population's lifetime (measured at B=64: windows 16-40
+            # wide at full-window cost, 924 vs 1505 tok/s); and the pad.
+            # Defaults (budget 4096, chunk 1024, 8 mixed rows, pad 64):
+            # 1x128 1x256 1x512 1x1024 8x128 8x256 32x128.
+            pad = sched.decode_batch_pad
+            budget_rows = (
                 (cfg.max_prefill_tokens or 4096)
-                // sched.prefill_chunk_buckets[0],
+                // Scheduler.STATIC_CHUNK_TOKENS[0]
             )
-            sched.prefill_batch_buckets = sorted(
-                {1,
-                 min(cfg.mixed_prefill_rows, sched.decode_batch_pad),
-                 min(budget_rows, sched.decode_batch_pad),
-                 sched.decode_batch_pad}
+            sched.prefill_rects = prefill_rectangles(
+                sorted(
+                    {1, pad}
+                    | {max(1, min(r, pad))
+                       for r in (cfg.mixed_prefill_rows, budget_rows)}
+                ),
+                Scheduler.STATIC_CHUNK_TOKENS,
+                sched.max_prefill_tokens,
+                cfg.prefill_chunk_size,
+                Scheduler.STATIC_SINGLE_ROW_TOKENS,
             )
         if cfg.decode_steps > 1 and cfg.mixed_prefill_rows > 0:
-            # normalize to bucket values: _pad_prefill_rect's fixed
-            # rectangle must be >= the bucketed prefill arrays, which
-            # round UP (a non-bucket rows/len would crash every mixed
-            # step and fail all in-flight requests)
-            cfg.mixed_prefill_rows = next_bucket(
-                cfg.mixed_prefill_rows, self.scheduler.prefill_batch_buckets
+            # the mixed window's FIXED rectangle is one of the set's
+            # (_pad_prefill_rect pads the builder's arrays out to it; a
+            # shape outside the set would crash every mixed step and
+            # fail all in-flight requests): the row count that holds
+            # the request, a length that exists AT that row count, then
+            # down until it fits the prefill token budget the HBM
+            # headroom sizing reserves for (see _auto_num_blocks area)
+            sched = self.scheduler
+            lens = sorted({t for _, t in sched.prefill_rects})
+            cap = max(lens[0], sched.max_prefill_tokens)
+            fit = mixed_rect_of(
+                sched.prefill_rects, cfg.mixed_prefill_rows,
+                cfg.mixed_prefill_len, cap,
             )
-            cfg.mixed_prefill_len = next_bucket(
-                cfg.mixed_prefill_len, self.scheduler.prefill_chunk_buckets
-            )
-            # the rectangle must fit the prefill token budget the HBM
-            # headroom sizing reserves for (see _auto_num_blocks area);
-            # shrink along the bucket lists so the fixed rectangle
-            # stays a bucket value (pad invariant)
-            pb = self.scheduler.prefill_batch_buckets
-            pc = self.scheduler.prefill_chunk_buckets
-            cap = max(pc[0], self.scheduler.max_prefill_tokens)
-
-            def down(v: int, buckets: list) -> int:
-                smaller = [b for b in buckets if b < v]
-                return smaller[-1] if smaller else buckets[0]
-
-            while cfg.mixed_prefill_len > max(cap, pc[0]) and (
-                cfg.mixed_prefill_len > pc[0]
-            ):
-                cfg.mixed_prefill_len = down(cfg.mixed_prefill_len, pc)
-            while (
-                cfg.mixed_prefill_rows * cfg.mixed_prefill_len > cap
-                and cfg.mixed_prefill_rows > pb[0]
-            ):
-                cfg.mixed_prefill_rows = down(cfg.mixed_prefill_rows, pb)
-            if cfg.mixed_prefill_rows * cfg.mixed_prefill_len > cap:
+            if fit is None:
                 # the smallest rectangle still exceeds the configured
                 # prefill budget: running it anyway would silently
                 # violate the HBM headroom that budget reserves
                 log.warning(
-                    "mixed prefill rectangle %dx%d exceeds "
+                    "no mixed prefill rectangle for %dx%d fits "
                     "max_prefill_tokens=%d; disabling mixed batching",
                     cfg.mixed_prefill_rows, cfg.mixed_prefill_len, cap,
                 )
                 cfg.mixed_prefill_rows = 0
-            self.scheduler.mixed_prefill_rows = cfg.mixed_prefill_rows
-            self.scheduler.mixed_prefill_len = cfg.mixed_prefill_len
+            else:
+                cfg.mixed_prefill_rows, cfg.mixed_prefill_len = fit
+            sched.mixed_prefill_rows = cfg.mixed_prefill_rows
+            sched.mixed_prefill_len = cfg.mixed_prefill_len
             # adaptive WIDE rectangle: same token budget, fewer rows —
             # long prompts at low decode occupancy prefill in
             # backlog/wide_len windows instead of backlog/len
@@ -774,30 +771,26 @@ class JaxEngine:
                 # never wider than one prefill chunk: _plan_prefill_batch
                 # caps every row's chunk at prefill_chunk_size, so a
                 # longer rectangle would dispatch permanently-padded
-                # dead tokens. Round DOWN to a bucket (next_bucket
-                # rounds up, which for a non-bucket chunk size like 512
-                # would reintroduce exactly that padding).
-                wl = next_bucket(min(wide, cfg.prefill_chunk_size), pc)
-                while wl > cfg.prefill_chunk_size and wl > pc[0]:
-                    wl = down(wl, pc)
-                while wl > max(cap, pc[0]) and wl > pc[0]:
-                    wl = down(wl, pc)
-                # the wide rect keeps the narrow rect's token budget
-                # (rows*len): shrink wl until at least one row fits —
-                # if that lands back at the narrow len, the budget is
-                # too small for a wide variant and it stays disabled
+                # dead tokens — the longest of the set's lengths that
+                # neither the request, the chunk nor the cap falls short
+                # of. The wide rect keeps the narrow rect's token budget
+                # (rows*len): if no longer length leaves it a row, the
+                # budget is too small for a wide variant and it stays
+                # disabled
                 budget = cfg.mixed_prefill_rows * cfg.mixed_prefill_len
-                while budget // wl < 1 and wl > pc[0]:
-                    wl = down(wl, pc)
-                wr = min(budget // wl, cap // wl)
-                if wl > cfg.mixed_prefill_len and wr >= 1:
-                    sched = self.scheduler
-                    if wr not in sched.prefill_batch_buckets:
-                        # the rectangle must be a batch bucket, or
-                        # bucketed prefill arrays round PAST it and
-                        # every wide mixed step crashes
-                        sched.prefill_batch_buckets = sorted(
-                            set(sched.prefill_batch_buckets) | {wr}
+                top = min(
+                    next_bucket(min(wide, cfg.prefill_chunk_size), lens),
+                    cfg.prefill_chunk_size, cap, budget,
+                )
+                wl = max([t for t in lens if t <= top], default=0)
+                if wl > cfg.mixed_prefill_len:
+                    wr = min(budget // wl, cap // wl)
+                    if (wr, wl) not in sched.prefill_rects:
+                        # the rectangle must be one of the set, or the
+                        # builder finds none inside it and every wide
+                        # mixed step crashes
+                        sched.prefill_rects = sorted(
+                            sched.prefill_rects + [(wr, wl)]
                         )
                     sched.mixed_prefill_wide_rows = wr
                     sched.mixed_prefill_wide_len = wl
@@ -948,14 +941,7 @@ class JaxEngine:
             {b for b in (sched.decode_batch_small, sched.decode_batch_mid,
                          sched.decode_batch_pad) if b}
         ) or [1]
-        ms = set(decode_buckets)
-        max_chunk = next_bucket(
-            self.config.prefill_chunk_size, sched.prefill_chunk_buckets
-        )
-        for b in sched.prefill_batch_buckets:
-            for chunk in sched.prefill_chunk_buckets:
-                if chunk <= max_chunk:
-                    ms.add(b * chunk)
+        ms = set(decode_buckets) | {b * t for b, t in sched.prefill_rects}
         if self.config.spec_decode:
             for b in decode_buckets:
                 ms.add(b * (self.config.spec_tokens + 1))
@@ -970,7 +956,7 @@ class JaxEngine:
             ]
         # lm_head reads [B, D] (last-token gather) on every non-spec
         # path; the spec verify path feeds the full [B, S] rectangle
-        lm_ms = set(decode_buckets) | set(sched.prefill_batch_buckets)
+        lm_ms = set(decode_buckets) | {b for b, _ in sched.prefill_rects}
         if self.config.spec_decode:
             lm_ms |= {b * (self.config.spec_tokens + 1) for b in decode_buckets}
         for m in sorted(lm_ms):
@@ -1061,51 +1047,53 @@ class JaxEngine:
         # followers' receive loop exists, so the step broadcast must not
         # fire here (the jit's own collectives line up because all ranks
         # prewarm the same shapes in the same sequence).
-        max_chunk = next_bucket(
-            self.config.prefill_chunk_size, sched.prefill_chunk_buckets
-        )
-        chunks = [c for c in sched.prefill_chunk_buckets if c <= max_chunk]
-        # two passes: the first call sees the init_cache sharding, later
-        # ones XLA's canonical output sharding — a different jit
-        # signature. Pass 2 ensures every shape is compiled against the
-        # steady-state sharding (cache hit if they're equal).
         p_outs: dict[int, tuple] = {}  # base-variant prefill outputs
-        for _ in range(2):
-            for chunk in chunks:
-                for b in sched.prefill_batch_buckets:
-                    # the planner only emits multi-row rectangles whose
-                    # padded area fits the prefill token budget (single-
-                    # row steps may use the full chunk regardless)
-                    if (
-                        b > sched.prefill_batch_buckets[0]
-                        and b * chunk > sched.max_prefill_tokens
-                    ):
-                        continue
-                    for pv, tv, bv in feat_variants:
-                        a = prefill_arrays(b, chunk)
-                        s = sampling_for(b, penalties=pv, toplp=tv, bias=bv)
-                        step_args = (
-                            self.params, self.k_cache, self.v_cache,
-                            a["tokens"], a["positions"],
-                            a["slot_mapping"], a["block_tables"],
-                            a["context_lens"], a["last_token_idx"],
-                            s.arrays,
-                        )
-                        if "mosaic_calls_in_step" not in self.device_report:
-                            # what the first step hands the chip's
-                            # compiler: a Pallas kernel that runs
-                            # interpreted lowers to plain HLO and is
-                            # not counted
-                            self.device_report["mosaic_calls_in_step"] = (
-                                self._step_fn.lower(*step_args)
-                                .as_text().count("tpu_custom_call")
-                            )
-                        out = self._step_fn(*step_args)
-                        self.k_cache, self.v_cache = out[-2], out[-1]
-                        if not (pv or tv or bv):
-                            # retained for the overlap-glue warm below
-                            p_outs[b] = out[:2]
-                        jax.block_until_ready(self.k_cache)
+
+        def warm_prefill(b: int, chunk: int) -> None:
+            t_rect = time.monotonic()
+            for pv, tv, bv in feat_variants:
+                a = prefill_arrays(b, chunk)
+                s = sampling_for(b, penalties=pv, toplp=tv, bias=bv)
+                step_args = (
+                    self.params, self.k_cache, self.v_cache,
+                    a["tokens"], a["positions"],
+                    a["slot_mapping"], a["block_tables"],
+                    a["context_lens"], a["last_token_idx"],
+                    s.arrays,
+                )
+                if "mosaic_calls_in_step" not in self.device_report:
+                    # what the first step hands the chip's compiler: a
+                    # Pallas kernel that runs interpreted lowers to
+                    # plain HLO and is not counted. The call below
+                    # reuses this trace and lowering (measured, PERF.md
+                    # PR 39): only the text is made for the count.
+                    self.device_report["mosaic_calls_in_step"] = (
+                        self._step_fn.lower(*step_args)
+                        .as_text().count("tpu_custom_call")
+                    )
+                out = self._step_fn(*step_args)
+                self.k_cache, self.v_cache = out[-2], out[-1]
+                if not (pv or tv or bv):
+                    # retained for the overlap-glue warm below
+                    p_outs[b] = out[:2]
+            # set-up time by program (its trace, lowering and load: the
+            # device runs it while the host traces the next one)
+            log.info(
+                "prewarm: prefill %dx%d in %.2fs",
+                b, chunk, time.monotonic() - t_rect,
+            )
+
+        # the FIRST call sees init_cache's arrays, every later one a
+        # step's own outputs, which XLA places its own way (its
+        # canonical output sharding is not init_cache's spelling:
+        # another jit signature). So the first rectangle is warmed once
+        # more, against the steady state; every other shape has met it
+        # already, and a whole second pass would find each program
+        # compiled and only run every padded rectangle again.
+        for b, chunk in sched.prefill_rects + sched.prefill_rects[:1]:
+            warm_prefill(b, chunk)
+        jax.block_until_ready(self.k_cache)
+        t_decode = time.monotonic()
         decode_buckets = sorted(
             {b for b in (sched.decode_batch_small, sched.decode_batch_mid,
                          sched.decode_batch_pad)
@@ -1340,8 +1328,7 @@ class JaxEngine:
                             self._chain_fn(lasts[b_from], pn, idx)
         if self.config.prewarm_guided:
             self._prewarm_guided(
-                chunks, decode_buckets, sampling_for, prefill_arrays,
-                decode_arrays,
+                decode_buckets, sampling_for, prefill_arrays, decode_arrays,
             )
         if self.kvbm is not None and self._mh_broadcast is None:
             # (single-host manager only: the multihost sharded offload
@@ -1364,14 +1351,17 @@ class JaxEngine:
                 data = self._kv_gather(ids)
                 self._kv_scatter(ids, data)
             jax.block_until_ready(self.k_cache)
+        log.info(
+            "prewarm: decode buckets %s, their chained variants and the "
+            "glue in %.2fs", decode_buckets, time.monotonic() - t_decode,
+        )
         prewarm_s = time.monotonic() - t0
         self.device_report["prewarm_s"] = round(prewarm_s, 3)
         ENGINE_PREWARM_SECONDS.set(prewarm_s)
         log.info("prewarm done in %.1fs", prewarm_s)
 
     def _prewarm_guided(
-        self, chunks, decode_buckets, sampling_for, prefill_arrays,
-        decode_arrays,
+        self, decode_buckets, sampling_for, prefill_arrays, decode_arrays,
     ) -> None:
         """Warm the guided (allow-mask) jit variants — the masked
         serial prefill rectangles and decode buckets, plus the masked
@@ -1407,26 +1397,20 @@ class JaxEngine:
         if self.config.prewarm_penalties:
             feat_variants.append((True, False, False))
             feat_variants.append((False, False, True))
-        for chunk in chunks:
-            for b in sched.prefill_batch_buckets:
-                if (
-                    b > sched.prefill_batch_buckets[0]
-                    and b * chunk > sched.max_prefill_tokens
-                ):
-                    continue
-                for pv, tv, bv in feat_variants:
-                    a = prefill_arrays(b, chunk)
-                    s = masked(
-                        sampling_for(b, penalties=pv, toplp=tv, bias=bv), b
-                    )
-                    out = self._step_fn(
-                        self.params, self.k_cache, self.v_cache,
-                        a["tokens"], a["positions"], a["slot_mapping"],
-                        a["block_tables"], a["context_lens"],
-                        a["last_token_idx"], s.arrays,
-                    )
-                    self.k_cache, self.v_cache = out[-2], out[-1]
-                    jax.block_until_ready(self.k_cache)
+        for b, chunk in sched.prefill_rects:
+            for pv, tv, bv in feat_variants:
+                a = prefill_arrays(b, chunk)
+                s = masked(
+                    sampling_for(b, penalties=pv, toplp=tv, bias=bv), b
+                )
+                out = self._step_fn(
+                    self.params, self.k_cache, self.v_cache,
+                    a["tokens"], a["positions"], a["slot_mapping"],
+                    a["block_tables"], a["context_lens"],
+                    a["last_token_idx"], s.arrays,
+                )
+                self.k_cache, self.v_cache = out[-2], out[-1]
+                jax.block_until_ready(self.k_cache)
         for Bd in decode_buckets:
             for pv, tv, bv in feat_variants:
                 a = decode_arrays(Bd)
@@ -2170,6 +2154,8 @@ class JaxEngine:
         prompt pays it twice for nothing. The dispatch still happens
         (and still broadcasts under multihost); donated caches chain
         the next step regardless."""
+        if kind == "prefill":
+            self._count_prefill(arrays)
         with self._dispatch_span(kind, arrays["tokens"]):
             outs = self._dispatch_device_step(
                 arrays, sampling, origin=origin, defer_sync=not sync
@@ -2178,6 +2164,15 @@ class JaxEngine:
             return None
         with step_span("dyn.step.harvest"):
             return self._harvest_device_step(outs)
+
+    def _count_prefill(self, arrays: dict[str, np.ndarray]) -> None:
+        """One prefill rectangle about to be dispatched: what its chunks
+        hold (pad rows have context 0) against what it pads to."""
+        real = arrays["context_lens"] > 0
+        self._prefill_tokens[0] += int(
+            (arrays["last_token_idx"][real] + 1).sum()
+        )
+        self._prefill_tokens[1] += arrays["tokens"].size
 
     def _dispatch_span(self, kind: str, tokens):
         """Count one device program of ``kind`` (program_counts) and
@@ -3927,6 +3922,7 @@ class JaxEngine:
             d_arrays["block_tables"].shape[1],
         )
         p_pad = self._pad_prefill_rect(p_arrays, P, T, width)
+        self._count_prefill(p_pad)
         d_arrays["block_tables"] = self.scheduler.widen_tables(
             d_arrays["block_tables"], width
         )
@@ -4094,7 +4090,7 @@ class JaxEngine:
         # dispatch the first window
         if works:
             with step_span("dyn.step.pack"):
-                p_arrays = sched.build_prefill_batch_arrays(works)
+                p_arrays = sched.build_prefill_batch_arrays(works, within=rect)
             # Multimodal chunks, top-logprobs AND penalty/bias batches
             # take a dedicated prefill step instead of the mixed
             # rectangle: embedding injection doesn't ride the fixed
@@ -4141,6 +4137,7 @@ class JaxEngine:
                     sampling_p = self._batch_sampling(
                         [w.seq for w in works], p_arrays["tokens"].shape[0]
                     )
+                self._count_prefill(p_arrays)
                 with self._dispatch_span("prefill", p_arrays["tokens"]):
                     outs = self._dispatch_device_step(
                         p_arrays, sampling_p,
@@ -4276,7 +4273,9 @@ class JaxEngine:
             p2 = None
             if nxt["works2"]:
                 with step_span("dyn.step.pack"):
-                    p2 = sched.build_prefill_batch_arrays(nxt["works2"])
+                    p2 = sched.build_prefill_batch_arrays(
+                        nxt["works2"], within=nxt["rect"]
+                    )
                 if "extra_embeds" in p2:
                     return False  # multimodal never rides the pipeline
             if self._mh_broadcast is not None:
@@ -4962,6 +4961,8 @@ class JaxEngine:
                 preemptions=sched.preemptions,
                 decode_dispatches=self._decode_dispatches[0],
                 decode_dispatches_chained=self._decode_dispatches[1],
+                prefill_tokens_real=self._prefill_tokens[0],
+                prefill_tokens_padded=self._prefill_tokens[1],
             )
             if sched.state_slots is not None:
                 out.update(
@@ -5056,6 +5057,11 @@ class JaxEngine:
                 "preemptions": sched.preemptions,
                 "prefix_queries": sched.prefix_queries,
                 "prefix_hits": sched.prefix_hits,
+                # the prefill shapes there are, and what the dispatched
+                # ones held against what they padded to
+                "prefill_rects": [f"{b}x{t}" for b, t in sched.prefill_rects],
+                "prefill_tokens_real": self._prefill_tokens[0],
+                "prefill_tokens_padded": self._prefill_tokens[1],
                 # bounded: the fleet view needs the shape of the batch,
                 # not one row per request at max_batch_size=256
                 "requests": [

@@ -167,6 +167,59 @@ class StepPlan:
         return self.prefill_batch[0] if self.prefill_batch else None
 
 
+def prefill_rectangles(
+    rows: list[int], tokens: list[int], budget: int, chunk_size: int,
+    single_row_tokens: tuple[int, ...] = (),
+) -> list[tuple[int, int]]:
+    """The ``(rows, tokens)`` prefill rectangles a step can name, sorted:
+    of the two ladders' product those no longer than the length that
+    holds ``chunk_size`` (no chunk is longer), at the smallest row count
+    whatever the area (a lone chunk must run) and at the others where
+    ``rows x tokens`` fits ``budget`` (the planner grows a batch no
+    further) — and ``single_row_tokens`` at the smallest row count
+    alone. Each is a whole-model program for whoever warms the set, and
+    nobody warms a shape outside it."""
+    top = next_bucket(chunk_size, tokens)
+    rects = {
+        (r, t) for t in tokens for r in rows
+        if r == rows[0] or r * t <= budget
+    } | {(rows[0], t) for t in single_row_tokens}
+    return sorted(rt for rt in rects if rt[1] <= top)
+
+
+def mixed_rect_of(
+    rects: list[tuple[int, int]], rows: int, length: int, cap: int
+) -> Optional[tuple[int, int]]:
+    """The mixed window's fixed rectangle for a requested ``rows`` x
+    ``length``: a member of ``rects`` — the row count that holds
+    ``rows`` (else the largest), AT it the length that holds ``length``
+    (else its longest), then a shorter length while one alone passes
+    ``cap`` and fewer rows of that length while the area does. None
+    where nothing fits."""
+    counts = sorted({r for r, _ in rects})
+    rows = next((r for r in counts if r >= rows), counts[-1])
+    lens = sorted(t for r, t in rects if r == rows)
+    length = next((t for t in lens if t >= length), lens[-1])
+    while length > cap and length > lens[0]:
+        length = lens[lens.index(length) - 1]
+    fewer = [r for r in counts if r <= rows and (r, length) in rects]
+    while rows * length > cap and rows > fewer[0]:
+        rows = fewer[fewer.index(rows) - 1]
+    return (rows, length) if rows * length <= cap else None
+
+
+def _area(rect: Optional[tuple[int, int]]) -> int:
+    return rect[0] * rect[1] if rect else 0
+
+
+def _ladder_to(buckets: list[int], top: int) -> list[int]:
+    """``buckets``, doubled on past its end until it holds ``top``."""
+    out = list(buckets)
+    while out[-1] < top:
+        out.append(out[-1] * 2)
+    return out
+
+
 class Scheduler:
     def __init__(
         self,
@@ -225,8 +278,17 @@ class Scheduler:
         # 32-deep population to 64 rows (~11% measured at c=32)
         self.decode_batch_mid: Optional[int] = None
         self.table_width_pad: Optional[int] = None
-        self.prefill_batch_buckets: list[int] = list(self.BATCH_BUCKETS)
-        self.prefill_chunk_buckets: list[int] = list(self.CHUNK_BUCKETS)
+        # THE set of prefill shapes, sorted: the planner grows a batch
+        # only into a member, the builder pads to the smallest member
+        # that covers the step, and every start-up loop compiles exactly
+        # these (engine._prewarm). Here what the two class ladders'
+        # product leaves reachable; a static-shape engine replaces it
+        # with the few rectangles of its coarser ladders (engine.py).
+        self.prefill_rects: list[tuple[int, int]] = prefill_rectangles(
+            _ladder_to(self.BATCH_BUCKETS, max_batch_size),
+            _ladder_to(self.CHUNK_BUCKETS, prefill_chunk_size),
+            self.max_prefill_tokens, prefill_chunk_size,
+        )
         self._arrival = 0
         # invoked on every finish (incl. cancellations reaped inside plan())
         self.on_finish: Optional[Callable[[Sequence, FinishReason], None]] = None
@@ -574,11 +636,14 @@ class Scheduler:
         """One chunk from each of several prefilling sequences, fused
         into a single step (total tokens bounded by max_prefill_tokens)
         — continuous batching's batched-prefill half. ``max_chunk_len``
-        additionally caps each row's chunk (the mixed-step rectangle)."""
+        additionally caps each row's chunk (the mixed-step rectangle,
+        which the caller sized to hold ``max_seqs`` such rows: none is
+        turned away for its area)."""
         budget = budget if budget is not None else self.max_prefill_tokens
         max_seqs = max_seqs if max_seqs is not None else self.max_batch_size
         works: list[PrefillWork] = []
         max_chunk = 0
+        rect: Optional[tuple[int, int]] = None
         for seq in self.prefilling:
             if len(works) >= max_seqs:
                 break
@@ -598,21 +663,20 @@ class Scheduler:
             # that area, not the sum of real tokens — one long chunk
             # plus many short ones must not inflate into a huge step
             new_max = max(max_chunk, chunk)
-            area = (
-                next_bucket(len(works) + 1, self.prefill_batch_buckets)
-                * next_bucket(new_max, self.prefill_chunk_buckets)
-            )
-            cur_area = (
-                next_bucket(len(works), self.prefill_batch_buckets)
-                * next_bucket(max_chunk, self.prefill_chunk_buckets)
-                if works
-                else 0
-            )
-            # a row whose admission leaves the padded rectangle unchanged
-            # is free — only reject when it actually GROWS the dispatch
-            # past the budget
-            if works and area > budget and area > cur_area:
-                break
+            if max_chunk_len is None:
+                grown = self.prefill_rect(len(works) + 1, new_max)
+                # a row whose admission leaves the padded rectangle
+                # unchanged is free — only reject when it actually GROWS
+                # the dispatch past the budget, or when no rectangle
+                # holds one row more (two 300-token chunks under static
+                # shapes: two 1 x 512 steps, not eight padded rows of
+                # 1 024)
+                if works and (
+                    grown is None
+                    or _area(grown) > max(budget, _area(rect))
+                ):
+                    break
+                rect = grown
             tokens = np.asarray(prompt[start : start + chunk], dtype=np.int32)
             works.append(
                 PrefillWork(
@@ -1267,6 +1331,15 @@ class Scheduler:
     # -- step-tensor construction (static-shaped, bucketed) ---------------
     BATCH_BUCKETS = [1, 2, 4, 8, 16, 32, 64, 128, 256]
     CHUNK_BUCKETS = [16, 32, 64, 128, 256, 512, 1024, 2048, 4096]
+    # static shapes, where every rectangle is compiled at start-up:
+    # chunk lengths at every row count the budget allows, and the one a
+    # single row has besides — between 256 and 1 024, which prompts of
+    # 257-512 tokens would otherwise pay four-fold for. One row because
+    # the static row ladder is coarse: 8 x 512 would be one more program
+    # for steps traffic almost never forms, and two waiting chunks of
+    # that length run as two single-row steps either way.
+    STATIC_CHUNK_TOKENS = [128, 256, 1024, 4096]
+    STATIC_SINGLE_ROW_TOKENS = (512,)
     TABLE_BUCKET = 8  # block-table width rounded to multiples of this
 
     def _release_state(self, seq: Sequence) -> None:
@@ -1325,18 +1398,40 @@ class Scheduler:
             return self.decode_batch_pad
         return b
 
+    def prefill_rect(
+        self, rows: int, tokens: int,
+        within: Optional[tuple[int, int]] = None,
+    ) -> Optional[tuple[int, int]]:
+        """The smallest-area rectangle of ``prefill_rects`` that covers
+        ``rows`` chunks of up to ``tokens`` tokens (and, for a mixed
+        window, lies inside ``within``), or None. Ties go to fewer
+        rows."""
+        fits = [
+            r for r in self.prefill_rects
+            if r[0] >= rows and r[1] >= tokens
+            and (within is None or (r[0] <= within[0] and r[1] <= within[1]))
+        ]
+        return min(fits, key=_area) if fits else None
+
     def build_prefill_batch_arrays(
-        self, works: list[PrefillWork]
+        self, works: list[PrefillWork],
+        within: Optional[tuple[int, int]] = None,
     ) -> dict[str, np.ndarray]:
-        """Fuse several sequences' prefill chunks into one [B, T] step
-        (rows padded to the chunk bucket, batch padded to the batch
-        bucket; pads write to the garbage slot 0 like decode pads)."""
+        """Fuse several sequences' prefill chunks into one [B, T] step,
+        padded to ``prefill_rect`` (pads write to the garbage slot 0 like
+        decode pads). ``within``: the mixed window's rectangle the
+        arrays are padded out to afterwards."""
         bs = self.block_size
         n = len(works)
-        B = next_bucket(n, self.prefill_batch_buckets)
-        T = next_bucket(
-            max(len(w.tokens) for w in works), self.prefill_chunk_buckets
-        )
+        longest = max(len(w.tokens) for w in works)
+        rect = self.prefill_rect(n, longest, within)
+        if rect is None:
+            # the planner grows a batch only into rectangles that exist
+            raise ValueError(
+                f"no prefill rectangle holds {n} x {longest} tokens "
+                f"(within {within}): {self.prefill_rects}"
+            )
+        B, T = rect
         max_blocks = max(len(w.seq.block_table) for w in works)
         width = self._table_width(max_blocks)
         tokens = np.zeros((B, T), np.int32)

@@ -401,24 +401,33 @@ def test_ssm_decode_update_compiles_for_v5e(rows, one_chip, no_compile_cache):
     assert "tpu_custom_call" in text and "ssm_decode_update" in text
 
 
-def _nemotron_stage():
+# the pool a cell runs with where its configuration pins none: what the
+# engine's own sizing gives on a 16 GB chip (PERF.md section 4)
+_STAGE_BLOCKS = {"kimi-linear-48b": 6175}
+
+
+def _stage(config: str):
+    """(ModelConfig, pool pages) of a benchmark configuration."""
     import json
     import os
 
     from dynamo_tpu.models import ModelConfig
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "perf", "configs", "nemotron-3-nano-30b.json")) as f:
+    with open(os.path.join(root, "perf", "configs", config + ".json")) as f:
         raw = json.load(f)
-    return ModelConfig.from_dict(raw), raw["serving"]["engine"]["num_blocks"]
+    pinned = raw["serving"]["engine"].get("num_blocks")
+    return ModelConfig.from_dict(raw), pinned or _STAGE_BLOCKS[config]
 
 
-def _compiled_nemotron_step(rows, T, one_chip, monkeypatch):
-    """The served step (int8 weights, bf16 pages, the pool the configuration
-    pins, 65 state slots) of ``rows`` x ``T`` tokens, for the described chip."""
-    from dynamo_tpu.models import hybrid, nemotron_h as nh
+def _compiled_family_step(config, rows, T, one_chip, monkeypatch):
+    """The served step (int8 weights, bf16 pages, the cell's pool, 65 state
+    slots) of ``rows`` x ``T`` tokens of a recurrent-state family at its
+    benchmark configuration, for the described chip."""
+    from dynamo_tpu.models import family as model_family, hybrid
 
-    cfg, num_blocks = _nemotron_stage()
+    cfg, num_blocks = _stage(config)
+    fam = model_family(cfg)
     monkeypatch.setattr(hybrid, "kernels_active", lambda: True)
     monkeypatch.setattr(llama, "pallas_matmul_active", lambda: True)
     monkeypatch.setattr(llama, "_qmm_interpret", lambda: False)
@@ -428,28 +437,33 @@ def _compiled_nemotron_step(rows, T, one_chip, monkeypatch):
         return {n: _sds(s, dtype_of(n), one_chip) for n, s in shapes.items()}
 
     params = {}
-    for name, (shape, dtype) in nh.param_shapes(cfg).items():
-        if name in nh.QUANT_AXIS:
+    for name, (shape, dtype) in fam.param_shapes(cfg).items():
+        if name in fam.QUANT_AXIS:
             params[name] = _sds(shape, jnp.int8, one_chip)
-            axis = nh.QUANT_AXIS[name] % len(shape)
+            axis = fam.QUANT_AXIS[name] % len(shape)
             params[name + "_scale"] = _sds(
                 shape[:axis] + shape[axis + 1:], jnp.float32, one_chip)
         else:
-            params[name] = _sds(shape, jnp.float32, one_chip)
-    pshape, sshape = nh.cache_shapes(cfg, num_blocks, BS, 65)
+            params[name] = _sds(shape, dtype, one_chip)
+    pshape, sshape = fam.cache_shapes(cfg, num_blocks, BS, 65)
     pages = sds_tree(pshape, lambda n: jnp.bfloat16)
     state = sds_tree(sshape, lambda n: jnp.float32)
-    state["counts"] = _sds((len(nh.COUNT_NAMES),), jnp.int32, one_chip)
+    state["counts"] = _sds((len(fam.COUNT_NAMES),), jnp.int32, one_chip)
     ids = _sds((rows,), jnp.int32, one_chip)
     grid = _sds((rows, T), jnp.int32, one_chip)
 
     def step(params, pages, state, tokens, positions, slots, tables, ctx, last):
-        return nh.forward(cfg, params, pages, state, tokens, positions, slots,
-                          tables, ctx, last, BS)
+        return fam.forward(cfg, params, pages, state, tokens, positions, slots,
+                           tables, ctx, last, BS)
 
     return jax.jit(step, donate_argnums=(1, 2)).lower(
         params, pages, state, grid, grid, _sds((rows * T,), jnp.int32, one_chip),
         _sds((rows, TABLE_W + 1), jnp.int32, one_chip), ids, ids).compile()
+
+
+def _compiled_nemotron_step(rows, T, one_chip, monkeypatch):
+    return _compiled_family_step(
+        "nemotron-3-nano-30b", rows, T, one_chip, monkeypatch)
 
 
 @pytest.mark.parametrize("rows", [8, 64])
@@ -485,3 +499,27 @@ def test_the_nemotron_h_largest_prefill_step_compiles_for_v5e_within_its_transie
     assert "ragged-dot" not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < nh.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("config,sorted_rows", [
+    ("kimi-linear-48b", True),       # 512 > its MOE_DENSE_TOKENS 64
+    ("qwen3-next-80b", False),       # 512 = its MOE_DENSE_TOKENS
+    ("nemotron-3-nano-30b", False),  # this family has no sorted form
+])
+def test_the_1x512_prefill_step_compiles_for_v5e_within_its_transients(
+    config, sorted_rows, one_chip, no_compile_cache, monkeypatch
+):
+    """The single-row 512-token rectangle (Scheduler.STATIC_SINGLE_ROW_TOKENS)
+    of each recurrent-state family at its cell's configuration: the one
+    program a start-up compiles that the parent did not. It compiles for
+    the described chip; at 512 tokens ``qwen3_next`` takes the every-expert
+    form (no ``ragged-dot``: the sorted rows and the bf16 copy of a layer's
+    experts are what its 1 024-token step pays for); and its temporaries
+    stay inside what the family reserves for a step."""
+    from dynamo_tpu.models import family as model_family
+
+    compiled = _compiled_family_step(config, 1, 512, one_chip, monkeypatch)
+    assert ("ragged-dot" in compiled.as_text()) == sorted_rows
+    mem = compiled.memory_analysis()
+    fam = model_family(_stage(config)[0])
+    assert mem.temp_size_in_bytes < fam.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes

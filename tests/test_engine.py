@@ -493,7 +493,8 @@ def test_prefill_batch_admits_free_rows_under_pinned_buckets():
     alloc = BlockAllocator(256, 4)
     sched = Scheduler(alloc, 4, max_batch_size=8, prefill_chunk_size=16,
                       max_prefill_tokens=16)
-    sched.prefill_batch_buckets = [8]  # bench-style pinning
+    # bench-style pinning: a set with one row count
+    sched.prefill_rects = [(8, t) for t in Scheduler.CHUNK_BUCKETS]
     for i in range(4):
         sched.add_request(_mk_seq(list(range(1, 17)), request_id=f"p{i}"))
     plan = sched.plan()

@@ -139,6 +139,10 @@ async def test_engine_spans_tile_submit_to_finish(buffered_tracer):
     assert counts["preemptions"] == 0
     assert counts["steps"]["prefill"] == 3 + again["engine.prefill"]["attrs"]["chunks"]
     assert counts["steps"]["decode"] >= 10
+    # every prefill dispatch counted: the tokens its chunks held, and the
+    # rows x tokens of the rectangle it ran (never fewer)
+    assert counts["prefill_tokens_real"] == 140 - cached
+    assert counts["prefill_tokens_padded"] >= counts["prefill_tokens_real"]
 
 
 # ---------------------------------------------------------------------------
