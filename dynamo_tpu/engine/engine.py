@@ -71,12 +71,18 @@ from dynamo_tpu.protocols.common import (
 from dynamo_tpu.runtime.engine import AsyncEngine, Context, EngineStream
 from dynamo_tpu.telemetry import autopsy, get_tracer, new_trace_id
 from dynamo_tpu.telemetry.debug import (
+    note_counts,
     register_count_provider,
     register_debug_provider,
     unregister_count_provider,
     unregister_debug_provider,
 )
-from dynamo_tpu.telemetry.spans import step_span
+from dynamo_tpu.telemetry.spans import (
+    StepClock,
+    bind_step_clock,
+    note_loop_thread,
+    step_span,
+)
 from dynamo_tpu.telemetry.attribution import (
     AttributionLedger,
     BlackBox,
@@ -357,6 +363,13 @@ class JaxEngine:
         # per-dispatch phase timings (_run_device_step fills; the step
         # recorder reads) — a plain dict, engine-thread only
         self._last_phases: dict[str, float] = {}
+        # the step loop's one clock (telemetry/spans.py): every
+        # dyn.step.* phase, the loop's wall, periods and dispatches by
+        # kind; bound to the engine thread at the top of _step_loop
+        self.step_clock = StepClock()
+        # the newest dispatch's token output, asked is_ready() just
+        # before the next dispatch: had the device's queue run dry?
+        self._newest_out: Any = None
         self._debug_name: Optional[str] = None
         try:
             self.PIPELINE_DEPTH = max(
@@ -391,6 +404,9 @@ class JaxEngine:
         # at its own start. spec_suspended is engine-affine — the loop-side
         # writer (planner degradation rung) declares its handoff.
         affinity.register_thread("loop")
+        # ... and the thread whose CPU the count history sets beside the
+        # engine thread's (the two share one interpreter lock)
+        note_loop_thread()
         affinity.guard_attrs(engine, {"spec_suspended": "engine"})
         engine._thread = threading.Thread(
             target=engine._step_loop, name="jax-engine", daemon=True
@@ -2098,7 +2114,6 @@ class JaxEngine:
             sampling.arrays,
         )
         idle_gap_s = self.overlap.note_dispatch()
-        t_disp = time.monotonic()
         if "extra_embeds" in arrays:
             out = self._step_fn_mm(
                 *base_args, arrays["extra_embeds"], arrays["embeds_mask"]
@@ -2106,11 +2121,10 @@ class JaxEngine:
         else:
             out = self._step_fn(*base_args)
         self.k_cache, self.v_cache = out[-2], out[-1]
-        t_done = time.monotonic()
-        self._last_phases = {
-            "dispatch_ms": round((t_done - t_disp) * 1e3, 3),
-            "idle_gap_ms": round(idle_gap_s * 1e3, 3),
-        }
+        self._newest_out = out[0]
+        # dispatch_ms is the enclosing dyn.step.dispatch phase's wall:
+        # the caller adds it when the phase has ended
+        self._last_phases = {"idle_gap_ms": round(idle_gap_s * 1e3, 3)}
         if defer_sync:
             self._unsynced_steps.append(
                 origin or f"shape={arrays['tokens'].shape}"
@@ -2125,14 +2139,10 @@ class JaxEngine:
         overlapped pipeline that result is already (or nearly) done."""
         from dynamo_tpu.parallel.multihost import host_value
 
-        t0 = time.monotonic()
         # (next_tokens, logprobs) base; (+ top_ids, top_lps) on the
         # top-logprobs variant
         res = tuple(host_value(x) for x in outs)
         self.overlap.note_complete(all_prior=True)
-        self._last_phases["sync_ms"] = round(
-            (time.monotonic() - t0) * 1e3, 3
-        )
         # a successful sync retires every earlier async dispatch
         # (in-order device execution): their deferred errors would have
         # surfaced in this host read
@@ -2156,14 +2166,17 @@ class JaxEngine:
         the next step regardless."""
         if kind == "prefill":
             self._count_prefill(arrays)
-        with self._dispatch_span(kind, arrays["tokens"]):
+        with self._dispatch_span(kind, arrays["tokens"]) as dispatch:
             outs = self._dispatch_device_step(
                 arrays, sampling, origin=origin, defer_sync=not sync
             )
+        self._last_phases["dispatch_ms"] = dispatch.ms
         if not sync:
             return None
-        with step_span("dyn.step.harvest"):
-            return self._harvest_device_step(outs)
+        with step_span("dyn.step.harvest") as harvest:
+            res = self._harvest_device_step(outs)
+        self._last_phases["sync_ms"] = harvest.ms
+        return res
 
     def _count_prefill(self, arrays: dict[str, np.ndarray]) -> None:
         """One prefill rectangle about to be dispatched: what its chunks
@@ -2176,17 +2189,26 @@ class JaxEngine:
 
     def _dispatch_span(self, kind: str, tokens):
         """Count one device program of ``kind`` (program_counts) and
-        mark its dispatch in a live profiler capture; ``tokens`` is the
-        step's token array (host or device), read for its shape only."""
+        return its ``dyn.step.dispatch`` phase; ``tokens`` is the step's
+        token array (host or device), read for its shape only. Asks the
+        device, without waiting, whether the newest step in flight has
+        finished: if so (or nothing is in flight) this dispatch goes to a
+        device whose queue had run dry — the host came late."""
         self._steps_dispatched[kind] = self._steps_dispatched.get(kind, 0) + 1
         slots = self.scheduler.state_slots if self.scheduler else None
         if slots is not None:
             self._state_slot_steps[0] += slots.num_used
             self._state_slot_steps[1] += slots.num_slots - 1
-        return step_span(
+        phase = step_span(
             "dyn.step.dispatch", kind=kind, rows=int(tokens.shape[0]),
             tokens=int(tokens.size),
         )
+        newest = self._newest_out
+        try:
+            phase.drained = newest is None or newest.is_ready()
+        except Exception:  # advisory: a count never fails a dispatch
+            log.debug("is_ready() of the newest step failed", exc_info=True)
+        return phase
 
     # ------------------------------------------------------------------
     # Engine thread loop
@@ -2194,9 +2216,12 @@ class JaxEngine:
     @affinity.thread_affinity("engine")
     def _step_loop(self) -> None:
         affinity.register_thread("engine")
+        self.step_clock.on_tick = self._note_counts
+        bind_step_clock(self.step_clock)
         try:
             self._step_loop_body()
         finally:
+            bind_step_clock(None)
             # OS thread idents are reused — a stale binding would blame
             # "engine" for a later unrelated thread's writes
             affinity.unregister_thread()
@@ -2235,17 +2260,20 @@ class JaxEngine:
                 self._disable_kvbm()
             return True
 
+        clock = self.step_clock
         while self._running:
+            clock.lap()
             # worker-liveness injection point: `kill` rules here model a
             # hard worker death between steps (one-shot by default)
             faults.fire("worker.liveness")
             with step_span("dyn.step.plan"):
                 self._drain_incoming()
-            if self._draining:
-                # graceful drain: hand off eligible in-flight streams at
-                # this step boundary (every generated token has already
-                # been emitted, so the router's commit log is exact)
-                self._migrate_eligible()
+                if self._draining:
+                    # graceful drain: hand off eligible in-flight streams
+                    # at this step boundary (every generated token has
+                    # already been emitted, so the router's commit log is
+                    # exact)
+                    self._migrate_eligible()
             if (
                 not self.scheduler.running
                 and not self.scheduler.prefilling
@@ -2261,19 +2289,24 @@ class JaxEngine:
                 # blocking sleep is deliberate: _step_loop runs on the
                 # dedicated "jax-engine" thread (launch()), never on the
                 # event loop, so this parks only the engine thread
-                for _ in range(8):
-                    before = len(self.scheduler.waiting)
-                    time.sleep(0.002)
-                    self._drain_incoming()
-                    if len(self.scheduler.waiting) == before:
-                        break
+                clock.note_idle()
+                with step_span("dyn.step.wait"):
+                    for _ in range(8):
+                        before = len(self.scheduler.waiting)
+                        time.sleep(0.002)
+                        self._drain_incoming()
+                        if len(self.scheduler.waiting) == before:
+                            break
             if not self.scheduler.has_work:
                 # idle: drain the offload queue (and run the pump's
                 # periodic G4 index refresh) before sleeping. SMALL
                 # batches per iteration: each block is a multi-MB
                 # device->host transfer, and a request arriving
                 # mid-batch must not wait out a 16-block gather.
-                if not pump_kvbm(4):
+                clock.note_idle()
+                with step_span("dyn.step.wait"):
+                    pumped = pump_kvbm(4)
+                if not pumped:
                     self._fail_all()
                     self._running = False  # dynalint: handoff=stop-flag — one-way bool, each side only ever writes False; readers poll per step/await
                     return
@@ -2335,7 +2368,11 @@ class JaxEngine:
             # steps (not measured on the attached chip);
             # pending commits are bounded by G1 size, revalidated at
             # pump time, and drain at idle moments.
-            if not pump_kvbm(self._kv_busy_pump_cap):
+            if self.kvbm is None:
+                continue
+            with step_span("dyn.step.wait"):
+                pumped = pump_kvbm(self._kv_busy_pump_cap)
+            if not pumped:
                 self._fail_all()
                 self._running = False  # dynalint: handoff=stop-flag — one-way bool, each side only ever writes False; readers poll per step/await
                 return
@@ -2695,6 +2732,62 @@ class JaxEngine:
                 f"first {summary['error']!r} during a {kind} step"
             )
 
+    def _route(self, plan) -> str:
+        """Which path steps ``plan`` (called under ``dyn.step.plan``: the
+        per-row divert scans are planning, and the device waits them out
+        like the rest of it)."""
+        kind = plan.kind
+        if kind == "idle":
+            return "idle"
+        if kind == "mixed":
+            if self._mixed_step_fn is not None:
+                return "mixed"
+            plan.kind = kind = "prefill"  # no fused window: prefill this step
+        seqs = plan.decode_seqs
+        if kind == "decode" and seqs:
+            if (
+                self._drafter is not None
+                and not self.spec_suspended
+                and not self._spec_divert(seqs)
+            ):
+                # overlapped speculative decode (the tentpole of
+                # docs/speculative_decoding.md's pipelined section):
+                # host drafting for step N+1 runs WHILE the device
+                # verifies step N
+                if self._overlap_ok() and not self._overlap_divert(seqs):
+                    return "spec_pipeline"
+                return "spec"
+            if (
+                self._multi_step_fn is None
+                and self._overlap_ok()
+                and not self._overlap_divert(seqs)
+            ):
+                # spec-suspended (degradation rung 2) and opted-out
+                # batches reach here too: the overlapped plain pipeline IS
+                # the literal plain-decode path (bit-identical to serial),
+                # so the opt-out contract holds
+                # overlapped single-step decode (docs/performance.md):
+                # dispatch N+1 before harvesting N so the TPU never idles
+                # for the host's plan+unpack time. --no-overlap restores
+                # the serial loop.
+                return "decode_pipeline"
+        if (
+            kind == "prefill"
+            and self._multi_step_fn is not None
+            and self._overlap_ok()
+            and plan.prefill_batch
+            and all(w.is_last_chunk for w in plan.prefill_batch)
+        ):
+            # cohort graduation without the hard sync: the prefill
+            # dispatch's first tokens chain on device into the first
+            # decode window (_window_pipeline prefill-only entry) —
+            # multimodal/penalty/top-logprobs batches fall back to the
+            # dedicated serial prefill inside the pipeline
+            return "prefill_window"
+        if kind != "prefill" and not seqs:
+            return "none"
+        return "serial"
+
     def _one_step(self) -> None:
         sched = self.scheduler
         assert sched is not None
@@ -2702,14 +2795,14 @@ class JaxEngine:
         # models a straggling dispatch, an error exercises the
         # quarantine path, a kill is a worker death. No-op without a plan.
         faults.fire("engine.step")
-        t_plan = time.monotonic()
         # clear BEFORE plan(): a failure inside planning must not be
         # attributed to the previous step's (healthy) requests
         self._last_plan = None
-        with step_span("dyn.step.plan"):
+        with step_span("dyn.step.plan") as planning:
             plan = sched.plan()
-        self._last_plan = plan  # step-failure attribution (quarantine)
-        plan_ms = round((time.monotonic() - t_plan) * 1e3, 3)
+            self._last_plan = plan  # step-failure attribution (quarantine)
+            route = self._route(plan)
+        plan_ms = planning.ms
         # phase stamps from an earlier, never-recorded dispatch (e.g. a
         # dedicated prefill inside the window pipeline) must not leak
         # into this step's record
@@ -2721,38 +2814,24 @@ class JaxEngine:
                 sched.num_running / max(1, self.config.max_batch_size)
             )
             ENGINE_QUEUE_DEPTH.set(sched.num_waiting)
-        if plan.kind == "idle":
+        if route == "idle":
             # blocking sleep is deliberate: _one_step executes on the
             # dedicated "jax-engine" thread, never on the event loop
+            self.step_clock.note_idle()
             with step_span("dyn.step.wait"):
                 time.sleep(0.001)
             return
-        if plan.kind == "mixed":
-            if self._mixed_step_fn is not None:
-                t0 = time.monotonic()
-                self._window_pipeline(
-                    plan.prefill_batch, plan.decode_seqs, rect=plan.rect
-                )
-                ENGINE_STEP_SECONDS.labels("mixed").observe(
-                    time.monotonic() - t0
-                )
-                return
-            plan.kind = "prefill"  # no fused window: prefill this step
-        spec_fell_through = False
-        if (
-            plan.kind == "decode"
-            and self._drafter is not None
-            and not self.spec_suspended
-            and plan.decode_seqs
-            and not self._spec_divert(plan.decode_seqs)
-        ):
-            if self._overlap_ok() and not self._overlap_divert(
-                plan.decode_seqs
-            ):
-                # overlapped speculative decode (the tentpole of
-                # docs/speculative_decoding.md's pipelined section):
-                # host drafting for step N+1 runs WHILE the device
-                # verifies step N
+        if route == "mixed":
+            t0 = time.monotonic()
+            self._window_pipeline(
+                plan.prefill_batch, plan.decode_seqs, rect=plan.rect
+            )
+            ENGINE_STEP_SECONDS.labels("mixed").observe(
+                time.monotonic() - t0
+            )
+            return
+        if route in ("spec_pipeline", "spec"):
+            if route == "spec_pipeline":
                 ran = self._spec_pipeline(plan.decode_seqs, plan_ms=plan_ms)
             else:
                 ran = self._run_spec_step(plan.decode_seqs)
@@ -2769,44 +2848,18 @@ class JaxEngine:
             # serial step (not the plain pipeline, which would keep
             # speculation off for its whole drain) and retry drafting
             # at the next plan.
-            spec_fell_through = True
-        if (
-            plan.kind == "decode"
-            and self._multi_step_fn is None
-            and not spec_fell_through
-            and self._overlap_ok()
-            and plan.decode_seqs
-            and not self._overlap_divert(plan.decode_seqs)
-        ):
-            # spec-suspended (degradation rung 2) and opted-out batches
-            # reach here too: the overlapped plain pipeline IS the
-            # literal plain-decode path (bit-identical to serial), so
-            # the opt-out contract holds
-            # overlapped single-step decode (docs/performance.md):
-            # dispatch N+1 before harvesting N so the TPU never idles
-            # for the host's plan+unpack time. --no-overlap restores
-            # the serial loop below.
+            route = "serial"
+        if route == "decode_pipeline":
             self._decode_pipeline(plan.decode_seqs, plan_ms=plan_ms)
             return
-        if (
-            plan.kind == "prefill"
-            and self._multi_step_fn is not None
-            and self._overlap_ok()
-            and plan.prefill_batch
-            and all(w.is_last_chunk for w in plan.prefill_batch)
-        ):
-            # cohort graduation without the hard sync: the prefill
-            # dispatch's first tokens chain on device into the first
-            # decode window (_window_pipeline prefill-only entry) —
-            # multimodal/penalty/top-logprobs batches fall back to the
-            # dedicated serial prefill inside the pipeline
+        if route == "prefill_window":
             t0 = time.monotonic()
             self._window_pipeline(plan.prefill_batch, [])
             ENGINE_STEP_SECONDS.labels("prefill").observe(
                 time.monotonic() - t0
             )
             return
-        if plan.kind != "prefill" and not plan.decode_seqs:
+        if route == "none":
             return
         with step_span("dyn.step.pack"):
             if plan.kind == "prefill":
@@ -2949,64 +3002,67 @@ class JaxEngine:
         t_step = time.monotonic()
         draft_s = 0.0
         if proposals is None:
-            t_draft = time.monotonic()
-            proposals = []
-            for seq in seqs:
-                # budget leaves room for the verify step's guaranteed
-                # +1 token: drafts past it would be discarded by
-                # _emit_window anyway, but their KV writes would still
-                # need blocks the growth reserve never budgeted
-                budget = self._spec_budget(seq)
-                props = (
-                    self._draft_tokens(seq, budget)
-                    if self._seq_spec_enabled(seq)
-                    else []
-                )
-                if props and seq.guided_state is not None:
-                    # guided spec: proposals filter through the SAME
-                    # automaton the verify masks apply — a draft the
-                    # mask would reject can never be proposed, so the
-                    # accepted prefix is exactly what serial guided
-                    # decode would have committed
-                    props = seq.guided_state.filter_drafts(props)
-                proposals.append(props)
+            # drafting is this step's planning: dyn.step.plan
+            with step_span("dyn.step.plan") as drafting:
+                proposals = []
+                for seq in seqs:
+                    # budget leaves room for the verify step's guaranteed
+                    # +1 token: drafts past it would be discarded by
+                    # _emit_window anyway, but their KV writes would still
+                    # need blocks the growth reserve never budgeted
+                    budget = self._spec_budget(seq)
+                    props = (
+                        self._draft_tokens(seq, budget)
+                        if self._seq_spec_enabled(seq)
+                        else []
+                    )
+                    if props and seq.guided_state is not None:
+                        # guided spec: proposals filter through the SAME
+                        # automaton the verify masks apply — a draft the
+                        # mask would reject can never be proposed, so the
+                        # accepted prefix is exactly what serial guided
+                        # decode would have committed
+                        props = seq.guided_state.filter_drafts(props)
+                    proposals.append(props)
             # the draft-phase histogram covers PROPOSAL cost only (the
             # drafter-tuning signal) — staging/array/sampling prep
             # below is fixed per-step engine work, not drafter work
-            draft_s = time.monotonic() - t_draft
+            draft_s = drafting.last_ns / 1e9
             SPEC_STEP_SECONDS.labels("draft").observe(draft_s)
             self.spec_draft_exposed_s_total += draft_s
         if not any(proposals):
             return False  # nothing staged: caller runs plain decode
-        works: list[tuple] = []
-        staged = 0
-        for seq, drafts in zip(seqs, proposals):
-            # carry read BEFORE staging: reserve_spec_tokens appends the
-            # drafts to token state, after which last_token() is a draft
-            carry = seq.tokens.last_token()
-            k = sched.reserve_spec_tokens(seq, drafts) if drafts else 0
-            staged += k
-            works.append((seq, [carry] + drafts[:k]))
-        if staged == 0:
-            # block pressure shrank every row's kept drafts to zero:
-            # rows are bare [carry] tokens, nothing was appended to any
-            # sequence — bail to plain decode instead of paying the
-            # (K+1)x rectangle to emit 1 token per sequence
-            return False
-        arrays = sched.build_spec_arrays(works, S)
-        B = arrays["tokens"].shape[0]
-        sampling = self._batch_sampling(seqs, B)
-        gmask = self._guided_spec_masks(works, S, B)
-        if gmask is not None:
-            # [B, S, V] per-position masks: verify applies the identical
-            # transform the serial masked path would at each position
-            sampling.arrays["allow_mask"] = gmask
-        t0 = time.monotonic()
+        with step_span("dyn.step.pack"):
+            works: list[tuple] = []
+            staged = 0
+            for seq, drafts in zip(seqs, proposals):
+                # carry read BEFORE staging: reserve_spec_tokens appends
+                # the drafts to token state, after which last_token() is
+                # a draft
+                carry = seq.tokens.last_token()
+                k = sched.reserve_spec_tokens(seq, drafts) if drafts else 0
+                staged += k
+                works.append((seq, [carry] + drafts[:k]))
+            if staged == 0:
+                # block pressure shrank every row's kept drafts to zero:
+                # rows are bare [carry] tokens, nothing was appended to
+                # any sequence — bail to plain decode instead of paying
+                # the (K+1)x rectangle to emit 1 token per sequence
+                return False
+            arrays = sched.build_spec_arrays(works, S)
+            B = arrays["tokens"].shape[0]
+            sampling = self._batch_sampling(seqs, B)
+            gmask = self._guided_spec_masks(works, S, B)
+            if gmask is not None:
+                # [B, S, V] per-position masks: verify applies the
+                # identical transform the serial masked path would at
+                # each position
+                sampling.arrays["allow_mask"] = gmask
         try:
             packed = self._dispatch_spec_step(arrays, sampling)
             # _harvest_spec_step is the spec path's designated harvest
             # point (DL010): the device->host sync happens inside it
-            toks, lps, n_emit, _ = self._harvest_spec_step(packed, S)
+            toks, lps, n_emit, sync_s = self._harvest_spec_step(packed, S)
         except Exception:
             # host token state must not keep staged (unverified) drafts
             # when the step dies — the quarantine retry would otherwise
@@ -3015,37 +3071,42 @@ class JaxEngine:
                 if len(row) > 1:
                     seq.tokens.unwind(len(row) - 1)
             raise
-        verify_s = time.monotonic() - t0
+        # the verify wall = its dispatch phase + its harvest phase
+        verify_s = self._last_phases["dispatch_ms"] / 1e3 + sync_s
         SPEC_STEP_SECONDS.labels("verify").observe(verify_s)
         proposed = sum(len(row) - 1 for _, row in works)
         accepted = int(sum(n_emit[i] - 1 for i in range(len(works))))
-        self._record_step(
-            "spec", draft_s + verify_s,
-            batch=len(works),
-            tokens=len(works) + accepted,  # accepted prefix + 1 per row
-            use_phases=False,  # draft/verify ms below ARE the phases
-            draft_ms=round(draft_s * 1e3, 3),
-            verify_ms=round(verify_s * 1e3, 3),
-            spec_proposed=proposed,
-            spec_accepted=accepted,
-        )
-        if proposed:
-            SPEC_PROPOSED_TOKENS.labels(self._drafter.kind).inc(proposed)
-            if accepted:
-                SPEC_ACCEPTED_TOKENS.labels(self._drafter.kind).inc(accepted)
-            SPEC_ACCEPT_RATE.set(accepted / proposed)
-            self.spec_proposed_total += proposed
-            self.spec_accepted_total += accepted
-        for i, (seq, row) in enumerate(works):
-            if len(row) > 1:
-                seq.tokens.unwind(len(row) - 1)  # rejected AND accepted
-                # drafts: the accepted prefix re-appends through
-                # append_token below so commits/penalty counts take the
-                # normal path
-            if seq.state != SeqState.RUNNING:
-                continue
-            n = int(n_emit[i])
-            self._emit_window(seq, toks[i, :n], lps[i, :n])
+        with step_span("dyn.step.record"):
+            self._record_step(
+                "spec", draft_s + verify_s,
+                batch=len(works),
+                tokens=len(works) + accepted,  # accepted prefix + 1 per row
+                use_phases=False,  # draft/verify ms below ARE the phases
+                draft_ms=round(draft_s * 1e3, 3),
+                verify_ms=round(verify_s * 1e3, 3),
+                spec_proposed=proposed,
+                spec_accepted=accepted,
+            )
+            if proposed:
+                SPEC_PROPOSED_TOKENS.labels(self._drafter.kind).inc(proposed)
+                if accepted:
+                    SPEC_ACCEPTED_TOKENS.labels(self._drafter.kind).inc(
+                        accepted
+                    )
+                SPEC_ACCEPT_RATE.set(accepted / proposed)
+                self.spec_proposed_total += proposed
+                self.spec_accepted_total += accepted
+        with step_span("dyn.step.emit"):
+            for i, (seq, row) in enumerate(works):
+                if len(row) > 1:
+                    seq.tokens.unwind(len(row) - 1)  # rejected AND accepted
+                    # drafts: the accepted prefix re-appends through
+                    # append_token below so commits/penalty counts take
+                    # the normal path
+                if seq.state != SeqState.RUNNING:
+                    continue
+                n = int(n_emit[i])
+                self._emit_window(seq, toks[i, :n], lps[i, :n])
         ENGINE_STEP_SECONDS.labels("spec").observe(time.monotonic() - t_step)
         return True
 
@@ -3118,18 +3179,19 @@ class JaxEngine:
         between the two, the host is free to emit the previous step and
         pre-draft the next one while the device verifies this one."""
         assert self._spec_step_fn is not None
-        arrays, sampling = self._stage_step_inputs(arrays, sampling)
-        idle_gap_s = self.overlap.note_dispatch()
-        t0 = time.monotonic()
-        packed, self.k_cache, self.v_cache = self._spec_step_fn(
-            self.params, self.k_cache, self.v_cache,
-            arrays["tokens"] if tokens_dev is None else tokens_dev,
-            arrays["positions"], arrays["slot_mapping"],
-            arrays["block_tables"], arrays["context_lens"],
-            arrays["draft_lens"], sampling.arrays,
-        )
+        with self._dispatch_span("spec", arrays["tokens"]) as dispatch:
+            arrays, sampling = self._stage_step_inputs(arrays, sampling)
+            idle_gap_s = self.overlap.note_dispatch()
+            packed, self.k_cache, self.v_cache = self._spec_step_fn(
+                self.params, self.k_cache, self.v_cache,
+                arrays["tokens"] if tokens_dev is None else tokens_dev,
+                arrays["positions"], arrays["slot_mapping"],
+                arrays["block_tables"], arrays["context_lens"],
+                arrays["draft_lens"], sampling.arrays,
+            )
+        self._newest_out = packed
         self._last_phases = {
-            "dispatch_ms": round((time.monotonic() - t0) * 1e3, 3),
+            "dispatch_ms": dispatch.ms,
             "idle_gap_ms": round(idle_gap_s * 1e3, 3),
         }
         self._unsynced_steps.append("spec-verify")
@@ -3142,13 +3204,13 @@ class JaxEngine:
         Returns (toks, lps, n_emit, sync_s)."""
         from dynamo_tpu.spec.verify import harvest_spec_output
 
-        t0 = time.monotonic()
-        toks, lps, n_emit = harvest_spec_output(packed, S)
+        with step_span("dyn.step.harvest") as harvest:
+            toks, lps, n_emit = harvest_spec_output(packed, S)
         self.overlap.note_complete(all_prior=True)
         # successful host sync: earlier async dispatches are known-good
         # (in-order execution) — retire deferred-error forensics
         self._unsynced_steps.clear()
-        return toks, lps, n_emit, time.monotonic() - t0
+        return toks, lps, n_emit, harvest.last_ns / 1e9
 
     @staticmethod
     def _seq_dead(seq: Sequence) -> bool:
@@ -3196,9 +3258,10 @@ class JaxEngine:
         ``plan_pipelined_spec`` result; returns the pipeline entry."""
         works = nxt["works"]
         B = nxt["arrays"]["context_lens"].shape[0]
-        sampling = self._batch_sampling(
-            [s for s, _ in works], B, offset=nxt["offsets"]
-        )
+        with step_span("dyn.step.pack"):
+            sampling = self._batch_sampling(
+                [s for s, _ in works], B, offset=nxt["offsets"]
+            )
         packed = self._dispatch_spec_step(
             nxt["arrays"], sampling, tokens_dev=tokens_dev
         )
@@ -3338,23 +3401,24 @@ class JaxEngine:
         sched = self.scheduler
         assert sched is not None and self._chain_spec_fn is not None
         S = self.config.spec_tokens + 1
-        # first step: serial-style (exposed) draft over clean state
-        t0 = time.monotonic()
-        entries = []
-        for seq in seqs:
-            drafts = (
-                self._draft_tokens(seq, self._spec_budget(seq))
-                if self._seq_spec_enabled(seq)
-                else []
-            )
-            entries.append((seq, 0, drafts))
-        draft_s = time.monotonic() - t0
+        # first step: serial-style (exposed) draft over clean state;
+        # drafting is planning (dyn.step.plan)
+        with step_span("dyn.step.plan") as drafting:
+            entries = []
+            for seq in seqs:
+                drafts = (
+                    self._draft_tokens(seq, self._spec_budget(seq))
+                    if self._seq_spec_enabled(seq)
+                    else []
+                )
+                entries.append((seq, 0, drafts))
+        draft_s = drafting.last_ns / 1e9
         SPEC_STEP_SECONDS.labels("draft").observe(draft_s)
         if not any(d for _, _, d in entries):
             return False  # nothing to verify: caller runs plain decode
         self.spec_draft_exposed_s_total += draft_s
-        t_plan = time.monotonic()
-        nxt = sched.plan_pipelined_spec(entries, S)
+        with step_span("dyn.step.plan") as planning:
+            nxt = sched.plan_pipelined_spec(entries, S)
         if nxt is None:
             # block pressure or another irregularity at entry: the
             # serial spec step handles it (reserve_spec_tokens shrinks
@@ -3369,12 +3433,15 @@ class JaxEngine:
             return False  # clamping dropped every draft: plain step
         # first step chains from nothing: host carry column (the
         # prewarmed serial signature)
-        arrays = nxt["arrays"]
-        for i, (seq, _) in enumerate(nxt["works"]):
-            arrays["tokens"][i, 0] = seq.tokens.last_token()
+        with step_span("dyn.step.pack") as packing:
+            arrays = nxt["arrays"]
+            for i, (seq, _) in enumerate(nxt["works"]):
+                arrays["tokens"][i, 0] = seq.tokens.last_token()
         entry = self._dispatch_spec_entry(
             nxt,
-            plan_ms=plan_ms + round((time.monotonic() - t_plan) * 1e3, 3),
+            plan_ms=plan_ms + round(
+                (planning.last_ns + packing.last_ns) / 1e6, 3
+            ),
             draft_ms=round(draft_s * 1e3, 3),
             tokens_dev=None,
         )
@@ -3386,12 +3453,13 @@ class JaxEngine:
             # recomputes the abandoned in-flight verify bit-identically
             faults.fire("engine.step")
             # ---- device busy: hide the next step's drafting ----
-            t0 = time.monotonic()
-            pres = self._spec_predraft(entry["works"])
-            predraft_s = time.monotonic() - t0
+            with step_span("dyn.step.plan") as predrafting:
+                pres = self._spec_predraft(entry["works"])
+            predraft_s = predrafting.last_ns / 1e9
             SPEC_STEP_SECONDS.labels("predraft").observe(predraft_s)
             self.spec_draft_hidden_s_total += predraft_s
-            self._drain_incoming_only()
+            with step_span("dyn.step.plan"):
+                self._drain_incoming_only()
             # ---- harvest step N (the designated sync) ----
             toks, lps, n_emit, sync_s = self._harvest_spec_step(
                 entry["packed"], S
@@ -3403,47 +3471,54 @@ class JaxEngine:
             # matters: draft_hidden_frac compares hidden vs exposed
             # *drafting* only — folding constant per-step plan time
             # into it would understate the hiding at high hit rates.
-            t_rep = time.monotonic()
-            entries = []
-            for i, (seq, drafts) in enumerate(entry["works"]):
-                n = int(n_emit[i])
-                emitted = [int(t) for t in toks[i, :n]]
-                pre = pres[i]
-                if (
-                    pre is not None
-                    and n == len(drafts) + 1
-                    and emitted
-                    and emitted[-1] == pre[0]
-                ):
-                    nxt_drafts = pre[1]
-                    self.spec_predraft_hits += 1
-                else:
-                    # realized tail diverged from the optimistic one:
-                    # re-draft from the actual tail so the proposal
-                    # stream stays byte-identical to serial spec
-                    nxt_drafts = self._draft_tokens(
-                        seq, self._spec_budget(seq, n), suffix=emitted
-                    )
-                    self.spec_predraft_misses += 1
-                entries.append((seq, n, nxt_drafts))
-            repair_s = time.monotonic() - t_rep
+            with step_span("dyn.step.plan") as repairing:
+                entries = []
+                for i, (seq, drafts) in enumerate(entry["works"]):
+                    n = int(n_emit[i])
+                    emitted = [int(t) for t in toks[i, :n]]
+                    pre = pres[i]
+                    if (
+                        pre is not None
+                        and n == len(drafts) + 1
+                        and emitted
+                        and emitted[-1] == pre[0]
+                    ):
+                        nxt_drafts = pre[1]
+                        self.spec_predraft_hits += 1
+                    else:
+                        # realized tail diverged from the optimistic
+                        # one: re-draft from the actual tail so the
+                        # proposal stream stays byte-identical to serial
+                        # spec
+                        nxt_drafts = self._draft_tokens(
+                            seq, self._spec_budget(seq, n), suffix=emitted
+                        )
+                        self.spec_predraft_misses += 1
+                    entries.append((seq, n, nxt_drafts))
+            # read at once: the next plan phase is the same object
+            exposed_ns = repairing.last_ns
+            repair_s = exposed_ns / 1e9
             SPEC_STEP_SECONDS.labels("draft").observe(repair_s)
             self.spec_draft_exposed_s_total += repair_s
-            flush = (
-                sched.admission_work()
-                or not self._running
-                # a drain must reach the serial loop's migrate sweep:
-                # the pipeline would otherwise hold its streams until
-                # they finish naturally, riding out the whole deadline
-                or self._draining
-                or not self._control.empty()
-                # degradation rung 2 (planner/degradation.py) flips
-                # spec_suspended from the loop thread: the serial loop
-                # honors it every plan, so the pipeline must not keep
-                # paying the verify rectangle for a whole batch drain
-                or self.spec_suspended
-            )
-            nxt = None if flush else sched.plan_pipelined_spec(entries, S)
+            with step_span("dyn.step.plan") as planning:
+                flush = (
+                    sched.admission_work()
+                    or not self._running
+                    # a drain must reach the serial loop's migrate
+                    # sweep: the pipeline would otherwise hold its
+                    # streams until they finish naturally, riding out
+                    # the whole deadline
+                    or self._draining
+                    or not self._control.empty()
+                    # degradation rung 2 (planner/degradation.py) flips
+                    # spec_suspended from the loop thread: the serial
+                    # loop honors it every plan, so the pipeline must
+                    # not keep paying the verify rectangle for a whole
+                    # batch drain
+                    or self.spec_suspended
+                )
+                nxt = None if flush else sched.plan_pipelined_spec(entries, S)
+            exposed_ns += planning.last_ns
             if nxt is not None and not any(d for _, d in nxt["works"]):
                 # zero proposals across the batch: the [B, S] rectangle
                 # would pay (K+1)x the work for 1 token/row — flush and
@@ -3452,9 +3527,11 @@ class JaxEngine:
                 nxt = None
             next_entry = None
             if nxt is not None:
-                tokens_dev = self._chain_spec_fn(
-                    entry["packed"], nxt["arrays"]["tokens"], nxt["src_idx"]
-                )
+                with step_span("dyn.step.pack") as chaining:
+                    tokens_dev = self._chain_spec_fn(
+                        entry["packed"], nxt["arrays"]["tokens"],
+                        nxt["src_idx"],
+                    )
                 # the attribution ledger's plan_ms carries the WHOLE
                 # exposed host span (repair + plan + chain): its
                 # overlapped branch bills the measured idle gap to plan
@@ -3462,13 +3539,15 @@ class JaxEngine:
                 # land ("exposed draft stays plan")
                 next_entry = self._dispatch_spec_entry(
                     nxt,
-                    plan_ms=round((time.monotonic() - t_rep) * 1e3, 3),
+                    plan_ms=round((exposed_ns + chaining.last_ns) / 1e6, 3),
                     draft_ms=round(repair_s * 1e3, 3),
                     tokens_dev=tokens_dev,
                 )
             # ---- emit step N under N+1's device time ----
-            late_stop = self._emit_spec_entry(entry, toks, lps, n_emit)
-            self._finish_spec_record(entry, sync_s)
+            with step_span("dyn.step.emit"):
+                late_stop = self._emit_spec_entry(entry, toks, lps, n_emit)
+            with step_span("dyn.step.record"):
+                self._finish_spec_record(entry, sync_s)
             if next_entry is None:
                 return True
             if late_stop:
@@ -3479,8 +3558,10 @@ class JaxEngine:
                 toks, lps, n_emit, sync_s = self._harvest_spec_step(
                     next_entry["packed"], S
                 )
-                self._emit_spec_entry(next_entry, toks, lps, n_emit)
-                self._finish_spec_record(next_entry, sync_s)
+                with step_span("dyn.step.emit"):
+                    self._emit_spec_entry(next_entry, toks, lps, n_emit)
+                with step_span("dyn.step.record"):
+                    self._finish_spec_record(next_entry, sync_s)
                 return True
             entry = next_entry
 
@@ -3569,11 +3650,12 @@ class JaxEngine:
         def dispatch(seqs_, arrays, sampling, p_ms: float) -> dict:
             t0 = time.monotonic()
             self._decode_dispatches[0] += 1
-            with self._dispatch_span("decode", arrays["tokens"]):
+            with self._dispatch_span("decode", arrays["tokens"]) as phase:
                 outs = self._dispatch_device_step(
                     arrays, sampling, origin="decode-pipeline"
                 )
                 packed = self._pack_pair_fn(outs[0], outs[1])
+            self._last_phases["dispatch_ms"] = phase.ms
             return {
                 "packed": packed,
                 "toks": outs[0],  # device column the next step chains off
@@ -3597,15 +3679,14 @@ class JaxEngine:
             # step bit-identically (KV slots rewritten with same values)
             faults.fire("engine.step")
             newest = pending[-1]
-            with step_span("dyn.step.plan"):
+            with step_span("dyn.step.plan") as planning:
                 self._drain_incoming_only()
                 if sched.admission_work():
                     return False  # drain: the serial planner admits/prefills
-                t_plan = time.monotonic()
                 nxt = sched.plan_pipelined_decode(newest["seqs"], lag)
             if nxt is None:
                 return False
-            with step_span("dyn.step.pack"):
+            with step_span("dyn.step.pack") as packing:
                 arrays = nxt["arrays"]
                 arrays["tokens"] = self._chain_next_fn(
                     newest["toks"], nxt["src_idx"]
@@ -3615,9 +3696,10 @@ class JaxEngine:
                     arrays["context_lens"].shape[0],
                     offset=nxt["offsets"],
                 )
+            # the exposed host span before this dispatch: plan + pack
             e = dispatch(
                 nxt["seqs"], arrays, sampling,
-                round((time.monotonic() - t_plan) * 1e3, 3),
+                round((planning.last_ns + packing.last_ns) / 1e6, 3),
             )
             self._decode_dispatches[1] += 1
             _lag_add(lag, e)
@@ -3626,11 +3708,11 @@ class JaxEngine:
 
         def harvest(e, depth: int) -> bool:
             t0 = time.monotonic()
-            with step_span("dyn.step.harvest"):
+            with step_span("dyn.step.harvest") as harvesting:
                 packed_h = host_value(e["packed"])
             self.overlap.note_complete()
             self._unsynced_steps.clear()
-            sync_ms = round((time.monotonic() - t0) * 1e3, 3)
+            sync_ms = harvesting.ms
             B = e["b"]
             finished = False
             with step_span("dyn.step.emit"):
@@ -3650,6 +3732,9 @@ class JaxEngine:
                     if seq.state != SeqState.RUNNING:
                         finished = True
                 _lag_sub(lag, e)
+                # the step's device outputs are freed HERE, under a phase
+                # (tens of microseconds a step), not when this frame ends
+                e["packed"] = e["toks"] = None
             dt = time.monotonic() - e["t_disp"]
             with step_span("dyn.step.record"):
                 ENGINE_STEP_SECONDS.labels("decode").observe(dt)
@@ -3843,6 +3928,7 @@ class JaxEngine:
             arrays["valid_steps"],
             sampling.arrays,
         )
+        self._newest_out = last_tok
         return packed, last_tok
 
     @staticmethod
@@ -3957,6 +4043,7 @@ class JaxEngine:
                 sampling_d.arrays,
             )
         )
+        self._newest_out = last_tok
         return flat, last_tok, p_next, B_d, P
 
     def _emit_mixed(
@@ -4198,11 +4285,11 @@ class JaxEngine:
 
         def harvest_entry(e) -> None:
             t0 = time.monotonic()
-            with step_span("dyn.step.harvest"):
+            with step_span("dyn.step.harvest") as harvesting:
                 host = host_value(
                     e["packed"] if e["kind"] == "prefill" else e["flat"]
                 )
-            with step_span("dyn.step.emit"):
+            with step_span("dyn.step.emit") as emitting:
                 if e["kind"] == "prefill":
                     # cohort-graduation entry: one packed [2P] transfer
                     # carrying first tokens + logprobs; the decode window
@@ -4228,13 +4315,17 @@ class JaxEngine:
                         self._emit_window(
                             seq, win[0][i], win[1][i], tops=tops
                         )
+                # the window's device outputs are freed HERE, under a
+                # phase, not when this frame ends
+                for key in ("flat", "last", "p_next", "packed"):
+                    e.pop(key, None)
             self.overlap.note_complete()
             # window sync succeeded: earlier async dispatches are
             # known-good (in-order execution) — retire deferred-error
             # forensics
             self._unsynced_steps.clear()
             _lag_sub(lag, e)
-            win_s = time.monotonic() - t0
+            win_s = (harvesting.last_ns + emitting.last_ns) / 1e9
             # one flight-recorder entry per WINDOW (the serving-path
             # unit of work): duration is the host-side sync+emit wait —
             # the dispatch overlapped earlier windows by design
@@ -4951,9 +5042,20 @@ class JaxEngine:
 
     def program_counts(self) -> dict:
         """Cumulative counts a profiler capture reads at its two edges
-        (telemetry/debug.py ``program_spans.json``)."""
+        (telemetry/debug.py ``program_spans.json``): the host's, and the
+        family's on-device ones."""
+        out = self._host_counts()
+        if self.scheduler is not None and self.scheduler.state_slots is not None:
+            out.update(self._read_family_counts())
+        return out
+
+    def _host_counts(self) -> dict:
+        """The cumulative counts the host keeps: plain ints, no device
+        read and no lock, so the engine thread can note them between two
+        steps (the count history) as well as a capture's edges can."""
         sched = self.scheduler
         out: dict = {"steps": dict(self._steps_dispatched)}
+        out.update(self.step_clock.counts())
         if sched is not None:
             out.update(
                 prompt_tokens=sched.prompt_tokens_admitted,
@@ -4968,9 +5070,14 @@ class JaxEngine:
                 out.update(
                     state_slot_steps_used=self._state_slot_steps[0],
                     state_slot_steps_total=self._state_slot_steps[1],
-                    **self._read_family_counts(),
                 )
         return out
+
+    def _note_counts(self, now_ns: int) -> None:
+        """The step clock's once-a-second tick (engine thread, at the end
+        of a ``record`` or ``wait`` phase): one entry of the count
+        history that ``program_spans.json`` carries."""
+        note_counts(self._debug_name or "engine", self._host_counts(), now_ns)
 
     def _read_family_counts(self) -> dict:
         """The counts a family keeps ON THE DEVICE in its state pytree
@@ -5034,6 +5141,9 @@ class JaxEngine:
             # graceful drain flag ("top" renders the DRAIN state from
             # this; absent on older builds → the '-' rule)
             "draining": self._draining,
+            # the step loop's phases and what else its one clock counts,
+            # cumulative (docs/observability.md "Step phases")
+            **self.step_clock.counts(),
         }
         if sched is not None:
             def req_row(seq) -> dict:

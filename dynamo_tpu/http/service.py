@@ -64,6 +64,7 @@ from dynamo_tpu.telemetry.instruments import (
     HTTP_REQUESTS,
     HTTP_TTFT,
 )
+from dynamo_tpu.telemetry.spans import note_loop_thread
 
 log = logging.getLogger("dynamo_tpu.http")
 
@@ -193,6 +194,9 @@ class HttpService:
         # provider stanza (lag window, task census, ledger rollup)
         self.lag_monitor.start()
         register_hostplane_provider("frontend", self._hostplane_stanza)
+        # THIS loop's thread serialises every token of every stream: an
+        # engine in the process counts its CPU beside its own thread's
+        note_loop_thread()
         log.info("OpenAI HTTP service on %s:%d", self.host, self.port)
 
     async def stop(self) -> None:

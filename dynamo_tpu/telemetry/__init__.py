@@ -9,7 +9,9 @@ Four pieces (docs/observability.md is the operator-facing guide):
   Perfetto/chrome://tracing flame graphs, export.py) and the in-memory
   buffer of every serving process, written beside a profiler capture
   (``program_spans.json``, debug.py). ``step_span`` marks the engine's
-  step phases on the profiler's own clock.
+  step phases: counted always by the step loop's ``StepClock`` (wall,
+  thread CPU, calls; periods and dispatches by kind), annotated on the
+  profiler's own clock inside a capture.
 - **Metrics** (metrics.py): one process registry of labeled counters/
   gauges/histograms with Prometheus text exposition and cardinality
   guard rails; the serving stack's catalog lives in instruments.py.

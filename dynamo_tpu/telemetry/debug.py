@@ -21,12 +21,17 @@ during which the event loop must keep serving.
 
 Beside each trace a capture writes ``program_spans.json``: the request
 spans the process holds in memory (telemetry/spans.py ``SpanBuffer``),
-the two clocks at ``start_trace``'s return and ``stop_trace``'s call, and
-the program's cumulative counts at those two instants (``program counts``
-providers: the engine's steps dispatched by kind, prompt tokens admitted
-and served from cache, preemptions). A process that took a capture
-writes the file again when it exits cleanly, so the copy a reader finds
-after shutdown covers every request the process finished.
+the two clocks at ``start_trace``'s return and ``stop_trace``'s call
+(``start`` / ``stop``), the program's cumulative counts at those two
+instants (``program counts`` providers: the engine's steps dispatched by
+kind, prompt tokens admitted and served from cache, preemptions), the two
+clocks again at ``stop_trace``'s RETURN (``end``: the seconds it spends
+serialising the trace are not untraced time), and ``history``: the
+host-side counts a step loop noted once a second since the process began
+(``note_counts``; the newest ``HISTORY_LEN`` entries), capture or no
+capture. A process that took a capture writes the file again when it
+exits cleanly, so the copy a reader finds after shutdown covers every
+request the process finished and every second it served.
 """
 
 from __future__ import annotations
@@ -39,6 +44,7 @@ import os
 import tempfile
 import threading
 import time
+from collections import deque
 from typing import Callable, Optional
 
 from dynamo_tpu.telemetry import spans
@@ -117,6 +123,15 @@ _last_capture: Optional[dict] = None
 MAX_PROFILE_MS = 30_000
 PROGRAM_SPANS_FILE = "program_spans.json"
 
+# the count history: ``{"monotonic_ns", "counts": {provider: counts}}``,
+# one entry a second a step loop (17 minutes of one engine). Appended by
+# the loop's own thread and copied by whoever writes the span file: a
+# deque's append and its copy are each one step under the interpreter
+# lock, so the history takes no lock of its own and none the event loop
+# takes.
+HISTORY_LEN = 1024
+_history: deque = deque(maxlen=HISTORY_LEN)
+
 
 def register_debug_provider(name: str, fn: Callable[[], dict]) -> None:
     _DEBUG_PROVIDERS.register(name, fn)
@@ -146,6 +161,17 @@ def unregister_count_provider(
     _COUNT_PROVIDERS.unregister(name, fn)
 
 
+def note_counts(name: str, counts: dict, monotonic_ns: int) -> None:
+    """One entry of the count history: ``counts`` are HOST-side numbers
+    only (the caller is a step loop between two steps: no device read,
+    no lock)."""
+    _history.append({"monotonic_ns": monotonic_ns, "counts": {name: counts}})
+
+
+def count_history() -> list[dict]:
+    return list(_history)
+
+
 def _edge() -> dict:
     """Both clocks and the program's cumulative counts, now."""
     return {"monotonic_ns": time.monotonic_ns(), "time_ns": time.time_ns(),
@@ -162,7 +188,8 @@ def write_program_spans(written: str) -> Optional[str]:
     buffer = spans.get_tracer().buffer
     kept, dropped = buffer.snapshot() if buffer is not None else ([], 0)
     doc = {"written": written, "pid": os.getpid(), "spans": kept,
-           "dropped": dropped, "start": cap["start"], "stop": cap["stop"]}
+           "dropped": dropped, "start": cap["start"], "stop": cap["stop"],
+           "end": cap["end"], "history": count_history()}
     tmp = cap["path"] + ".tmp"
     with open(tmp, "w") as f:
         json.dump(doc, f, default=str)
@@ -204,10 +231,11 @@ def profile_blocking(ms: int, out_dir: str = "") -> dict:
             spans.set_capture_live(False)
             stopped = _edge()
             jax.profiler.stop_trace()
+        ended = {"monotonic_ns": time.monotonic_ns(), "time_ns": time.time_ns()}
         if _last_capture is None:
             atexit.register(_write_program_spans_at_exit)
         _last_capture = {"path": os.path.join(d, PROGRAM_SPANS_FILE),
-                         "start": started, "stop": stopped}
+                         "start": started, "stop": stopped, "end": ended}
         write_program_spans("capture_end")
         log.info("profiler capture (%d ms) -> %s", ms, d)
         return {"trace_dir": d, "duration_ms": ms}

@@ -31,8 +31,9 @@ monotonic stamps, passed as they are).
 Sinks, all behind ``Tracer._export``: the JSONL file (``DYN_TRACE_FILE``)
 and the in-memory ``SpanBuffer`` every serving process keeps
 (``Tracer.keep_in_memory``; cli ``run``), which a profiler capture writes
-beside its trace. Per-STEP phases are not spans of this stream: they go
-to the profiler only, through ``step_span`` (bottom of this file).
+beside its trace. Per-STEP phases are not spans of this stream: they are
+counted always and annotated inside a capture, through ``step_span``
+(bottom of this file).
 
 Env knobs:
   DYN_TRACE_FILE    append finished spans as JSONL here (enables tracing)
@@ -43,7 +44,6 @@ Env knobs:
 
 from __future__ import annotations
 
-import contextlib
 import json
 import logging
 import os
@@ -427,17 +427,30 @@ def propagation_context(span: Any, inbound: Any = None) -> Optional[dict]:
     return None
 
 
-# -- step phases on the profiler's clock ------------------------------------
+# -- step phases: counted always, annotated inside a capture ------------------
 # The engine's step loops mark their phases (``dyn.step.plan`` / ``pack`` /
 # ``dispatch`` / ``harvest`` / ``emit`` / ``record`` / ``wait``) with
 # ``step_span``. A phase is per STEP, not per request, so it never enters
-# the span stream above: it is a ``jax.profiler.TraceAnnotation`` and
-# lands in a capture's ``.xplane.pb`` on the thread that made it, on the
-# clock of the device's own lines. telemetry/debug.py turns the switch
-# around each capture; with no capture live a phase costs one global read.
-_NO_PHASE = contextlib.nullcontext()
+# the span stream above. It is clocked ALWAYS: the thread's ``StepClock``
+# keeps one preallocated ``StepPhase`` a name, which reads the monotonic
+# clock on entry and exit and the thread's CPU clock on exit and adds to
+# its cumulative ``wall_ns`` / ``cpu_ns`` / ``calls`` (phases do not nest,
+# so nothing is allocated a step). Inside a capture a phase ALSO enters a
+# ``jax.profiler.TraceAnnotation`` of its name and attributes, which lands
+# in the capture's ``.xplane.pb`` on the thread that made it, on the clock
+# of the device's own lines; telemetry/debug.py turns that switch around
+# each capture. docs/observability.md "Step phases".
+PHASE_PREFIX = "dyn.step."
+PHASES = ("plan", "pack", "dispatch", "harvest", "emit", "record", "wait")
+# the phases in which the engine thread WORKS (``harvest`` waits for the
+# device, ``wait`` for a request): their wall less their thread CPU is
+# time the thread stood there without running
+HOST_WORK = ("plan", "pack", "dispatch", "emit", "record")
+HISTORY_TICK_NS = 1_000_000_000
 _capture_live = False
 _annotation: Any = None
+_monotonic_ns = time.monotonic_ns
+_thread_time_ns = time.thread_time_ns
 
 
 def set_capture_live(live: bool) -> None:
@@ -452,9 +465,254 @@ def set_capture_live(live: bool) -> None:
     _capture_live = live
 
 
-def step_span(name: str, **attrs: Any):
-    """Context manager for one phase of an engine step; ``attrs`` are
-    scalars shown beside the event in the trace viewer."""
-    if not _capture_live:
-        return _NO_PHASE
-    return _annotation(name, **attrs)
+class StepPhase:
+    """One phase of a step loop, entered with ``with``; owned by one
+    thread's ``StepClock``. ``last_ns`` is the wall of the newest pass:
+    the flight recorder's ``plan_ms`` / ``dispatch_ms`` / ``sync_ms`` are
+    read from it, so a step has ONE clocking."""
+
+    __slots__ = ("name", "attrs", "wall_ns", "cpu_ns", "calls", "last_ns",
+                 "_clock", "_t0", "_ann")
+
+    def __init__(self, clock: "StepClock", name: str):
+        self._clock = clock
+        self.name = name
+        self.attrs: dict = {}
+        self.wall_ns = self.cpu_ns = self.calls = self.last_ns = 0
+        self._t0 = 0
+        self._ann: Any = None
+
+    @property
+    def ms(self) -> float:
+        """The newest pass, as the flight recorder spells a duration."""
+        return round(self.last_ns / 1e6, 3)
+
+    def __enter__(self) -> "StepPhase":
+        if _capture_live:
+            self._ann = _annotation(self.name, **self.attrs)
+            self._ann.__enter__()
+        self._t0 = _monotonic_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        # ONE read of the thread's CPU clock a pass (a syscall: 0.3 us on
+        # a bare kernel, 6 us where syscalls are sandboxed): the phases
+        # tile the loop, so this pass's CPU is the clock's growth since
+        # the pass before ended (what ran between the two, under no phase,
+        # is charged here: unphased_ns says how little that is)
+        clock = self._clock
+        cpu = _thread_time_ns()
+        self.cpu_ns += cpu - clock._cpu_mark
+        clock._cpu_mark = cpu
+        self.last_ns = _monotonic_ns() - self._t0
+        self.wall_ns += self.last_ns
+        self.calls += 1
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(exc_type, exc, tb)
+
+
+class _DispatchPhase(StepPhase):
+    """``dispatch`` also counts by ``kind`` and closes the period of the
+    dispatch before it. ``drained`` is what the device answered just
+    before this one (set by the engine, read once)."""
+
+    __slots__ = ("drained",)
+
+    def __init__(self, clock: "StepClock", name: str):
+        super().__init__(clock, name)
+        self.drained = False
+
+    def __enter__(self) -> "StepPhase":
+        StepPhase.__enter__(self)
+        self._clock._note_dispatch(
+            self.attrs.get("kind") or "step", self._t0, self.drained)
+        self.drained = False
+        return self
+
+
+class _TickPhase(StepPhase):
+    """``record`` and ``wait`` end with the once-a-second history tick."""
+
+    __slots__ = ()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        StepPhase.__exit__(self, exc_type, exc, tb)
+        now = self._t0 + self.last_ns
+        if now - self._clock.ticked_ns >= HISTORY_TICK_NS:
+            self._clock.tick(now)
+
+
+class StepClock:
+    """What one step-loop thread counts, cumulatively and always:
+
+    - ``phases[name]``: ``wall_ns`` / ``cpu_ns`` / ``calls`` of every
+      ``dyn.step.*`` phase;
+    - ``loop_wall_ns``: the loop's wall up to the newest ``lap()`` or
+      tick; ``unphased_ns`` = that less every phase's wall AT THAT
+      INSTANT = what no phase covered (the tiling as a number; a
+      pipeline stays inside one lap for many steps, so between ticks
+      the pair is up to a second old, and always consistent);
+    - ``dispatches[kind]``, and ``period_ns[kind]``: at each dispatch the
+      wall since the one before it, less the ``wait`` between them, added
+      under the EARLIER one's kind;
+    - ``dispatches_device_drained``: dispatches issued to a device whose
+      queue had run dry (not the first after ``note_idle()``: no work is
+      not starvation);
+    - the thread's, the event loop's and the process's CPU clocks as of
+      the newest tick.
+
+    Written by its own thread alone (plain ints: a reader on another
+    thread sees values a step apart, never torn ones); ``on_tick`` is
+    called on that thread once a second from ``record`` / ``wait``."""
+
+    def __init__(self) -> None:
+        self.phases: dict[str, StepPhase] = {}
+        for name in PHASES:
+            kind = (_DispatchPhase if name == "dispatch" else
+                    _TickPhase if name in ("record", "wait") else StepPhase)
+            self.phases[name] = kind(self, PHASE_PREFIX + name)
+        self._by_name = {p.name: p for p in self.phases.values()}
+        self._wait = self.phases["wait"]
+        self.loop_wall_ns = 0
+        self.unphased_ns = 0
+        self._lap_ns = 0
+        self.dispatches: dict[str, int] = {}
+        self.period_ns: dict[str, int] = {}
+        self.dispatches_device_drained = 0
+        self._last_kind: Optional[str] = None
+        self._last_dispatch_ns = 0
+        self._wait_ns_then = 0
+        self._idled = False
+        # the owning thread's CPU clock when its newest phase ended
+        # (bind_step_clock / step_clock set it on that thread)
+        self._cpu_mark = 0
+        self.cpu_ns: dict[str, int] = {}
+        self.ticked_ns = 0
+        self.on_tick: Optional[Any] = None
+
+    def phase(self, name: str) -> StepPhase:
+        phase = self._by_name.get(name)
+        if phase is None:  # a name outside the seven: clocked all the same
+            phase = self._by_name[name] = StepPhase(self, name)
+            self.phases[name.removeprefix(PHASE_PREFIX)] = phase
+        return phase
+
+    def lap(self) -> None:
+        """The loop's wall runs up to here (each iteration's top)."""
+        self._lap(_monotonic_ns())
+
+    def _lap(self, now: int) -> None:
+        # called between two phases: every phase's wall is whole
+        if self._lap_ns:
+            self.loop_wall_ns += now - self._lap_ns
+            self.unphased_ns = self.loop_wall_ns - sum(
+                p.wall_ns for p in self.phases.values())
+        self._lap_ns = now
+
+    def note_idle(self) -> None:
+        """The loop found no work: the next dispatch meets a device that
+        ran dry for want of requests, not of a host."""
+        self._idled = True
+
+    def _note_dispatch(self, kind: str, now: int, drained: bool) -> None:
+        wait = self._wait
+        if self._last_kind is not None:
+            self.period_ns[self._last_kind] = (
+                self.period_ns.get(self._last_kind, 0)
+                + now - self._last_dispatch_ns
+                - (wait.wall_ns - self._wait_ns_then))
+        if drained and not self._idled:
+            self.dispatches_device_drained += 1
+        self._idled = False
+        self.dispatches[kind] = self.dispatches.get(kind, 0) + 1
+        self._last_kind, self._last_dispatch_ns = kind, now
+        self._wait_ns_then = wait.wall_ns
+
+    def tick(self, now: int) -> None:
+        """Once a second, on the owning thread as a phase ends: note the
+        CPU clocks (this thread's as that phase just read it) and hand the
+        counts to ``on_tick`` (the count history)."""
+        self.ticked_ns = now
+        self._lap(now)
+        self.cpu_ns["engine"] = self._cpu_mark
+        self.cpu_ns["process"] = time.process_time_ns()
+        loop = loop_thread_cpu_ns()
+        if loop is not None:
+            self.cpu_ns["loop"] = loop
+        if self.on_tick is not None:
+            self.on_tick(now)
+
+    def counts(self) -> dict:
+        """Every count above, JSON-able."""
+        phases = {
+            name: {"wall_ns": p.wall_ns, "cpu_ns": p.cpu_ns, "calls": p.calls}
+            for name, p in list(self.phases.items())
+        }
+        work = [phases[n] for n in HOST_WORK]
+        return {
+            "step_phases": phases,
+            "loop_wall_ns": self.loop_wall_ns,
+            "unphased_ns": self.unphased_ns,
+            "offcpu_ns": sum(p["wall_ns"] - p["cpu_ns"] for p in work),
+            "dispatches": dict(self.dispatches),
+            "period_ns": dict(self.period_ns),
+            "dispatches_device_drained": self.dispatches_device_drained,
+            "cpu_ns": dict(self.cpu_ns),
+        }
+
+
+_bound = threading.local()
+
+
+def step_clock() -> StepClock:
+    """The calling thread's clock (made at its first phase)."""
+    clock = getattr(_bound, "clock", None)
+    if clock is None:
+        clock = StepClock()
+        bind_step_clock(clock)
+    return clock
+
+
+def bind_step_clock(clock: Optional[StepClock]) -> None:
+    """Make ``clock`` the calling thread's (an engine binds its own at
+    the top of its step loop); None unbinds."""
+    _bound.clock = clock
+    if clock is not None:
+        clock._cpu_mark = _thread_time_ns()
+
+
+def step_span(name: str, **attrs: Any) -> StepPhase:
+    """Context manager for one phase of an engine step: clocked always,
+    and inside a capture also a trace annotation with ``attrs`` (scalars
+    shown beside the event in the trace viewer)."""
+    clock = getattr(_bound, "clock", None) or step_clock()
+    phase = clock._by_name.get(name) or clock.phase(name)
+    phase.attrs = attrs
+    return phase
+
+
+# -- the event loop's CPU clock, readable from the engine thread --------------
+_loop_cpu_clock: Optional[int] = None
+
+
+def note_loop_thread() -> None:
+    """Call ON the asyncio loop's thread (http/service.py ``start``, the
+    engine's ``launch``): keeps that thread's CPU-time clock id, which any
+    thread may read. Nothing where the platform has no such clock."""
+    global _loop_cpu_clock
+    getcpuclockid = getattr(time, "pthread_getcpuclockid", None)
+    if getcpuclockid is not None and hasattr(time, "clock_gettime_ns"):
+        _loop_cpu_clock = getcpuclockid(threading.get_ident())
+
+
+def loop_thread_cpu_ns() -> Optional[int]:
+    """CPU ns the noted loop thread has used, or None (not noted, no such
+    clock here, or the thread is gone)."""
+    if _loop_cpu_clock is None:
+        return None
+    try:
+        return time.clock_gettime_ns(_loop_cpu_clock)
+    except OSError:
+        return None

@@ -148,12 +148,18 @@ async def test_engine_spans_tile_submit_to_finish(buffered_tracer):
 # ---------------------------------------------------------------------------
 # step phases and the file beside a capture
 # ---------------------------------------------------------------------------
-def test_step_span_is_inert_without_a_capture():
+def test_step_span_annotates_nothing_without_a_capture(monkeypatch):
+    """Outside a capture a phase is clocked (ISSUE 40) and makes no
+    ``TraceAnnotation``: the profiler is not touched."""
+    made = []
+    monkeypatch.setattr(tspans, "_annotation", lambda *a, **kw: made.append(a))
     assert not tspans._capture_live
     phase = step_span("dyn.step.plan", kind="decode")
-    assert phase is tspans._NO_PHASE
+    assert phase is step_span("dyn.step.plan")  # one object a name a thread
+    calls = phase.calls
     with phase:
         pass
+    assert made == [] and phase.calls == calls + 1
 
 
 def test_a_capture_holds_step_spans_and_writes_the_span_file(
