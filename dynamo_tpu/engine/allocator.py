@@ -122,22 +122,32 @@ class BlockAllocator:
                 break
         return n
 
-    def free_need(self, seq_hashes: list[int], n_total: int) -> int:
-        """How many blocks allocating this prompt would take from the
-        FREE pool (no allocation): fresh blocks plus matched prefix
-        blocks that are currently cached-free. Matched blocks pinned by
-        other sequences cost the free pool nothing — charging them
-        would make admission stall on exactly the shared-prefix
-        workloads prefix caching exists for."""
-        need = n_total
+    def pinned_prefix(self, seq_hashes: list[int]) -> tuple[int, int]:
+        """Of this prompt's leading cached blocks, how many some sequence
+        holds now, and how many of those exactly ONE sequence holds (no
+        allocation). The first cost the FREE pool nothing at admission —
+        charging them would make admission stall on exactly the
+        shared-prefix workloads prefix caching exists for; the second
+        are pages their one holder's finish would have given back and,
+        once this prompt pins them too, will not."""
+        pinned = alone = 0
         if self.enable_prefix_caching:
             for h in seq_hashes:
                 bid = self._hash_index.get(h)
                 if bid is None:
                     break
                 if bid not in self._free:
-                    need -= 1  # actively shared: already pinned elsewhere
-        return max(0, need)
+                    pinned += 1
+                    alone += self._blocks[bid].ref_count == 1
+        return pinned, alone
+
+    def held_alone(self, block_ids: list[int]) -> int:
+        """How many of a sequence's blocks nobody else pins: what its
+        finish gives back to the free pool."""
+        if not self.enable_prefix_caching:
+            return len(block_ids)  # nothing is ever shared
+        blocks = self._blocks
+        return sum(blocks[bid].ref_count == 1 for bid in block_ids)
 
     # -- allocation -------------------------------------------------------
     def allocate_prefix(self, seq_hashes: list[int]) -> tuple[list[int], int]:

@@ -656,6 +656,9 @@ class JaxEngine:
             max_prefill_tokens=cfg.max_prefill_tokens,
         )
         self.scheduler.decode_lookahead = max(1, cfg.decode_steps)
+        self.scheduler.dispatches_ahead = self.PIPELINE_DEPTH + 1
+        if self._drafter is not None:
+            self.scheduler.spec_tokens = cfg.spec_tokens
         if stateful:
             self.scheduler.state_slots = StateSlots(self._state_slot_count)
         if cfg.static_shapes:
@@ -5061,6 +5064,9 @@ class JaxEngine:
                 prompt_tokens=sched.prompt_tokens_admitted,
                 cached_prompt_tokens=sched.prompt_tokens_cached,
                 preemptions=sched.preemptions,
+                admit_blocked_reserve=sched.admit_blocked_reserve,
+                admit_reserve_peak_pages=sched.admit_reserve_peak_pages,
+                admit_reserve_sum_pages=sched.admit_reserve_sum_pages,
                 decode_dispatches=self._decode_dispatches[0],
                 decode_dispatches_chained=self._decode_dispatches[1],
                 prefill_tokens_real=self._prefill_tokens[0],

@@ -8,10 +8,11 @@ L3); here it is native and shaped for XLA's compilation model:
   step function compiles a handful of variants and then never recompiles;
 - prefill is **chunked** (prefill_chunk_size) so long prompts can't starve
   decode; one prefill chunk or one decode batch per engine step;
-- admission is capacity-checked against the block allocator, with
-  vLLM-style recompute preemption: if decode can't grow a sequence, the
-  youngest sequence is rolled back to the waiting queue and its blocks
-  freed.
+- admission is capacity-checked against the block allocator — a prompt
+  goes in only if the pool holds the population at the worst instant of
+  its future (_growth_reserve) — with vLLM-style recompute preemption
+  behind it: if decode still can't grow a sequence, the youngest
+  sequence is rolled back to the waiting queue and its blocks freed.
 
 Pure host-side logic — fully unit-testable without a device.
 """
@@ -23,7 +24,7 @@ import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 
@@ -212,6 +213,17 @@ def _area(rect: Optional[tuple[int, int]]) -> int:
     return rect[0] * rect[1] if rect else 0
 
 
+class _TimelineRow(NamedTuple):
+    """A row of the page timeline (Scheduler._growth_reserve)."""
+
+    length: int  # tokens it has
+    left: int  # tokens it may still generate (_NEVER: no stated end)
+    end: int  # the length its pages can reach
+    held: int  # pages it holds
+    alone: int  # of those, pages nobody else pins
+    unprefilled: int  # prompt tokens still to prefill
+
+
 def _ladder_to(buckets: list[int], top: int) -> list[int]:
     """``buckets``, doubled on past its end until it holds ``top``."""
     out = list(buckets)
@@ -246,6 +258,16 @@ class Scheduler:
         # (engine sets this from EngineConfig.decode_steps); block
         # allocation must cover the whole window up front
         self.decode_lookahead = 1
+        # speculative decoding: the longest draft run a verify step
+        # checks (engine sets it from EngineConfig.spec_tokens when a
+        # drafter is configured) — a row then advances by 1 to
+        # spec_tokens + 1 tokens a step (_growth_reserve's rate)
+        self.spec_tokens = 0
+        # dispatches whose pages a row may hold beyond its applied
+        # length: those the engine's pipelines keep in flight and the
+        # one being planned (engine sets it from its PIPELINE_DEPTH;
+        # _growth_reserve's slack)
+        self.dispatches_ahead = 3
         # mixed prefill+decode: when decode has work AND prefill chunks
         # are pending, emit a "mixed" plan whose prefill batch fits the
         # engine's fixed [mixed_prefill_rows, mixed_prefill_len]
@@ -305,13 +327,21 @@ class Scheduler:
         # recompute-preemption count (observability: healthy serving
         # should sit at ~0 — see _growth_reserve)
         self.preemptions = 0
+        # what admission's page reserve did (cumulative; program_spans.json
+        # counts): passes of _admit that stopped at it with rows to
+        # spare, and over every check the pages it asked to be free —
+        # the timeline's peak — beside the sum of every row's growth
+        # (_growth_reserve)
+        self.admit_blocked_reserve = 0
+        self.admit_reserve_peak_pages = 0
+        self.admit_reserve_sum_pages = 0
         # models with recurrent layers (engine sets it): a slot of the
         # state plane per admitted sequence, taken at admission and
         # given back at finish, abort and preemption. Every block table
         # then carries the row's slot as its LAST column.
         self.state_slots: Optional[StateSlots] = None
         # the head of ``waiting`` that the last _admit could not place
-        # (growth reserve, max_batch_size, state slots, no blocks). It
+        # (page reserve, max_batch_size, state slots, no blocks). It
         # stays unplaceable until something is freed, so finish() and
         # _preempt() forget it; a new head (the old one reaped, an
         # arrival into an empty queue, a preempted victim put first) is
@@ -501,46 +531,130 @@ class Scheduler:
                 )
                 self.finish(seq, FinishReason.TIMEOUT)
 
-    def _growth_reserve(self) -> int:
-        """Blocks the CURRENT population still needs to finish its
-        generations (exact when max_tokens is known; one decode window
-        otherwise). Admission leaves this many blocks free: without the
-        reserve, blocks freed by a preemption are instantly consumed by
-        the next waiting prompt, and the following decode window
-        preempts again — a recompute cascade in which every admission
-        costs a running request its entire prompt's prefill windows
-        (observed as a c=64 ISL-3000 collapse to 35 out tok/s with
-        ~9-minute TTFT outliers; 20 preemptions per 120 s even in
-        healthy runs)."""
-        r = 0
-        for seq in self.running:
-            if seq.max_new_tokens is not None:
-                end = seq.total_len + max(
-                    0, seq.max_new_tokens - seq.generated
-                )
-            else:
-                end = seq.total_len + self.decode_lookahead
-            r += max(
-                0,
-                seq.blocks_needed(end, self.block_size)
-                - len(seq.block_table),
+    # tokens left to a row that states no end
+    _NEVER = 1 << 40
+
+    def _timeline_rows(self) -> list[_TimelineRow]:
+        """The admitted population as ``_growth_reserve`` reads it."""
+        alloc = self.allocator
+        return [
+            self._timeline_row(
+                seq, len(seq.block_table), alloc.held_alone(seq.block_table)
             )
-        for seq in self.prefilling:
-            # a prefilling seq holds its full prompt's blocks already;
-            # reserve its generation growth
-            if seq.max_new_tokens is not None:
-                end = seq.total_len + seq.max_new_tokens
-            else:
-                end = seq.total_len + self.decode_lookahead
-            r += max(
-                0,
-                seq.blocks_needed(end, self.block_size)
-                - len(seq.block_table),
+            for pool in (self.running, self.prefilling) for seq in pool
+        ]
+
+    def _window(self) -> int:
+        """The most tokens one dispatch adds to a row: a fused window,
+        or a draft run and the token its verify step samples."""
+        return max(self.decode_lookahead, self.spec_tokens + 1)
+
+    def _timeline_row(
+        self, seq: Sequence, held: int, alone: int
+    ) -> _TimelineRow:
+        length = seq.total_len
+        unprefilled = (
+            0 if seq.state == SeqState.RUNNING
+            else max(1, length - seq.num_computed)
+        )
+        if seq.max_new_tokens is None:
+            # no stated end: never finishes on the timeline, and holds
+            # one decode window beyond what it has
+            return _TimelineRow(
+                length, self._NEVER, length + self.decode_lookahead,
+                held, alone, unprefilled,
             )
-        return r
+        left = max(1, seq.max_new_tokens - seq.generated)
+        end = length + left
+        if self.max_model_len and end > self.max_model_len:
+            # should_finish ends it at max_model_len whatever it asked
+            # for; the planners clamp a row's last window to max_tokens
+            # and not to this, so that window's pages may be held whole
+            left = max(1, self.max_model_len - length)
+            end = length + left - 1 + self._window()
+        return _TimelineRow(length, left, end, held, alone, unprefilled)
+
+    def _growth_reserve(
+        self, rows: list[_TimelineRow], newly_shared: int
+    ) -> tuple[int, int]:
+        """Pages that must be free NOW for ``rows`` — the admitted
+        population and, last, the candidate with nothing allocated yet —
+        never to run out, as ``(peak, total)``.
+
+        ``total`` is every row's growth to its stated end (``max_tokens``
+        or ``max_model_len``, whichever comes first; one decode window
+        where none is stated), added up — the pool's need only if no
+        row finished before the last one had reached its end. Until
+        PR 41 admission kept that sum free, less the candidate's own
+        growth, which it reserved only from the next admission on: in a
+        pool of rows with like ends the timeline admits one row fewer
+        than that did, the row that could then run out. ``peak``
+        is the need if every row runs to its stated end AND gives its
+        pages back when it gets there: the most the population holds at
+        any future instant, less what it holds now. The rows of a decode
+        batch advance together, so after ``t`` more tokens the rows
+        alive are those with more than ``t`` left, each holding the
+        pages of ``length + t + slack`` tokens (never past its end);
+        occupancy only rises between two finishes, so the peak lies just
+        before one of them: at most one instant a row, all evaluated at
+        once.
+
+        It is still a worst-case guarantee — a row that stops early
+        (EOS, a stop string, a cancel, a deadline) only lowers every
+        later instant — given what the scheduler can observe:
+
+        - a finish gives back only pages nobody else pins: a prefix
+          page with ``ref_count > 1`` is counted as never returned, and
+          so (``newly_shared``) is one the candidate is about to pin
+          beside its one holder;
+        - ``slack`` is what the planners allocate ahead of a row's
+          applied length: ``dispatches_ahead`` dispatches (what the
+          engine's pipelines keep in flight and the one being planned)
+          of up to ``decode_lookahead`` (or a draft run's
+          ``spec_tokens + 1``) tokens each, and under mixed batching the windows the running
+          rows decode while the rows still in chunked prefill catch up;
+        - under speculation rows advance by 1 to ``spec_tokens + 1``
+          tokens a step, each at its own pace: after ``t`` steps a live
+          row has at most ``rate * t`` more tokens and may still be
+          alive if it has more than ``t`` left. ``rate`` 1 is the exact
+          timeline; as it grows the bound tends to ``total``, which it
+          never exceeds.
+
+        Why anything is reserved at all: without it, blocks freed by a
+        preemption are instantly consumed by the next waiting prompt,
+        and the following decode window preempts again — a recompute
+        cascade in which every admission costs a running request its
+        entire prompt's prefill windows (observed as a c=64 ISL-3000
+        collapse to 35 out tok/s with ~9-minute TTFT outliers; 20
+        preemptions per 120 s even in healthy runs)."""
+        bs = self.block_size
+        length, left, end, held, alone, unprefilled = np.array(
+            rows, np.int64
+        ).T
+        shared = held - alone
+
+        def own(tokens):
+            # pages of its own a row holds then: never fewer than now
+            return np.maximum(alone, -(-tokens // bs) - shared)
+
+        now = int(alone[:-1].sum())  # the candidate holds nothing yet
+        total = int(own(end).sum()) - now
+        rate = self.spec_tokens + 1
+        slack = self.dispatches_ahead * self._window()
+        if self.mixed_prefill_rows > 0:
+            # a window a chunk still to prefill, the candidate's included
+            chunks = -(-unprefilled // self.mixed_prefill_len)
+            slack += self.decode_lookahead * int(chunks.sum())
+        ts = np.unique(np.append(left[left < self._NEVER] - 1, 0))[:, None]
+        at = own(np.minimum(end, length + rate * ts + slack))
+        peak = int(np.where(left > ts, at, 0).sum(axis=1).max())
+        return min(peak + newly_shared - now, total), total
 
     def _admit(self) -> None:
-        reserve = None  # computed lazily, refreshed per admission
+        # the population's timeline rows (built lazily, grown by each
+        # admission), and the pages this pass's admissions came to share
+        # with the one row that held them
+        rows, shared = None, 0
         while self.waiting and (
             len(self.running) + len(self.prefilling) < self.max_batch_size
         ):
@@ -550,24 +664,29 @@ class Scheduler:
                 self.finish(seq, FinishReason.ERROR)
                 continue
             seq_hashes = seq.tokens.sequence_hashes()
-            # blocks for the whole prompt + 1 growth block
             n_prompt_blocks = seq.blocks_needed(seq.total_len, self.block_size)
-            if reserve is None:
-                reserve = self._growth_reserve()
-            # charge only what admission actually takes from the free
-            # pool: actively-shared prefix blocks are already pinned
-            free_need = self.allocator.free_need(
-                seq_hashes[:n_prompt_blocks], n_prompt_blocks
+            # the prompt takes from the free pool only what no sequence
+            # holds already: actively-shared prefix blocks are pinned
+            pinned, alone = self.allocator.pinned_prefix(
+                seq_hashes[:n_prompt_blocks]
             )
-            if self.allocator.num_free < free_need + reserve:
+            need = free_need = n_prompt_blocks - pinned
+            if rows is None:
+                rows = self._timeline_rows()
+            rows.append(self._timeline_row(seq, n_prompt_blocks, free_need))
+            weighed = len(rows) > 1
+            if weighed:
+                # somebody runs: their worst case and this prompt's have
+                # to fit the pool together (alone, a prompt goes in
+                # whatever its end: waiting would free nothing)
+                need, total = self._growth_reserve(rows, shared + alone)
+                self.admit_reserve_peak_pages += need
+                self.admit_reserve_sum_pages += total
+            if self.allocator.num_free < need:
+                self.admit_blocked_reserve += weighed
                 break  # backpressure: the population's growth comes first
             if self.state_slots is not None and not self.state_slots.num_free:
                 break  # every state slot is held: wait for a finish
-            # admitting this seq adds its own growth to the reserve
-            reserve += seq.blocks_needed(
-                seq.total_len + (seq.max_new_tokens or self.decode_lookahead),
-                self.block_size,
-            ) - n_prompt_blocks
             try:
                 complete = seq_hashes[: n_prompt_blocks]
                 blocks, cached = self.allocator.allocate_prefix(complete)
@@ -603,6 +722,7 @@ class Scheduler:
             except NoBlocksError:
                 break  # backpressure: try again next step
             self.waiting.popleft()
+            shared += alone
             if seq.t_admit == 0.0:
                 # first admission only: a preempted-and-readmitted seq
                 # keeps its original queue-wait measurement
@@ -701,9 +821,10 @@ class Scheduler:
                 seq.t_prefill_done = time.monotonic()
             self.running.append(seq)
 
-    def _seq_lookahead(self, seq: Sequence) -> int:
+    def _seq_lookahead(self, seq: Sequence, lag: int = 0) -> int:
         """Fused-decode window steps this sequence can actually keep:
-        clamped to its remaining-token budget. Near max_tokens the
+        clamped to its remaining-token budget (less ``lag`` tokens that
+        in-flight windows have sampled and the host has not applied). Near max_tokens the
         window's surplus is discarded, and allocating blocks for it would
         trigger phantom preemptions under pressure. Block allocation
         (_plan_decode) and the device-side KV-write mask
@@ -712,7 +833,9 @@ class Scheduler:
         possibly-shared block."""
         lookahead = self.decode_lookahead
         if seq.max_new_tokens is not None:
-            lookahead = min(lookahead, max(1, seq.max_new_tokens - seq.generated))
+            lookahead = min(
+                lookahead, max(1, seq.max_new_tokens - seq.generated - lag)
+            )
         return lookahead
 
     def _plan_decode(self) -> list[Sequence]:
@@ -921,15 +1044,21 @@ class Scheduler:
         next_seqs = survivors + graduated
         if not next_seqs or len(next_seqs) > self.max_batch_size:
             return None
-        K = self.decode_lookahead
+        # the steps of the next window each row keeps
+        vmap = {
+            id(s): self._seq_lookahead(s, lag.get(id(s), 0)) for s in next_seqs
+        }
         # block allocation for the whole next window (no preemption on
-        # this path; rollback on exhaustion). lag covers a graduated
+        # this path; rollback on exhaustion) — for the steps a row keeps
+        # and no further, so no row ever holds pages past its stated
+        # end (_growth_reserve counts on it). lag covers a graduated
         # row's in-flight sampled token, so one formula serves all.
         added: list[Sequence] = []
         ok = True
         for seq in next_seqs:
             needed = seq.blocks_needed(
-                seq.total_len + lag.get(id(seq), 0) + K, self.block_size
+                seq.total_len + lag.get(id(seq), 0) + vmap[id(seq)],
+                self.block_size,
             )
             while len(seq.block_table) < needed:
                 try:
@@ -994,7 +1123,6 @@ class Scheduler:
         valid_steps = np.zeros((B,), np.int32)
         src_idx = np.zeros((B,), np.int32)
         offsets = [0] * n
-        vmap: dict[int, int] = {}
         if grad_base is None:
             grad_base = self._decode_batch(len(seqs)) if seqs else 0
         for i, s in enumerate(next_seqs):
@@ -1008,12 +1136,8 @@ class Scheduler:
             positions[i, 0] = s.total_len - 1 + gen_after
             self._fill_table(tables, i, s)
             ctx[i] = s.total_len + gen_after
-            v = K
-            if s.max_new_tokens is not None:
-                v = min(v, max(1, s.max_new_tokens - s.generated - gen_after))
-            valid_steps[i] = v
+            valid_steps[i] = vmap[id(s)]
             offsets[i] = gen_after
-            vmap[id(s)] = v
         arrays = {
             "tokens": np.zeros((B, 1), np.int32),  # device chain overrides
             "positions": positions,
@@ -1298,7 +1422,7 @@ class Scheduler:
             ):
                 # a page of the blocked head's own prompt is now held
                 # by a running row: admitting it costs one page less
-                # (allocator.free_need), so it has to be tried again
+                # (allocator.pinned_prefix), so it has to be tried again
                 self._blocked_head = None
         seq.committed_blocks = max(seq.committed_blocks, n_complete_computed)
 

@@ -139,7 +139,9 @@ def test_scheduler_decode_arrays_shapes():
 
 
 def test_scheduler_preemption_frees_blocks():
-    alloc = BlockAllocator(8, 4)  # 7 usable
+    # 8 usable; neither row states its end, so admission reserves each
+    # one decode window (a 4th block) and no more: both go in at once
+    alloc = BlockAllocator(9, 4)
     sched = Scheduler(alloc, 4, max_batch_size=4)
     a = _mk_seq(list(range(12)), request_id="a")  # 3 blocks
     b = _mk_seq(list(range(12)), request_id="b")  # 3 blocks
@@ -151,8 +153,8 @@ def test_scheduler_preemption_frees_blocks():
             break
         sched.complete_prefill_chunk(plan.prefill)
     assert sched.num_running == 2
-    # grow a: next token needs block 4 for a; only 1 free; then b needs one
-    # too -> b (younger) gets preempted when pool is exhausted
+    # both grow a 4th block (2 free -> 0); at 17 tokens a needs a 5th
+    # -> b (younger) gets preempted when pool is exhausted
     for seq in (a, b):
         sched.append_token(seq, 1)  # fills to 13 tokens
     for _ in range(4):
@@ -176,18 +178,18 @@ def _blocked_scheduler(cause):
     from dynamo_tpu.engine.allocator import StateSlots
 
     # 9 usable pages of 4 tokens; a: 8-token prompt, 24 more to come
-    # (8 pages at its end), so its reserve is 6 of the 7 pages free
+    # (8 pages at its end), and b as long: their ends do not fit together
     alloc = BlockAllocator(10, 4)
     sched = Scheduler(alloc, 4, max_batch_size=1 if cause == "batch" else 4)
     if cause == "slots":
         sched.state_slots = StateSlots(2)  # one usable slot
-    a = _mk_seq(list(range(8)), max_tokens=24 if cause == "reserve" else 4,
-                request_id="a")
+    budget = 24 if cause == "reserve" else 4
+    a = _mk_seq(list(range(8)), max_tokens=budget, request_id="a")
     sched.add_request(a)
     plan = sched.plan()
     sched.complete_prefill_chunk(plan.prefill)
     assert sched.running == [a] and not sched.admission_work()
-    b = _mk_seq(list(range(100, 108)), max_tokens=4, request_id="b")
+    b = _mk_seq(list(range(100, 108)), max_tokens=budget, request_id="b")
     sched.add_request(b)
     # an arrival into an EMPTY queue has not been tried: work
     assert sched.admission_work()
@@ -227,11 +229,11 @@ def test_admission_work_sees_what_can_move_a_blocked_queue(event):
     to do for a blocked queue: a waiting request cancelled or past its
     deadline (reaped within a step), a preemption (the victim is the new
     head), and a running row committing a page of the head's own prompt
-    (allocator.free_need falls by one)."""
+    (one page fewer to take from the free pool)."""
     import time
 
     sched, a, b = _blocked_scheduler("reserve")
-    c = _mk_seq(list(range(200, 208)), max_tokens=4, request_id="c")
+    c = _mk_seq(list(range(200, 208)), max_tokens=24, request_id="c")
     sched.add_request(c)
     assert not sched.admission_work()
     if event == "cancel_head":
@@ -246,7 +248,7 @@ def test_admission_work_sees_what_can_move_a_blocked_queue(event):
     elif event == "head_shares_page":
         # b's prompt is a's prompt and the 4 tokens a generates next
         sched.waiting.clear()
-        b = _mk_seq(list(range(8)) + [1, 1, 1, 1, 9], max_tokens=4,
+        b = _mk_seq(list(range(8)) + [1, 1, 1, 1, 9], max_tokens=24,
                     request_id="b2")
         sched.add_request(b)
         sched.plan()

@@ -12,6 +12,7 @@ import asyncio
 import os
 
 import numpy as np
+import pytest
 
 from dynamo_tpu.engine.allocator import BlockAllocator
 from dynamo_tpu.engine.config import EngineConfig
@@ -181,45 +182,64 @@ def test_cohort_takes_dedicated_prefill_not_trickle():
     assert sched.plan().kind == "mixed"
 
 
-def test_admission_reserves_population_growth():
-    """Admission must leave the blocks the RUNNING population still
-    needs to finish: without the reserve, a freed block is instantly
-    eaten by the next waiting prompt and decode growth preempts a
-    running sequence — a recompute cascade under closed-loop pressure
-    (observed as the ISL-3000 c=64 collapse)."""
-    alloc = BlockAllocator(16, 4)
+@pytest.mark.parametrize("a_budget, b_prompt, b_waits", [
+    # A ends at 32 tokens (8 blocks) and B, 36 + 4 tokens (10 blocks),
+    # ends while A is inside the three windows of slack of its own end:
+    # 18 > 15, B must WAIT (no reserve would admit it and later preempt
+    # a running sequence)
+    (12, 36, True),
+    # A ends at 48 tokens (12 blocks), B at 12 + 4 (4 blocks): the sum of
+    # their growth wants 16 > 15, but when B ends A has at most 20 + 3 +
+    # 12 tokens (9 blocks) — 13 at the peak, and A's 12 alone afterwards:
+    # B goes in beside A at once
+    (28, 12, False),
+], ids=["ends_overlap_waits", "short_answer_fits"])
+def test_admission_reserves_population_growth(a_budget, b_prompt, b_waits):
+    """Admission must leave free what the population needs at the WORST
+    INSTANT of its future — every row run to its end, its pages given
+    back when it gets there (Scheduler._growth_reserve): without the
+    reserve, a freed block is instantly eaten by the next waiting prompt
+    and decode growth preempts a running sequence — a recompute cascade
+    under closed-loop pressure (observed as the ISL-3000 c=64 collapse).
+    Either way nobody is ever preempted."""
+    alloc = BlockAllocator(16, 4)  # 15 usable
     sched = Scheduler(alloc, 4, max_batch_size=8, prefill_chunk_size=64)
     sched.decode_lookahead = 4
-    # A: 20-token prompt (5 blocks), will generate 12 more -> needs 8
-    # blocks total, i.e. growth reserve 3 once prefilled
-    a = _mk_seq(list(range(20)), max_tokens=12, request_id="a")
+    a = _mk_seq(list(range(20)), max_tokens=a_budget, request_id="a")
     sched.add_request(a)
     plan = sched.plan()
     assert plan.kind == "prefill"
     for w in plan.prefill_batch:
         sched.complete_prefill_chunk(w)
     assert sched.num_running == 1
-    # B: 36-token prompt (9 blocks), DISTINCT from A (a shared prefix
-    # would be charged only for its fresh tail). free = 11, but A's
-    # growth needs 3 -> 9 + 3 > 11: B must WAIT (no reserve would
-    # admit it and later preempt A)
-    b = _mk_seq(list(range(100, 136)), max_tokens=4, request_id="b")
+    # B's prompt is DISTINCT from A's (a shared prefix would be charged
+    # only for its fresh tail)
+    b = _mk_seq(list(range(100, 100 + b_prompt)), max_tokens=4,
+                request_id="b")
     sched.add_request(b)
     plan = sched.plan()
-    assert plan.kind == "decode"  # B not admitted
-    assert len(sched.waiting) == 1
+    assert plan.kind == ("decode" if b_waits else "prefill")
+    assert len(sched.waiting) == b_waits
+    assert sched.admit_blocked_reserve == b_waits
+    assert sched.admit_reserve_peak_pages <= sched.admit_reserve_sum_pages
+    for w in plan.prefill_batch:
+        sched.complete_prefill_chunk(w)
     # A decodes to completion without ever being preempted
     while a.state == SeqState.RUNNING:
-        sched.plan()
-        sched.append_token(a, 1)
-        r = sched.should_finish(a)
-        if r is not None:
-            sched.finish(a, r)
+        for s in sched.plan().decode_seqs:
+            for _ in range(sched._seq_lookahead(s)):
+                sched.append_token(s, 1)
+            r = sched.should_finish(s)
+            if r is not None:
+                sched.finish(s, r)
     assert sched.preemptions == 0
-    # A's blocks freed -> B admits now
-    plan = sched.plan()
-    assert plan.kind == "prefill"
-    assert plan.prefill.seq.request_id == "b"
+    if b_waits:
+        # A's blocks freed -> B admits now
+        plan = sched.plan()
+        assert plan.kind == "prefill"
+        assert plan.prefill.seq.request_id == "b"
+    else:
+        assert b.state == SeqState.FINISHED
 
 
 # ---------------------------------------------------------------------------

@@ -340,10 +340,13 @@ def _spy(eng) -> dict:
 
 async def _launch_blocking(cause: str, overlap: bool):
     """An engine in which two long requests leave no room for a third,
-    for ``cause``: the growth reserve (27 pages: the two need 24 at
-    their ends, a 20-token prompt 3 more than the 2 left over), the
-    batch rows, or the state slots (kimi tiny; the test holds one of
-    three slots, so the rows are not what runs out)."""
+    for ``cause``: the page reserve (22 pages of 8 tokens: when r0 ends
+    at 75 tokens it holds 10, r1 is as long by then, and a third row
+    beside them — one that outlives r0 — holds 3 however late it came
+    in; once r0 is gone, r1's 14 at its end and the 7 the third row has
+    grown to by then fit),
+    the batch rows, or the state slots (kimi tiny; the test holds one
+    of three slots, so the rows are not what runs out)."""
     from dynamo_tpu.engine.engine import JaxEngine
 
     if cause == "slots":
@@ -352,7 +355,7 @@ async def _launch_blocking(cause: str, overlap: bool):
         eng, _ = await launch(max_batch_size=3, overlap=overlap)
         await eng.acall_on_thread(eng.scheduler.state_slots.acquire)
         return eng
-    kw = dict(num_blocks=27) if cause == "reserve" else dict(max_batch_size=2)
+    kw = dict(num_blocks=23) if cause == "reserve" else dict(max_batch_size=2)
     return await JaxEngine.launch(_engine_config(overlap=overlap, **kw))
 
 
@@ -363,7 +366,9 @@ async def _run_blocking(cause: str, overlap: bool, watch=None):
     eng = await _launch_blocking(cause, overlap)
     try:
         log = _spy(eng)
-        budgets = [64, 96, 8, 8]
+        # the reserve lets a short answer in beside the long ones (it is
+        # over before they have grown): the one it holds back is longer
+        budgets = [64, 96, 70 if cause == "reserve" else 8, 8]
         streams = asyncio.gather(*[
             _generate(eng, p, max_tokens=n, request_id=f"r{i}")
             for i, (p, n) in enumerate(zip(_LONG + _BLOCKED, budgets))
@@ -398,7 +403,8 @@ async def test_blocked_queue_keeps_chaining_and_moves_at_first_finish(cause):
     over, log, counts = await _run_blocking(cause, True, chained_while_waiting)
     serial, serial_log, serial_counts = await _run_blocking(cause, False)
     assert over == serial
-    assert [len(o) for o in over] == [64, 96, 8, 8]
+    assert [len(o) for o in over] == [
+        64, 96, 70 if cause == "reserve" else 8, 8]
     for lg, c in ((log, counts), (serial_log, serial_counts)):
         assert list(lg["admit"]) == ["r0", "r1", "r2", "r3"]  # FIFO
         # r2 waited, and went in at r0's finish: no dispatch in between
@@ -406,6 +412,11 @@ async def test_blocked_queue_keeps_chaining_and_moves_at_first_finish(cause):
         finishes = {step for step, _ in lg["finish"].values()}
         assert lg["admit"]["r3"] in finishes
         assert c["kv_preemptions"] == 0 and c["preemptions"] == 0
+        # the reserve's own counts name the cause: it stopped admission,
+        # and never asked for more pages than the sum would have
+        assert (c["admit_blocked_reserve"] > 0) == (cause == "reserve")
+        assert (0 < c["admit_reserve_peak_pages"]
+                <= c["admit_reserve_sum_pages"])
     d, chained = counts["decode_dispatches"], counts["decode_dispatches_chained"]
     assert counts["overlap"]["decode_dispatches"] == d
     assert counts["overlap"]["decode_dispatches_chained"] == chained
@@ -424,13 +435,14 @@ async def test_blocked_request_leaves_the_queue_within_a_step(how):
 
     from dynamo_tpu.engine.engine import JaxEngine
 
-    # 30 pages: the two long ones need 28 at their ends, and hold 4
+    # 30 pages: the two long ones need 28 at their ends, and a third
+    # row as long as they are would need 14 more beside them
     eng = await JaxEngine.launch(_engine_config(overlap=True, num_blocks=31))
     try:
         log = _spy(eng)
         streams = asyncio.gather(*[
             _generate(eng, p, max_tokens=n, request_id=f"r{i}")
-            for i, (p, n) in enumerate(zip(_LONG + _BLOCKED, [100, 100, 8, 8]))
+            for i, (p, n) in enumerate(zip(_LONG + _BLOCKED, [100] * 4))
         ])
         await eng.wait_for_state(
             lambda e: len(e.scheduler.waiting) == 2
@@ -467,7 +479,7 @@ async def test_blocked_request_leaves_the_queue_within_a_step(how):
         head.is_cancelled = lambda: False
         outs = await streams
         assert [len(o[0]) for o in outs] == [
-            100, 100, *((0, 8) if how == "cancelled" else (8, 0))]
+            100, 100, *((0, 100) if how == "cancelled" else (100, 0))]
         assert eng.scheduler.preemptions == 0
     finally:
         await eng.shutdown()

@@ -143,6 +143,11 @@ async def test_engine_spans_tile_submit_to_finish(buffered_tracer):
     # rows x tokens of the rectangle it ran (never fewer)
     assert counts["prefill_tokens_real"] == 140 - cached
     assert counts["prefill_tokens_padded"] >= counts["prefill_tokens_real"]
+    # one request at a time: each arrived at an empty engine, so admission
+    # never weighed one against the page reserve
+    assert [counts[k] for k in (
+        "admit_blocked_reserve", "admit_reserve_peak_pages",
+        "admit_reserve_sum_pages")] == [0] * 3
 
 
 # ---------------------------------------------------------------------------

@@ -332,6 +332,10 @@ async def test_engine_greedy_spec_bit_identical():
         _engine_config(spec_decode="ngram", spec_tokens=4)
     )
     try:
+        # admission's page reserve reads its rate and its slack from the
+        # engine's own configuration (Scheduler._growth_reserve)
+        assert engine.scheduler.spec_tokens == 4
+        assert engine.scheduler.dispatches_ahead == engine.PIPELINE_DEPTH + 1
         spec, fs = await _generate(engine, SPEC_PROMPT, max_tokens=13,
                                    request_id="spec")
         assert fs.finish_reason == FinishReason.LENGTH

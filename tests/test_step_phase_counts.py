@@ -389,6 +389,8 @@ async def test_the_history_ticks_once_a_second_with_host_counts_only(
                 "dispatches", "period_ns", "dispatches_device_drained",
                 "steps", "decode_dispatches", "decode_dispatches_chained",
                 "prefill_tokens_real", "prefill_tokens_padded",
+                "admit_blocked_reserve", "admit_reserve_peak_pages",
+                "admit_reserve_sum_pages",
                 "state_slot_steps_used"):
         assert key in newest, key
     cpu = newest["cpu_ns"]
