@@ -642,7 +642,9 @@ class JaxEngine:
             cfg.block_size,
             # a cached page prefix is worth nothing without the recurrent
             # state at its end, and no state is snapshotted at page
-            # boundaries: every admission is a counted miss, recomputed
+            # boundaries: every admission is a counted miss, recomputed.
+            # A family that owns its pages and keeps NO such state
+            # (latent rows alone) is served from them like any other
             enable_prefix_caching=cfg.enable_prefix_caching and not stateful,
             on_event=self._on_kv_event,
         )
@@ -940,14 +942,15 @@ class JaxEngine:
         covered: its unrolled layers call ``qmm`` per weight with a
         float32 result (models/kimi_linear.py ``_mm``), which this
         llama-family shape list does not describe; its kernels at
-        published widths are compiled by tests/test_chip_compile.py."""
+        published widths are compiled by tests/test_chip_compile.py.
+        The same holds for every family that owns its pages."""
         from dynamo_tpu.models.llama import pallas_matmul_active
 
         if (
             jax.default_backend() != "tpu"
             or not pallas_matmul_active()
             or self.config.quantization != "int8"
-            or self.model_config.has_recurrent_state
+            or self.model_config.owns_pages
         ):
             return
         mc, sched = self.model_config, self.scheduler
@@ -1580,16 +1583,18 @@ class JaxEngine:
             )
         fam = model_family(mc)
         reserved = 0
-        if mc.has_recurrent_state:
+        if mc.owns_pages:
             # the family's own pages (latent rows, or the K and V of its
-            # attention layers alone); the state plane and the family's
-            # own step transients come off the top
+            # attention layers alone); its own step transients come off
+            # the top, and the state plane where it keeps one
             bytes_per_block_total = fam.page_bytes_per_block(
                 mc, self.config.block_size, itemsize
             )
-            reserved = fam.state_bytes(
-                mc, self._state_slot_count, itemsize
-            ) + fam.STEP_TRANSIENT_BYTES
+            reserved = fam.STEP_TRANSIENT_BYTES
+            if mc.has_recurrent_state:
+                reserved += fam.state_bytes(
+                    mc, self._state_slot_count, itemsize
+                )
         if getattr(devices[0], "platform", "") != "tpu":
             # CPU/virtual test backends: a modest fixed pool (their
             # memory_stats describe host RAM, which would size a
@@ -2528,11 +2533,12 @@ class JaxEngine:
         return len(seq_hashes)
 
     def refuse_kv_transfer(self) -> None:
-        if self.model_config is not None and self.model_config.has_recurrent_state:
+        if self.model_config is not None and self.model_config.owns_pages:
             raise NotImplementedError(
                 "KV block export/import (disaggregated transfer, fleet "
-                "fabric) moves K/V pages only: a model with recurrent "
-                "state beside its pages is not supported"
+                "fabric) moves K/V pages only: a model whose family lays "
+                "its pages out itself (latent rows, recurrent state "
+                "beside its pages) is not supported"
             )
 
     async def export_kv_blocks(
@@ -5048,8 +5054,7 @@ class JaxEngine:
         (telemetry/debug.py ``program_spans.json``): the host's, and the
         family's on-device ones."""
         out = self._host_counts()
-        if self.scheduler is not None and self.scheduler.state_slots is not None:
-            out.update(self._read_family_counts())
+        out.update(self._read_family_counts())
         return out
 
     def _host_counts(self) -> dict:
@@ -5083,7 +5088,12 @@ class JaxEngine:
         """The step clock's once-a-second tick (engine thread, at the end
         of a ``record`` or ``wait`` phase): one entry of the count
         history that ``program_spans.json`` carries."""
-        note_counts(self._debug_name or "engine", self._host_counts(), now_ns)
+        # host-side numbers, and the family's device counts AS LAST READ
+        # (a capture's edge, ``program_counts``): the tick reads no
+        # device array and waits for no step
+        counts = self._host_counts()
+        counts.update(self._family_counts)
+        note_counts(self._debug_name or "engine", counts, now_ns)
 
     def _read_family_counts(self) -> dict:
         """The counts a family keeps ON THE DEVICE in its state pytree
@@ -5211,6 +5221,18 @@ class JaxEngine:
                 "used_slots": slots.num_used,
                 "bytes": self._plane_bytes[1],
                 "page_pool_bytes": self._plane_bytes[0],
+            }
+        elif (sched is not None and self.model_config is not None
+                and self.model_config.owns_pages):
+            # a family's own pages with no state beside them (latent
+            # rows): the plane's bytes beside kv_pool's own counts, the
+            # prefix cache keeping what is free and reusable
+            pool = out["kv_pool"]
+            out["page_plane"] = {
+                "page_pool_bytes": self._plane_bytes[0],
+                "pages_total": pool["total_blocks"],
+                "pages_in_use": pool["active_blocks"],
+                "pages_cached_reusable": pool["cached_free_blocks"],
             }
         out["hbm"] = self.hbm.refresh()
         # the device this engine actually runs on, the kernel impls that

@@ -20,20 +20,35 @@ the loader reach a model through nothing else):
 - optionally ``FAMILIES``: the ``model_type`` values it serves beside its
   own file name.
 
-A family that draws its own parameters and keeps a fixed-size state per
-sequence beside the paged rows says so and gives the engine what it
-sizes and checks with (``models/kimi_linear.py``, ``models/qwen3_next.py``):
+A family that draws its own parameters says so, and gives the engine
+what it sizes and checks with. TWO further questions are asked of it,
+each on its own (``ModelConfig.owns_pages``, ``.has_recurrent_state``):
 
 - ``init_params_quantized(cfg, seed, mesh, specs)`` (the loader then
   takes random weights only);
-- ``RECURRENT_STATE = True``: every admitted sequence gets a slot of the
-  state plane, the table's last column; prefix reuse is off;
-- ``page_bytes_per_block(cfg, block_size, itemsize)``,
-  ``state_bytes(cfg, state_slots, itemsize)``, ``STEP_TRANSIENT_BYTES``:
-  what a block of pages, the state plane and a step's temporaries take;
+- DOES IT LAY ITS PAGES OUT ITSELF? ``page_bytes_per_block(cfg,
+  block_size, itemsize)`` and ``STEP_TRANSIENT_BYTES`` say so: what a
+  block of its pages (latent rows, or the K and V of its attention layers
+  alone) and a step's temporaries take. The engine sizes the pool with
+  them, and refuses what moves K/V pages by their llama layout: block
+  export / import (``engine.refuse_kv_transfer``), KVBM offload and int8
+  pages (``check_engine``). The allocator, the scheduler, chunked prefill
+  and the PREFIX CACHE treat such pages like any others: a hit's pages
+  are read by the prefill of the row's remaining tokens (a start position
+  > 0 over cached pages is what a second prefill chunk already is), KV
+  events are published, a preempted row resumes from its cached pages
+  (``models/deepseek_v3.py``: latent pages and nothing else);
+- DOES IT KEEP RECURRENT STATE? ``RECURRENT_STATE = True`` and
+  ``state_bytes(cfg, state_slots, itemsize)``: every admitted sequence
+  gets a slot of the state plane (``init_cache(..., state_slots)``), the
+  table's last column; prefix reuse is off, because a cached page prefix
+  is worth nothing without the state at its end
+  (``models/kimi_linear.py``, ``models/qwen3_next.py``,
+  ``models/nemotron_h.py``: all three also own their pages);
 - ``check_engine(engine_config)``: raises for what it does not build;
 - ``COUNT_NAMES``: the cumulative int32 counts it keeps on the device in
-  ``cache_b["counts"]`` (``engine.program_counts``).
+  ``cache_b["counts"]`` (``engine.program_counts``; the once-a-second
+  count history repeats them as last read).
 """
 
 import functools

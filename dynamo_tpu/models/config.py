@@ -109,6 +109,17 @@ class ModelConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 0.0001
+    # deepseek_v3 (models/deepseek_v3.py): latent attention with a rotary
+    # part in every layer (rope_interleave: the rotated pairs are the
+    # adjacent ones), first_k_dense_replace dense layers then
+    # n_routed_experts sigmoid-routed experts (scoring_func, topk_method:
+    # top num_experts_per_tok of score + e_score_correction_bias) plus
+    # n_shared_experts shared ones as one MLP; qk_head_dim is
+    # qk_nope_head_dim + qk_rope_head_dim, checked where given
+    rope_interleave: bool = True
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    qk_head_dim: Optional[int] = None
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -126,6 +137,17 @@ class ModelConfig:
         from dynamo_tpu.models import family
 
         return bool(getattr(family(self), "RECURRENT_STATE", False))
+
+    @property
+    def owns_pages(self) -> bool:
+        """The family lays its pages out itself (latent rows, or the K
+        and V of its attention layers alone) and sizes them for the
+        engine (``page_bytes_per_block``). Whether it ALSO keeps
+        recurrent state is ``has_recurrent_state``'s to say: a family
+        with pages of its own and no state keeps the prefix cache."""
+        from dynamo_tpu.models import family
+
+        return hasattr(family(self), "page_bytes_per_block")
 
     def layer_letters(self) -> list[str]:
         """``hybrid_override_pattern`` as one letter a layer (``M``,

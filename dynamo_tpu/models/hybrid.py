@@ -17,6 +17,9 @@ layer that holds a share of the experts.
   what an expert IS — the stacks that read the rows, the activation that
   joins them, the stack that writes back — is the family's to say
   (``ExpertForm``: ``GATED_SILU`` three matrices, ``RELU2`` two);
+- the sigmoid router (``sigmoid_routing``) and latent attention with the
+  key up-projection absorbed into the queries (``mla_mixer``: NoPE for
+  ``kimi_linear``, a rotary part for ``deepseek_v3``);
 - what no such family builds yet, refused at start-up (``check_engine``).
 
 A family keeps what is its own: the layer plan, the mixers, the router,
@@ -304,8 +307,163 @@ def delta_chunked(q, k, v, glog, beta, S, chunk: Optional[int] = None):
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (MLA), the key up-projection absorbed into the queries
+# ---------------------------------------------------------------------------
+
+
+class Latent(NamedTuple):
+    """The sizes of latent attention. A cached row is ``[c | k_r]``:
+    ``rank`` values of the normalised latent, ``rope`` of the key part
+    all heads share, stored in ``Cpad`` lanes (whole 128-lane tiles)."""
+    H: int
+    nope: int
+    rope: int
+    vd: int
+    rank: int
+    Cpad: int
+    query_tokens: int = 512   # query tokens whose scores exist at once (XLA path)
+
+    @property
+    def C(self) -> int:
+        return self.rank + self.rope
+
+
+def rotary_pairs(x: jax.Array, positions: jax.Array, theta: float,
+                 interleave: bool) -> jax.Array:
+    """``x [B, T, ..., d]`` (float32) rotated at ``positions [B, T]``:
+    pair ``i`` of ``d / 2`` turns by ``p * theta^(-2i/d)``. ``interleave``:
+    the pairs are the adjacent ``(x_2i, x_2i+1)`` and stay where they are
+    (HF's ``apply_rotary_pos_emb_interleave`` moves them to ``(i, i + d/2)``
+    first and then uses ``rotate_half``: the same dot products, since q and
+    k are permuted alike); else the pairs are ``(x_i, x_i+d/2)``."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)     # [d/2]
+    ang = positions.astype(jnp.float32)[..., None] * inv             # [B, T, d/2]
+    ang = ang.reshape(*positions.shape, *(1,) * (x.ndim - 3), d // 2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if interleave:
+        xp = x.reshape(*x.shape[:-1], d // 2, 2)
+        a, b = xp[..., 0], xp[..., 1]
+        return jnp.stack([a * cos - b * sin, a * sin + b * cos], -1).reshape(x.shape)
+    a, b = x[..., : d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
+
+
+def mla_mixer(p: Params, h: jax.Array, mi, latent: jax.Array, m: Latent,
+              eps: float, positions: jax.Array, slot_mapping: jax.Array,
+              tables: jax.Array, context_lens: jax.Array, block_size: int,
+              kernels: bool,
+              rotate: Optional[Callable[[jax.Array], jax.Array]] = None,
+              flash_prefill: bool = False,
+              attend_scope: str = "mla_attend"):
+    """Latent attention of layer ``mi`` of the ``mla_*`` stacks over the
+    paged latent plane ``latent [Lm, slots, Cpad]``: ``h [B, T, D]`` ->
+    (out [B, T, D] float32, latent with this step's rows written).
+
+    The cached row is ``[c | k_r]`` (``rotate``, where given, has turned
+    ``k_r`` — and turns the queries' ``q_r`` — by the token's position,
+    so nothing is rotated again when a row is read). Queries absorb the
+    key half of ``W_kvb``: attention runs over the cached rows
+    themselves, one shared ``rank + rope`` wide "head", and the value
+    half is applied after, to the output in latent space. Decode
+    (``T == 1``) with ``kernels`` (the family's ``kernels_active()``)
+    goes through ``ops/mla.py``
+    ``mla_decode_attention``; prefill through ``mla_prefill_attention``
+    where ``flash_prefill`` (the row's own pages, a page a grid step, no
+    score ever in HBM), else through plain XLA over the gathered table,
+    ``m.query_tokens`` query tokens at a time."""
+    B, T, _ = h.shape
+    act = h.dtype
+    q = mm(p, "mla_wq", h, mi).reshape(B, T, m.H, m.nope + m.rope)
+    kv = mm(p, "mla_wkva", h, mi)                              # [B, T, C]
+    c = llama.rmsnorm(kv[..., : m.rank], p["mla_kvnorm"][mi], eps)
+    k_r = kv[..., m.rank:]
+    q_r = q[..., m.nope:]
+    if rotate is not None:
+        k_r, q_r = rotate(k_r), rotate(q_r)
+    lane_pad = jnp.zeros((B, T, m.Cpad - m.C), c.dtype)
+    row = jnp.concatenate([c, k_r.astype(c.dtype), lane_pad], -1)
+    latent = latent.at[mi, slot_mapping].set(
+        row.reshape(B * T, m.Cpad).astype(latent.dtype))
+    wkvb = weight(p, "mla_wkvb", mi, act).reshape(m.rank, m.H, m.nope + m.vd)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.concatenate([
+            jnp.einsum("bthn,chn->bthc", q[..., : m.nope].astype(act),
+                       wkvb[..., : m.nope]),
+            q_r.astype(act),
+            jnp.zeros((B, T, m.H, m.Cpad - m.C), act)], axis=-1)  # [B, T, H, Cpad]
+    root = math.sqrt(m.nope + m.rope)
+    interpret = jax.default_backend() != "tpu"
+
+    def scaled(q):     # the softmax scale folded into a kernel's queries
+        return (q.astype(jnp.float32) / root).astype(act)
+
+    def out_of(o_lat):
+        o = jnp.einsum("bthc,chv->bthv", o_lat, wkvb[..., m.nope:])
+        return mm(p, "mla_wo", o.reshape(B, T, m.H * m.vd).astype(act), mi)
+
+    if T == 1 and kernels:
+        # flash decode over the row's own pages, each read once
+        from dynamo_tpu.ops.mla import mla_decode_attention
+
+        o_lat = mla_decode_attention(
+            scaled(q_lat[:, 0]), latent, jnp.int32(mi), tables, context_lens,
+            block_size=block_size, rank=m.rank, interpret=interpret)[:, None]
+        return out_of(o_lat), latent
+    if flash_prefill and kernels:
+        from dynamo_tpu.ops.mla import mla_prefill_attention
+
+        with jax.named_scope(attend_scope):
+            o_lat = mla_prefill_attention(
+                scaled(q_lat), latent, jnp.int32(mi), tables, positions[:, 0],
+                context_lens,
+                block_size=block_size, rank=m.rank, interpret=interpret)
+        return out_of(o_lat), latent
+    S = tables.shape[1] * block_size
+    slot_ids = (tables[:, :, None] * block_size
+                + jnp.arange(block_size, dtype=tables.dtype)).reshape(B, S)
+    rows = latent[mi, slot_ids].astype(act)                    # [B, S, Cpad]
+    key_pos = jnp.arange(S, dtype=jnp.int32)[None, None, None, :]
+
+    def attend(q_blk, pos_blk):                                # [B, t, H, C]
+        s = einsum_f32("bthc,bsc->bhts", q_blk, rows) * (1.0 / root)
+        mask = (key_pos <= pos_blk[:, None, :, None]) & (
+            key_pos < context_lens[:, None, None, None])
+        pr = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+        return jnp.einsum("bhts,bsc->bthc", pr.astype(act),
+                          rows[..., : m.rank])
+
+    with jax.named_scope(attend_scope):
+        tq = max(1, min(T, m.query_tokens // B))
+        if tq >= T or T % tq:
+            o_lat = attend(q_lat, positions)
+        else:
+            qb = jnp.moveaxis(q_lat.reshape(B, T // tq, tq, m.H, m.Cpad), 1, 0)
+            pb = jnp.moveaxis(positions.reshape(B, T // tq, tq), 1, 0)
+            o_lat = jnp.moveaxis(jax.lax.map(
+                lambda a: attend(*a), (qb, pb)), 0, 1
+            ).reshape(B, T, m.H, m.rank)
+    return out_of(o_lat.astype(act)), latent
+
+
+# ---------------------------------------------------------------------------
 # The held experts
 # ---------------------------------------------------------------------------
+
+
+def sigmoid_routing(router: jax.Array, bias: jax.Array, x: jax.Array, k: int,
+                    renormalize: bool, scale: float):
+    """x [N, D] -> (weights [N, k] float32, expert ids [N, k]) over ALL
+    experts: scores sigmoid (float32, ``router [D, E]``), chosen by score
+    + selection ``bias``, weighted by the scores themselves, renormalised
+    over the chosen, scaled."""
+    with jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(x.astype(jnp.float32) @ router)
+    _, topi = jax.lax.top_k(s + bias, k)
+    w = jnp.take_along_axis(s, topi, axis=-1)
+    if renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scale, topi
 
 
 class ExpertForm(NamedTuple):

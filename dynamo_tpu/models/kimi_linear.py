@@ -43,7 +43,6 @@ and through the plain mixed dot where not (``mla_wkva``: 576 outputs,
 
 from __future__ import annotations
 
-import math
 from typing import Any, Optional
 
 import jax
@@ -103,6 +102,8 @@ class Geometry:
         # row-major layout the step wants and back (measured: 11 ms of a
         # 29 ms decode step, PERF.md PR 29)
         self.Cpad = -(-self.C // 128) * 128
+        self.latent = hybrid.Latent(self.H, self.nope, self.rope, self.vd,
+                                    self.rank, self.Cpad, MLA_QUERY_TOKENS)
         self.F = cfg.intermediate_size
         self.Fe = cfg.moe_intermediate_size
         self.E = cfg.num_experts                # held here
@@ -323,7 +324,7 @@ def check_engine(config) -> None:
 
 # shared with the other hybrid family (models/hybrid.py)
 kernels_active = hybrid.kernels_active
-_mm, _weight, _einsum_f32 = hybrid.mm, hybrid.weight, hybrid.einsum_f32
+_mm = hybrid.mm
 _gated_mlp = hybrid.gated_mlp
 kda_decode, kda_chunked = hybrid.delta_decode, hybrid.delta_chunked
 kda_chunk_for = hybrid.delta_chunk_for
@@ -339,15 +340,10 @@ def kda_decay_log(p: Params, f: jax.Array, idx: int, g: Geometry) -> jax.Array:
 
 def moe_routing(cfg: ModelConfig, p: Params, x: jax.Array, idx: int):
     """x [N, D] -> (weights [N, k] float32, expert ids [N, k]) over ALL
-    experts: scores sigmoid, chosen by score + selection bias, weighted
-    by the scores themselves, renormalised over the chosen, scaled."""
-    with jax.default_matmul_precision("highest"):
-        s = jax.nn.sigmoid(x.astype(jnp.float32) @ p["router"][idx])
-    _, topi = jax.lax.top_k(s + p["router_bias"][idx], cfg.num_experts_per_token)
-    w = jnp.take_along_axis(s, topi, axis=-1)
-    if cfg.moe_renormalize:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return w * cfg.routed_scaling_factor, topi
+    experts (``hybrid.sigmoid_routing``)."""
+    return hybrid.sigmoid_routing(
+        p["router"][idx], p["router_bias"][idx], x, cfg.num_experts_per_token,
+        cfg.moe_renormalize, cfg.routed_scaling_factor)
 
 
 def moe_ffn(cfg: ModelConfig, g: Geometry, p: Params, h: jax.Array,
@@ -466,63 +462,9 @@ def forward(
         return out, kda_plane, conv_plane
 
     def mla_mixer(h, mi, latent):
-        q = _mm(params, "mla_wq", h, mi).astype(act).reshape(
-            B, T, g.H, g.nope + g.rope)
-        kv = _mm(params, "mla_wkva", h, mi)                    # [B, T, C]
-        c = llama.rmsnorm(kv[..., : g.rank], params["mla_kvnorm"][mi], eps)
-        lane_pad = jnp.zeros((B, T, g.Cpad - g.C), c.dtype)
-        row = jnp.concatenate([c, kv[..., g.rank:].astype(c.dtype), lane_pad], -1)
-        latent = latent.at[mi, slot_mapping].set(
-            row.reshape(B * T, g.Cpad).astype(latent.dtype))
-        wkvb = _weight(params, "mla_wkvb", mi, h.dtype).reshape(
-            g.rank, g.H, g.nope + g.vd)
-        # queries absorb the key up-projection: attention runs over the
-        # cached rows [c | k_r] themselves, one shared 576-wide "head"
-        q_lat = jnp.concatenate([
-            jnp.einsum("bthn,chn->bthc", q[..., : g.nope], wkvb[..., : g.nope]),
-            q[..., g.nope:],
-            jnp.zeros((B, T, g.H, g.Cpad - g.C), q.dtype)], axis=-1)  # [B, T, H, Cpad]
-        if T == 1 and kernels_active():
-            # flash decode over the row's own pages, each read once
-            from dynamo_tpu.ops.mla import mla_decode_attention
-
-            o_lat = mla_decode_attention(
-                (q_lat[:, 0].astype(jnp.float32)
-                 / math.sqrt(g.nope + g.rope)).astype(h.dtype),
-                latent, jnp.int32(mi), tables, context_lens,
-                block_size=block_size, rank=g.rank,
-                interpret=jax.default_backend() != "tpu")[:, None]
-            o = jnp.einsum("bthc,chv->bthv", o_lat, wkvb[..., g.nope:])
-            return (_mm(params, "mla_wo",
-                        o.reshape(B, T, g.H * g.vd).astype(act), mi), latent)
-        S = tables.shape[1] * block_size
-        slot_ids = (tables[:, :, None] * block_size
-                    + jnp.arange(block_size, dtype=tables.dtype)).reshape(B, S)
-        rows = latent[mi, slot_ids].astype(h.dtype)            # [B, S, Cpad]
-        key_pos = jnp.arange(S, dtype=jnp.int32)[None, None, None, :]
-        scale = 1.0 / math.sqrt(g.nope + g.rope)
-
-        def attend(q_blk, pos_blk):                            # [B, t, H, C]
-            s = _einsum_f32("bthc,bsc->bhts", q_blk, rows) * scale
-            mask = (key_pos <= pos_blk[:, None, :, None]) & (
-                key_pos < context_lens[:, None, None, None])
-            pr = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
-            return jnp.einsum("bhts,bsc->bthc", pr.astype(h.dtype),
-                              rows[..., : g.rank])
-
-        with jax.named_scope("mla_attend"):
-            tq = max(1, min(T, MLA_QUERY_TOKENS // B))
-            if tq >= T:
-                o_lat = attend(q_lat, positions)
-            else:
-                qb = jnp.moveaxis(q_lat.reshape(B, T // tq, tq, g.H, g.Cpad), 1, 0)
-                pb = jnp.moveaxis(positions.reshape(B, T // tq, tq), 1, 0)
-                o_lat = jnp.moveaxis(jax.lax.map(
-                    lambda a: attend(*a), (qb, pb)), 0, 1
-                ).reshape(B, T, g.H, g.rank)
-        o = jnp.einsum("bthc,chv->bthv", o_lat.astype(act), wkvb[..., g.nope:])
-        return (_mm(params, "mla_wo", o.reshape(B, T, g.H * g.vd).astype(act), mi),
-                latent)
+        return hybrid.mla_mixer(
+            params, h, mi, latent, g.latent, eps, positions, slot_mapping,
+            tables, context_lens, block_size, kernels_active())
 
     for layer in range(g.L):
         mixer, mi, ffn, fi = g.kind_index(layer)

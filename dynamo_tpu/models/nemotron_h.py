@@ -396,15 +396,10 @@ def ssd_chunked(x, dt, glog, Bm, C, S, chunk: int):
 
 def moe_routing(cfg: ModelConfig, p: Params, x: jax.Array, idx: int):
     """x [N, D] -> (weights [N, k] float32, expert ids [N, k]) over ALL
-    experts: scores sigmoid, chosen by score + correction bias, weighted
-    by the scores themselves, renormalised over the chosen, scaled."""
-    with jax.default_matmul_precision("highest"):
-        s = jax.nn.sigmoid(x.astype(jnp.float32) @ p["router"][idx])
-    _, topi = jax.lax.top_k(s + p["router_bias"][idx], cfg.num_experts_per_tok)
-    w = jnp.take_along_axis(s, topi, axis=-1)
-    if cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return w * cfg.routed_scaling_factor, topi
+    experts (``hybrid.sigmoid_routing``; the bias is the correction bias)."""
+    return hybrid.sigmoid_routing(
+        p["router"][idx], p["router_bias"][idx], x, cfg.num_experts_per_tok,
+        cfg.norm_topk_prob, cfg.routed_scaling_factor)
 
 
 def moe_ffn(cfg: ModelConfig, g: Geometry, p: Params, h: jax.Array,

@@ -162,9 +162,10 @@ def unregister_count_provider(
 
 
 def note_counts(name: str, counts: dict, monotonic_ns: int) -> None:
-    """One entry of the count history: ``counts`` are HOST-side numbers
-    only (the caller is a step loop between two steps: no device read,
-    no lock)."""
+    """One entry of the count history: ``counts`` are numbers the caller
+    has WITHOUT WAITING (a step loop between two steps: host-side counts,
+    and a family's device counts as LAST READ at a capture's edge — no
+    device read, no lock)."""
     _history.append({"monotonic_ns": monotonic_ns, "counts": {name: counts}})
 
 

@@ -403,7 +403,7 @@ def test_ssm_decode_update_compiles_for_v5e(rows, one_chip, no_compile_cache):
 
 # the pool a cell runs with where its configuration pins none: what the
 # engine's own sizing gives on a 16 GB chip (PERF.md section 4)
-_STAGE_BLOCKS = {"kimi-linear-48b": 6175}
+_STAGE_BLOCKS = {"kimi-linear-48b": 6175, "kanana-2-30b": 2048}
 
 
 def _stage(config: str):
@@ -523,3 +523,109 @@ def test_the_1x512_prefill_step_compiles_for_v5e_within_its_transients(
     mem = compiled.memory_analysis()
     fam = model_family(_stage(config)[0])
     assert mem.temp_size_in_bytes < fam.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
+
+
+# -- deepseek_v3 (Kanana-2): latent pages alone, a 16k table ------------------
+# max_model_len 16 384 + one decode step = 129 pages, padded to TABLE_BUCKET
+DS_TABLE_W = 136
+DS_POOL = _STAGE_BLOCKS["kanana-2-30b"]   # the engine's own sizing gives 2 277
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_mla_decode_attention_compiles_at_a_16k_table(rows, one_chip, no_compile_cache):
+    """The decode kernel as it stands, over 640-lane rows of a 12-layer
+    plane and a table 136 pages wide."""
+    from dynamo_tpu.ops.mla import mla_decode_attention
+
+    text = _compile_text(
+        functools.partial(mla_decode_attention, block_size=BS, rank=512),
+        _sds((rows, 32, 640), jnp.bfloat16, one_chip),
+        _sds((12, DS_POOL * BS, 640), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((rows, DS_TABLE_W), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text and "mla_decode_attention" in text
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 128), (1, 1024), (4, 1024), (8, 256)])
+def test_mla_prefill_attention_compiles_for_v5e(
+    rows, tokens, one_chip, no_compile_cache
+):
+    from dynamo_tpu.ops.mla import mla_prefill_attention
+
+    ids = _sds((rows,), jnp.int32, one_chip)
+    text = _compile_text(
+        functools.partial(mla_prefill_attention, block_size=BS, rank=512),
+        _sds((rows, tokens, 32, 640), jnp.bfloat16, one_chip),
+        _sds((12, DS_POOL * BS, 640), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((rows, DS_TABLE_W), jnp.int32, one_chip),
+        ids, ids)
+    assert "tpu_custom_call" in text and "mla_prefill_attention" in text
+
+
+def _compiled_deepseek_step(rows, T, one_chip, monkeypatch):
+    """The served step (int8 weights, bf16 latent pages, no state plane)
+    of ``rows`` x ``T`` tokens at the benchmark's configuration."""
+    from dynamo_tpu.models import deepseek_v3 as ds, hybrid
+
+    cfg, _ = _stage("kanana-2-30b")
+    monkeypatch.setattr(hybrid, "kernels_active", lambda: True)
+    monkeypatch.setattr(ds, "kernels_active", lambda: True)
+    monkeypatch.setattr(llama, "pallas_matmul_active", lambda: True)
+    monkeypatch.setattr(llama, "_qmm_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = {}
+    for name, (shape, dtype) in ds.param_shapes(cfg).items():
+        if name in ds.QUANT_AXIS:
+            params[name] = _sds(shape, jnp.int8, one_chip)
+            axis = ds.QUANT_AXIS[name] % len(shape)
+            params[name + "_scale"] = _sds(
+                shape[:axis] + shape[axis + 1:], jnp.float32, one_chip)
+        else:
+            params[name] = _sds(shape, dtype, one_chip)
+    pages = {"latent": _sds((12, DS_POOL * BS, 640), jnp.bfloat16, one_chip)}
+    counts = {"counts": _sds((len(ds.COUNT_NAMES),), jnp.int32, one_chip)}
+    ids = _sds((rows,), jnp.int32, one_chip)
+    grid = _sds((rows, T), jnp.int32, one_chip)
+
+    def step(params, pages, counts, tokens, positions, slots, tables, ctx, last):
+        return ds.forward(cfg, params, pages, counts, tokens, positions, slots,
+                          tables, ctx, last, BS)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pages, counts, grid, grid,
+        _sds((rows * T,), jnp.int32, one_chip),
+        _sds((rows, DS_TABLE_W), jnp.int32, one_chip), ids, ids).compile()
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_the_deepseek_v3_decode_step_compiles_for_v5e_within_its_transients(
+    rows, one_chip, no_compile_cache, monkeypatch
+):
+    """Twelve latent-attention decode kernels over a 136-page table, and
+    no copy of the 4 GB plane at the program's edge."""
+    from dynamo_tpu.models import deepseek_v3 as ds
+
+    compiled = _compiled_deepseek_step(rows, 1, one_chip, monkeypatch)
+    assert compiled.as_text().count("mla_decode_attention") >= 12
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < ds.STEP_TRANSIENT_BYTES // 2
+
+
+@pytest.mark.parametrize("rows,tokens", [(4, 1024), (1, 512)])
+def test_the_deepseek_v3_prefill_step_compiles_for_v5e_within_its_transients(
+    rows, tokens, one_chip, no_compile_cache, monkeypatch
+):
+    """``max_prefill_tokens`` 4 096 as 4 rows of a whole 1 024-token chunk
+    under a 136-page table: twelve flash prefill kernels, the sorted-rows
+    experts, and nothing that grows with the table's width among the
+    temporaries — they stay inside what the family reserves (the
+    described chip's compiler counts 1.86 GB at 4 x 1 024, 0.58 GB at
+    1 x 512)."""
+    from dynamo_tpu.models import deepseek_v3 as ds
+
+    compiled = _compiled_deepseek_step(rows, tokens, one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("mla_prefill_attention") >= 12 and "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < ds.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
