@@ -52,6 +52,7 @@ from dynamo_tpu.engine.scheduler import (
     StepPlan,
     mixed_rect_of,
     prefill_rectangles,
+    seq_gone,
 )
 from dynamo_tpu.models import ModelConfig, family as model_family
 from dynamo_tpu.utils import affinity, compile_fence, transfer_fence
@@ -234,6 +235,7 @@ class JaxEngine:
         self._multi_step_fn: Optional[Callable] = None
         self._mixed_step_fn: Optional[Callable] = None
         self._chain_next_fn: Optional[Callable] = None
+        self._chain_join_fn: Optional[Callable] = None
         self._pack_pair_fn: Optional[Callable] = None
         # wide mixed rectangle (rows, len), set when enabled (see
         # _initialize; scheduler._mixed_rect picks per population)
@@ -306,6 +308,12 @@ class JaxEngine:
         # single-step decode dispatches of _decode_pipeline, and those
         # of them issued with a step still in flight (program_counts)
         self._decode_dispatches = [0, 0]
+        # what that pipeline did without emptying itself, and why it
+        # emptied itself when it did (program_counts): prefill dispatches
+        # issued with a step in flight, finishes no step in flight held a
+        # row of, and drains by reason
+        self._inline = {"prefill_dispatches_inline": 0, "finishes_inline": 0}
+        self._pipeline_drains = dict.fromkeys(self.DRAIN_REASONS, 0)
         # prefill dispatches: the prompt tokens their chunks held, and
         # rows x tokens of the rectangles they ran (program_counts)
         self._prefill_tokens = [0, 0]
@@ -1189,6 +1197,18 @@ class JaxEngine:
                 for b_to in decode_buckets:
                     if b_to != b_from:
                         self._chain_next_fn(tok, np.zeros((b_to,), np.int32))
+            # the pipeline's in-line admission: a prefill batch's packed
+            # harvest, and its sampled column joined with the host's
+            # tokens into each decode bucket — two small programs a row
+            # count of the rectangles and one a (row count, bucket) pair;
+            # the steps themselves are the ones warmed above
+            for b, (nt, lp) in p_outs.items():
+                jax.block_until_ready(self._pack_pair_fn(nt, lp))
+                for Bd in decode_buckets:
+                    self._chain_join_fn(
+                        nt, np.zeros((Bd, 1), np.int32),
+                        np.zeros((Bd,), np.int32),
+                    )
         if self._multi_step_fn is not None and self._overlap_ok():
             # cohort-graduation glue (the window pipeline's prefill-only
             # entry): packed prefill harvest + first-token chain from
@@ -1964,6 +1984,19 @@ class JaxEngine:
                 jnp.take(next_tokens, src_idx)[:, None], ns_rep2
             )
 
+        def chain_join(column, host_tokens, src_idx):
+            """Next decode step's [B', 1] token column where the newest
+            dispatch is a prefill batch (the decode pipeline's in-line
+            admission): a row whose last chunk that was takes its first
+            token from the batch's sampled ``column`` [P] on the device,
+            every other row (``src_idx`` -1) the token the host already
+            holds in ``host_tokens`` [B', 1]."""
+            took = jnp.take(column, jnp.maximum(src_idx, 0))
+            return jax.lax.with_sharding_constraint(
+                jnp.where(src_idx >= 0, took, host_tokens[:, 0])[:, None],
+                ns_rep2,
+            )
+
         def pack_pair(next_tokens, logprobs):
             """One packed [2B] host transfer for a single-step
             dispatch's outputs (token ids exact in f32: vocab < 2^24) —
@@ -2052,6 +2085,7 @@ class JaxEngine:
         # overlapped-pipeline glue (both K regimes): on-device token
         # chaining off a single-step/prefill dispatch + packed harvest
         self._chain_next_fn = jax.jit(chain_next)
+        self._chain_join_fn = jax.jit(chain_join)
         self._pack_pair_fn = jax.jit(pack_pair)
 
     def _stage_step_inputs(
@@ -2754,11 +2788,7 @@ class JaxEngine:
             plan.kind = kind = "prefill"  # no fused window: prefill this step
         seqs = plan.decode_seqs
         if kind == "decode" and seqs:
-            if (
-                self._drafter is not None
-                and not self.spec_suspended
-                and not self._spec_divert(seqs)
-            ):
+            if self._would_speculate(seqs):
                 # overlapped speculative decode (the tentpole of
                 # docs/speculative_decoding.md's pipelined section):
                 # host drafting for step N+1 runs WHILE the device
@@ -2960,6 +2990,17 @@ class JaxEngine:
         return (
             self._drafter is not None
             and getattr(seq.request, "speculative", None) is not False
+        )
+
+    def _would_speculate(self, seqs: list) -> bool:
+        """Does ``_route`` send a decode batch of ``seqs`` down a
+        speculative path now? Asked again by the plain decode pipeline
+        before every dispatch, since it outlives the plan that chose
+        it."""
+        return (
+            self._drafter is not None
+            and not self.spec_suspended
+            and not self._spec_divert(seqs)
         )
 
     def _spec_divert(self, seqs: list) -> bool:
@@ -3225,9 +3266,7 @@ class JaxEngine:
     def _seq_dead(seq: Sequence) -> bool:
         """Late-detected stop: cancellation or deadline expiry observed
         after a step that includes the row went in flight."""
-        if seq.is_cancelled and seq.is_cancelled():
-            return True
-        return bool(seq.deadline) and time.monotonic() >= seq.deadline
+        return seq_gone(seq, time.monotonic())
 
     def _spec_predraft(self, works: list) -> list:
         """Optimistic pre-draft for the NEXT verify step, computed
@@ -3609,6 +3648,12 @@ class JaxEngine:
             or any(s.guided_state is not None for s in seqs)
         )
 
+    # why _decode_pipeline emptied itself (``pipeline_drains.<reason>``)
+    DRAIN_REASONS = (
+        "unpredicted_finish", "admission", "blocks", "irregular", "control",
+        "speculation",
+    )
+
     def _decode_pipeline(self, seqs: list, plan_ms: float = 0.0) -> None:
         """Double-buffered single-step decode — the decode_steps == 1
         serving path restructured so the device never waits out the
@@ -3625,22 +3670,46 @@ class JaxEngine:
         - scheduler state (token appends, stop checks, block frees,
           prefix-cache commits) runs ONE STEP BEHIND dispatch.
           ``plan_pipelined_decode`` predicts every ``should_finish``
-          condition a step ahead so an in-flight step never writes KV
-          into blocks a harvest-time ``finish()`` frees; a token
-          sampled past a late-detected stop (cancellation, deadline,
-          backend stop-string) is DISCARDED at harvest — never
-          appended, never emitted, never content-addressed — and the
-          pipeline flushes so ``plan()`` reaps with nothing in flight;
-        - the pipeline NEVER preempts and never admits: block pressure
-          drains it back to the serial planner, and so does a waiting
-          queue — but only while that planner could act on it
-          (``Scheduler.admission_work``). A queue whose head the last
-          ``_admit`` could not place moves at the next finish, which
-          flushes the pipeline anyway: a saturated server keeps
-          pipelining.
+          condition a step ahead, so a row that finishes that way is a
+          row of no step in flight when the harvest's ``finish()``
+          frees its pages and its state slot: the pipeline GOES ON
+          (``finishes_inline``). A finish no step in flight foresaw
+          (EOS, a stop string) leaves the row in one; a token sampled
+          past a late-detected stop (cancellation, deadline) is
+          DISCARDED at harvest — never appended, never emitted, never
+          content-addressed. Both flush the pipeline, so ``plan()``
+          reaps with nothing in flight;
+        - admission and prefill run IN LINE. Where the scheduler has
+          work of that kind (``Scheduler.admission_work``: not a queue
+          whose head the last ``_admit`` could not place — a saturated
+          server keeps decoding), ``plan_pipelined_admission`` reaps
+          the queue, admits and chooses the chunks as ``plan()`` would,
+          and the rectangle is dispatched unsynced BEHIND the step in
+          flight, an entry of kind ``prefill`` like any other; chunks
+          go first, as in ``plan()``. The decode step after a prompt's
+          last chunk is planned over the survivors plus that row, its
+          first token joined on the device from the prefill's sampled
+          column (``chain_join``; every other row's token is by then
+          the host's). The entry's harvest emits the first token and
+          moves the row to ``running``. The device runs the programs
+          the serial loop would, in its order; only the host-made gaps
+          between them go;
+        - the pipeline NEVER preempts. What still empties it, each on
+          something observed (``pipeline_drains.<reason>``): a finish
+          or stop it did not foresee (``unpredicted_finish``); no page
+          for the next step (``blocks``: the serial planner preempts
+          with nothing in flight); a prefill batch that needs another
+          compiled variant — multimodal embeddings, penalties, logit
+          bias, top-logprobs, a guided mask (``irregular``); an
+          admission that copies pages in from an offload tier
+          (``admission``); a control call, shutdown or graceful drain
+          (``control``); a batch that ``_route`` would now send down a
+          speculative path — a drafter is configured and the row that
+          opted out has left the batch, or ``spec_suspended`` went back
+          (``speculation``).
 
         Greedy output is bit-identical to the serial loop (same step
-        program over the same values); sampled output draws the
+        programs over the same values); sampled output draws the
         identical seed stream (seeds offset by the in-flight lag).
         """
         sched = self.scheduler
@@ -3650,27 +3719,37 @@ class JaxEngine:
         from dynamo_tpu.parallel.multihost import host_value
 
         lag: dict[int, int] = {}
+        # the decode population as of the newest dispatch: the newest
+        # decode step's rows, then each row whose last chunk a prefill
+        # dispatched since was
+        rows = seqs
 
-        def _dead(seq) -> bool:
-            if seq.is_cancelled and seq.is_cancelled():
-                return True
-            return bool(seq.deadline) and time.monotonic() >= seq.deadline
-
-        def dispatch(seqs_, arrays, sampling, p_ms: float) -> dict:
+        def dispatch(
+            kind: str, seqs_, works, arrays, sampling, p_ms: float
+        ) -> dict:
             t0 = time.monotonic()
-            self._decode_dispatches[0] += 1
-            with self._dispatch_span("decode", arrays["tokens"]) as phase:
+            if kind == "decode":
+                self._decode_dispatches[0] += 1
+            with self._dispatch_span(kind, arrays["tokens"]) as phase:
                 outs = self._dispatch_device_step(
-                    arrays, sampling, origin="decode-pipeline"
+                    arrays, sampling,
+                    origin="decode-pipeline" if kind == "decode" else
+                    "prefill:" + ",".join(w.seq.request_id for w in works),
                 )
                 packed = self._pack_pair_fn(outs[0], outs[1])
             self._last_phases["dispatch_ms"] = phase.ms
             return {
+                "kind": kind,
                 "packed": packed,
                 "toks": outs[0],  # device column the next step chains off
                 "seqs": seqs_,
+                "works": works,
                 "b": arrays["context_lens"].shape[0],
-                "vmap": {id(s): 1 for s in seqs_},
+                # the one token a decode row, or a prompt's last chunk,
+                # adds (_lag_add)
+                "vmap": {id(s): 1 for s in seqs_} | {
+                    id(w.seq): 1 for w in works if w.is_last_chunk
+                },
                 "t_disp": t0,
                 "plan_ms": p_ms,
                 # consumed here, not by _record_step's use_phases: at
@@ -3678,7 +3757,36 @@ class JaxEngine:
                 "phases": dict(self._last_phases),
             }
 
-        def try_extend() -> bool:
+        def dispatch_prefill(works, plan_ns: int) -> dict:
+            with step_span("dyn.step.pack") as packing:
+                arrays = sched.build_prefill_batch_arrays(works)
+                sampling = self._batch_sampling(
+                    [w.seq for w in works], arrays["tokens"].shape[0]
+                )
+            # a failure from here on is laid at these prompts' door
+            # (_quarantine_step_failure), until their entry is harvested
+            self._last_plan = StepPlan(
+                kind="prefill", prefill_batch=works, decode_seqs=rows
+            )
+            self._count_prefill(arrays)
+            self._inline["prefill_dispatches_inline"] += 1
+            e = dispatch(
+                "prefill", [], works, arrays, sampling,
+                round((plan_ns + packing.last_ns) / 1e6, 3),
+            )
+            for w in works:
+                if not w.is_last_chunk:
+                    # as the serial loop does right after its unsynced
+                    # dispatch: the prompt's next chunk is planned from it
+                    sched.complete_prefill_chunk(w)
+            return e
+
+        def try_extend() -> str:
+            """Plan and dispatch one more program behind the newest in
+            flight. "" when it did; else why not: a drain reason, or
+            "wait" / "done" (Scheduler.plan_pipelined_decode), which
+            empty nothing."""
+            nonlocal rows
             # each extension is one logical engine step: the fault point
             # (docs/robustness.md) must see it, or a whole decode inside
             # one _one_step call would evade per-step fault plans. Fired
@@ -3688,34 +3796,66 @@ class JaxEngine:
             # step bit-identically (KV slots rewritten with same values)
             faults.fire("engine.step")
             newest = pending[-1]
+            works = None
             with step_span("dyn.step.plan") as planning:
                 self._drain_incoming_only()
+                if self._would_speculate(rows):
+                    # the opted-out row ended, or speculation is no
+                    # longer suspended: this batch is _route's to send
+                    # down the spec path, not this pipeline's to keep
+                    return "speculation"
                 if sched.admission_work():
-                    return False  # drain: the serial planner admits/prefills
-                nxt = sched.plan_pipelined_decode(newest["seqs"], lag)
-            if nxt is None:
-                return False
-            with step_span("dyn.step.pack") as packing:
-                arrays = nxt["arrays"]
-                arrays["tokens"] = self._chain_next_fn(
-                    newest["toks"], nxt["src_idx"]
+                    works, why = sched.plan_pipelined_admission(lag)
+                    if works is None:
+                        return why
+                    batch = [w.seq for w in works]
+                    if self._overlap_divert(batch) or any(
+                        s.mm_segments for s in batch
+                    ):
+                        # a separately compiled variant, whose chained
+                        # signatures nobody warmed: the serial loop's
+                        return "irregular"
+                if not works:
+                    column = None if newest["kind"] == "decode" else {
+                        id(w.seq): r for r, w in enumerate(newest["works"])
+                        if w.is_last_chunk
+                    }
+                    nxt, why = sched.plan_pipelined_decode(rows, lag, column)
+                    if nxt is None:
+                        return why
+            if works:
+                e = dispatch_prefill(works, planning.last_ns)
+                rows = rows + [w.seq for w in works if w.is_last_chunk]
+            else:
+                with step_span("dyn.step.pack") as packing:
+                    arrays = nxt["arrays"]
+                    if column is None:
+                        arrays["tokens"] = self._chain_next_fn(
+                            newest["toks"], nxt["src_idx"]
+                        )
+                    else:
+                        arrays["tokens"] = self._chain_join_fn(
+                            newest["toks"], arrays["tokens"], nxt["src_idx"]
+                        )
+                    sampling = self._batch_sampling(
+                        nxt["seqs"],
+                        arrays["context_lens"].shape[0],
+                        offset=nxt["offsets"],
+                    )
+                self._decode_dispatches[1] += 1
+                # the exposed host span before this dispatch: plan + pack
+                e = dispatch(
+                    "decode", nxt["seqs"], (), arrays, sampling,
+                    round((planning.last_ns + packing.last_ns) / 1e6, 3),
                 )
-                sampling = self._batch_sampling(
-                    nxt["seqs"],
-                    arrays["context_lens"].shape[0],
-                    offset=nxt["offsets"],
-                )
-            # the exposed host span before this dispatch: plan + pack
-            e = dispatch(
-                nxt["seqs"], arrays, sampling,
-                round((planning.last_ns + packing.last_ns) / 1e6, 3),
-            )
-            self._decode_dispatches[1] += 1
+                rows = nxt["seqs"]
             _lag_add(lag, e)
             pending.append(e)
-            return True
+            return ""
 
         def harvest(e, depth: int) -> bool:
+            """Apply one entry's tokens. True where that ended a row of
+            a step still in flight, or found a row dead: flush."""
             t0 = time.monotonic()
             with step_span("dyn.step.harvest") as harvesting:
                 packed_h = host_value(e["packed"])
@@ -3723,34 +3863,54 @@ class JaxEngine:
             self._unsynced_steps.clear()
             sync_ms = harvesting.ms
             B = e["b"]
-            finished = False
+            kind = e["kind"]
+            ended: list = []
+            late = False
             with step_span("dyn.step.emit"):
                 toks = packed_h[:B].astype(np.int32)
                 lps = packed_h[B : 2 * B]
+                for i, work in enumerate(e["works"]):
+                    seq = work.seq
+                    if not work.is_last_chunk or seq.state != SeqState.PREFILL:
+                        continue
+                    sched.complete_prefill_chunk(work)
+                    self._emit_token(seq, int(toks[i]), float(lps[i]))
+                    if seq.state != SeqState.RUNNING:
+                        ended.append(seq)
                 for i, seq in enumerate(e["seqs"]):
                     if seq.state != SeqState.RUNNING:
                         continue
-                    if _dead(seq):
+                    if self._seq_dead(seq):
                         # late-detected stop: DISCARD the in-flight token
                         # — nothing appended means nothing emitted and
                         # nothing the prefix cache could ever
                         # content-address
-                        finished = True
+                        late = True
                         continue
                     self._emit_token(seq, int(toks[i]), float(lps[i]))
                     if seq.state != SeqState.RUNNING:
-                        finished = True
+                        ended.append(seq)
                 _lag_sub(lag, e)
                 # the step's device outputs are freed HERE, under a phase
                 # (tens of microseconds a step), not when this frame ends
                 e["packed"] = e["toks"] = None
+                if kind == "prefill":
+                    self._last_plan = StepPlan(kind="decode", decode_seqs=rows)
+                held = ended and {id(s) for p in pending for s in p["seqs"]}
+                foreseen = not late and not any(id(s) in held for s in ended)
+                if foreseen and pending:
+                    # freed with steps in flight, none of which holds a
+                    # row of them: what plan_pipelined_decode left out
+                    self._inline["finishes_inline"] += len(ended)
             dt = time.monotonic() - e["t_disp"]
+            n_rows = len(e["seqs"]) or len(e["works"])
             with step_span("dyn.step.record"):
-                ENGINE_STEP_SECONDS.labels("decode").observe(dt)
+                ENGINE_STEP_SECONDS.labels(kind).observe(dt)
                 self._record_step(
-                    "decode", dt,
-                    batch=len(e["seqs"]),
-                    tokens=len(e["seqs"]),
+                    kind, dt,
+                    batch=n_rows,
+                    prefill_rows=len(e["works"]),
+                    tokens=len(e["vmap"]),
                     overlapped=True,
                     use_phases=False,  # per-entry stamps below
                     plan_ms=e["plan_ms"],
@@ -3761,37 +3921,41 @@ class JaxEngine:
                     overlap_ms=round((t0 - e["t_disp"]) * 1e3, 3),
                     **e["phases"],
                 )
-            return finished
+            return not foreseen
 
         with step_span("dyn.step.pack"):
             arrays = sched.build_decode_arrays(seqs)
             sampling = self._batch_sampling(seqs, arrays["tokens"].shape[0])
-        entry = dispatch(seqs, arrays, sampling, plan_ms)
+        entry = dispatch("decode", seqs, (), arrays, sampling, plan_ms)
         _lag_add(lag, entry)
         pending = deque([entry])
+        drain = ""  # why the pipeline stopped growing, once it has
         while pending:
-            # extend BEFORE harvesting: nothing has been freed since the
-            # last harvest, so planning here never touches blocks an
-            # in-flight step writes. _running/_control: shutdown and
-            # engine-thread calls flush rather than starve.
-            while (
-                len(pending) < self.PIPELINE_DEPTH
-                and self._running
-                and not self._draining
-                and self._control.empty()
-            ):
-                if not try_extend():
+            # extend BEFORE harvesting. What the last harvest freed was
+            # a row's of no step in flight (or the pipeline is flushing),
+            # so planning here never hands out a page or a state slot an
+            # in-flight step writes. _running/_draining/_control:
+            # shutdown and engine-thread calls flush rather than starve.
+            while not drain and len(pending) < self.PIPELINE_DEPTH:
+                if not (
+                    self._running
+                    and not self._draining
+                    and self._control.empty()
+                ):
+                    drain = "control"
                     break
-            finished = harvest(pending.popleft(), depth=len(pending) + 1)
-            if finished and pending:
-                # a finish freed blocks (or a stop was detected) with a
-                # step in flight: predicted finishes were already
-                # excluded from it, and no allocation can occur until
-                # the pipeline drains — flush so plan()/admission and
-                # the reap run with nothing in flight
-                while pending:
-                    harvest(pending.popleft(), depth=len(pending))
-                return
+                why = try_extend()
+                if why:
+                    # "wait" / "done": nothing to plan until a harvest
+                    drain = why if why in self.DRAIN_REASONS else ""
+                    break
+            if harvest(pending.popleft(), depth=len(pending) + 1) and pending:
+                # a stop nobody foresaw, with a step in flight that
+                # holds the row: flush, so that plan() reaps and admits
+                # with nothing in flight
+                drain = drain or "unpredicted_finish"
+        if drain:
+            self._pipeline_drains[drain] += 1
 
     def _batch_sampling(
         self, seqs: list, B: int, offset=0
@@ -5074,6 +5238,8 @@ class JaxEngine:
                 admit_reserve_sum_pages=sched.admit_reserve_sum_pages,
                 decode_dispatches=self._decode_dispatches[0],
                 decode_dispatches_chained=self._decode_dispatches[1],
+                **self._inline,
+                pipeline_drains=dict(self._pipeline_drains),
                 prefill_tokens_real=self._prefill_tokens[0],
                 prefill_tokens_padded=self._prefill_tokens[1],
             )
@@ -5255,6 +5421,11 @@ class JaxEngine:
             # step in flight: the share says how often it overlaps
             "decode_dispatches": self._decode_dispatches[0],
             "decode_dispatches_chained": self._decode_dispatches[1],
+            # what it did in line (prefill dispatches behind a step in
+            # flight, finishes no step in flight held a row of), and
+            # why it emptied itself when it did
+            **self._inline,
+            "pipeline_drains": dict(self._pipeline_drains),
         }
         # serve-phase compile fence (DYN_COMPILE_FENCE): mode + lifetime
         # escalation count, so `top`//debug/state show whether a fenced

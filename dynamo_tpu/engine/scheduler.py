@@ -24,7 +24,7 @@ import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, Container, NamedTuple, Optional
 
 import numpy as np
 
@@ -213,6 +213,19 @@ def _area(rect: Optional[tuple[int, int]]) -> int:
     return rect[0] * rect[1] if rect else 0
 
 
+def _cancelled(seq: "Sequence") -> bool:
+    return bool(seq.is_cancelled and seq.is_cancelled())
+
+
+def _expired(seq: "Sequence", now: float) -> bool:
+    return bool(seq.deadline) and now >= seq.deadline
+
+
+def seq_gone(seq: "Sequence", now: float) -> bool:
+    """Cancelled, or past its deadline at ``now`` (monotonic)."""
+    return _cancelled(seq) or _expired(seq, now)
+
+
 class _TimelineRow(NamedTuple):
     """A row of the page timeline (Scheduler._growth_reserve)."""
 
@@ -368,18 +381,22 @@ class Scheduler:
         return bool(self.waiting or self.prefilling or self.running)
 
     def admission_work(self) -> bool:
-        """Is there admission or prefill work the serial planner could
-        do now? The overlapped decode pipelines (engine._decode_pipeline
-        and _spec_pipeline) never admit, and drain back to plan() when
-        this says yes — not merely when somebody waits: _admit looks at
-        waiting[0] only, and a head it could not place stays unplaceable
-        while the pipeline runs, because nothing is freed inside it
-        (every finish, late stop or cancellation flushes it first) and
-        decode growth only takes pages. So a saturated server, whose
-        queue only a finish can move, keeps pipelining; arrivals queue
-        behind the blocked head and change nothing. A cancelled or
-        expired waiting request still counts as work: plan() reaps it
-        within a step."""
+        """Is there admission or prefill work a planner could do now?
+        The overlapped decode pipeline (engine._decode_pipeline) asks
+        before every dispatch and, on yes, admits and prefills IN LINE
+        (``plan_pipelined_admission``); the spec pipeline, which never
+        admits, drains back to plan() instead. Yes is not merely
+        "somebody waits": _admit looks at waiting[0] only, and a head
+        it could not place stays unplaceable until something is freed
+        — a finish or a preemption, both of which forget it
+        (``_blocked_head``), inside the pipeline or out of it; decode
+        growth only takes pages. So a saturated server, whose queue
+        only a finish can move, keeps pipelining; arrivals queue
+        behind the blocked head and change nothing. A prefilling
+        sequence counts as work even with its last chunk in flight
+        (the in-line planner then finds nothing to plan and says so),
+        and so does a cancelled or expired waiting request: either
+        planner reaps it within a step."""
         if self.prefilling:
             return True
         if not self.waiting:
@@ -387,11 +404,7 @@ class Scheduler:
         if self.waiting[0] is not self._blocked_head:
             return True
         now = time.monotonic()
-        return any(
-            (seq.is_cancelled and seq.is_cancelled())
-            or (bool(seq.deadline) and now >= seq.deadline)
-            for seq in self.waiting
-        )
+        return any(seq_gone(seq, now) for seq in self.waiting)
 
     # -- planning ---------------------------------------------------------
     def plan(self) -> StepPlan:
@@ -496,21 +509,20 @@ class Scheduler:
             return self.mixed_prefill_wide_rows, self.mixed_prefill_wide_len
         return self.mixed_prefill_rows, self.mixed_prefill_len
 
-    def _reap_cancelled(self) -> None:
+    def _reap_cancelled(self, queued_only: bool = False) -> None:
         """Remove cancelled AND deadline-expired sequences from every
         pool. finish() frees their KV blocks, so an expired request
-        costs nothing past the step that notices it."""
+        costs nothing past the step that notices it. ``queued_only``:
+        from ``waiting`` alone, whose sequences hold nothing a step in
+        flight could write (plan_pipelined_admission)."""
         now = time.monotonic()
-
-        def _expired(seq: Sequence) -> bool:
-            return bool(seq.deadline) and now >= seq.deadline
-
-        for pool, stage in ((self.waiting, "queue"), (self.prefilling, "prefill")):
+        pools = ((self.waiting, "queue"), (self.prefilling, "prefill"))
+        for pool, stage in pools[:1] if queued_only else pools:
             for seq in list(pool):
-                if seq.is_cancelled and seq.is_cancelled():
+                if _cancelled(seq):
                     pool.remove(seq)
                     self.finish(seq, FinishReason.CANCELLED)
-                elif _expired(seq):
+                elif _expired(seq, now):
                     pool.remove(seq)
                     DEADLINE_EXPIRED.labels(stage).inc()
                     log.warning(
@@ -518,11 +530,13 @@ class Scheduler:
                         seq.request_id, stage,
                     )
                     self.finish(seq, FinishReason.TIMEOUT)
+        if queued_only:
+            return
         for seq in list(self.running):
-            if seq.is_cancelled and seq.is_cancelled():
+            if _cancelled(seq):
                 self.running.remove(seq)
                 self.finish(seq, FinishReason.CANCELLED)
-            elif _expired(seq):
+            elif _expired(seq, now):
                 self.running.remove(seq)
                 DEADLINE_EXPIRED.labels("decode").inc()
                 log.warning(
@@ -752,13 +766,15 @@ class Scheduler:
         budget: Optional[int] = None,
         max_seqs: Optional[int] = None,
         max_chunk_len: Optional[int] = None,
+        skip: Container[int] = (),
     ) -> list[PrefillWork]:
         """One chunk from each of several prefilling sequences, fused
         into a single step (total tokens bounded by max_prefill_tokens)
         — continuous batching's batched-prefill half. ``max_chunk_len``
         additionally caps each row's chunk (the mixed-step rectangle,
         which the caller sized to hold ``max_seqs`` such rows: none is
-        turned away for its area)."""
+        turned away for its area). ``skip``: ids of sequences to pass
+        over (their last chunk is in flight, plan_pipelined_admission)."""
         budget = budget if budget is not None else self.max_prefill_tokens
         max_seqs = max_seqs if max_seqs is not None else self.max_batch_size
         works: list[PrefillWork] = []
@@ -767,6 +783,8 @@ class Scheduler:
         for seq in self.prefilling:
             if len(works) >= max_seqs:
                 break
+            if id(seq) in skip:
+                continue
             prompt = seq.tokens.all_tokens()
             start = seq.num_computed
             remaining = len(prompt) - start
@@ -868,42 +886,102 @@ class Scheduler:
                 safe.append(seq)
         return safe
 
+    def plan_pipelined_admission(
+        self, lag: dict
+    ) -> tuple[Optional[list[PrefillWork]], str]:
+        """What ``plan()`` does before a prefill step — reap, admit,
+        choose the chunks, with the same arguments and so the same
+        priority of prefill chunks over decode — while the decode
+        pipeline has steps in flight (engine._decode_pipeline calls it
+        where ``admission_work()`` says yes). Returns ``(works, "")``,
+        the chunks of the next prefill dispatch: ``[]`` when there is
+        nothing to prefill now (the head cannot be placed, or every
+        prefilling sequence's last chunk is in flight: those are in
+        ``lag``, one token each); or ``(None, why)`` to drain the
+        pipeline: ``"unpredicted_finish"`` — a sequence in
+        prefill is cancelled or past its deadline, and only ``plan()``,
+        with nothing in flight, may free pages a dispatched chunk
+        writes; ``"admission"`` — pages come in from another tier
+        (``onboard``) by a copy into the cache that is not the
+        pipeline's to order, and that tier is pumped between two plans.
+
+        Why admitting here is safe: ``_admit`` only takes from the free
+        pool and from pages a running row pins, and it never preempts.
+        A page is free because nobody writes it: a row that finished in
+        line is a row of no step in flight (the engine flushes for every
+        other finish before it comes back here), and a new row's prefill
+        is dispatched after every step in flight, on a device that runs
+        its programs in order. ``_growth_reserve`` reads rows up to
+        ``lag`` tokens behind the pages they hold; its ``slack``
+        (``dispatches_ahead``) is what covers that, so it asks for at
+        most a page a row more than it needs and never less."""
+        now = time.monotonic()
+        if any(seq_gone(seq, now) for seq in self.prefilling):
+            return None, "unpredicted_finish"
+        if self.onboard is not None:
+            return None, "admission"
+        self._reap_cancelled(queued_only=True)
+        self._admit()
+        return self._plan_prefill_batch(skip=lag), ""
+
     def plan_pipelined_decode(
-        self, seqs: list[Sequence], lag: dict
-    ) -> Optional[dict]:
-        """Plan the NEXT single-token decode step while one is in
+        self, seqs: list[Sequence], lag: dict,
+        column: Optional[dict[int, int]] = None,
+    ) -> tuple[Optional[dict], str]:
+        """Plan the NEXT single-token decode step while steps are in
         flight (the decode_steps == 1 overlapped pipeline,
         engine._decode_pipeline / docs/performance.md).
 
+        ``seqs`` is the decode population as of the newest dispatch;
         ``lag`` maps id(seq) -> tokens sampled by in-flight steps but
-        not yet applied to host state (one per step here). Sequences
-        that FINISH inside the in-flight lag — max_tokens reached,
-        max_model_len hit, or block-table cap — are simply not rows of
-        the next step, mirroring ``should_finish`` one step ahead so a
-        predicted finish never leaves an in-flight step writing KV into
-        blocks a harvest-time ``finish()`` just freed. Returns None
-        (flush the pipeline) on anything irregular: cancellation,
-        deadline expiry, a non-RUNNING state, or block exhaustion —
-        this path NEVER preempts (a preemption would free blocks an
-        in-flight step still writes); the outer serial plan() handles
-        pressure with nothing in flight.
+        not yet applied to host state (one per decode step, and one for
+        a prompt whose last chunk is in flight). ``column`` maps
+        id(seq) -> the row's index in the NEWEST dispatch's sampled
+        token column, from which the engine gathers its input token on
+        the device; by default the newest dispatch is a decode step
+        over ``seqs`` in their order. A row the column does not hold
+        must be one the host is level with (no lag): its token goes in
+        from the host.
 
-        Returns {"seqs", "arrays", "src_idx", "offsets", "vmap"}: the
-        next step's rows, its decode arrays (the token column is a
-        placeholder — the engine chains it on device from the in-flight
-        step's sampled tokens via ``src_idx``), per-row seed offsets
-        (= lags), and the one token each row will add.
+        Sequences that FINISH inside the in-flight lag — max_tokens
+        reached, max_model_len hit, or block-table cap — are simply not
+        rows of the next step, mirroring ``should_finish`` one step
+        ahead so a predicted finish never leaves an in-flight step
+        writing KV into blocks a harvest-time ``finish()`` just freed;
+        one that already has finished that way, with nothing of its own
+        in flight, is passed over. Returns ``(None, why)`` on anything
+        else: ``"unpredicted_finish"`` (a
+        cancellation, a deadline, a row in a state no step in flight
+        foresaw), ``"blocks"`` (pool exhausted — this path NEVER
+        preempts: a preemption would free blocks an in-flight step
+        still writes; the outer serial plan() handles pressure with
+        nothing in flight), ``"wait"`` (a lagging row's token is not in
+        the newest column: plan again after the next harvest) or
+        ``"done"`` (no row is left).
+
+        Else returns the plan and ``""``. The plan is {"seqs", "arrays",
+        "src_idx", "offsets", "vmap"}: the next step's rows, its decode
+        arrays (the token column holds the host's tokens — the engine
+        overrides it on device from the column via ``src_idx``, -1 where
+        the host's token stands), per-row seed offsets (= lags), and the
+        one token each row will add.
         """
         now = time.monotonic()
+        if column is None:
+            column = {id(s): j for j, s in enumerate(seqs)}
         survivors: list[Sequence] = []
         for seq in seqs:
-            if seq.state != SeqState.RUNNING:
-                return None
-            if seq.is_cancelled and seq.is_cancelled():
-                return None
-            if bool(seq.deadline) and now >= seq.deadline:
-                return None
             gl = lag.get(id(seq), 0)
+            if seq.state == SeqState.FINISHED and not gl:
+                continue  # finished in line, at an earlier harvest
+            if seq.state != SeqState.RUNNING and not (
+                seq.state == SeqState.PREFILL and gl and id(seq) in column
+            ):
+                return None, "unpredicted_finish"
+            if seq_gone(seq, now):
+                return None, "unpredicted_finish"
+            if gl and id(seq) not in column:
+                return None, "wait"
             if (
                 seq.max_new_tokens is not None
                 and seq.max_new_tokens - seq.generated <= gl
@@ -915,7 +993,7 @@ class Scheduler:
                 continue  # should_finish's can't-grow-further clause
             survivors.append(seq)
         if not survivors:
-            return None
+            return None, "done"
         bs = self.block_size
         # block growth for the next step's KV write (the in-flight
         # token's slot) — no preemption; rollback on exhaustion
@@ -937,12 +1015,12 @@ class Scheduler:
         if not ok:
             for seq in reversed(added):
                 self.allocator.free_sequence([seq.block_table.pop()])
-            return None
-        old_row = {id(s): j for j, s in enumerate(seqs)}
+            return None, "blocks"
         n = len(survivors)
         B = self._decode_batch(n)
         max_blocks = max(len(s.block_table) for s in survivors)
         width = self._table_width(max_blocks)
+        tokens = np.zeros((B, 1), np.int32)
         positions = np.zeros((B, 1), np.int32)
         slot_mapping = np.zeros((B,), np.int32)
         tables = np.zeros((B, width), np.int32)
@@ -952,7 +1030,9 @@ class Scheduler:
         vmap: dict[int, int] = {}
         for i, s in enumerate(survivors):
             gl = lag.get(id(s), 0)
-            src_idx[i] = old_row[id(s)]
+            src_idx[i] = column.get(id(s), -1)
+            if src_idx[i] < 0:
+                tokens[i, 0] = s.tokens.tail_tokens(1)[0]
             pos = s.total_len - 1 + gl
             positions[i, 0] = pos
             slot_mapping[i] = s.block_table[pos // bs] * bs + pos % bs
@@ -961,7 +1041,7 @@ class Scheduler:
             offsets[i] = gl
             vmap[id(s)] = 1
         arrays = {
-            "tokens": np.zeros((B, 1), np.int32),  # device chain overrides
+            "tokens": tokens,
             "positions": positions,
             "slot_mapping": slot_mapping,
             "block_tables": tables,
@@ -974,7 +1054,7 @@ class Scheduler:
             "src_idx": src_idx,
             "offsets": offsets,
             "vmap": vmap,
-        }
+        }, ""
 
     def plan_pipelined_mixed(
         self, seqs: list[Sequence], works: list[PrefillWork], lag: dict,
@@ -1010,22 +1090,16 @@ class Scheduler:
         if self.waiting:
             self._admit()
         now = time.monotonic()
-
-        def _dead(seq: Sequence) -> bool:
-            if seq.is_cancelled and seq.is_cancelled():
-                return True
-            return bool(seq.deadline) and now >= seq.deadline
-
         for w in works:
             if not w.is_last_chunk:
                 return None
-            if _dead(w.seq):
+            if seq_gone(w.seq, now):
                 return None
         survivors: list[Sequence] = []
         for seq in seqs:
             if seq.state != SeqState.RUNNING:
                 return None
-            if _dead(seq):
+            if seq_gone(seq, now):
                 return None
             if (
                 seq.max_new_tokens is not None
@@ -1294,9 +1368,7 @@ class Scheduler:
         for row, (seq, gl, drafts) in enumerate(entries):
             if seq.state != SeqState.RUNNING:
                 return None
-            if seq.is_cancelled and seq.is_cancelled():
-                return None
-            if bool(seq.deadline) and now >= seq.deadline:
+            if seq_gone(seq, now):
                 return None
             if (
                 seq.max_new_tokens is not None

@@ -495,7 +495,7 @@ def test_pipelined_planner_lag_is_inside_the_slack(depth):
             for ahead in range(1, depth):
                 rows = list(sched.running)
                 lag = {id(s): ahead for s in rows}
-                nxt = sched.plan_pipelined_decode(rows, lag)
+                nxt, _ = sched.plan_pipelined_decode(rows, lag)
                 assert nxt is not None or all(
                     s.max_new_tokens - s.generated <= ahead for s in rows
                 )
@@ -575,6 +575,52 @@ def test_vectorised_reserve_equals_the_plain_loop(
         assert sched._growth_reserve(rows, shared) == _reserve_by_loop(
             sched, rows, shared
         )
+
+
+# ---------------------------------------------------------------------------
+# read with steps in flight (the decode pipeline's in-line admission)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_reserve_read_with_steps_in_flight_never_asks_less(depth):
+    """``_admit`` inside the decode pipeline sees rows up to ``depth - 1``
+    tokens behind the pages they hold (sampled, not yet applied), beside
+    a candidate it is level with. What it then keeps free is never less
+    than what the same population needs once those tokens are applied
+    and nothing is in flight but the step being planned (one dispatch
+    ahead) — ``slack`` is what covers the lag — and at most a page a row
+    more."""
+    bs = 16
+    piped = _sched(Scheduler, 64, bs, depth=depth)
+    level = _sched(Scheduler, 64, bs, depth=0)  # dispatches_ahead == 1
+    rng = random.Random(depth)
+    over = []
+    for _ in range(1500):
+        applied, seen = [], []
+        for _ in range(rng.randint(1, 12)):
+            lag = rng.randint(0, depth - 1)
+            left = rng.randint(lag + 1, 200)
+            length = rng.randint(lag + 1, 300)
+            # the planner took the pages of its in-flight tokens' slots
+            held = -(-(length + 1) // bs) + rng.randint(0, 1)
+            alone = rng.randint(0, held)
+            applied.append(
+                _TimelineRow(length, left, length + left, held, alone, 0))
+            seen.append(_TimelineRow(
+                length - lag, left + lag, length + left, held, alone, 0))
+        prompt, answer = rng.randint(1, 300), rng.randint(1, 200)
+        pages = -(-prompt // bs)
+        cand = _TimelineRow(
+            prompt, answer, prompt + answer, pages, rng.randint(0, pages),
+            prompt)
+        shared = rng.randint(0, 2)
+        asked = piped._growth_reserve(seen + [cand], shared)
+        needed = level._growth_reserve(applied + [cand], shared)
+        assert asked[0] >= needed[0] and asked[1] == needed[1]
+        over.append(asked[0] - needed[0])
+        assert over[-1] <= len(seen) + 1
+    assert max(over) > 0  # the bound is a bound, not an identity
 
 
 # ---------------------------------------------------------------------------

@@ -485,10 +485,11 @@ async def test_blocked_request_leaves_the_queue_within_a_step(how):
         await eng.shutdown()
 
 
-async def test_arrival_into_an_empty_queue_drains_the_pipeline():
+async def test_arrival_into_an_empty_queue_is_admitted_before_the_next_dispatch():
     """Nobody has tried to place a request that arrives into an empty
-    queue: the pipeline drains for it before the next dispatch, and it is
-    admitted at once — exactly as before the blocked-queue rule."""
+    queue: it is admitted before another program is dispatched — by the
+    pipeline itself, in line (ISSUE 43; until then the pipeline drained
+    for it) — and its tokens are the serial loop's."""
     from dynamo_tpu.engine.engine import JaxEngine
 
     async def run(overlap):
@@ -510,3 +511,471 @@ async def test_arrival_into_an_empty_queue_drains_the_pipeline():
     serial0, serial1, _ = await run(False)
     assert (over0, over1) == (serial0, serial1)
     assert log["admit"]["r1"] == log["intake"]["r1"] < log["finish"]["r0"][0]
+
+
+# ---------------------------------------------------------------------------
+# The pipeline admits and finishes in line (ISSUE 43)
+# ---------------------------------------------------------------------------
+
+
+def _inline_counts(eng) -> dict:
+    c = eng.program_counts()
+    return {
+        "unchained": c["decode_dispatches"] - c["decode_dispatches_chained"],
+        "prefill_inline": c["prefill_dispatches_inline"],
+        "prefill": c["steps"].get("prefill", 0),
+        "finishes_inline": c["finishes_inline"],
+        "drains": dict(c["pipeline_drains"]),
+        "preemptions": c["preemptions"],
+    }
+
+
+async def _launch_family(family: str, overlap: bool, **kw):
+    """A tiny engine of ``family``: the dense llama (``dense``; with
+    ``prefix`` its clients share a two-page prompt head, so admissions hit
+    the prefix cache) or kimi with its state plane (``slots``: three
+    slots, so every admission after the third takes one a finish gave
+    back)."""
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    if family == "slots":
+        from tests.test_kimi_linear_engine import launch
+
+        eng, _ = await launch(max_batch_size=3, overlap=overlap, **kw)
+        return eng
+    return await JaxEngine.launch(_engine_config(overlap=overlap, **kw))
+
+
+async def _closed_loop(eng, family: str, clients=3, requests=4,
+                       temperature=None):
+    """``clients`` closed-loop clients, each sending its next request as
+    soon as the last one ended — by ``max_tokens``, as the benchmark's
+    closed cells do. Returns every stream's tokens, in order."""
+    head = list(range(40, 56)) if family == "prefix" else []
+
+    async def client(c: int) -> list:
+        outs = []
+        for k in range(requests):
+            n = 5 + (7 * c + 3 * k) % 14
+            prompt = head + [(11 * c + 5 * k + j) % 90 + 1 for j in range(n)]
+            toks, _ = await _generate(
+                eng, prompt, max_tokens=4 + (c + 2 * k) % 9,
+                request_id=f"c{c}-{k}", temperature=temperature, seed=7 + c,
+            )
+            outs.append(toks)
+        return outs
+
+    return await asyncio.gather(*[client(c) for c in range(clients)])
+
+
+@pytest.mark.parametrize("family", ["dense", "slots", "prefix"])
+async def test_closed_loop_in_line_matches_the_serial_loop(family):
+    """Finishes by ``max_tokens`` with an arrival after each: the
+    pipeline takes both in line (it is entered once, and hardly ever
+    again), and every stream's tokens are the serial loop's — on the
+    dense path (sampled too: a graduated row's seed offset is its lag),
+    with a state slot that a finish gave back and the next admission
+    took, and with admissions that hit the prefix cache."""
+    slots_taken: list[int] = []
+
+    async def run(overlap):
+        eng = await _launch_family(family, overlap)
+        try:
+            sched = eng.scheduler
+            if family == "slots":
+                acquire = sched.state_slots.acquire
+
+                def spy():
+                    slots_taken.append(acquire())
+                    return slots_taken[-1]
+
+                sched.state_slots.acquire = spy
+            outs = [await _closed_loop(eng, family)]
+            if family == "dense":
+                outs.append(await _closed_loop(eng, family, temperature=0.8))
+            return outs, _inline_counts(eng), sched.prefix_hits
+        finally:
+            await eng.shutdown()
+
+    over, counts, hits = await run(True)
+    n_slots = len(slots_taken)
+    serial, serial_counts, _ = await run(False)
+    assert over == serial
+    assert serial_counts["prefill_inline"] == serial_counts["finishes_inline"] == 0
+    # nearly every admission went in behind a step in flight, and nearly
+    # every finish left the pipeline running: what is not in line is the
+    # way in (the first prefill on an idle engine) and the way out (the
+    # last rows' finish, with nothing left in flight)
+    # (how nearly is a matter of timing: whenever every client is
+    # between two requests at once, the pipeline runs out of rows)
+    assert counts["prefill_inline"] * 2 >= counts["prefill"] > 0
+    assert counts["finishes_inline"] >= 4
+    # no way back in but behind a prefill on an engine with no row left
+    assert counts["unchained"] <= counts["prefill"] - counts["prefill_inline"]
+    assert not any(counts["drains"].values()), counts["drains"]
+    assert counts["preemptions"] == 0
+    if family == "slots":
+        # three slots, twelve admissions: each after the third took a
+        # slot that a finish had just given back, with steps in flight
+        assert n_slots == 12 and set(slots_taken[:n_slots]) == {1, 2, 3}
+    if family == "prefix":
+        assert hits >= 6
+
+
+async def test_predicted_finish_leaves_the_pipeline_running():
+    """A row that ends by ``max_tokens`` beside one that goes on: its
+    pages are freed at the harvest (``finishes_inline``), and the
+    pipeline is neither flushed nor entered again — every later decode
+    dispatch is chained onto a step in flight."""
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    eng = await JaxEngine.launch(_engine_config(overlap=True))
+    try:
+        free0 = eng.allocator.num_free
+        long_ = asyncio.ensure_future(
+            _generate(eng, _LONG[0], max_tokens=60, request_id="long"))
+        short, _ = await _generate(eng, _LONG[1], max_tokens=6,
+                                   request_id="short")
+        # (no engine-thread call here: a control call is a drain)
+        await eng.wait_for_state(lambda e: e._inline["finishes_inline"] == 1)
+        running_then = [s.request_id for s in eng.scheduler.running]
+        free_then = eng.allocator.num_free
+        assert len(short) == 6 and len((await long_)[0]) == 60
+        counts = _inline_counts(eng)
+    finally:
+        await eng.shutdown()
+    # the short row's pages came back while the long one decoded on
+    assert running_then == ["long"] and free_then > free0 - 11
+    assert counts["finishes_inline"] == 1
+    assert counts["unchained"] == 1  # one way in, and no way back in
+    assert not any(counts["drains"].values())
+
+
+@pytest.mark.parametrize("how", ["stop_token", "cancelled", "deadline"])
+async def test_unforeseen_stop_still_flushes(how):
+    """A stop the planner could not foresee — the backend's stop-token
+    (EOS) detection, a cancellation, a deadline — ends a row of a step
+    in flight: the pipeline flushes (``pipeline_drains.unpredicted_finish``),
+    the serial planner reaps, the other row's tokens are untouched."""
+    import time
+
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    async def run(overlap, eos=None):
+        eng = await JaxEngine.launch(_engine_config(overlap=overlap))
+        try:
+            other = asyncio.ensure_future(
+                _generate(eng, _LONG[0], max_tokens=40, request_id="other"))
+            ctx = Context()
+            req = PreprocessedRequest(
+                request_id="stopped", token_ids=_LONG[1],
+                sampling=SamplingOptions(use_greedy=True),
+                stop=StopConditions(max_tokens=64, ignore_eos=eos is None),
+            )
+            stream = eng.as_async_engine().generate(req, ctx)
+            if how == "stop_token" and eos is not None:
+                from dynamo_tpu.backend import Backend
+                from dynamo_tpu.tokenizer import Tokenizer
+
+                backend = Backend(Tokenizer.from_file(MODEL_DIR),
+                                  eos_token_ids=[eos])
+                _, state = await backend.forward(req, ctx)
+                stream = backend.backward(stream, state, ctx)
+            got = []
+            async for item in stream:
+                got.extend(item.token_ids)
+                if len(got) < 3 or item.is_final:
+                    continue
+                if how == "cancelled":
+                    ctx.stop_generating()
+                    break
+                for seq in list(eng.scheduler.running):
+                    if how == "deadline" and seq.request_id == "stopped":
+                        seq.deadline = time.monotonic() - 1.0
+            await eng.wait_for_state(
+                lambda e: all(s.request_id != "stopped"
+                              for s in e.scheduler.running))
+            return got, (await other)[0], _inline_counts(eng)
+        finally:
+            await eng.shutdown()
+
+    eos = None
+    if how == "stop_token":
+        # the fourth greedy token of the stream becomes the model's EOS
+        eos = (await run(False))[0][3]
+    got, other, counts = await run(True, eos)
+    _, serial_other, _ = await run(False, eos)
+    assert other == serial_other and len(other) == 40
+    assert 3 <= len(got) < 64
+    assert counts["drains"]["unpredicted_finish"] >= 1
+    assert counts["finishes_inline"] == 0
+
+
+@pytest.mark.parametrize("how", ["opted_out_row_ends", "suspension_lifts"])
+async def test_pipeline_gives_the_batch_back_once_it_could_speculate(how):
+    """The plain pipeline outlives the plan that chose it. With a drafter
+    configured, ``_route`` sends a batch here only while a row of it opted
+    out of speculation, or while speculation is suspended; when the
+    opted-out row has ended (by ``max_tokens``: in line, no flush) or the
+    suspension lifts, the pipeline empties itself
+    (``pipeline_drains.speculation``) and the next plan goes down the
+    spec path — it does not wait for an unrelated drain."""
+    from dynamo_tpu.engine.engine import JaxEngine
+    from dynamo_tpu.planner.degradation import ServingDegradation
+
+    prompt = [1, 2, 3, 4, 5, 6, 1, 2, 3, 4, 5, 6, 1, 2, 3]  # drafts hit
+
+    async def generate(eng, rid, prompt_ids, max_tokens, speculative=None,
+                       at_token=None):
+        req = PreprocessedRequest(
+            request_id=rid, token_ids=list(prompt_ids),
+            sampling=SamplingOptions(use_greedy=True),
+            stop=StopConditions(max_tokens=max_tokens, ignore_eos=True),
+            speculative=speculative,
+        )
+        out = []
+        async for item in eng.as_async_engine().generate(req, Context()):
+            out.extend(item.token_ids)
+            if at_token and len(out) >= at_token[0]:
+                at_token[1]()
+                at_token = None
+        return out
+
+    eng = await JaxEngine.launch(
+        _engine_config(overlap=True, spec_decode="ngram", spec_tokens=4))
+    try:
+        if how == "opted_out_row_ends":
+            plain = asyncio.ensure_future(
+                generate(eng, "plain", _LONG[1], 6, speculative=False))
+            await eng.wait_for_state(
+                lambda e: [s.request_id for s in e.scheduler.running]
+                == ["plain"])
+            spec = asyncio.ensure_future(generate(eng, "spec", prompt, 60))
+            assert len(await plain) == 6
+            steps_then = eng.spec_pipeline_steps
+        else:
+            rungs = ServingDegradation(engine=eng)
+            rungs.set_level(2)
+            steps_then = 0
+            spec = asyncio.ensure_future(generate(
+                eng, "spec", prompt, 60,
+                at_token=(5, lambda: rungs.set_level(0))))
+        got = await spec
+        counts = _inline_counts(eng)
+        assert eng.spec_pipeline_steps > steps_then
+    finally:
+        await eng.shutdown()
+    assert len(got) == 60
+    assert counts["drains"]["speculation"] == 1
+    if how == "opted_out_row_ends":
+        assert counts["finishes_inline"] == 1
+    assert not counts["drains"]["unpredicted_finish"]
+
+
+def _bare_scheduler(pages: int, **kw):
+    from dynamo_tpu.engine.allocator import BlockAllocator
+    from dynamo_tpu.engine.scheduler import Scheduler
+
+    return Scheduler(BlockAllocator(pages + 1, 8), 8, max_batch_size=4,
+                     prefill_chunk_size=32, **kw)
+
+
+def _bare_seq(rid: str, n_prompt: int, max_tokens: int):
+    from tests.test_admission_timeline import _seq
+
+    return _seq(range(1, n_prompt + 1), 8, max_tokens, rid)
+
+
+def _prefill_all(sched):
+    """Serial prefill steps until every placeable prompt has its first
+    token (5)."""
+    while (plan := sched.plan()).kind == "prefill":
+        for work in plan.prefill_batch:
+            sched.complete_prefill_chunk(work)
+            if work.is_last_chunk:
+                sched.append_token(work.seq, 5)
+
+
+def test_in_line_planners_drain_on_exhaustion_and_never_preempt():
+    """No page for the next step: ``plan_pipelined_decode`` gives the
+    pages it took back and refuses (``blocks``), and the in-line
+    admission leaves a head it cannot place where it is — neither
+    preempts, whatever is in flight."""
+    sched = _bare_scheduler(pages=16)
+    a, b = _bare_seq("a", 15, 40), _bare_seq("b", 15, 40)
+    sched.add_request(a)
+    sched.add_request(b)
+    _prefill_all(sched)
+    assert sched.running == [a, b]
+    for _ in range(7):
+        sched.append_token(a, 7)
+        sched.append_token(b, 7)
+    while sched.allocator.num_free > 1:  # somebody else's pages
+        sched.allocator.allocate_block()
+    # both rows hold three pages and sit at 23 tokens: the step after
+    # the one in flight writes position 24, on a fourth page each — and
+    # one page is free
+    lag = {id(a): 1, id(b): 1}
+    tables = [list(a.block_table), list(b.block_table)]
+    assert [len(t) for t in tables] == [3, 3]
+    assert sched.plan_pipelined_decode([a, b], lag) == (None, "blocks")
+    assert [a.block_table, b.block_table] == tables
+    assert sched.allocator.num_free == 1  # what a took was given back
+    c = _bare_seq("c", 9, 8)
+    sched.add_request(c)
+    assert sched.admission_work()
+    assert sched.plan_pipelined_admission(lag) == ([], "")
+    assert list(sched.waiting) == [c] and not sched.admission_work()
+    assert sched.preemptions == 0 and sched.running == [a, b]
+
+
+def test_in_line_admission_takes_what_a_predicted_finish_freed():
+    """The planner's own sequence of events, without an engine: row a
+    is left out of the step planned behind its last one (``lag`` says
+    it ends there); its finish at the harvest frees its pages with that
+    step in flight; the head that was blocked is admitted by the next
+    in-line plan, its chunk planned, and once that is dispatched the
+    following decode step holds b from the host and c from the prefill's
+    column."""
+    from dynamo_tpu.engine.scheduler import SeqState
+
+    sched = _bare_scheduler(pages=8)
+    a, b = _bare_seq("a", 15, 2), _bare_seq("b", 15, 40)
+    sched.add_request(a)
+    sched.add_request(b)
+    _prefill_all(sched)
+    c = _bare_seq("c", 20, 8)
+    sched.add_request(c)
+    sched.plan()  # c cannot be placed: the reserve keeps b's growth
+    assert not sched.admission_work() and sched.admit_blocked_reserve == 1
+    lag = {id(a): 1, id(b): 1}  # step N in flight over [a, b]
+    nxt, _ = sched.plan_pipelined_decode([a, b], lag)  # step N+1
+    assert nxt["seqs"] == [b] and nxt["src_idx"][0] == 1
+    sched.append_token(a, 7)  # harvest of N: a reaches max_tokens
+    sched.append_token(b, 7)
+    sched.finish(a, sched.should_finish(a))
+    lag = {id(b): 1}  # N+1 in flight
+    assert sched.admission_work()  # the finish forgot the blocked head
+    works, _ = sched.plan_pipelined_admission(lag)
+    assert [w.seq for w in works] == [c] and works[0].is_last_chunk
+    assert c.state == SeqState.PREFILL and sched.preemptions == 0
+    # P dispatched behind N+1, N+1 harvested: b is level with the host
+    sched.append_token(b, 9)
+    lag = {id(c): 1}
+    # c's chunk is in flight
+    assert sched.plan_pipelined_admission(lag) == ([], "")
+    nxt, _ = sched.plan_pipelined_decode([a, b, c], lag, {id(c): 0})
+    assert nxt["seqs"] == [b, c]
+    assert nxt["src_idx"][:2].tolist() == [-1, 0]
+    assert nxt["arrays"]["tokens"][0, 0] == 9
+    assert nxt["arrays"]["context_lens"][:2].tolist() == [18, 21]
+    assert nxt["offsets"] == [0, 1]
+    # a row that lags and is not in the newest column has to wait
+    assert sched.plan_pipelined_decode(
+        [b, c], {id(b): 1, id(c): 1}, {id(c): 0}
+    ) == (None, "wait")
+
+
+async def test_multi_chunk_prompt_runs_its_chunks_in_order_in_line():
+    """A prompt of three chunks that arrives while another row decodes:
+    its chunks are dispatched back to back, in order, each behind a step
+    in flight, and the decode step after the last holds both rows — the
+    serial loop's order, and its tokens."""
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    prompt = [(3 * j) % 90 + 1 for j in range(80)]  # chunks of 32, 32, 16
+
+    async def run(overlap):
+        eng = await JaxEngine.launch(_engine_config(overlap=overlap))
+        try:
+            dispatched = []
+            inner = eng._dispatch_device_step
+
+            def spy(arrays, sampling, **kw):
+                ctx = arrays["context_lens"]
+                dispatched.append((
+                    "prefill" if arrays["tokens"].shape[1] > 1 else "decode",
+                    int(ctx.max()), int((ctx > 0).sum()),
+                ))
+                return inner(arrays, sampling, **kw)
+
+            eng._dispatch_device_step = spy
+            first = asyncio.ensure_future(
+                _generate(eng, _LONG[0], max_tokens=50, request_id="r0"))
+            await eng.wait_for_state(
+                lambda e: e.overlap.steps_dispatched >= 6
+                and (not overlap or e._decode_dispatches[1] >= 4))
+            late, _ = await _generate(eng, prompt, max_tokens=6,
+                                      request_id="r1")
+            return (await first)[0], late, dispatched, _inline_counts(eng)
+        finally:
+            await eng.shutdown()
+
+    over0, over1, order, counts = await run(True)
+    serial0, serial1, serial_order, _ = await run(False)
+    assert (over0, over1) == (serial0, serial1)
+    for seen in (order, serial_order):
+        at = seen.index(("prefill", 32, 1))
+        # the chunks end at 32, 64 and 80 tokens of context, nothing
+        # between them, and the next step decodes two rows
+        assert [s[:2] for s in seen[at:at + 3]] == [
+            ("prefill", 32), ("prefill", 64), ("prefill", 80)]
+        assert seen[at + 3][0] == "decode" and seen[at + 3][2] == 2
+    assert counts["prefill_inline"] == 3 and counts["unchained"] == 1
+    assert not any(counts["drains"].values())
+
+
+@pytest.fixture
+def fatal_fence():
+    from dynamo_tpu.utils import compile_fence
+
+    compile_fence.set_mode("fatal")
+    compile_fence.reset()
+    yield compile_fence
+    compile_fence.set_mode(None)
+    compile_fence.reset()
+
+
+async def test_in_line_admission_compiles_nothing_after_prewarm(
+    tmp_path, fatal_fence
+):
+    """Under the FATAL compile fence, after prewarm: an in-line admission
+    at each decode bucket (4 and 8 rows here), of one prompt (the
+    single-row rectangles) and of a burst (the eight-row ones) — the
+    packed harvest of a prefill batch and the join of its column into
+    each bucket were warmed beside the chain gathers."""
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    eng = await JaxEngine.launch(_engine_config(
+        overlap=True, prewarm=True, max_batch_size=8, num_blocks=128,
+        flight_dump_dir=str(tmp_path),
+    ))
+    try:
+        assert fatal_fence.stats()["events_total"] == 0  # prewarm sanctioned
+        assert sorted({eng.scheduler.decode_batch_small,
+                       eng.scheduler.decode_batch_pad}) == [4, 8]
+        streams = [asyncio.ensure_future(_generate(
+            eng, _LONG[i], max_tokens=70, request_id=f"l{i}")) for i in (0, 1)]
+        await eng.wait_for_state(lambda e: e._decode_dispatches[1] >= 3)
+        # one arrival into the 4-row bucket, then a burst that fills it
+        # and goes on into the 8-row one, then one arrival there
+        for wave in ([20], [9, 14, 30], [12]):
+            before = eng._inline["prefill_dispatches_inline"]
+            streams += [asyncio.ensure_future(_generate(
+                eng, list(range(3, 3 + n)), max_tokens=40,
+                request_id=f"w{n}")) for n in wave]
+            await eng.wait_for_state(
+                lambda e: e._inline["prefill_dispatches_inline"] > before
+                and not e.scheduler.waiting and not e.scheduler.prefilling
+                and e._decode_dispatches[1] >= 3)
+        outs = await asyncio.gather(*streams)
+        assert [len(o[0]) for o in outs] == [70, 70, 40, 40, 40, 40, 40]
+        recs = [r for r in eng.recorder.snapshot(512)
+                if r["kind"] == "serve_compile"]
+        assert recs == [], recs
+        assert fatal_fence.stats()["events_total"] == 0
+        counts = _inline_counts(eng)
+        assert counts["prefill_inline"] >= 3
+        assert not any(counts["drains"].values())
+    finally:
+        await eng.shutdown()

@@ -457,3 +457,83 @@ async def test_the_span_file_carries_the_history_and_the_captures_end(
     assert len(later) >= 2
     assert len(again["history"]) > len(doc["history"]) \
         or len(again["history"]) == tdebug.HISTORY_LEN
+
+
+# ---------------------------------------------------------------------------
+# the decode pipeline's in-line admissions and finishes (ISSUE 43)
+# ---------------------------------------------------------------------------
+INLINE_COUNTS = ("prefill_dispatches_inline", "finishes_inline")
+
+
+async def test_in_line_counts_show_everywhere_and_the_phases_still_tile(
+        tmp_path, monkeypatch):
+    """Arrivals that the pipeline prefills behind a step in flight, and
+    finishes it does not flush for: the three counts are in
+    ``/debug/state``, in the count history and at a capture's edges;
+    the in-line ``prefill`` dispatches are the clock's ``dispatches.prefill``
+    / ``period_ns.prefill`` like any other; and the phases still tile
+    the loop's wall."""
+    import jax
+
+    monkeypatch.setattr(tspans, "HISTORY_TICK_NS", 40_000_000)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, *a, **kw: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    engine = await _launch("overlapped-decode")
+    name = engine._debug_name
+
+    async def tick() -> dict:
+        await _until_idle(engine)
+        await engine.acall_on_thread(
+            lambda: engine.step_clock.tick(time.monotonic_ns()))
+        return engine.step_clock.counts()
+
+    try:
+        began = time.monotonic_ns()
+        seen = [await tick()]
+        for r in range(3):
+            chained = engine._decode_dispatches[1]
+            long_ = asyncio.ensure_future(_gen(
+                engine, PROMPTS[0], max_tokens=48, request_id=f"long{r}"))
+            await engine.wait_for_state(
+                lambda e: e._decode_dispatches[1] >= chained + 3)
+            for k in (1, 2):  # each arrives, and ends, while long_ decodes
+                await _gen(engine, PROMPTS[k], max_tokens=5,
+                           request_id=f"short{r}-{k}")
+            await long_
+            seen.append(await tick())
+        out = await tdebug.capture_profile(60, str(tmp_path / "cap"))
+        with open(os.path.join(out["trace_dir"],
+                               tdebug.PROGRAM_SPANS_FILE)) as f:
+            doc = json.load(f)
+        state = engine.debug_state()
+        counts = engine.program_counts()
+    finally:
+        await engine.shutdown()
+    # six arrivals behind a step in flight, six finishes beside a row
+    # that went on; nothing emptied the pipeline but the ticks above
+    assert counts["prefill_dispatches_inline"] == 6
+    assert counts["finishes_inline"] == 6
+    assert set(counts["pipeline_drains"]) == {
+        "unpredicted_finish", "admission", "blocks", "irregular", "control",
+        "speculation"}
+    assert sum(counts["pipeline_drains"].values()) == 0
+    for key in (*INLINE_COUNTS, "pipeline_drains"):
+        assert state["overlap"][key] == counts[key]
+        for edge in ("start", "stop"):
+            assert doc[edge]["counts"][name][key] == counts[key]
+    mine = [e["counts"][name] for e in doc["history"]
+            if name in e["counts"] and e["monotonic_ns"] >= began]
+    assert len(mine) >= 4
+    for key in INLINE_COUNTS:
+        grown = [c[key] for c in mine]
+        assert grown == sorted(grown) and grown[-1] == counts[key]
+    assert all(sum(c["pipeline_drains"].values()) == 0 for c in mine)
+    # an in-line prefill is a ``prefill`` dispatch of the one clock
+    after = seen[-1]
+    assert after["dispatches"] == counts["steps"]
+    assert after["dispatches"]["prefill"] == 9  # three ways in, six in line
+    assert after["period_ns"]["prefill"] > 0
+    shares = [(b["unphased_ns"] - a["unphased_ns"])
+              / (b["loop_wall_ns"] - a["loop_wall_ns"])
+              for a, b in zip(seen, seen[1:])]
+    assert 0 <= min(shares) < 0.03, shares
