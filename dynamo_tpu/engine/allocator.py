@@ -66,6 +66,107 @@ class StateSlots:
         self._free.append(slot)
 
 
+class WindowPlane:
+    """The SECOND page plane of a model whose window layers attend only
+    the last ``window`` keys (``models/__init__.py``: the third question
+    asked of a family): its own block ids, its own free list, and a
+    table a row (``Sequence.window_table``) indexed by the SAME absolute
+    column as the row's full-plane table — column ``c`` holds positions
+    ``c * block_size ...`` — in which a column behind the window reads 0.
+    Both attention kernels walk only a row's live pages under a window
+    (``ops/paged_attention.py``), so a dead column is never dereferenced;
+    the table's bytes are 4 a column a row (512 B at a 128-page table: a
+    ring of the live columns would save those and cost every reader a
+    modulus).
+
+    A page goes back once every key in it is older than ``p - (window -
+    1)`` for the row's NEXT query position ``p`` (``release_behind``,
+    called as the host's view of the row advances: after a prefill
+    chunk, after an appended token). That is safe with steps in flight:
+    a dispatch carries its own snapshot of the table, a page is only
+    handed to a row by a LATER dispatch, and the device runs its
+    programs in order. Block 0 is the garbage block, as in the full
+    plane. Nothing here is content-addressed: a released plane has no
+    prefix to reuse."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int):
+        if num_blocks < 2:
+            raise ValueError("need at least 2 blocks (block 0 is reserved)")
+        if window < 1:
+            raise ValueError(f"window {window} < 1")
+        self.num_blocks = num_blocks
+        self.block_size = block_size
+        self.window = window
+        self._free = list(range(num_blocks - 1, 0, -1))
+        # pages handed back behind a live row's window (not a finished
+        # or preempted row's whole table)
+        self.released_total = 0
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_used(self) -> int:
+        return self.num_blocks - 1 - len(self._free)
+
+    def first_live(self, next_query: int) -> int:
+        """The first column a query at position ``next_query`` (or any
+        later one) can still read."""
+        return max(0, next_query - (self.window - 1)) // self.block_size
+
+    @staticmethod
+    def pages_spanned(window: int, block_size: int, tokens: int) -> int:
+        """The most pages the keys of ``tokens`` consecutive queries
+        touch under ``window``: a run of ``window - 1 + tokens``
+        positions, wherever it starts."""
+        run = window - 1 + max(1, tokens)
+        return (run - 2) // block_size + 2 if run > 1 else 1
+
+    def span_pages(self, tokens: int) -> int:
+        """``pages_spanned`` at this plane's window and page size."""
+        return self.pages_spanned(self.window, self.block_size, tokens)
+
+    def release_behind(self, table: list[int], next_query: int) -> int:
+        """Hand back the row's pages behind the window of its next
+        query; returns how many."""
+        # the columns a row holds are one run (``cover`` fills every hole
+        # from the first live column up, this takes from the run's left),
+        # so the walk goes down from the window's edge and ends at the
+        # first column already given back: a token's worth, not a row's
+        n = 0
+        col = min(self.first_live(next_query), len(table)) - 1
+        while col >= 0 and table[col]:
+            self._free.append(table[col])
+            table[col] = 0
+            n += 1
+            col -= 1
+        self.released_total += n
+        return n
+
+    def cover(self, table: list[int], cols: int, next_query: int) -> None:
+        """Make the row hold columns ``first_live(next_query) ... cols -
+        1``. Raises NoBlocksError (the scheduler's admission reserve is
+        what keeps that from happening) with nothing taken."""
+        lo = min(self.first_live(next_query), cols)
+        table.extend([0] * (cols - len(table)))
+        missing = [c for c in range(lo, cols) if not table[c]]
+        if len(missing) > len(self._free):
+            raise NoBlocksError(
+                f"window plane: need {len(missing)} pages, have "
+                f"{len(self._free)}")
+        for c in missing:
+            table[c] = self._free.pop()
+
+    def free_row(self, table: list[int]) -> None:
+        """A finished, cancelled or preempted row's pages."""
+        self._free.extend(b for b in table if b)
+        table.clear()
+
+    def held(self, table: list[int]) -> int:
+        return len(table) - table.count(0)
+
+
 class BlockAllocator:
     def __init__(
         self,

@@ -35,7 +35,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu import faults
-from dynamo_tpu.engine.allocator import BlockAllocator, StateSlots
+from dynamo_tpu.engine.allocator import (
+    BlockAllocator,
+    StateSlots,
+    WindowPlane,
+)
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.kvbm import BlockLayout, KvbmConfig, KvBlockManager
 from dynamo_tpu.ops.block_copy import gather_blocks, scatter_blocks
@@ -305,6 +309,10 @@ class JaxEngine:
         # models with recurrent state: state slots held, summed over the
         # programs dispatched, and slots there were (program_counts)
         self._state_slot_steps = [0, 0]
+        # models with a released window plane: its pages in use, and the
+        # rows admitted, each summed over the programs dispatched
+        # (program_counts: their ratio is the window pages a row)
+        self._window_page_steps = [0, 0]
         # single-step decode dispatches of _decode_pipeline, and those
         # of them issued with a step still in flight (program_counts)
         self._decode_dispatches = [0, 0]
@@ -636,6 +644,20 @@ class JaxEngine:
             )
         stateful = self.model_config.has_recurrent_state
         cache_kw = {"state_slots": self._state_slot_count} if stateful else {}
+        window = self.model_config.released_window
+        if window:
+            cache_kw["window_blocks"] = self._window_plane_blocks
+            # each plane's bytes as the family counts its pages (what
+            # _auto_num_blocks sized the pool with), for /debug/state
+            fam = model_family(self.model_config)
+            itemsize = jnp.dtype(cfg.kv_cache_dtype).itemsize
+            planes = (("full", num_blocks),
+                      ("window", self._window_plane_blocks))
+            self._page_plane_bytes = {
+                plane: blocks * fam.page_bytes_per_block(
+                    self.model_config, cfg.block_size, itemsize, plane=plane)
+                for plane, blocks in planes
+            }
         self.k_cache, self.v_cache = model_family(self.model_config).init_cache(
             self.model_config,
             num_blocks,
@@ -653,7 +675,10 @@ class JaxEngine:
             # boundaries: every admission is a counted miss, recomputed.
             # A family that owns its pages and keeps NO such state
             # (latent rows alone) is served from them like any other
-            enable_prefix_caching=cfg.enable_prefix_caching and not stateful,
+            # nor is one worth anything without the released plane's last
+            # pages (a family whose window layers free behind the window)
+            enable_prefix_caching=cfg.enable_prefix_caching
+            and not stateful and not window,
             on_event=self._on_kv_event,
         )
         self.scheduler = Scheduler(
@@ -671,6 +696,10 @@ class JaxEngine:
             self.scheduler.spec_tokens = cfg.spec_tokens
         if stateful:
             self.scheduler.state_slots = StateSlots(self._state_slot_count)
+        if window:
+            self.scheduler.window_plane = WindowPlane(
+                self._window_plane_blocks, cfg.block_size, window
+            )
         if cfg.static_shapes:
             # one compiled decode/mixed shape: pad the decode batch to
             # max_batch_size and the table width to the max_model_len
@@ -940,6 +969,32 @@ class JaxEngine:
         sequence that can be admitted, and the garbage slot 0."""
         return self.config.max_batch_size + 1
 
+    @property
+    def _window_plane_blocks(self) -> int:
+        """Pages of the window plane of a model whose window layers
+        release behind the window (allocator.WindowPlane): what
+        ``max_batch_size`` decoding rows hold at their bound — the
+        window's pages and the look-ahead of the dispatches in flight —
+        and beside it one prefill batch's tokens and the longest chunk's
+        span, and the garbage block 0. No knob: from the batch size,
+        the chunk size, the window and the page size alone; admission
+        (Scheduler._window_admits) keeps every admitted row's bound
+        free, so a smaller plane would admit fewer rows and never run
+        out."""
+        cfg = self.config
+        span = functools.partial(
+            WindowPlane.pages_spanned, self.model_config.released_window,
+            cfg.block_size)
+        ahead = 1 + (self.PIPELINE_DEPTH + 1) * max(1, cfg.decode_steps)
+        return (
+            1
+            + cfg.max_batch_size * span(ahead)
+            + -(-min(cfg.max_prefill_tokens or cfg.prefill_chunk_size,
+                     cfg.max_batch_size * cfg.prefill_chunk_size)
+                // cfg.block_size)
+            + span(cfg.prefill_chunk_size)
+        )
+
     def _verify_qmatmul_compiles(self) -> None:
         """Hand the chip's compiler every qmatmul shape the step
         functions can reach, with the tiling ``default_tiles`` gives it
@@ -1007,9 +1062,9 @@ class JaxEngine:
         sched = self.scheduler
         assert sched is not None
         t0 = time.monotonic()
-        width = (
+        width = sched.table_width_of(
             sched.table_width_pad or sched.TABLE_BUCKET
-        ) + sched.table_extra
+        )
 
         def sampling_for(
             n: int, penalties: bool = False, toplp: bool = False,
@@ -1469,9 +1524,9 @@ class JaxEngine:
                 jax.block_until_ready(self.k_cache)
         if self._spec_step_fn is not None:
             Ssp = self.config.spec_tokens + 1
-            width = (
+            width = sched.table_width_of(
                 sched.table_width_pad or sched.TABLE_BUCKET
-            ) + sched.table_extra
+            )
             for Bd in decode_buckets:
                 sa = {
                     "tokens": np.zeros((Bd, Ssp), np.int32),
@@ -1614,6 +1669,14 @@ class JaxEngine:
             if mc.has_recurrent_state:
                 reserved += fam.state_bytes(
                     mc, self._state_slot_count, itemsize
+                )
+            if mc.released_window:
+                # the window plane comes off the top like a state plane:
+                # its size does not follow from free memory
+                reserved += self._window_plane_blocks * (
+                    fam.page_bytes_per_block(
+                        mc, self.config.block_size, itemsize, plane="window"
+                    )
                 )
         if getattr(devices[0], "platform", "") != "tpu":
             # CPU/virtual test backends: a modest fixed pool (their
@@ -2241,6 +2304,12 @@ class JaxEngine:
         if slots is not None:
             self._state_slot_steps[0] += slots.num_used
             self._state_slot_steps[1] += slots.num_slots - 1
+        plane = self.scheduler.window_plane if self.scheduler else None
+        if plane is not None:
+            self._window_page_steps[0] += plane.num_used
+            self._window_page_steps[1] += (
+                len(self.scheduler.running) + len(self.scheduler.prefilling)
+            )
         phase = step_span(
             "dyn.step.dispatch", kind=kind, rows=int(tokens.shape[0]),
             tokens=int(tokens.size),
@@ -4897,7 +4966,10 @@ class JaxEngine:
             attrs={"service": "engine",
                    "prompt_tokens": len(seq.request.token_ids),
                    "cached_tokens": seq.num_cached_prompt,
-                   "chunks": seq.prefill_chunks},
+                   "chunks": seq.prefill_chunks,
+                   # window-plane pages its chunks handed back (0 for
+                   # a model without that plane)
+                   "window_pages_released": seq.window_pages_released},
         )
         decode = {"service": "engine", "tokens": seq.generated,
                   "finish_reason": str(reason.value)}
@@ -5248,6 +5320,21 @@ class JaxEngine:
                     state_slot_steps_used=self._state_slot_steps[0],
                     state_slot_steps_total=self._state_slot_steps[1],
                 )
+            if sched.window_plane is not None:
+                plane = sched.window_plane
+                out.update(
+                    window_page_steps=self._window_page_steps[0],
+                    window_row_steps=self._window_page_steps[1],
+                    window_pages_released_total=plane.released_total,
+                    admit_blocked_window=sched.admit_blocked_window,
+                    # as they stand (not cumulative): both planes
+                    window_pages_in_use=plane.num_used,
+                    window_pages_total=plane.num_blocks - 1,
+                    full_pages_in_use=(
+                        self.allocator.num_blocks - 1 - self.allocator.num_free
+                    ),
+                    full_pages_total=self.allocator.num_blocks - 1,
+                )
         return out
 
     def _note_counts(self, now_ns: int) -> None:
@@ -5387,6 +5474,24 @@ class JaxEngine:
                 "used_slots": slots.num_used,
                 "bytes": self._plane_bytes[1],
                 "page_pool_bytes": self._plane_bytes[0],
+            }
+        elif sched is not None and sched.window_plane is not None:
+            # two page planes: the full layers' pages live as long as
+            # the row, the window layers' are released behind the window
+            pool, plane = out["kv_pool"], sched.window_plane
+            out["page_planes"] = {
+                "full": {
+                    "bytes": self._page_plane_bytes["full"],
+                    "pages_total": pool["total_blocks"],
+                    "pages_in_use": pool["active_blocks"],
+                },
+                "window": {
+                    "bytes": self._page_plane_bytes["window"],
+                    "pages_total": plane.num_blocks - 1,
+                    "pages_in_use": plane.num_used,
+                    "window": plane.window,
+                },
+                "window_pages_released_total": plane.released_total,
             }
         elif (sched is not None and self.model_config is not None
                 and self.model_config.owns_pages):

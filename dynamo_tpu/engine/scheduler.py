@@ -32,6 +32,7 @@ from dynamo_tpu.engine.allocator import (
     BlockAllocator,
     NoBlocksError,
     StateSlots,
+    WindowPlane,
 )
 from dynamo_tpu.protocols.common import FinishReason, PreprocessedRequest
 from dynamo_tpu.telemetry import autopsy
@@ -61,6 +62,12 @@ class Sequence:
     tokens: TokenBlockSequence
     state: SeqState = SeqState.WAITING
     block_table: list[int] = field(default_factory=list)
+    # the row's pages of the window plane by absolute column, 0 where
+    # released or not yet held (allocator.WindowPlane; models whose
+    # window layers free behind the window)
+    window_table: list[int] = field(default_factory=list)
+    # of them, how many its prefill chunks handed back (engine.prefill span)
+    window_pages_released: int = 0
     # slot of the state plane while admitted (0 = none; recurrent models)
     state_slot: int = 0
     num_computed: int = 0  # tokens whose KV is in cache
@@ -353,6 +360,16 @@ class Scheduler:
         # given back at finish, abort and preemption. Every block table
         # then carries the row's slot as its LAST column.
         self.state_slots: Optional[StateSlots] = None
+        # models whose window layers release their pages behind the
+        # window (engine sets it): a second page plane, filled a column
+        # at a time as a row's steps are built (_fill_table), released as
+        # the row advances (_release_window), counted by admission
+        # (_window_admits), freed whole at finish, abort and preemption.
+        # Every block table then carries the row's window-plane columns
+        # after its full-plane columns, as many again.
+        self.window_plane: Optional[WindowPlane] = None
+        # passes of _admit that stopped at the window plane's reserve
+        self.admit_blocked_window = 0
         # the head of ``waiting`` that the last _admit could not place
         # (page reserve, max_batch_size, state slots, no blocks). It
         # stays unplaceable until something is freed, so finish() and
@@ -701,6 +718,9 @@ class Scheduler:
                 break  # backpressure: the population's growth comes first
             if self.state_slots is not None and not self.state_slots.num_free:
                 break  # every state slot is held: wait for a finish
+            if self.window_plane is not None and not self._window_admits(seq):
+                self.admit_blocked_window += 1
+                break  # the admitted rows' window pages come first
             try:
                 complete = seq_hashes[: n_prompt_blocks]
                 blocks, cached = self.allocator.allocate_prefix(complete)
@@ -760,6 +780,45 @@ class Scheduler:
         # the loop ends with a request still waiting only where it could
         # not place the head (the while's batch cap, or one of the breaks)
         self._blocked_head = self.waiting[0] if self.waiting else None
+
+    # -- the window plane ------------------------------------------------
+    def window_row_bound(self, seq: Sequence) -> int:
+        """The most window-plane pages ``seq`` holds from now on: the
+        window's keys and what one dispatch adds ahead of them — its next
+        prefill chunk while it has prompt left, afterwards the tokens
+        the decode planners allocate ahead of its applied length
+        (``dispatches_ahead`` dispatches of a window each, and the token
+        in hand). Never its length."""
+        ahead = 1 + self.dispatches_ahead * self._window()
+        if seq.state != SeqState.RUNNING:
+            left = seq.total_len - seq.num_computed
+            ahead = max(ahead, min(max(1, left), self.prefill_chunk_size))
+        return self.window_plane.span_pages(ahead)
+
+    def _window_admits(self, seq: Sequence) -> bool:
+        """Does the window plane hold ``seq``'s bound beside what every
+        admitted row may still take up to its own? (Alone, a prompt goes
+        in whatever: the plane is sized to hold a row, and waiting would
+        free nothing.)"""
+        plane = self.window_plane
+        owed = sum(
+            max(0, self.window_row_bound(s) - plane.held(s.window_table))
+            for pool in (self.running, self.prefilling) for s in pool
+        )
+        return plane.num_free - owed >= self.window_row_bound(seq) or not (
+            self.running or self.prefilling)
+
+    def _release_window(self, seq: Sequence) -> int:
+        """Hand back ``seq``'s window-plane pages that no query from
+        position ``num_computed`` on can read; returns how many."""
+        if self.window_plane is None:
+            return 0
+        return self.window_plane.release_behind(
+            seq.window_table, seq.num_computed)
+
+    def _free_window(self, seq: Sequence) -> None:
+        if self.window_plane is not None:
+            self.window_plane.free_row(seq.window_table)
 
     def _plan_prefill_batch(
         self,
@@ -831,6 +890,7 @@ class Scheduler:
         seq = work.seq
         seq.num_computed = work.start_pos + len(work.tokens)
         seq.prefill_chunks += 1
+        seq.window_pages_released += self._release_window(seq)
         self._commit_full_blocks(seq)
         if work.is_last_chunk:
             self.prefilling.remove(seq)
@@ -1450,6 +1510,7 @@ class Scheduler:
         self.running.remove(victim)
         self.allocator.free_sequence(victim.block_table)
         victim.block_table = []
+        self._free_window(victim)
         self._release_state(victim)
         victim.num_computed = 0
         victim.num_cached_prompt = 0
@@ -1478,6 +1539,7 @@ class Scheduler:
         # computed would let _commit_full_blocks content-address a block
         # whose last slot holds garbage, poisoning the prefix cache.
         seq.num_computed = seq.total_len - 1
+        self._release_window(seq)
         self._commit_full_blocks(seq)
 
     def _commit_full_blocks(self, seq: Sequence) -> None:
@@ -1520,6 +1582,7 @@ class Scheduler:
         if seq.block_table:
             self.allocator.free_sequence(seq.block_table)
             seq.block_table = []
+        self._free_window(seq)
         self._release_state(seq)
         if self.on_finish is not None:
             self.on_finish(seq, reason)
@@ -1545,39 +1608,75 @@ class Scheduler:
 
     @property
     def table_extra(self) -> int:
-        """Columns of a block table beyond its pages: the state slot."""
-        return 0 if self.state_slots is None else 1
+        """Columns of a block table beyond its pages that do not grow
+        with them: the state slot."""
+        return self.table_width_of(0)
+
+    def table_width_of(self, pages: int) -> int:
+        """Columns of a block table that holds ``pages`` page columns:
+        one more for the state slot where the model keeps recurrent
+        state, as many again for the window plane's columns where it
+        has one."""
+        if self.window_plane is not None:
+            return 2 * pages
+        return pages + (0 if self.state_slots is None else 1)
+
+    def _table_pages(self, width: int) -> int:
+        """``table_width_of``, backwards."""
+        if self.window_plane is not None:
+            return width // 2
+        return width - (0 if self.state_slots is None else 1)
 
     def _table_width(self, max_blocks: int) -> int:
         """Block-table width for a step: the fixed serving cap when set
         (one compiled shape), bucketed otherwise — growing past the cap
         degrades to a wider bucket rather than corrupting tables. With
-        state slots, one column more (``_fill_table``)."""
+        state slots or a window plane, the columns beyond the pages
+        (``table_width_of``, ``_fill_table``)."""
         w = max(
             self.TABLE_BUCKET,
             -(-max_blocks // self.TABLE_BUCKET) * self.TABLE_BUCKET,
         )
         if self.table_width_pad is not None and w <= self.table_width_pad:
             w = self.table_width_pad
-        return w + self.table_extra
+        return self.table_width_of(w)
 
-    def _fill_table(self, tables: np.ndarray, i: int, seq: Sequence) -> None:
-        """Row ``i``: the sequence's pages, and in the last column its
-        state slot where the model keeps recurrent state."""
+    def _fill_table(
+        self, tables: np.ndarray, i: int, seq: Sequence,
+        cols: Optional[int] = None,
+    ) -> None:
+        """Row ``i``: the sequence's pages; in the last column its state
+        slot where the model keeps recurrent state; in the table's second
+        half its window-plane pages where the model has that plane —
+        made to hold, now, the first ``cols`` columns that the window of
+        the row's next query can read (by default as many as the full
+        plane holds: what the decode planners allocated ahead; a prefill
+        chunk names its own end, the prompt's later pages being held in
+        the full plane alone)."""
         tables[i, : len(seq.block_table)] = seq.block_table
         if self.state_slots is not None:
             tables[i, -1] = seq.state_slot
+        if self.window_plane is not None:
+            self.window_plane.cover(
+                seq.window_table,
+                len(seq.block_table) if cols is None else cols,
+                seq.num_computed,
+            )
+            half = tables.shape[1] // 2
+            tables[i, half: half + len(seq.window_table)] = seq.window_table
 
     def widen_tables(self, tables: np.ndarray, width: int) -> np.ndarray:
         """``tables`` padded to ``width`` columns (the state-slot column
-        stays the last)."""
+        stays the last, the window plane's columns the second half)."""
         w0 = tables.shape[1]
         if w0 >= width:
             return tables
         out = np.zeros((tables.shape[0], width), np.int32)
-        pages = w0 - self.table_extra
+        pages = self._table_pages(w0)
         out[:, :pages] = tables[:, :pages]
-        if self.table_extra:
+        if self.window_plane is not None:
+            out[:, width // 2: width // 2 + pages] = tables[:, pages:]
+        elif self.state_slots is not None:
             out[:, -1] = tables[:, -1]
         return out
 
@@ -1647,7 +1746,8 @@ class Scheduler:
                 slot_mapping[i * T + j] = (
                     w.seq.block_table[pos // bs] * bs + pos % bs
                 )
-            self._fill_table(tables, i, w.seq)
+            self._fill_table(
+                tables, i, w.seq, cols=(w.start_pos + t - 1) // bs + 1)
             ctx[i] = w.start_pos + t
             last_idx[i] = t - 1
             mm = self._mm_chunk_arrays(w.seq, w.start_pos, t, T)

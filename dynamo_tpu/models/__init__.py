@@ -21,8 +21,9 @@ the loader reach a model through nothing else):
   own file name.
 
 A family that draws its own parameters says so, and gives the engine
-what it sizes and checks with. TWO further questions are asked of it,
-each on its own (``ModelConfig.owns_pages``, ``.has_recurrent_state``):
+what it sizes and checks with. THREE further questions are asked of it,
+each on its own (``ModelConfig.owns_pages``, ``.has_recurrent_state``,
+``.released_window``):
 
 - ``init_params_quantized(cfg, seed, mesh, specs)`` (the loader then
   takes random weights only);
@@ -45,6 +46,24 @@ each on its own (``ModelConfig.owns_pages``, ``.has_recurrent_state``):
   is worth nothing without the state at its end
   (``models/kimi_linear.py``, ``models/qwen3_next.py``,
   ``models/nemotron_h.py``: all three also own their pages);
+- DOES A PLANE OF ITS PAGES RELEASE BEHIND A WINDOW? ``released_window(
+  cfg)`` gives the window (0: none does), ``page_bytes_per_block(...,
+  plane="window")`` that plane's bytes and ``init_cache(...,
+  window_blocks)`` its pages: layers that attend only the last
+  ``window`` keys keep their K and V in a SECOND plane with its own
+  block ids (``engine/allocator.py`` ``WindowPlane``), whose table rides
+  as the second half of ``block_tables`` by the same absolute column and
+  whose pages go back as they fall behind ``p - (window - 1)`` for the
+  row's next query ``p`` — after each prefill chunk and as decode
+  advances — so a row holds the window's pages and a dispatch's
+  look-ahead of that plane, never its length. Admission reserves every
+  admitted row's bound of it beside the full plane's timeline,
+  preemption, cancellation and finish free both. Prefix reuse is off,
+  because a cached full-plane prefix is worth nothing without the
+  window plane's last pages (every admission a counted miss, as the
+  state families), and what moves pages by their llama layout stays
+  refused (``models/mimo_v2_flash.py``: it owns its pages and keeps no
+  recurrent state);
 - ``check_engine(engine_config)``: raises for what it does not build;
 - ``COUNT_NAMES``: the cumulative int32 counts it keeps on the device in
   ``cache_b["counts"]`` (``engine.program_counts``; the once-a-second

@@ -120,6 +120,27 @@ class ModelConfig:
     scoring_func: str = "sigmoid"
     topk_method: str = "noaux_tc"
     qk_head_dim: Optional[int] = None
+    # mimo_v2_flash (models/mimo_v2_flash.py): hybrid_layer_pattern gives
+    # every layer 0 (full attention: num_key_value_heads, rope_theta, no
+    # sink unless add_full_attention_sink_bias) or 1 (window attention
+    # over sliding_window keys: swa_num_key_value_heads, swa_rope_theta,
+    # a learned sink a head if add_swa_attention_sink_bias); K heads are
+    # head_dim wide and V heads v_head_dim (swa_* the same), V scaled by
+    # attention_value_scale; the first partial_rotary_factor of a head is
+    # rotated; moe_layer_freq gives every layer 0 (dense MLP) or 1
+    # (n_routed_experts sigmoid-routed experts, no shared one)
+    hybrid_layer_pattern: Optional[list] = None
+    moe_layer_freq: Optional[list | int] = None
+    swa_num_attention_heads: Optional[int] = None
+    swa_num_key_value_heads: Optional[int] = None
+    swa_head_dim: Optional[int] = None
+    swa_v_head_dim: Optional[int] = None
+    swa_rope_theta: Optional[float] = None
+    attention_value_scale: Optional[float] = None
+    add_swa_attention_sink_bias: bool = False
+    add_full_attention_sink_bias: bool = False
+    sliding_window_size: Optional[int] = None
+    attention_chunk_size: Optional[int] = None
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -148,6 +169,18 @@ class ModelConfig:
         from dynamo_tpu.models import family
 
         return hasattr(family(self), "page_bytes_per_block")
+
+    @property
+    def released_window(self) -> int:
+        """The window behind which a page plane of the family is
+        RELEASED (0: none is): the third question asked of a family
+        (``models/__init__.py``; ``released_window(cfg)`` of its module).
+        The engine then keeps a second plane with its own ids and table,
+        and prefix reuse is off."""
+        from dynamo_tpu.models import family
+
+        fn = getattr(family(self), "released_window", None)
+        return int(fn(self)) if fn is not None else 0
 
     def layer_letters(self) -> list[str]:
         """``hybrid_override_pattern`` as one letter a layer (``M``,
@@ -229,7 +262,7 @@ class ModelConfig:
             kwargs["max_position_embeddings"] = int(raw["model_max_length"])
         # nemotron_h names the RMSNorm epsilon norm_eps / layer_norm_epsilon
         if "rms_norm_eps" not in raw:
-            for key in ("norm_eps", "layer_norm_epsilon"):
+            for key in ("norm_eps", "layer_norm_epsilon", "layernorm_epsilon"):
                 if key in raw:
                     kwargs["rms_norm_eps"] = float(raw[key])
                     break

@@ -374,12 +374,16 @@ def paged_attention_reference(
     context_lens: jax.Array,  # [B] total valid tokens per sequence
     block_size: int,
     sliding_window: Optional[int] = None,
+    sinks: Optional[jax.Array] = None,  # [H] f32: a learned logit a head
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Gather-then-attend paged attention (XLA reference path).
 
     Works on any backend; the Pallas kernel (ops/paged_attention.py) is the
     TPU fast path with identical semantics. ``sliding_window`` masks keys
-    older than the window (Mistral-family).
+    older than the window (Mistral-family). As there, V rows may be
+    narrower than K rows, ``sinks`` is one more softmax column a head
+    that adds no value, and ``scale`` replaces ``Dh ** -0.5``.
     """
     if kv_cache_is_quantized(k_cache_l):
         # int8 cache: dequantize each layer-slice pair in f32, then run
@@ -403,7 +407,8 @@ def paged_attention_reference(
             vv_l[slot_ids].astype(jnp.float32) * vsc[..., None]
         ).astype(q.dtype)
         return _reference_attend(
-            q, keys, vals, positions, context_lens, sliding_window
+            q, keys, vals, positions, context_lens, sliding_window, sinks,
+            scale,
         )
     B, T, H, Dh = q.shape
     Hk = k_cache_l.shape[-2]
@@ -420,7 +425,7 @@ def paged_attention_reference(
         keys = keys.astype(q.dtype)
         vals = vals.astype(q.dtype)
     return _reference_attend(
-        q, keys, vals, positions, context_lens, sliding_window
+        q, keys, vals, positions, context_lens, sliding_window, sinks, scale
     )
 
 
@@ -431,6 +436,8 @@ def _reference_attend(
     positions: jax.Array,
     context_lens: jax.Array,
     sliding_window: Optional[int],
+    sinks: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Masked-attention tail of the XLA reference path.
 
@@ -442,7 +449,8 @@ def _reference_attend(
     S = keys.shape[1]
     group = H // Hk
     qg = q.reshape(B, T, Hk, group, Dh)
-    scale = 1.0 / math.sqrt(Dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(Dh)
     scores = jnp.einsum(
         "btkgd,bskd->bkgts", qg, keys, preferred_element_type=jnp.float32
     ) * scale  # [B, Hk, G, T, S]
@@ -454,9 +462,18 @@ def _reference_attend(
     if sliding_window is not None:
         mask = mask & (key_pos > pos_q - sliding_window)
     scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    if sinks is None:
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    else:
+        # the sink is one more column of the softmax, then dropped
+        col = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(1, Hk, group, 1, 1),
+            scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, col], axis=-1), axis=-1
+        )[..., :-1].astype(q.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, vals)
-    return out.reshape(B, T, H, Dh)
+    return out.reshape(B, T, H, vals.shape[-1])
 
 
 # Mesh for multi-device Pallas attention: attention is local per

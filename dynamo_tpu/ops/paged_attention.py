@@ -82,7 +82,8 @@ _DECODE_VMEM_LIMIT_BYTES = 48 * 2**20
 
 
 def decode_pages_per_block(
-    block_size: int, Hk: int, Dh: int, itemsize: int
+    block_size: int, Hk: int, Dh: int, itemsize: int,
+    Dv: Optional[int] = None,
 ) -> int:
     """Pages of one compute block of the decode kernel, from what the
     call sees: as many as the double buffer's VMEM budget holds of this
@@ -93,9 +94,11 @@ def decode_pages_per_block(
     Qwen's 4 heads 8, a tp=4 shard's 2 or 1 heads 16 or 32; the v5e
     sweep was flat from 4 to 16 pages at both geometries and lost 25 %
     at 16 384 columns (PERF.md, PR 30). Never above 32: the int8 path
-    unrolls its scale spread over the pages."""
-    page_bytes = block_size * Hk * Dh * itemsize
-    by_vmem = _DECODE_KV_BUFFER_BYTES // (4 * page_bytes)
+    unrolls its scale spread over the pages. ``Dv``: the width of a V
+    row where it is not K's ``Dh`` (a K page and a V page then differ
+    in bytes; the budget holds two slots of each)."""
+    kv_page_bytes = block_size * Hk * (Dh + (Dv or Dh)) * itemsize
+    by_vmem = _DECODE_KV_BUFFER_BYTES // (2 * kv_page_bytes)
     by_columns = _DECODE_BLOCK_COLUMNS // (block_size * Hk)
     return max(1, min(by_vmem, by_columns, 32))
 
@@ -104,11 +107,12 @@ def _decode_kernel_stacked(
     layer_ref,  # scalar prefetch: [1] int32 — layer to read
     tables_ref,  # scalar prefetch: [B, W] int32
     ctx_ref,  # scalar prefetch: [B] int32
-    *refs,  # q, k, v, [ks, vs,] o, then scratch — scales iff quantized
+    *refs,  # q, [sinks,] k, v, [ks, vs,] o, then scratch — scales iff quantized
     block_size: int,
     scale: float,
     window: Optional[int],
     quantized: bool,
+    sinks: bool = False,
 ):
     """THE flash-decode kernel body: one grid step a ROW, over a stacked
     cache left in HBM as pages ``[L, N, bs*Hk, Dh]`` (the per-layer API
@@ -147,7 +151,18 @@ def _decode_kernel_stacked(
     (token, head): ``spread_ref`` [bs, bs*Hk], 1 where column // Hk ==
     token, moves each scale over its token's Hk columns through an f32
     (``HIGHEST``: exact against 0 / 1) dot — the lane dim is never
-    reshaped. An fp8 cache has no scales and upcasts in the kernel."""
+    reshaped. An fp8 cache has no scales and upcasts in the kernel.
+
+    K rows and V rows may differ in width (``Dk`` from the queries and
+    K's buffer, ``Dv`` from V's: the accumulator and the output are
+    ``Dv`` wide). ``sinks``: one learned logit a query head, ``[H, 1]``
+    float32, that takes probability and adds no value — one more column
+    of the softmax, which in the online recurrence is its START: ``m``
+    the sink, ``l`` 1, ``acc`` 0."""
+    sink_ref = None
+    if sinks:
+        q_ref, sink_ref, *refs = refs
+        refs = (q_ref, *refs)
     if quantized:
         (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
          k_buf, v_buf, sems, state, bias_ref,
@@ -159,6 +174,7 @@ def _decode_kernel_stacked(
     b = pl.program_id(0)
     B = pl.num_programs(0)
     H, Dh = q_ref.shape[1], q_ref.shape[2]
+    Dv = v_buf.shape[3]
     P, rows = k_buf.shape[1], k_buf.shape[2]  # rows = bs * Hk a page
     bs = block_size
     Hk = rows // bs
@@ -260,7 +276,7 @@ def _decode_kernel_stacked(
         # value is exactly representable in bf16, so the HBM read is
         # byte-halved and the dot itself stays bf16 x bf16.
         k = k_buf[slot].reshape(C, Dh)
-        v = v_buf[slot].reshape(C, Dh)
+        v = v_buf[slot].reshape(C, Dv)
         if k.dtype != q.dtype:
             k = k.astype(q.dtype)
             v = v.astype(q.dtype)
@@ -294,9 +310,10 @@ def _decode_kernel_stacked(
     _, l, acc = jax.lax.fori_loop(
         0, n_blocks, block,
         (
-            jnp.full((H, 1), -1e30, jnp.float32),
-            jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, Dh), jnp.float32),
+            jnp.full((H, 1), -1e30, jnp.float32) if sink_ref is None
+            else sink_ref[...],
+            jnp.full((H, 1), 0.0 if sink_ref is None else 1.0, jnp.float32),
+            jnp.zeros((H, Dv), jnp.float32),
         ),
     )
     state[0] = (slot0 + n_blocks) % 2
@@ -307,13 +324,14 @@ def _decode_kernel_stacked(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "block_size", "sliding_window", "interpret", "pages_per_block"
+        "block_size", "sliding_window", "interpret", "pages_per_block",
+        "scale", "name",
     ),
 )
 def paged_attention_decode_stacked(
     q: jax.Array,  # [B, H, Dh]
     k_cache: jax.Array,  # [L, n_slots, Hkv, Dh] — the FULL stacked cache
-    v_cache: jax.Array,
+    v_cache: jax.Array,  # [L, n_slots, Hkv, Dv] (Dv = Dh unless it says so)
     layer_idx: jax.Array,  # scalar int32 — layer to attend over
     block_tables: jax.Array,  # [B, W] int32
     context_lens: jax.Array,  # [B] int32
@@ -323,6 +341,9 @@ def paged_attention_decode_stacked(
     k_scale: Optional[jax.Array] = None,  # [L, N, Hkv, bs] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
     pages_per_block: Optional[int] = None,
+    sinks: Optional[jax.Array] = None,  # [H] f32: a learned logit a head
+    scale: Optional[float] = None,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """Decode attention over layer ``layer_idx`` of the stacked cache.
 
@@ -335,29 +356,44 @@ def paged_attention_decode_stacked(
     ``k_scale``/``v_scale``: per-(slot, head) dequant scales for an
     int8 cache, stored [L, N, Hk, bs] (layout rationale:
     ops/kv_quant.py). ``pages_per_block``: pages of one compute block;
-    by default sized from the geometry (``decode_pages_per_block``)."""
+    by default sized from the geometry (``decode_pages_per_block``).
+
+    What a CALL says, not the model: the KV heads and the widths come
+    from the arrays (V rows may be narrower than K rows; the output is
+    as wide as V), ``sliding_window`` and ``sinks`` (``[H]`` float32,
+    the kernel's docstring) from the arguments, so layers of different
+    kinds in one model each make their own call. ``scale``: the score
+    scale where it is not ``Dh ** -0.5`` (K rows stored wider than the
+    head, the rest zeros)."""
     B, H, Dh = q.shape
     L, S, Hk, _ = k_cache.shape
+    Dv = v_cache.shape[-1]
     N = S // block_size
     rows = block_size * Hk
-    scale = 1.0 / math.sqrt(Dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(Dh)
     quantized = k_scale is not None
     P = pages_per_block or decode_pages_per_block(
-        block_size, Hk, Dh, k_cache.dtype.itemsize
+        block_size, Hk, Dh, k_cache.dtype.itemsize, Dv
     )
 
     # a page as its (token, head) rows: the same bytes in the same order
     kp = k_cache.reshape(L, N, rows, Dh)
-    vp = v_cache.reshape(L, N, rows, Dh)
+    vp = v_cache.reshape(L, N, rows, Dv)
     layer_arr = jnp.asarray(layer_idx, jnp.int32).reshape(1)
 
     row_spec = pl.BlockSpec((1, H, Dh), lambda b, lyr, t, c: (b, 0, 0))
+    out_spec = row_spec if Dv == Dh else pl.BlockSpec(
+        (1, H, Dv), lambda b, lyr, t, c: (b, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [row_spec, in_hbm, in_hbm]
     inputs = [q, kp, vp]
+    if sinks is not None:
+        in_specs.insert(1, pl.BlockSpec((H, 1), lambda b, lyr, t, c: (0, 0)))
+        inputs.insert(1, sinks.astype(jnp.float32).reshape(H, 1))
     scratch = [
         pltpu.VMEM((2, P, rows, Dh), k_cache.dtype),
-        pltpu.VMEM((2, P, rows, Dh), v_cache.dtype),
+        pltpu.VMEM((2, P, rows, Dv), v_cache.dtype),
         pltpu.SemaphoreType.DMA((4 if quantized else 2, 2)),
         pltpu.SMEM((2,), jnp.int32),
         pltpu.VMEM((H, P * rows), jnp.float32),  # head mask
@@ -375,22 +411,24 @@ def paged_attention_decode_stacked(
         num_scalar_prefetch=3,  # layer, block_tables, context_lens
         grid=(B,),
         in_specs=in_specs,
-        out_specs=row_spec,
+        out_specs=out_spec,
         scratch_shapes=scratch,
     )
+    kernel_kw = {"sinks": True} if sinks is not None else {}
     return pl.pallas_call(
         functools.partial(
             _decode_kernel_stacked, block_size=block_size, scale=scale,
-            window=sliding_window, quantized=quantized,
+            window=sliding_window, quantized=quantized, **kernel_kw,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # rows run in order: a row starts the next row's first block
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_DECODE_VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
+        name=name,
     )(layer_arr, block_tables, context_lens, *inputs)
 
 
@@ -399,12 +437,13 @@ def _prefill_kernel_stacked(
     starts_ref,  # scalar prefetch: [B] int32 — first query position per row
     tables_ref,  # scalar prefetch: [B, W] int32
     ctx_ref,     # scalar prefetch: [B] int32 (context incl. this chunk)
-    *refs,  # q, k, v, [ks, vs,] o, acc, m, l — scales iff quantized
+    *refs,  # q, [sinks,] k, v, [ks, vs,] o, acc, m, l — scales iff quantized
     block_size: int,
     tq: int,
     scale: float,
     window: Optional[int],
     quantized: bool,
+    sinks: bool = False,
 ):
     """Flash prefill over the paged cache: one query TILE of ``tq``
     tokens vs one KV page per grid step, causal (+ sliding window)
@@ -413,7 +452,17 @@ def _prefill_kernel_stacked(
     them in before attending), so chunked long prompts attend their
     full prefix without any [T, S] score materialization — the XLA
     reference path's [B, Hk, G, T, S] scores tensor is ~400 MB at
-    T=1024/S=3072 and its HBM traffic dominates long-prompt TTFT."""
+    T=1024/S=3072 and its HBM traffic dominates long-prompt TTFT.
+
+    V pages may be narrower than K pages (the accumulator and the
+    output are as wide as V). ``sinks``: a learned logit a query head,
+    laid over the kernel's (kv head, token, group) rows as ``[rows, 1]``
+    float32 — the state a tile starts from is ``m`` the sink, ``l`` 1,
+    ``acc`` 0 (the decode kernel's docstring)."""
+    sink_ref = None
+    if sinks:
+        q_ref, sink_ref, *refs = refs
+        refs = (q_ref, *refs)
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
@@ -425,8 +474,12 @@ def _prefill_kernel_stacked(
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if sink_ref is None:
+            m_ref[:] = jnp.full_like(m_ref, -1e30)
+            l_ref[:] = jnp.zeros_like(l_ref)
+        else:
+            m_ref[:] = sink_ref[...]
+            l_ref[:] = jnp.ones_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     ctx = ctx_ref[b]
@@ -518,7 +571,7 @@ def _prefill_kernel_stacked(
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        Tq, H, Dh = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
+        Tq, H, Dh = q_ref.shape[2], q_ref.shape[3], v_ref.shape[4]
         Hk = k_ref.shape[3]
         G = H // Hk
         # rows with no valid key (padded rows/tokens): clamp, not NaN
@@ -529,7 +582,8 @@ def _prefill_kernel_stacked(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "sliding_window", "interpret"),
+    static_argnames=(
+        "block_size", "sliding_window", "interpret", "scale", "name"),
 )
 def paged_attention_prefill_stacked(
     q: jax.Array,  # [B, T, H, Dh] — a (possibly chunked) prefill rectangle
@@ -544,9 +598,13 @@ def paged_attention_prefill_stacked(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # [L, N, Hkv, bs] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
+    sinks: Optional[jax.Array] = None,  # [H] f32: a learned logit a head
+    scale: Optional[float] = None,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """Flash prefill attention over the paged cache; returns
-    [B, T, H, Dh]. Requires the chunk's K/V to already be scattered
+    [B, T, H, Dv] (``Dv`` = V's width, ``Dh`` unless the V cache is
+    narrower; ``sinks`` and ``scale`` as the decode wrapper says). Requires the chunk's K/V to already be scattered
     into the cache (models/llama.py writes before attending). Rows are
     contiguous token runs: q[b, t] sits at absolute position
     start_pos[b] + t (padded rows: start 0 / ctx 0 -> all-masked).
@@ -554,9 +612,11 @@ def paged_attention_prefill_stacked(
     constraints documented on paged_attention_decode_stacked)."""
     B, T, H, Dh = q.shape
     L, S, Hk, _ = k_cache.shape
+    Dv = v_cache.shape[-1]
     N = S // block_size
     W = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(Dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(Dh)
     quantized = k_scale is not None
     # query tile: 128 keeps the kernel's VMEM state ~2 MB for the 8B
     # geometry at block_size=16; halve while the f32 working-set
@@ -575,7 +635,7 @@ def paged_attention_prefill_stacked(
     n_tiles = T // tq
 
     kp = k_cache.reshape(L, N, block_size, Hk, Dh)
-    vp = v_cache.reshape(L, N, block_size, Hk, Dh)
+    vp = v_cache.reshape(L, N, block_size, Hk, Dv)
     q5 = q.reshape(B, n_tiles, tq, H, Dh)
     layer_arr = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     starts = jnp.asarray(start_pos, jnp.int32)
@@ -602,9 +662,18 @@ def paged_attention_prefill_stacked(
             lambda b, qi, j, lyr, st, t, c: (b, qi, 0, 0, 0),
         ),
         pl.BlockSpec((1, 1, block_size, Hk, Dh), kv_index),
-        pl.BlockSpec((1, 1, block_size, Hk, Dh), kv_index),
+        pl.BlockSpec((1, 1, block_size, Hk, Dv), kv_index),
     ]
     inputs = [q5, kp, vp]
+    n_rows = Hk * tq * (H // Hk)
+    if sinks is not None:
+        # a tile's rows run (kv head, token, group): head = hk * G + g
+        per_row = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(Hk, 1, H // Hk),
+            (Hk, tq, H // Hk)).reshape(n_rows, 1)
+        in_specs.insert(1, pl.BlockSpec(
+            (n_rows, 1), lambda b, qi, j, lyr, st, t, c: (0, 0)))
+        inputs.insert(1, per_row)
     if quantized:
         def scale_index(b, qi, j, lyr, st, t, c):
             return kv_index(b, qi, j, lyr, st, t, c)[:2] + (0, 0)
@@ -620,30 +689,34 @@ def paged_attention_prefill_stacked(
         grid=(B, n_tiles, W),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, tq, H, Dh),
+            (1, 1, tq, H, Dv),
             lambda b, qi, j, lyr, st, t, c: (b, qi, 0, 0, 0),
         ),
         scratch_shapes=[
-            pltpu.VMEM((Hk * tq * (H // Hk), Dh), jnp.float32),
-            pltpu.VMEM((Hk * tq * (H // Hk), 1), jnp.float32),
-            pltpu.VMEM((Hk * tq * (H // Hk), 1), jnp.float32),
+            pltpu.VMEM((n_rows, Dv), jnp.float32),
+            pltpu.VMEM((n_rows, 1), jnp.float32),
+            pltpu.VMEM((n_rows, 1), jnp.float32),
         ],
     )
+    kernel_kw = {"sinks": True} if sinks is not None else {}
     out = pl.pallas_call(
         functools.partial(
             _prefill_kernel_stacked, block_size=block_size, tq=tq,
             scale=scale, window=sliding_window, quantized=quantized,
+            **kernel_kw,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, n_tiles, tq, H, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, n_tiles, tq, H, Dv), q.dtype),
         interpret=interpret,
+        name=name,
     )(layer_arr, starts, block_tables, context_lens, *inputs)
-    return out.reshape(B, T, H, Dh)
+    return out.reshape(B, T, H, Dv)
 
 
 @functools.partial(
     jax.jit, static_argnames=(
-        "block_size", "sliding_window", "interpret", "pages_per_block"
+        "block_size", "sliding_window", "interpret", "pages_per_block",
+        "scale",
     ),
 )
 def paged_attention_decode(
@@ -658,6 +731,8 @@ def paged_attention_decode(
     k_scale: Optional[jax.Array] = None,  # [N, Hkv, bs] f32 (int8 cache)
     v_scale: Optional[jax.Array] = None,
     pages_per_block: Optional[int] = None,
+    sinks: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Returns [B, H, Dh] attention outputs.
 
@@ -671,5 +746,5 @@ def paged_attention_decode(
         interpret=interpret,
         k_scale=None if k_scale is None else k_scale[None],
         v_scale=None if v_scale is None else v_scale[None],
-        pages_per_block=pages_per_block,
+        pages_per_block=pages_per_block, sinks=sinks, scale=scale,
     )

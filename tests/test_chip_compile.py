@@ -403,7 +403,8 @@ def test_ssm_decode_update_compiles_for_v5e(rows, one_chip, no_compile_cache):
 
 # the pool a cell runs with where its configuration pins none: what the
 # engine's own sizing gives on a 16 GB chip (PERF.md section 4)
-_STAGE_BLOCKS = {"kimi-linear-48b": 6175, "kanana-2-30b": 2048}
+_STAGE_BLOCKS = {"kimi-linear-48b": 6175, "kanana-2-30b": 2048,
+                 "mimo-v2-flash": 3000}
 
 
 def _stage(config: str):
@@ -629,3 +630,256 @@ def test_the_deepseek_v3_prefill_step_compiles_for_v5e_within_its_transients(
     assert text.count("mla_prefill_attention") >= 12 and "ragged-dot" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < ds.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# One chip: the mimo_v2_flash family (MiMo-V2-Flash) at its published widths
+# (64 query heads; K 192 stored in 256 lanes, V 128; window layers 8 KV heads,
+# a window of 128 and a learned sink a head; full layers 4 KV heads) under a
+# 128-page table a plane — and the dense families' kernels, which the
+# arguments this family added must leave as they were
+# ---------------------------------------------------------------------------
+
+MI_H, MI_DK, MI_DV, MI_TABLE_W = 64, 256, 128, 136
+MI_KINDS = {"full": (3, 4, None, False, 3000), "window": (9, 8, 128, True, 235)}
+
+
+def _lowered_digest(text: str) -> str:
+    """sha256 of a lowered program, its Mosaic kernels printed without
+    source locations (the serialised kernel holds the line numbers of
+    ``ops/paged_attention.py``, which any edit above a line moves)."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    def body(m):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            return ir.Module.parse(base64.b64decode(m.group(1))).operation.get_asm(
+                enable_debug_info=False)
+
+    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+# (prefill, rows, tokens, int8 cache, H, Hk, Dh, window) -> the digest of the
+# program the kernel wrappers lowered to at the PARENT of PR 44 (commit
+# 859a831), for the described chip
+_DENSE_DIGESTS = {
+    "decode-llama-bf16-B64": ((False, 64, 1, False, 32, 8, 128, None), "268366c771f5c272"),
+    "decode-llama-int8-B64": ((False, 64, 1, True, 32, 8, 128, None), "38d71077b341ba91"),
+    "decode-mistral-window-bf16-B8": ((False, 8, 1, False, 32, 8, 128, 4096), "a3e590bcfa314c82"),
+    "decode-qwen-bf16-B64": ((False, 64, 1, False, 28, 4, 128, None), "1434539047811899"),
+    "decode-head256-bf16-B64": ((False, 64, 1, False, 16, 2, 256, None), "4964b5e1b83ea506"),
+    "prefill-llama-bf16-1x1024": ((True, 1, 1024, False, 32, 8, 128, None), "9d5d2bd197cfb305"),
+    "prefill-mistral-window-bf16-4x1024": ((True, 4, 1024, False, 32, 8, 128, 4096), "cbeb2373e00c00c7"),
+    "prefill-llama-int8-32x128": ((True, 32, 128, True, 32, 8, 128, None), "7a4f751b8cbc2a89"),
+    "prefill-head256-bf16-1x1024": ((True, 1, 1024, False, 16, 2, 256, None), "409e017765d1a2af"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DENSE_DIGESTS))
+def test_the_dense_and_hybrid_geometries_lower_to_the_programs_they_did(
+    case, one_chip, no_compile_cache
+):
+    """Sinks, a V width of its own and a stated scale are arguments that
+    default to what the kernels did: at the geometries the benchmark's
+    other cells call them with (Llama / Mistral 32/8 with and without a
+    window, Qwen2.5 28/4, qwen3-next's 16/2 heads of 256, bf16 and int8
+    pages) the lowered program — kernel body, grid, scratch, operands — is
+    the one the parent commit lowered, bit for bit once source locations
+    are dropped."""
+    (prefill, b, t, int8, h, hk, dh, window), want = _DENSE_DIGESTS[case]
+    cdt = jnp.int8 if int8 else jnp.bfloat16
+    shapes = [
+        _sds((b, t, h, dh) if prefill else (b, h, dh), jnp.bfloat16, one_chip),
+        _sds((L, NUM_BLOCKS * BS, hk, dh), cdt, one_chip),
+        _sds((L, NUM_BLOCKS * BS, hk, dh), cdt, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((b, TABLE_W), jnp.int32, one_chip),
+    ]
+    shapes += [_sds((b,), jnp.int32, one_chip)] * (2 if prefill else 1)
+    if int8:
+        shapes += [_sds((L, NUM_BLOCKS, hk, BS), jnp.float32, one_chip)] * 2
+    base = functools.partial(
+        paged_attention_prefill_stacked if prefill
+        else paged_attention_decode_stacked,
+        block_size=BS, sliding_window=window)
+    fn = base if not int8 else (
+        lambda *a: base(*a[:-2], k_scale=a[-2], v_scale=a[-1]))
+    assert _lowered_digest(jax.jit(fn).lower(*shapes).as_text()) == want
+
+
+@pytest.mark.parametrize("rows", [4, 32, 64])
+@pytest.mark.parametrize("kind", sorted(MI_KINDS))
+def test_mimo_decode_attention_reads_both_planes_stored_rows_in_place(
+    kind, rows, one_chip, no_compile_cache
+):
+    """K rows 256 lanes and V rows 128 under one kernel call, the kind's
+    own KV heads, window and sinks as arguments; the planes ``[layers,
+    slots * Hk, width]`` reach the kernel's page view as a bitcast (no
+    copy of a pool a call), under the kind's own op name."""
+    from dynamo_tpu.models import mimo_v2_flash as mm
+
+    layers, hk, window, sinks, pages = MI_KINDS[kind]
+    slots = pages * BS
+    kpool = _sds((layers, slots * hk, MI_DK), jnp.bfloat16, one_chip)
+    vpool = _sds((layers, slots * hk, MI_DV), jnp.bfloat16, one_chip)
+
+    def decode(q, k, v, layer, tables, ctx, sink):
+        return mm.DECODE["win" if kind == "window" else "full"](
+            q, k.reshape(layers, slots, hk, MI_DK),
+            v.reshape(layers, slots, hk, MI_DV), layer, tables, ctx,
+            block_size=BS, sliding_window=window,
+            sinks=sink if sinks else None, scale=192 ** -0.5)
+
+    text = _compile_text(
+        decode, _sds((rows, MI_H, MI_DK), jnp.bfloat16, one_chip), kpool, vpool,
+        _sds((), jnp.int32, one_chip),
+        _sds((rows, MI_TABLE_W), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip), _sds((MI_H,), jnp.float32, one_chip))
+    assert "tpu_custom_call" in text
+    assert f"paged_attention_decode_stacked_{kind}" in text
+    pool_ops = [ln for ln in text.splitlines()
+                if f"bf16[{layers},{pages}," in ln or f"bf16[{layers},{slots * hk}," in ln]
+    assert pool_ops and all(
+        " bitcast(" in ln or " parameter(" in ln or "custom-call(" in ln
+        or "ENTRY" in ln or "HloModule" in ln for ln in pool_ops), pool_ops[:3]
+
+
+@pytest.mark.parametrize("kind", sorted(MI_KINDS))
+def test_mimo_k_rows_stored_192_wide_are_refused_by_the_decode_kernel(
+    kind, one_chip, no_compile_cache
+):
+    """Why K rows are stored in 256 lanes (``mimo_v2_flash``'s docstring):
+    a pool whose rows are the published 192 wide is laid out in 256 lanes
+    by the described chip's compiler all the same, and the decode kernel's
+    page copy from it is refused — 192 stored lanes would save no byte."""
+    from dynamo_tpu.models import mimo_v2_flash as mm
+
+    layers, hk, window, sinks, pages = MI_KINDS[kind]
+    slots = pages * BS
+
+    def decode(q, k, v, layer, tables, ctx, sink):
+        return mm.DECODE["win" if kind == "window" else "full"](
+            q, k.reshape(layers, slots, hk, 192),
+            v.reshape(layers, slots, hk, MI_DV), layer, tables, ctx,
+            block_size=BS, sliding_window=window,
+            sinks=sink if sinks else None)
+
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\), but is 192"):
+        _compile_text(
+            decode, _sds((4, MI_H, 192), jnp.bfloat16, one_chip),
+            _sds((layers, slots * hk, 192), jnp.bfloat16, one_chip),
+            _sds((layers, slots * hk, MI_DV), jnp.bfloat16, one_chip),
+            _sds((), jnp.int32, one_chip),
+            _sds((4, MI_TABLE_W), jnp.int32, one_chip),
+            _sds((4,), jnp.int32, one_chip), _sds((MI_H,), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 1024), (8, 256), (32, 128)])
+@pytest.mark.parametrize("kind", sorted(MI_KINDS))
+def test_mimo_prefill_attention_compiles_for_v5e(
+    kind, rows, tokens, one_chip, no_compile_cache
+):
+    """The rows' own pages, gathered: all 136 columns of the full plane,
+    the window's live span of the window plane (10 columns under a
+    1 024-token chunk, 3 under 128)."""
+    from dynamo_tpu.models import mimo_v2_flash as mm
+
+    _, hk, window, sinks, _ = MI_KINDS[kind]
+    cols = MI_TABLE_W if window is None else mm.window_span(
+        window, BS, tokens, MI_TABLE_W)
+    assert window is None or cols == {1024: 10, 256: 4, 128: 3}[tokens]
+    own_k = _sds((1, rows * cols * BS, hk, MI_DK), jnp.bfloat16, one_chip)
+    own_v = _sds((1, rows * cols * BS, hk, MI_DV), jnp.bfloat16, one_chip)
+
+    def prefill(q, k, v, layer, tables, start, ctx, sink):
+        return mm.PREFILL["win" if kind == "window" else "full"](
+            q, k, v, layer, tables, start, ctx, block_size=BS,
+            sliding_window=window, sinks=sink if sinks else None,
+            scale=192 ** -0.5)
+
+    ids = _sds((rows,), jnp.int32, one_chip)
+    text = _compile_text(
+        prefill, _sds((rows, tokens, MI_H, MI_DK), jnp.bfloat16, one_chip),
+        own_k, own_v, _sds((), jnp.int32, one_chip),
+        _sds((rows, cols), jnp.int32, one_chip), ids, ids,
+        _sds((MI_H,), jnp.float32, one_chip))
+    assert "tpu_custom_call" in text
+    assert f"paged_attention_prefill_stacked_{kind}" in text
+
+
+def _compiled_mimo_step(rows, T, one_chip, monkeypatch):
+    """The served step (int8 weights, bf16 pages in both planes) of
+    ``rows`` x ``T`` tokens at the benchmark's configuration."""
+    from dynamo_tpu.models import hybrid, mimo_v2_flash as mm
+
+    cfg, _ = _stage("mimo-v2-flash")
+    monkeypatch.setattr(hybrid, "kernels_active", lambda: True)
+    monkeypatch.setattr(mm, "kernels_active", lambda: True)
+    monkeypatch.setattr(llama, "pallas_matmul_active", lambda: True)
+    monkeypatch.setattr(llama, "_qmm_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = {}
+    for name, (shape, dtype) in mm.param_shapes(cfg).items():
+        if name in mm.QUANT_AXIS:
+            params[name] = _sds(shape, jnp.int8, one_chip)
+            axis = mm.QUANT_AXIS[name] % len(shape)
+            params[name + "_scale"] = _sds(
+                shape[:axis] + shape[axis + 1:], jnp.float32, one_chip)
+        else:
+            params[name] = _sds(shape, dtype, one_chip)
+    pages = {n: _sds(s, jnp.bfloat16, one_chip) for n, s in mm.cache_shapes(
+        cfg, MI_KINDS["full"][4], BS, MI_KINDS["window"][4]).items()}
+    counts = {"counts": _sds((len(mm.COUNT_NAMES),), jnp.int32, one_chip)}
+    ids = _sds((rows,), jnp.int32, one_chip)
+    grid = _sds((rows, T), jnp.int32, one_chip)
+
+    def step(params, pages, counts, tokens, positions, slots, tables, ctx, last):
+        return mm.forward(cfg, params, pages, counts, tokens, positions, slots,
+                          tables, ctx, last, BS)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pages, counts, grid, grid,
+        _sds((rows * T,), jnp.int32, one_chip),
+        _sds((rows, 2 * MI_TABLE_W), jnp.int32, one_chip), ids, ids).compile()
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_the_mimo_decode_step_compiles_for_v5e_within_its_transients(
+    rows, one_chip, no_compile_cache, monkeypatch
+):
+    """Three full and nine window decode kernels, each under its kind's
+    name, and no copy of either plane (3.5 GB + 1.7 GB) at the program's
+    edge or before a kernel."""
+    from dynamo_tpu.models import mimo_v2_flash as mm
+
+    compiled = _compiled_mimo_step(rows, 1, one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("paged_attention_decode_stacked_full") >= 3
+    assert text.count("paged_attention_decode_stacked_window") >= 9
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < mm.STEP_TRANSIENT_BYTES // 2
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 1024), (32, 128), (1, 512)])
+def test_the_mimo_prefill_step_compiles_for_v5e_within_its_transients(
+    rows, tokens, one_chip, no_compile_cache, monkeypatch
+):
+    """A whole 1 024-token chunk, and ``max_prefill_tokens`` 4 096 as 32
+    rows of 128: twelve flash prefill kernels under the two kinds' names,
+    the sorted-rows experts, and the temporaries inside what the family
+    reserves."""
+    from dynamo_tpu.models import mimo_v2_flash as mm
+
+    compiled = _compiled_mimo_step(rows, tokens, one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert text.count("paged_attention_prefill_stacked_full") >= 3
+    assert text.count("paged_attention_prefill_stacked_window") >= 9
+    assert "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < mm.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
