@@ -316,6 +316,11 @@ class JaxEngine:
         # single-step decode dispatches of _decode_pipeline, and those
         # of them issued with a step still in flight (program_counts)
         self._decode_dispatches = [0, 0]
+        # decode dispatches (the pipeline's and the serial loop's): the
+        # rows of the buckets they ran, and those of them that were
+        # padding (program_counts: what a kernel that skips padded rows
+        # is spared)
+        self._decode_rows = [0, 0]
         # what that pipeline did without emptying itself, and why it
         # emptied itself when it did (program_counts): prefill dispatches
         # issued with a step in flight, finishes no step in flight held a
@@ -2271,6 +2276,8 @@ class JaxEngine:
         the next step regardless."""
         if kind == "prefill":
             self._count_prefill(arrays)
+        elif kind == "decode":
+            self._count_decode(arrays)
         with self._dispatch_span(kind, arrays["tokens"]) as dispatch:
             outs = self._dispatch_device_step(
                 arrays, sampling, origin=origin, defer_sync=not sync
@@ -2291,6 +2298,13 @@ class JaxEngine:
             (arrays["last_token_idx"][real] + 1).sum()
         )
         self._prefill_tokens[1] += arrays["tokens"].size
+
+    def _count_decode(self, arrays: dict[str, np.ndarray]) -> None:
+        """One decode bucket about to be dispatched: its rows, and those
+        of them that are padding (context 0)."""
+        ctx = arrays["context_lens"]
+        self._decode_rows[0] += ctx.shape[0]
+        self._decode_rows[1] += int(np.count_nonzero(ctx == 0))
 
     def _dispatch_span(self, kind: str, tokens):
         """Count one device program of ``kind`` (program_counts) and
@@ -3799,6 +3813,7 @@ class JaxEngine:
             t0 = time.monotonic()
             if kind == "decode":
                 self._decode_dispatches[0] += 1
+                self._count_decode(arrays)
             with self._dispatch_span(kind, arrays["tokens"]) as phase:
                 outs = self._dispatch_device_step(
                     arrays, sampling,
@@ -5310,6 +5325,8 @@ class JaxEngine:
                 admit_reserve_sum_pages=sched.admit_reserve_sum_pages,
                 decode_dispatches=self._decode_dispatches[0],
                 decode_dispatches_chained=self._decode_dispatches[1],
+                decode_rows_dispatched=self._decode_rows[0],
+                decode_rows_padded=self._decode_rows[1],
                 **self._inline,
                 pipeline_drains=dict(self._pipeline_drains),
                 prefill_tokens_real=self._prefill_tokens[0],

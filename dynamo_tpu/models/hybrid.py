@@ -307,6 +307,58 @@ def delta_chunked(q, k, v, glog, beta, S, chunk: Optional[int] = None):
 
 
 # ---------------------------------------------------------------------------
+# The causal depthwise convolution of a recurrent layer
+# ---------------------------------------------------------------------------
+
+
+def conv_tail_shape(K: int, C: int) -> tuple[int, int]:
+    """How a slot's tail — the last ``K - 1`` inputs of ``C`` channels —
+    is stored: its rows one after another in rows of one lane tile (of
+    ``C`` where ``C`` is no multiple of 128: tests). A slot is then whole
+    (8, 128) tiles, contiguous in HBM, that ``ops/conv_tail.py`` moves
+    with ONE copy; a ``[., K - 1, C]`` plane pads 3 rows to 8 and is
+    copied at the step's edges, and of a flat ``[., (K - 1) C]`` one a
+    slot is one sublane of every tile."""
+    lane = 128 if C % 128 == 0 else C
+    return (K - 1) * C // lane, lane
+
+
+def conv_step(plane, idx: int, sslot, fresh, n_valid, x, cw, bias=None, *,
+              kernels: bool):
+    """``y[t] = sum_i full[t + i] * cw[i] (+ bias)`` over this step's
+    tokens, ``full`` the slot's tail (zeros for a ``fresh`` row) followed
+    by ``x``, and the tail moved on to the last ``K - 1`` VALID inputs.
+    ``plane`` [L, slots, *conv_tail_shape] float32, layer ``idx`` of it;
+    ``sslot``, ``fresh``, ``n_valid`` [B]; ``x`` [B, T, C] float32; ``cw``
+    [K, C]; ``bias`` [C] or None. Returns (y [B, T, C] float32 before
+    the activation, plane). A decode step with ``kernels`` (the family's
+    ``kernels_active()``) goes through ``ops/conv_tail.py``, which moves
+    live rows' tails only; everything else through the XLA lines below,
+    which are that kernel's oracle."""
+    B, T, C = x.shape
+    K = cw.shape[0]
+    if T == 1 and kernels:
+        from dynamo_tpu.ops.conv_tail import conv_tail_update
+
+        y, plane = conv_tail_update(
+            plane, jnp.int32(idx), sslot, fresh, x[:, 0].astype(jnp.float32),
+            cw, bias, interpret=jax.default_backend() != "tpu")
+        return y[:, None], plane
+    tail = jnp.where(fresh[:, None, None], 0,
+                     plane[idx, sslot].reshape(B, K - 1, C))
+    full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    y = sum(full[:, i:i + T].astype(jnp.float32) * cw[i] for i in range(K))
+    if bias is not None:
+        y = bias + y
+    # the last K-1 VALID inputs: input j sits at full[j + K - 1]
+    rows = n_valid[:, None] + jnp.arange(K - 1)[None, :]
+    new_tail = jnp.take_along_axis(full, rows[:, :, None], axis=1)
+    plane = plane.at[idx, sslot].set(
+        new_tail.reshape(B, *plane.shape[2:]).astype(plane.dtype))
+    return y, plane
+
+
+# ---------------------------------------------------------------------------
 # Latent attention (MLA), the key up-projection absorbed into the queries
 # ---------------------------------------------------------------------------
 

@@ -24,7 +24,7 @@ How this differs from ``models/llama.py`` for the engine:
 - the two cache pytrees the step threads through are ``pages``
   (``{"latent": [Lm, slots, C padded to whole 128-lane tiles]}``) and
   ``state`` (``{"kda": [Lk, S, H, d, d] f32, "conv": [Lk, S, (kernel-1) *
-  3*H*d] f32, "counts": int32 [3]}``) instead of K and V;
+  3*H*d / 128, 128] f32, "counts": int32 [3]}``) instead of K and V;
 - a row's table is its pages THEN its state slot: the scheduler appends
   the slot as the table's last column (``Scheduler.state_slots``), so no
   step function grows an argument. Slot 0, like page 0, is the garbage
@@ -267,9 +267,9 @@ def cache_shapes(cfg: ModelConfig, num_blocks: int, block_size: int,
     pages = {"latent": (max(1, Lm), num_blocks * block_size, g.Cpad)}
     state = {
         "kda": (max(1, Lk), state_slots, g.Hl, g.dl, g.dl),
-        # a slot's tail rows side by side: the minor dimension stays whole
-        # lane tiles (a [., 3, 3HD] plane is copied at the step's edges too)
-        "conv": (max(1, Lk), state_slots, (g.kernel - 1) * 3 * g.HD),
+        # a slot's tail rows one after another, in rows of one lane tile
+        "conv": (max(1, Lk), state_slots,
+                 *hybrid.conv_tail_shape(g.kernel, 3 * g.HD)),
     }
     return pages, state
 
@@ -376,7 +376,7 @@ def forward(
     cfg: ModelConfig,
     params: Params,
     pages: dict,              # {"latent": [Lm, slots, Cpad]}
-    state: dict,              # {"kda": [Lk, S, H, d, d], "conv": [Lk, S, 3 * 3HD], "counts": [3]}
+    state: dict,              # {"kda": [Lk, S, H, d, d], "conv": [Lk, S, 3 * 3HD / 128, 128], "counts": [3]}
     tokens: jax.Array,        # [B, T]
     positions: jax.Array,     # [B, T] (padded: 0)
     slot_mapping: jax.Array,  # [B*T] flat page slots (padded: 0)
@@ -415,15 +415,9 @@ def forward(
     def kda_mixer(h, ki, kda_plane, conv_plane):
         qkv = jnp.concatenate(
             [_mm(params, n, h, ki) for n in ("kda_wq", "kda_wk", "kda_wv")], -1)
-        tail = jnp.where(fresh[:, None, None], 0, conv_plane[ki, sslot].reshape(
-            B, g.kernel - 1, 3 * g.HD))
-        full = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)  # float32
-        K1 = g.kernel
-        cw = params["kda_conv"][ki]                            # [K1, 3HD]
-        y = sum(full[:, i:i + T].astype(jnp.float32) * cw[i] for i in range(K1))
-        # the last K1-1 VALID inputs: input j sits at full[j + K1 - 1]
-        rows = n_valid[:, None] + jnp.arange(K1 - 1)[None, :]
-        new_tail = jnp.take_along_axis(full, rows[:, :, None], axis=1)
+        y, conv_plane = hybrid.conv_step(
+            conv_plane, ki, sslot, fresh, n_valid, qkv, params["kda_conv"][ki],
+            kernels=kernels_active())
         q, k, v = (a.reshape(B, T, g.Hl, g.dl)
                    for a in jnp.split(jax.nn.silu(y), 3, axis=-1))
         q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) \
@@ -452,8 +446,6 @@ def forward(
             else:
                 o, S = kda_chunked(q, k, v, glog, beta, S)
             kda_plane = kda_plane.at[ki, sslot].set(S)
-        conv_plane = conv_plane.at[ki, sslot].set(
-            new_tail.reshape(B, -1).astype(conv_plane.dtype))
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) \
             * params["kda_onorm"][ki]
         gate = _mm(params, "kda_wgb", _mm(params, "kda_wga", h, ki).astype(act), ki)

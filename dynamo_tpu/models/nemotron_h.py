@@ -277,8 +277,9 @@ def cache_shapes(cfg: ModelConfig, num_blocks: int, block_size: int,
     state = {
         # the state size N minor: a head's [P, N] block is whole lane tiles
         "ssm": (max(1, Lm), state_slots, g.Hm, g.dm, g.N),
-        # a slot's tail rows side by side (models/kimi_linear.py cache_shapes)
-        "conv": (max(1, Lm), state_slots, (g.kernel - 1) * g.conv),
+        # a slot's tail rows one after another, in rows of one lane tile
+        "conv": (max(1, Lm), state_slots,
+                 *hybrid.conv_tail_shape(g.kernel, g.conv)),
     }
     return pages, state
 
@@ -453,7 +454,7 @@ def forward(
     cfg: ModelConfig,
     params: Params,
     pages: dict,              # {"k", "v": [La, slots * Hk, Dh]}
-    state: dict,              # {"ssm": [Lm, S, H, P, N], "conv": [Lm, S, 3 * conv], "counts": [5]}
+    state: dict,              # {"ssm": [Lm, S, H, P, N], "conv": [Lm, S, 3 * conv / 128, 128], "counts": [5]}
     tokens: jax.Array,        # [B, T]
     positions: jax.Array,     # [B, T] (padded: 0)
     slot_mapping: jax.Array,  # [B*T] flat page slots (padded: 0)
@@ -491,20 +492,13 @@ def forward(
     x = x.astype(jnp.float32)
 
     def ssm_mixer(h, mi, ssm_plane, conv_plane):
-        K1 = g.kernel
         zx = mm(params, "m_win", h, mi)                        # [B, T, inner + conv]
         z, xbc = zx[..., : g.inner], zx[..., g.inner:]
         dt = mm(params, "m_wdt", h, mi).astype(jnp.float32)    # [B, T, H]
         with jax.named_scope("ssm_conv"):
-            tail = jnp.where(fresh[:, None, None], 0, conv_plane[
-                mi, sslot].reshape(B, K1 - 1, g.conv))
-            full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-            cw = params["m_conv"][mi]                          # [K1, conv]
-            y = params["m_conv_bias"][mi] + sum(
-                full[:, i:i + T].astype(jnp.float32) * cw[i] for i in range(K1))
-            # the last K1-1 VALID inputs: input j sits at full[j + K1 - 1]
-            rows = n_valid[:, None] + jnp.arange(K1 - 1)[None, :]
-            new_tail = jnp.take_along_axis(full, rows[:, :, None], axis=1)
+            y, conv_plane = hybrid.conv_step(
+                conv_plane, mi, sslot, fresh, n_valid, xbc, params["m_conv"][mi],
+                params["m_conv_bias"][mi], kernels=kernels)
             y = jax.nn.silu(y)
         xs = y[..., : g.inner].reshape(B, T, g.Hm, g.dm)
         Bm = y[..., g.inner: g.inner + g.G * g.N].reshape(B, T, g.G, g.N)
@@ -530,8 +524,6 @@ def forward(
             else:
                 o, S = ssd_chunked(xs, dt, glog, Bm, C, S, g.chunk)
             ssm_plane = ssm_plane.at[mi, sslot].set(S)
-        conv_plane = conv_plane.at[mi, sslot].set(
-            new_tail.reshape(B, -1).astype(conv_plane.dtype))
         o = o + params["m_D"][mi][:, None] * xs
         # gate first, then the norm over each of the G groups of channels
         o = o.reshape(B, T, g.inner) * jax.nn.silu(z.astype(jnp.float32))

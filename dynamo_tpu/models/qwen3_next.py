@@ -250,9 +250,9 @@ def cache_shapes(cfg: ModelConfig, num_blocks: int, block_size: int,
     pages = {"k": kv, "v": kv}
     state = {
         "gdn": (max(1, Lg), state_slots, g.Hl, g.dk, g.dl),
-        # a slot's tail rows side by side: the minor dimension stays whole
-        # 128-lane tiles (models/kimi_linear.py cache_shapes)
-        "conv": (max(1, Lg), state_slots, (g.kernel - 1) * g.conv),
+        # a slot's tail rows one after another, in rows of one lane tile
+        "conv": (max(1, Lg), state_slots,
+                 *hybrid.conv_tail_shape(g.kernel, g.conv)),
     }
     return pages, state
 
@@ -371,7 +371,7 @@ def forward(
     cfg: ModelConfig,
     params: Params,
     pages: dict,              # {"k", "v": [La, slots * Hk, Dh]}
-    state: dict,              # {"gdn": [Lg, S, Hv, dk, dv], "conv": [Lg, S, 3 * conv], "counts": [5]}
+    state: dict,              # {"gdn": [Lg, S, Hv, dk, dv], "conv": [Lg, S, 3 * conv / 128, 128], "counts": [5]}
     tokens: jax.Array,        # [B, T]
     positions: jax.Array,     # [B, T] (padded: 0)
     slot_mapping: jax.Array,  # [B*T] flat page slots (padded: 0)
@@ -409,18 +409,13 @@ def forward(
     x = x.astype(jnp.float32)
 
     def gdn_mixer(h, gi, gdn_plane, conv_plane):
-        K1, rep = g.kernel, g.Hl // g.Hlk
+        rep = g.Hl // g.Hlk
         qkvz = mm(params, "gdn_wqkvz", h, gi)                  # [B, T, conv + VD]
         qkv, z = qkvz[..., : g.conv], qkvz[..., g.conv:]
         ba = mm(params, "gdn_wba", h, gi).astype(jnp.float32)  # [B, T, 2 Hv]
-        tail = jnp.where(fresh[:, None, None], 0, conv_plane[gi, sslot].reshape(
-            B, K1 - 1, g.conv))
-        full = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)  # float32
-        cw = params["gdn_conv"][gi]                            # [K1, conv]
-        y = sum(full[:, i:i + T].astype(jnp.float32) * cw[i] for i in range(K1))
-        # the last K1-1 VALID inputs: input j sits at full[j + K1 - 1]
-        rows = n_valid[:, None] + jnp.arange(K1 - 1)[None, :]
-        new_tail = jnp.take_along_axis(full, rows[:, :, None], axis=1)
+        y, conv_plane = hybrid.conv_step(
+            conv_plane, gi, sslot, fresh, n_valid, qkv, params["gdn_conv"][gi],
+            kernels=kernels)
         y = jax.nn.silu(y)
         q = y[..., : g.QK].reshape(B, T, g.Hlk, g.dk)
         k = y[..., g.QK: 2 * g.QK].reshape(B, T, g.Hlk, g.dk)
@@ -452,8 +447,6 @@ def forward(
             else:
                 o, S = hybrid.delta_chunked(q, k, v, glog[..., None], beta, S)
             gdn_plane = gdn_plane.at[gi, sslot].set(S)
-        conv_plane = conv_plane.at[gi, sslot].set(
-            new_tail.reshape(B, -1).astype(conv_plane.dtype))
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) \
             * params["gdn_onorm"][gi]
         o = o * jax.nn.silu(z.astype(jnp.float32).reshape(o.shape))

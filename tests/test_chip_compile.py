@@ -255,15 +255,47 @@ def test_tp4_attention_compiles_for_v5e(
 
 @pytest.mark.parametrize("rows", [4, 64])
 def test_kda_decode_update_compiles_for_v5e(rows, one_chip, no_compile_cache):
-    from dynamo_tpu.ops.kda import kda_decode_update
+    """A row's whole 2 MB state a block: the compiler takes the four
+    buffers and the passes' temporaries inside the limit the call sets."""
+    from dynamo_tpu.ops import kda
 
+    assert kda.head_block(32) == 32 and kda.vmem_limit(32, 128) < 16 << 20
     vec = _sds((rows, 32, 128), jnp.float32, one_chip)
     ids = _sds((rows,), jnp.int32, one_chip)
     text = _compile_text(
-        kda_decode_update, _sds((7, 65, 32, 128, 128), jnp.float32, one_chip),
+        kda.kda_decode_update, _sds((7, 65, 32, 128, 128), jnp.float32, one_chip),
         _sds((), jnp.int32, one_chip), ids, ids, vec, vec, vec, vec,
         _sds((rows, 32), jnp.float32, one_chip))
     assert "tpu_custom_call" in text and "kda_decode_update" in text
+
+
+# the three recurrent-state families' convolution tails: channels, bias
+_TAILS = {"kimi_linear": (7, 12288, False), "qwen3_next": (6, 8192, False),
+          "nemotron_h": (6, 6144, True)}
+
+
+@pytest.mark.parametrize("rows", [4, 32, 64])
+@pytest.mark.parametrize("family", sorted(_TAILS))
+def test_conv_tail_update_compiles_for_v5e(family, rows, one_chip, no_compile_cache):
+    """One slot's tail is whole (8, 128) tiles of the stored plane, which
+    the kernel (a Mosaic call) copies itself; beside the plane it aliases
+    the program holds no copy of it."""
+    from dynamo_tpu.models import hybrid
+    from dynamo_tpu.ops.conv_tail import conv_tail_update
+
+    layers, C, bias = _TAILS[family]
+    plane = (layers, 65, *hybrid.conv_tail_shape(4, C))
+    ids = _sds((rows,), jnp.int32, one_chip)
+    args = [_sds(plane, jnp.float32, one_chip), _sds((), jnp.int32, one_chip),
+            ids, ids, _sds((rows, C), jnp.float32, one_chip),
+            _sds((4, C), jnp.float32, one_chip)]
+    if bias:
+        args.append(_sds((C,), jnp.float32, one_chip))
+    compiled = jax.jit(conv_tail_update, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "conv_tail_update" in text
+    one_layer = 65 * 3 * C * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
 
 
 @pytest.mark.parametrize("rows", [4, 64])
@@ -347,7 +379,7 @@ def test_prefill_attention_at_head_256_compiles_for_v5e(
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("rows", [8, 64])
+@pytest.mark.parametrize("rows", [8, 32, 64])
 def test_the_delta_rule_update_compiles_on_the_qwen3_next_plane(
     rows, one_chip, no_compile_cache
 ):
@@ -481,6 +513,7 @@ def test_the_nemotron_h_decode_step_compiles_for_v5e_within_its_transients(
     compiled = _compiled_nemotron_step(rows, 1, one_chip, monkeypatch)
     text = compiled.as_text()
     assert text.count("ssm_decode_update") >= 6
+    assert text.count("conv_tail_update") >= 6
     assert "paged_attention_decode" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes

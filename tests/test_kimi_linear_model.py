@@ -18,6 +18,7 @@ from dynamo_tpu.models import ModelConfig, family, kimi_linear as kl, llama
 from dynamo_tpu.models.reference import kimi_linear as ref
 from dynamo_tpu.protocols.common import PreprocessedRequest, StopConditions
 from dynamo_tpu.tokens import TokenBlockSequence
+from tests import state_plane_cases as spc
 from tests.kimi_tiny import tiny_kimi
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -146,24 +147,25 @@ def test_more_rows_take_shorter_chunks(rows, T, want):
     assert kl.kda_chunk_for(rows, T) == want and T % want == 0
 
 
-def test_the_kernel_updates_the_plane_in_place_like_the_plain_step():
+@pytest.mark.parametrize("H,d", [(16, 128), (64, 16), (12, 16)],
+                         ids=["one_block", "two_blocks", "odd_heads"])
+@pytest.mark.parametrize("name", sorted(spc.CASES))
+def test_the_kernel_updates_live_rows_in_place_and_moves_nothing_else(name, H, d):
+    """``kda_decode_update`` (interpreted) against the plain step, with
+    padded rows anywhere in the batch: H 64 is two head blocks, 12 is
+    neither a multiple of the block nor of a pass."""
     from dynamo_tpu.ops.kda import kda_decode_update
 
     rng = np.random.default_rng(0)
-    Lk, S, H, d, B = 3, 6, 16, 128, 5
-    plane = rng.normal(size=(Lk, S, H, d, d)).astype(np.float32)
-    q, k, v, glog, beta, _ = kda_inputs(B, 1, H, d, seed=2)
-    slots = jnp.asarray([2, 4, 1, 5, 0], jnp.int32)
-    fresh = jnp.asarray([0, 1, 0, 0, 0], jnp.int32)
+    slots, fresh = spc.case(name)
+    plane = rng.normal(size=(3, spc.SLOTS, H, d, d)).astype(np.float32)
+    q, k, v, glog, beta, _ = kda_inputs(len(slots), 1, H, d, seed=2)
     S0 = jnp.where(fresh[:, None, None, None] != 0, 0.0, jnp.asarray(plane)[1, slots])
     o_want, S_want = kl.kda_decode(q[:, 0], k[:, 0], v[:, 0], glog[:, 0], beta[:, 0], S0)
     o, new = kda_decode_update(jnp.asarray(plane), 1, slots, fresh, q[:, 0], k[:, 0],
                                v[:, 0], glog[:, 0], beta[:, 0], interpret=True)
-    np.testing.assert_allclose(o, o_want, atol=2e-3)
-    np.testing.assert_allclose(new[1, slots], S_want, atol=1e-4)
-    untouched = np.ones((Lk, S), bool)
-    untouched[1, np.asarray(slots)] = False
-    assert np.array_equal(np.asarray(new)[untouched], plane[untouched])
+    spc.check_rows(o, o_want, slots, atol=2e-3)
+    spc.check_plane(new, plane, 1, slots, S_want, atol=1e-4)
 
 
 # -- the expert layer ----------------------------------------------------------------
@@ -284,7 +286,7 @@ def test_right_padding_and_a_garbage_row_do_not_move_the_logits():
     np.testing.assert_allclose(logits[0], want, atol=2e-4)
     assert state["counts"].tolist()[0] == 4      # four expert layers ran
     # the convolution tail holds the last three REAL inputs, not padding
-    tail = np.asarray(state["conv"][0, 2])      # [3 rows x 3HD], side by side
+    tail = np.asarray(state["conv"][0, 2])      # 3 rows of 3HD, one after another
     assert np.abs(tail).max() > 0 and np.abs(tail - 7.0).min() > 1e-3
 
 

@@ -69,7 +69,8 @@ def test_weights_state_and_pages_are_what_the_issue_reckoned():
     pages, state = nh.cache_shapes(cfg, 10, 128, 65)
     assert pages["k"] == pages["v"] == (2, 1280 * 2, 128)   # (token, head) rows
     assert state["ssm"] == (6, 65, 64, 64, 128)              # the state size minor
-    assert state["conv"] == (6, 65, 3 * 6144) and state["conv"][-1] % 128 == 0
+    # a slot's 3 tail rows in rows of one lane tile: ONE block of whole tiles
+    assert state["conv"] == (6, 65, 3 * 6144 // 128, 128) and 6144 // 128 % 8 == 0
 
 
 def test_the_family_is_found_by_its_name():

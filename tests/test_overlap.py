@@ -424,6 +424,14 @@ async def test_blocked_queue_keeps_chaining_and_moves_at_first_finish(cause):
     # admission's prefill or a finish, never once per step
     assert 0 < d - chained <= 10 and d > 90
     assert serial_counts["decode_dispatches"] == 0
+    # both loops count the rows of the buckets their decode dispatches ran
+    # and the padding among them: the same streams took the same rows
+    for c in (counts, serial_counts):
+        rows, padded = c["decode_rows_dispatched"], c["decode_rows_padded"]
+        assert rows >= c["steps"]["decode"] > 0 and 0 <= padded < rows
+    assert (counts["decode_rows_dispatched"] - counts["decode_rows_padded"]
+            == serial_counts["decode_rows_dispatched"]
+            - serial_counts["decode_rows_padded"])
 
 
 @pytest.mark.parametrize("how", ["cancelled", "deadline"])

@@ -17,6 +17,7 @@ from dynamo_tpu.models import ModelConfig, family, hybrid, kimi_linear, llama
 from dynamo_tpu.models import qwen3_next as qn
 from dynamo_tpu.models.reference import kimi_linear as kimi_ref
 from dynamo_tpu.models.reference import qwen3_next as ref
+from tests import state_plane_cases as spc
 from tests.qwen3_next_tiny import tiny_qwen3_next
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -56,7 +57,8 @@ def test_weights_state_and_pages_are_what_the_issue_reckoned():
     pages, state = qn.cache_shapes(cfg, 10, 128, 65)
     assert pages["k"] == pages["v"] == (2, 1280 * 2, 256)   # (token, head) rows
     assert state["gdn"] == (6, 65, 32, 128, 128)
-    assert state["conv"] == (6, 65, 3 * 8192) and state["conv"][-1] % 128 == 0
+    # a slot's 3 tail rows in rows of one lane tile: ONE block of whole tiles
+    assert state["conv"] == (6, 65, 3 * 8192 // 128, 128) and 8192 // 128 % 8 == 0
 
 
 def test_a_family_is_found_by_its_name_or_an_error_names_what_exists():
@@ -162,23 +164,25 @@ def test_chunked_scalar_decay_survives_underflow_and_ignores_padding():
     np.testing.assert_allclose(S[0], S_short[0], atol=1e-4)
 
 
-def test_the_decode_kernel_takes_the_broadcast_decay():
+@pytest.mark.parametrize("name", sorted(spc.CASES))
+def test_the_decode_kernel_takes_the_broadcast_decay(name):
+    """``kda_decode_update`` given a head's scalar decay broadcast over
+    its key channels is ``hybrid.delta_decode``, padded rows anywhere."""
     from dynamo_tpu.ops.kda import kda_decode_update
 
     rng = np.random.default_rng(0)
-    Lg, S, H, d, B = 2, 5, 8, 128, 3
-    plane = rng.normal(size=(Lg, S, H, d, d)).astype(np.float32)
-    q, k, v, glog, beta, _ = gdn_inputs(B, 1, H, d, seed=2)
-    slots = jnp.asarray([2, 4, 0], jnp.int32)
-    fresh = jnp.asarray([0, 1, 0], jnp.int32)
+    H, d = 8, 128
+    slots, fresh = spc.case(name)
+    plane = rng.normal(size=(2, spc.SLOTS, H, d, d)).astype(np.float32)
+    q, k, v, glog, beta, _ = gdn_inputs(len(slots), 1, H, d, seed=2)
     S0 = jnp.where(fresh[:, None, None, None] != 0, 0.0, jnp.asarray(plane)[1, slots])
     o_want, S_want = hybrid.delta_decode(
         q[:, 0], k[:, 0], v[:, 0], glog[:, 0], beta[:, 0], S0)
     o, new = kda_decode_update(
         jnp.asarray(plane), 1, slots, fresh, q[:, 0], k[:, 0], v[:, 0],
         jnp.broadcast_to(glog[:, 0], q[:, 0].shape), beta[:, 0], interpret=True)
-    np.testing.assert_allclose(o, o_want, atol=2e-3)
-    np.testing.assert_allclose(new[1, slots], S_want, atol=1e-4)
+    spc.check_rows(o, o_want, slots, atol=2e-3)
+    spc.check_plane(new, plane, 1, slots, S_want, atol=1e-4)
 
 
 # -- the expert block ------------------------------------------------------------
