@@ -1,104 +1,213 @@
-"""Latent attention over the paged latent cache. Decode: one Pallas
-flash-decode kernel over a (row, table column) grid, a page a step: what
-ops/paged_attention.py's decode kernel was before PR 30 (PERF.md §7).
-Prefill (``mla_prefill_attention``, at the file's end): the same page
-walk under a tile of query tokens, causal.
+"""Latent attention over the paged latent cache. Decode
+(``mla_decode_attention``): one Pallas flash-decode kernel with
+``grid=(rows,)`` whose body walks a row's LIVE pages, several to a
+compute block, each page its own DMA into a double buffer — the plan of
+ops/paged_attention.py's decode kernel (PR 30) with a body of its own,
+because here key and value are ONE plane. Prefill
+(``mla_prefill_attention``, at the file's end): a (row, tile, table
+column) grid, a page a step, under a tile of query tokens, causal.
 
 The cache holds ONE row a token a layer: ``[c | k_r]``, the normalised
 latent (``rank`` values) and the shared key part (``rope`` values:
 unrotated for ``kimi_linear``, already rotated for ``deepseek_v3`` — the
-kernels do not care). The queries arrive with the latent's key up-projection already
-absorbed (models/kimi_linear.py), so every one of the H heads scores the
+kernels do not care), stored in whole 128-lane tiles (the models'
+``Cpad``: a plane whose rows are 576 wide is laid out in 640 lanes all
+the same, and the chip's compiler refuses a page copy from it). The
+queries arrive with the latent's key up-projection already absorbed
+(models/hybrid.py ``mla_mixer``), so every one of the H heads scores the
 row itself, all ``rank + rope`` of it, and the values are the row's first
-``rank`` columns: one page tile ``[block, rank + rope]`` serves as key
-and value of all heads at once, and is read from HBM once. Pages are
-addressed through the BlockSpec (layer, block table and contexts ride as
-scalar prefetch), so only the pages a row's context covers move; rows
-with context 0 (padding) touch nothing.
+``rank`` columns: one block of pages ``[P * block, C]`` in VMEM serves
+as key and value of all heads at once, and crosses HBM once. Layer,
+block table and contexts ride as scalar prefetch; only the pages a
+row's context covers move, table columns past them are never
+dereferenced, and rows with context 0 (padding) touch nothing.
+
+On a v5e (PERF.md, PR 47) a call takes 0.23 ms at 64 rows of 5-30 live
+pages and 19 us at one row of 68, 68-72 % of the HBM floor of the 576
+published values a row (the stored 640 lanes bound it at 90), where the
+grid over the table took 0.72 ms and 93 us; flat from 6 to 16 pages a
+block; the scores as ``q @ rows^T`` beat ``rows @ q^T`` by 15-20 %.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.paged_attention import (
+    _DECODE_BLOCK_COLUMNS,
+    _DECODE_KV_BUFFER_BYTES,
+    _DECODE_VMEM_LIMIT_BYTES,
+)
 
-def _kernel(layer_ref, tables_ref, ctx_ref, q_ref, page_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, block_size: int, rank: int):
-    b, j = pl.program_id(0), pl.program_id(1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+def latent_pages_per_block(block_size: int, C: int, itemsize: int) -> int:
+    """Pages of one compute block of the latent decode kernel, by
+    ``ops/paged_attention.py`` ``decode_pages_per_block``'s rule and its
+    two constants (one budget): as many as the double buffer holds of
+    this plane's pages — ONE plane: a page is key and value at once —
+    while the block stays within the dense kernel's ceiling on a
+    block's MXU work. That ceiling is stated in score columns of
+    128-lane rows; a latent column is ``ceil(C / 128)`` lane tiles of
+    MXU weights where a dense one is one, so it counts as that many. A
+    block's compute does not shrink with the pages it holds, and at 32
+    query rows its cost is the weight tiles pushed, not the rows
+    streamed: a short row pays for a whole block."""
+    by_vmem = _DECODE_KV_BUFFER_BYTES // (2 * block_size * C * itemsize)
+    by_columns = _DECODE_BLOCK_COLUMNS // (block_size * -(-C // 128))
+    return max(1, min(by_vmem, by_columns))
+
+
+def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, pages_hbm, o_ref,
+                   buf, sems, state, *, block_size: int, rank: int):
+    """One grid step a ROW over the stacked plane left in HBM as pages
+    ``[Lm, N, bs, C]``. A row walks its LIVE pages only,
+    ``ceil(ctx / bs)`` of them, ``P`` to a compute block, each page one
+    DMA into the ``[2, P, bs, C]`` double buffer; the next block (and,
+    from a row's last block, the first block of the row after it, where
+    that row is live) is in flight while the current one is computed.
+    Table columns past the live range are never dereferenced; a row of
+    context 0 runs no block and starts no copy. The layer is an index,
+    never a slice (a slice of the carried plane before a custom call is
+    a copy of the layer).
+
+    One block is ONE dot of the ``H`` query rows against its ``P * bs``
+    latent rows (all ``C`` lanes), one online-softmax update, one dot of
+    the probabilities against the same rows' first ``rank`` lanes: the
+    block in VMEM is key and value of every head at once."""
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    H, C = q_ref.shape[1], q_ref.shape[2]
+    P, bs = buf.shape[1], block_size
+    cols = P * bs
+    lyr = layer_ref[0]
+
+    @pl.when(b == 0)
+    def _first_row():
+        # slot of this row's first block; whether the row before has
+        # already started it
+        state[0] = 0
+        state[1] = 0
+        # page slots a block leaves unfilled are masked by position, but
+        # 0 x NaN is NaN in the PV dot: no slot may hold uninitialised
+        # VMEM (a filled slot holds cache values, which are finite)
+        buf[...] = jnp.zeros_like(buf)
+
+    def live_pages(row):
+        return (ctx_ref[row] + bs - 1) // bs
+
+    def block_copies(row, n, i, slot, fn):
+        """``fn`` (start or wait) on the copies of block ``i`` of ``row``
+        into ``slot``: one a live page, none for the block's slots past
+        the row's last live page."""
+        def page_copy(p, carry):
+            fn(pltpu.make_async_copy(
+                pages_hbm.at[lyr, tables_ref[row, i * P + p]],
+                buf.at[slot, p], sems.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(P, n - i * P), page_copy, 0)
+
+    start = lambda *a: block_copies(*a, lambda c: c.start())  # noqa: E731
+    wait = lambda *a: block_copies(*a, lambda c: c.wait())  # noqa: E731
 
     ctx = ctx_ref[b]
+    n_pages = live_pages(b)
+    n_blocks = (n_pages + P - 1) // P
+    slot0 = state[0]
+    nxt = jnp.minimum(b + 1, B - 1)
+    n_pages_nxt = live_pages(nxt)
+    prefetch_nxt = (b + 1 < B) & (n_pages_nxt > 0) & (n_blocks > 0)
 
-    @pl.when(j * block_size < ctx)
-    def _page():
-        q = q_ref[0]                                   # [H, C], scaled
-        rows = page_ref[...]                           # [block, C]
+    @pl.when((n_blocks > 0) & (state[1] == 0))
+    def _own_first_block():
+        start(b, n_pages, 0, slot0)
+
+    q = q_ref[0]                                       # [H, C], scaled
+
+    def block(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start(b, n_pages, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == n_blocks) & prefetch_nxt)
+        def _next_row():
+            start(nxt, n_pages_nxt, 0, 1 - slot)
+
+        wait(b, n_pages, i, slot)
+        rows = buf[slot].reshape(cols, C)
         if rows.dtype != q.dtype:
             rows = rows.astype(q.dtype)
         s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        valid = pos < ctx
-        s = jnp.where(valid, s, -1e30)
-        m_prev = m_ref[:]
+        pos = i * cols + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        s = jnp.where(pos < ctx, s, -1e30)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        # every block holds a key of the row's live range, so m_new is a
+        # real score and a masked column's exp is exactly 0
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(rows.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.dot(p.astype(rows.dtype), rows[:, :rank],
+                     preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-9)).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, block,
+        (jnp.full((H, 1), -1e30, jnp.float32),
+         jnp.zeros((H, 1), jnp.float32),
+         jnp.zeros((H, rank), jnp.float32)))
+    state[0] = (slot0 + n_blocks) % 2
+    state[1] = prefetch_nxt.astype(jnp.int32)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-9)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "rank", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "rank", "interpret", "pages_per_block"))
 def mla_decode_attention(q, latent, layer, tables, context_lens, *,
-                         block_size: int, rank: int, interpret: bool = False):
+                         block_size: int, rank: int, interpret: bool = False,
+                         pages_per_block: Optional[int] = None):
     """``q`` [B, H, C] (key up-projection absorbed, softmax scale folded
     in); ``latent`` [Lm, slots, C], the whole stacked plane; ``layer``
     scalar int32; ``tables`` [B, W] page ids; ``context_lens`` [B].
-    Returns the attention output IN LATENT SPACE, [B, H, rank]."""
+    Returns the attention output IN LATENT SPACE, [B, H, rank]; a row of
+    context 0 gets zeros. ``pages_per_block``: pages of one compute
+    block, for tests; by default sized from the call's own geometry
+    (``latent_pages_per_block``)."""
     B, H, C = q.shape
     Lm, slots, _ = latent.shape
     pages = latent.reshape(Lm, slots // block_size, block_size, C)
-    W = tables.shape[1]
-
-    def page_index(b, j, lyr, t, c):
-        last = jnp.maximum((c[b] - 1) // block_size, 0)
-        return (lyr[0], t[b, jnp.minimum(j, last)], 0, 0)
+    P = pages_per_block or latent_pages_per_block(
+        block_size, C, latent.dtype.itemsize)
+    row = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, H, width), lambda b, lyr, t, c: (b, 0, 0))
 
     return pl.pallas_call(
-        functools.partial(_kernel, block_size=block_size, rank=rank),
+        functools.partial(_decode_kernel, block_size=block_size, rank=rank),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer, tables, contexts
-            grid=(B, W),
-            in_specs=[
-                pl.BlockSpec((1, H, C), lambda b, j, lyr, t, c: (b, 0, 0)),
-                pl.BlockSpec((None, None, block_size, C), page_index),
-            ],
-            out_specs=pl.BlockSpec((1, H, rank), lambda b, j, lyr, t, c: (b, 0, 0)),
+            grid=(B,),
+            in_specs=[row(C), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row(rank),
             scratch_shapes=[
-                pltpu.VMEM((H, rank), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
-                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((2, P, block_size, C), latent.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # rows run in order: a row starts the next row's first block
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_DECODE_VMEM_LIMIT_BYTES),
         name="mla_decode_attention",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),

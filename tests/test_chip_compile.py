@@ -298,17 +298,68 @@ def test_conv_tail_update_compiles_for_v5e(family, rows, one_chip, no_compile_ca
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer
 
 
-@pytest.mark.parametrize("rows", [4, 64])
-def test_mla_decode_attention_compiles_for_v5e(rows, one_chip, no_compile_cache):
+def _mla_decode_text(rows, lanes, layers, pool, table_w, one_chip, **kw) -> str:
     from dynamo_tpu.ops.mla import mla_decode_attention
 
-    text = _compile_text(
-        functools.partial(mla_decode_attention, block_size=BS, rank=512),
-        _sds((rows, 32, 576), jnp.bfloat16, one_chip),
-        _sds((2, NUM_BLOCKS * BS, 576), jnp.bfloat16, one_chip),
-        _sds((), jnp.int32, one_chip), _sds((rows, TABLE_W), jnp.int32, one_chip),
+    return _compile_text(
+        functools.partial(mla_decode_attention, block_size=BS, rank=512, **kw),
+        _sds((rows, 32, lanes), jnp.bfloat16, one_chip),
+        _sds((layers, pool * BS, lanes), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((rows, table_w), jnp.int32, one_chip),
         _sds((rows,), jnp.int32, one_chip))
-    assert "tpu_custom_call" in text and "mla_decode_attention" in text
+
+
+def _assert_mla_decode_fits_its_vmem(text: str, lanes: int, P=None):
+    """The kernel is there, asks for the dense decode kernel's scoped
+    VMEM and no more (a kernel over it does not compile), and its double
+    buffer at the rule's pages a block is inside that rule's budget."""
+    import re
+
+    from dynamo_tpu.ops import paged_attention as pa
+    from dynamo_tpu.ops.mla import latent_pages_per_block
+
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and "mla_decode_attention" in line)
+    asked = re.search(r'"scoped_memory_configs":\[\{"memory_space":"1",'
+                      r'"offset":"0","size":"(\d+)"', call)
+    assert asked and int(asked.group(1)) == pa._DECODE_VMEM_LIMIT_BYTES
+    P = P or latent_pages_per_block(BS, lanes, 2)
+    assert 2 * P * BS * lanes * 2 <= pa._DECODE_KV_BUFFER_BYTES
+    assert pa._DECODE_KV_BUFFER_BYTES < pa._DECODE_VMEM_LIMIT_BYTES
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_mla_decode_attention_compiles_for_v5e(rows, one_chip, no_compile_cache):
+    """``kimi_linear``'s plane: 2 layers of rows stored in 640 lanes
+    under a 40-page table, at the pages a block the rule gives."""
+    text = _mla_decode_text(rows, 640, 2, NUM_BLOCKS, TABLE_W, one_chip)
+    _assert_mla_decode_fits_its_vmem(text, 640)
+
+
+@pytest.mark.parametrize("pages", [1, 25])
+def test_mla_decode_attention_compiles_at_the_ends_of_its_block_rule(
+    pages, one_chip, no_compile_cache
+):
+    """A page a block, and as many as the double buffer's budget holds of
+    640-lane pages (what the rule would give a plane whose block ceiling
+    did not bind first)."""
+    text = _mla_decode_text(64, 640, 2, NUM_BLOCKS, TABLE_W, one_chip,
+                            pages_per_block=pages)
+    _assert_mla_decode_fits_its_vmem(text, 640, pages)
+
+
+def test_mla_decode_refuses_a_plane_stored_576_lanes_wide(
+    one_chip, no_compile_cache
+):
+    """Why both latent families store ``rank + rope`` = 576 values in 640
+    lanes (``Cpad``): the described chip's compiler lays a ``[..., 576]``
+    plane out in 640 lanes all the same and refuses the kernel's page
+    copy from it — at the 6 pages a block the rule gives that width too."""
+    from dynamo_tpu.ops.mla import latent_pages_per_block
+
+    assert latent_pages_per_block(BS, 576, 2) == latent_pages_per_block(BS, 640, 2)
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\), but is 576"):
+        _mla_decode_text(4, 576, 2, NUM_BLOCKS, TABLE_W, one_chip)
 
 
 @pytest.mark.parametrize("k,n", [(2304, 4096), (2304, 128), (128, 4096),
@@ -567,17 +618,10 @@ DS_POOL = _STAGE_BLOCKS["kanana-2-30b"]   # the engine's own sizing gives 2 277
 
 @pytest.mark.parametrize("rows", [4, 64])
 def test_mla_decode_attention_compiles_at_a_16k_table(rows, one_chip, no_compile_cache):
-    """The decode kernel as it stands, over 640-lane rows of a 12-layer
-    plane and a table 136 pages wide."""
-    from dynamo_tpu.ops.mla import mla_decode_attention
-
-    text = _compile_text(
-        functools.partial(mla_decode_attention, block_size=BS, rank=512),
-        _sds((rows, 32, 640), jnp.bfloat16, one_chip),
-        _sds((12, DS_POOL * BS, 640), jnp.bfloat16, one_chip),
-        _sds((), jnp.int32, one_chip), _sds((rows, DS_TABLE_W), jnp.int32, one_chip),
-        _sds((rows,), jnp.int32, one_chip))
-    assert "tpu_custom_call" in text and "mla_decode_attention" in text
+    """The decode kernel over 640-lane rows of a 12-layer plane and a
+    table 136 pages wide: the table's width is no axis of its grid."""
+    text = _mla_decode_text(rows, 640, 12, DS_POOL, DS_TABLE_W, one_chip)
+    _assert_mla_decode_fits_its_vmem(text, 640)
 
 
 @pytest.mark.parametrize("rows,tokens", [(1, 128), (1, 1024), (4, 1024), (8, 256)])
@@ -707,6 +751,12 @@ _DENSE_DIGESTS = {
     "decode-mistral-window-bf16-B8": ((False, 8, 1, False, 32, 8, 128, 4096), "a3e590bcfa314c82"),
     "decode-qwen-bf16-B64": ((False, 64, 1, False, 28, 4, 128, None), "1434539047811899"),
     "decode-head256-bf16-B64": ((False, 64, 1, False, 16, 2, 256, None), "4964b5e1b83ea506"),
+    # held since PR 47 (the parent's, commit 7625667: that PR gave latent
+    # decode a body of its own and left this file's alone): the decode
+    # programs of mistral-7b's and qwen2.5-7b's cells at their buckets
+    "decode-mistral-window-bf16-B64": ((False, 64, 1, False, 32, 8, 128, 4096), "f588bba7ba7c8baf"),
+    "decode-mistral-window-bf16-B32": ((False, 32, 1, False, 32, 8, 128, 4096), "542c21c77fb4f801"),
+    "decode-qwen-bf16-B32": ((False, 32, 1, False, 28, 4, 128, None), "04311eee5d519b66"),
     "prefill-llama-bf16-1x1024": ((True, 1, 1024, False, 32, 8, 128, None), "9d5d2bd197cfb305"),
     "prefill-mistral-window-bf16-4x1024": ((True, 4, 1024, False, 32, 8, 128, 4096), "cbeb2373e00c00c7"),
     "prefill-llama-int8-32x128": ((True, 32, 128, True, 32, 8, 128, None), "7a4f751b8cbc2a89"),
