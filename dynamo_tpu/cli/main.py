@@ -394,10 +394,6 @@ def build_parser() -> argparse.ArgumentParser:
                      help="print JSON rows instead of the table")
     top.add_argument("--no-clear", action="store_true",
                      help="don't clear the screen between frames")
-    top.add_argument("--watch-roofline", action="store_true",
-                     help="sort workers by roofline_frac ascending — "
-                          "the worker losing the most throughput to "
-                          "its loss buckets renders first")
 
     # observability: `dynamo-tpu autopsy <rid>` (per-request timeline)
     autopsy_p = sub.add_parser(

@@ -156,7 +156,6 @@ def _engine_row(url: str, state: dict, prev: Optional[dict],
     hbm = eng.get("hbm") or {}
     load = eng.get("load") or {}
     rec = eng.get("flight_recorder") or {}
-    attr = (eng.get("attribution") or {}).get("window") or {}
     tok_rate: Optional[float] = None
     # tokens_generated_total counts ALL generated tokens (goodput only
     # counts SLO-met ones and stays 0 when no targets are configured).
@@ -183,10 +182,6 @@ def _engine_row(url: str, state: dict, prev: Optional[dict],
         "kv_total": pool.get("total_blocks"),
         "tok_s": tok_rate,
         "slo": slo.get("attainment") if slo.get("enabled") else None,
-        # perf attribution (telemetry/attribution.py): live roofline
-        # fraction + the window's dominant loss bucket per worker
-        "roofline": attr.get("roofline_frac"),
-        "loss_bucket": attr.get("top_loss_bucket") or None,
         "hbm": hbm.get("bytes_in_use"),
         "slow_steps": rec.get("slow_steps"),
         "preemptions": sched.get("preemptions"),
@@ -196,7 +191,7 @@ def _engine_row(url: str, state: dict, prev: Optional[dict],
 
 HEADER = (
     f"{'WORKER':<28} {'MODEL':<12} {'STATE':>5} {'RUN':>5} {'WAIT':>5} "
-    f"{'KV%':>7} {'TOK/S':>8} {'ROOF%':>7} {'LOSS':>10} {'SLO%':>7} "
+    f"{'KV%':>7} {'TOK/S':>8} {'SLO%':>7} "
     f"{'HBM':>9} {'SSTEP':>5} {'SLOW':>5} {'PREEMPT':>7} "
     f"{'LAG99':>7} {'STRM':>6} {'RPS':>7}"
 )
@@ -226,8 +221,6 @@ def render_frame(rows: list[dict], out: TextIO) -> None:
             f"{run_s:>5} "
             f"{str(r['waiting'] if r['waiting'] is not None else '-'):>5} "
             f"{_pct(r['kv_usage']):>7} {tok} "
-            f"{_pct(r.get('roofline')):>7} "
-            f"{str(r.get('loss_bucket') or '-')[:10]:>10} "
             f"{_pct(r['slo']):>7} "
             f"{_fmt_bytes(r['hbm']):>9} "
             f"{str(r['slow_steps'] if r['slow_steps'] is not None else '-'):>5} "
@@ -245,14 +238,10 @@ async def run_top(
     raw: bool = False,
     clear: bool = True,
     out: TextIO = sys.stdout,
-    watch_roofline: bool = False,
 ) -> int:
     """Poll ``urls`` and render frames until ``iterations`` runs out
     (None = forever). Returns an exit code (1 when EVERY worker errored
-    on the final frame — a dead fleet should fail scripts).
-    ``watch_roofline`` sorts the table by roofline_frac ascending —
-    the worker bleeding the most throughput floats to the top (workers
-    without a decode window sort last; errored rows stay last)."""
+    on the final frame — a dead fleet should fail scripts)."""
     prev: dict[str, tuple[dict, float]] = {}
     prev_hp: dict[str, Optional[dict]] = {}
     n = 0
@@ -292,13 +281,6 @@ async def run_top(
                 rows.append(row)
                 prev[url] = (res, now)
                 prev_hp[url] = hp
-            if watch_roofline:
-                rows.sort(key=lambda r: (
-                    "error" in r and r.get("error") is not None,
-                    r.get("roofline") is None,
-                    r.get("roofline") if r.get("roofline") is not None
-                    else 0.0,
-                ))
             if raw:
                 payload = {
                     r["url"] if "url" in r else urls[i]: r
@@ -327,7 +309,6 @@ def cmd_top(args: Any) -> int:
             iterations=1 if args.once else args.iterations,
             raw=args.raw,
             clear=not args.no_clear,
-            watch_roofline=getattr(args, "watch_roofline", False),
         ))
     except KeyboardInterrupt:
         return 0

@@ -88,13 +88,8 @@ from dynamo_tpu.telemetry.spans import (
     note_loop_thread,
     step_span,
 )
-from dynamo_tpu.telemetry.attribution import (
-    AttributionLedger,
-    BlackBox,
-    register_attribution_provider,
-    unregister_attribution_provider,
-)
-from dynamo_tpu.telemetry.hbm import HbmAccountant, tree_bytes
+from dynamo_tpu.telemetry.blackbox import BlackBox
+from dynamo_tpu.telemetry.hbm import HbmAccountant, device_peaks, tree_bytes
 from dynamo_tpu.telemetry.instruments import (
     COMPILE_FENCE_EVENTS,
     ENGINE_BATCH_OCCUPANCY,
@@ -191,12 +186,6 @@ class ForwardPassMetrics:
     slo_enabled: bool = False
     slo_attainment: float = 1.0
     goodput_tokens_total: int = 0
-    # perf attribution (telemetry/attribution.py): live achieved/roofline
-    # ratio and the window's dominant loss bucket. -1.0 = no decode
-    # window yet (aggregators exclude it from the fleet mean — a fresh
-    # worker must not read as either perfect or broken).
-    roofline_frac: float = -1.0
-    top_loss_bucket: str = ""
 
     def to_dict(self) -> dict:
         return self.__dict__.copy()
@@ -366,19 +355,11 @@ class JaxEngine:
             SloConfig(ttft_ms=config.slo_ttft_ms, itl_ms=config.slo_itl_ms)
         )
         self.hbm = HbmAccountant()
-        # continuous perf attribution (telemetry/attribution.py): the
-        # per-step loss-bucket ledger behind dynamo_step_time_frac /
-        # dynamo_roofline_frac; the byte model installs in
-        # _initialize_inner once the geometry is known. Engine-thread
-        # writes, snapshot reads.
-        self.attribution = AttributionLedger()
         # anomaly-triggered black-box capture: slow-step/idle-gap
-        # watchdog trips and roofline-band drops bundle the flight
-        # recorder ring + attribution window + /debug/state into one
-        # timestamped dump dir (rate-limited)
+        # watchdog trips bundle the flight recorder ring + /debug/state
+        # into one timestamped dump dir (rate-limited)
         self.blackbox = BlackBox(
             recorder=self.recorder,
-            ledger=self.attribution,
             dump_dir=config.flight_dump_dir,
         )
         # per-dispatch phase timings (_run_device_step fills; the step
@@ -439,9 +420,6 @@ class JaxEngine:
         engine._debug_name = "engine"
         register_debug_provider(engine._debug_name, engine.debug_state)
         register_count_provider(engine._debug_name, engine.program_counts)
-        register_attribution_provider(
-            engine._debug_name, engine.attribution_state
-        )
         if faults.ACTIVE is not None and engine.recorder is not None:
             # fired faults land in the flight recorder's ring so an
             # anomaly dump shows the injected chaos next to the steps
@@ -542,12 +520,11 @@ class JaxEngine:
             ep=cfg.expert_parallel_size,
         )
         devices = jax.devices()[: mesh_cfg.size]
-        from dynamo_tpu.telemetry.roofline import device_peaks
         from dynamo_tpu.utils.jaxtools import warn_if_cpu_fallback
 
         # an accelerator with no published peaks fails HERE, before any
-        # weight loads, rather than being reported against a v5e roofline
-        peaks = device_peaks(devices[0])
+        # weight loads
+        device_peaks(devices[0])
         warn_if_cpu_fallback(log, f"engine {cfg.model_name!r}")
         self.mesh = build_mesh(mesh_cfg, devices)
         from dynamo_tpu.models.llama import set_attention_mesh
@@ -610,15 +587,6 @@ class JaxEngine:
             quantize=cfg.quantization,
         )
         self.eos_token_ids = self.model_config.eos_token_ids
-        # install the attribution ledger's byte model: geometry + quant
-        # + kv dtype are now final, so the live roofline denominator
-        # (roofline.py) can be computed
-        from dynamo_tpu.telemetry.roofline import build_roofline
-
-        self.attribution.configure(build_roofline(
-            self.model_config, cfg.quantization, cfg.kv_cache_dtype,
-            hbm_bw=peaks.hbm_bytes_per_s,
-        ))
 
         if jnp.dtype(cfg.kv_cache_dtype) == jnp.int8:
             # int8 KV limits (ops/kv_quant.py documents the layout):
@@ -2439,9 +2407,7 @@ class JaxEngine:
                     continue  # more queued: keep draining
                 # no work: the wait for the next request is load, not a
                 # device idle gap — drop the overlap tracker's anchor
-                # and break the attribution timeline for the same reason
                 self.overlap.note_idle()
-                self.attribution.note_idle()
                 with step_span("dyn.step.wait"):
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
@@ -2706,7 +2672,6 @@ class JaxEngine:
     def _record_step(
         self, kind: str, duration_s: float,
         batch: int = 0, prefill_rows: int = 0, use_phases: bool = True,
-        tokens: int = 0, overlapped: bool = False,
         **extra,
     ) -> None:
         """One flight-recorder entry per device step: kind, batch
@@ -2718,12 +2683,8 @@ class JaxEngine:
         ``_last_phases`` there would attribute a stale, unrelated
         dispatch's timings to this step.
 
-        ``tokens``/``overlapped`` feed the attribution ledger
-        (telemetry/attribution.py): tokens emitted by this step and
-        whether its dispatch overlapped other host work (the decode/
-        window pipelines) — the ledger's partition rules differ
-        (docstring there). A slow-step/idle-gap watchdog dump or a
-        ledger roofline-band anomaly triggers the black-box bundle."""
+        A slow-step/idle-gap watchdog dump triggers the black-box
+        bundle."""
         sched = self.scheduler
         self._step_counter += 1
         self._update_pool_gauges()
@@ -2749,27 +2710,6 @@ class JaxEngine:
         if use_phases:
             fields.update(phases)
         fields.update(extra)
-        # attribution ledger: live context from the scheduler (advisory
-        # — one step stale under the pipelines); the spec step's
-        # draft/verify stamps map onto plan/sync (host drafting ahead
-        # of the harvest-blocking verify)
-        try:
-            anomaly = self.attribution.note_step(
-                kind, duration_s,
-                batch=batch or fields["running"],
-                tokens=tokens,
-                context_tokens=sum(
-                    s.num_computed for s in sched.running
-                ),
-                plan_ms=fields.get("plan_ms") or fields.get("draft_ms") or 0.0,
-                dispatch_ms=fields.get("dispatch_ms") or 0.0,
-                sync_ms=fields.get("sync_ms") or fields.get("verify_ms") or 0.0,
-                idle_gap_ms=fields.get("idle_gap_ms") or 0.0,
-                overlapped=overlapped,
-            )
-        except Exception:  # advisory: never fail a step on accounting
-            log.debug("attribution note failed", exc_info=True)
-            anomaly = None
         dump = None
         if self.recorder is not None:
             dump = self.recorder.record(kind, duration_s, **fields)
@@ -2777,8 +2717,6 @@ class JaxEngine:
             # watchdog tripped (slow step or idle gap): preserve the
             # full forensic context, not just the ring
             self.blackbox.trigger(f"watchdog:{kind}")
-        elif anomaly is not None:
-            self.blackbox.trigger(anomaly)
         self._check_compile_fence(kind)
         self._check_transfer_fence(kind)
 
@@ -3034,10 +2972,6 @@ class JaxEngine:
                 plan.kind, dt,
                 batch=len(seqs),
                 prefill_rows=len(plan.prefill_batch),
-                tokens=(
-                    sum(1 for w in plan.prefill_batch if w.is_last_chunk)
-                    if plan.kind == "prefill" else len(seqs)
-                ),
                 plan_ms=plan_ms,
                 synced=need_sync,
             )
@@ -3213,7 +3147,6 @@ class JaxEngine:
             self._record_step(
                 "spec", draft_s + verify_s,
                 batch=len(works),
-                tokens=len(works) + accepted,  # accepted prefix + 1 per row
                 use_phases=False,  # draft/verify ms below ARE the phases
                 draft_ms=round(draft_s * 1e3, 3),
                 verify_ms=round(verify_s * 1e3, 3),
@@ -3418,7 +3351,7 @@ class JaxEngine:
         rows, and nothing allocates until after its harvest, so their
         freed blocks cannot race its writes."""
         late = False
-        proposed = accepted = emitted = 0
+        proposed = accepted = 0
         for i, (seq, drafts) in enumerate(entry["works"]):
             if seq.state != SeqState.RUNNING:
                 continue
@@ -3431,11 +3364,9 @@ class JaxEngine:
             n = int(n_emit[i])
             proposed += len(drafts)
             accepted += n - 1
-            emitted += n
             self._emit_window(seq, toks[i, :n], lps[i, :n])
         entry["proposed"] = proposed
         entry["accepted"] = accepted
-        entry["tokens"] = emitted
         if proposed:
             SPEC_PROPOSED_TOKENS.labels(self._drafter.kind).inc(proposed)
             if accepted:
@@ -3446,13 +3377,9 @@ class JaxEngine:
         return late
 
     def _finish_spec_record(self, entry: dict, sync_s: float) -> None:
-        """Flight-recorder + attribution row for one pipelined spec
-        step (kind "spec", overlapped): exposed draft/plan time rides
-        ``plan_ms`` (the ledger's overlapped branch bills the measured
-        idle gap to plan first), the harvest block is ``sync_ms``, and
-        the hidden pre-draft simply isn't loss — the device was busy
-        under it, so it lands in the device-phase buckets and the
-        fractions still sum to 1.0 by construction."""
+        """Flight-recorder row for one pipelined spec step (kind
+        "spec"): exposed draft/plan time rides ``plan_ms``, the harvest
+        block is ``sync_ms``."""
         self.spec_pipeline_steps += 1
         tot = self.spec_draft_hidden_s_total + self.spec_draft_exposed_s_total
         if tot > 0:
@@ -3467,8 +3394,6 @@ class JaxEngine:
         self._record_step(
             "spec", dt,
             batch=len(entry["works"]),
-            tokens=entry.get("tokens", 0),
-            overlapped=True,
             use_phases=False,  # per-entry stamps below
             plan_ms=entry["plan_ms"],
             draft_ms=entry["draft_ms"],
@@ -3663,11 +3588,8 @@ class JaxEngine:
                         entry["packed"], nxt["arrays"]["tokens"],
                         nxt["src_idx"],
                     )
-                # the attribution ledger's plan_ms carries the WHOLE
-                # exposed host span (repair + plan + chain): its
-                # overlapped branch bills the measured idle gap to plan
-                # first, which is exactly where exposed drafting should
-                # land ("exposed draft stays plan")
+                # the record's plan_ms carries the WHOLE exposed host
+                # span (repair + plan + chain)
                 next_entry = self._dispatch_spec_entry(
                     nxt,
                     plan_ms=round((exposed_ns + chaining.last_ns) / 1e6, 3),
@@ -3994,8 +3916,6 @@ class JaxEngine:
                     kind, dt,
                     batch=n_rows,
                     prefill_rows=len(e["works"]),
-                    tokens=len(e["vmap"]),
-                    overlapped=True,
                     use_phases=False,  # per-entry stamps below
                     plan_ms=e["plan_ms"],
                     sync_ms=sync_ms,
@@ -4591,8 +4511,6 @@ class JaxEngine:
                     "window_" + e["kind"], win_s,
                     batch=len(e["seqs"]),
                     prefill_rows=len(e["works"]),
-                    tokens=sum(e["vmap"].values()),
-                    overlapped=True,
                     pipeline_depth=len(pending),
                     use_phases=False,  # dispatched via the window fns,
                     # not _run_device_step — its phase stamps belong
@@ -5274,10 +5192,6 @@ class JaxEngine:
     def stats(self) -> ForwardPassMetrics:
         sched, alloc = self.scheduler, self.allocator
         assert sched is not None and alloc is not None
-        # cached rollup (refreshed every GAUGE_EVERY steps): stats()
-        # feeds admission control per HTTP request and the metrics
-        # publisher per interval — neither may pay an O(window) pass
-        attr = self.attribution.summary_cached()
         return ForwardPassMetrics(
             request_active_slots=sched.num_running,
             request_total_slots=self.config.max_batch_size,
@@ -5293,11 +5207,6 @@ class JaxEngine:
             slo_enabled=self.slo.config.enabled,
             slo_attainment=self.slo.attainment,
             goodput_tokens_total=self.slo.goodput_tokens,
-            roofline_frac=(
-                attr["roofline_frac"]
-                if attr["roofline_frac"] is not None else -1.0
-            ),
-            top_loss_bucket=attr["top_loss_bucket"],
         )
 
     def program_counts(self) -> dict:
@@ -5394,17 +5303,6 @@ class JaxEngine:
         for name, d in zip(names, delta.tolist()):
             self._family_counts[name] = self._family_counts.get(name, 0) + int(d)
         return dict(self._family_counts)
-
-    def attribution_state(self) -> dict:
-        """Provider behind ``/debug/attribution``: the ledger window +
-        recent per-step rows and the black-box capture stats."""
-        # gauges refresh here too, so /metrics scraped next to the
-        # endpoint agrees with the snapshot (mirrors _update_pool_gauges)
-        self.attribution.refresh_gauges()
-        return {
-            "attribution": self.attribution.snapshot(),
-            "blackbox": self.blackbox.stats(),
-        }
 
     def debug_state(self) -> dict:
         """Live snapshot for ``/debug/state`` (telemetry/debug.py):
@@ -5554,10 +5452,6 @@ class JaxEngine:
         # worker has compiled anything mid-serve
         out["compile_fence"] = compile_fence.stats()
         out["transfer_fence"] = transfer_fence.stats()
-        # perf attribution (telemetry/attribution.py): where the decode
-        # window's wall time went, the live roofline fraction, and the
-        # black-box capture state — what `top`'s ROOF%/LOSS columns read
-        out["attribution"] = self.attribution.snapshot()
         out["blackbox"] = self.blackbox.stats()
         if self.recorder is not None:
             out["flight_recorder"] = self.recorder.stats()
@@ -5625,9 +5519,6 @@ class JaxEngine:
         if self._debug_name is not None:
             unregister_debug_provider(self._debug_name, self.debug_state)
             unregister_count_provider(self._debug_name, self.program_counts)
-            unregister_attribution_provider(
-                self._debug_name, self.attribution_state
-            )
             self._debug_name = None
         from dynamo_tpu.models.llama import (
             get_attention_mesh,

@@ -152,7 +152,7 @@ class HttpService:
         # dumps its own flight ring + black-box bundle (loop_stall)
         self.hostplane = LEDGER
         if lag_monitor is None:
-            from dynamo_tpu.telemetry.attribution import BlackBox
+            from dynamo_tpu.telemetry.blackbox import BlackBox
             from dynamo_tpu.telemetry.recorder import FlightRecorder
 
             rec = FlightRecorder(capacity=256)
@@ -167,7 +167,6 @@ class HttpService:
                 web.get("/live", self._health),
                 web.get("/metrics", self._metrics),
                 web.get("/debug/state", self._debug_state),
-                web.get("/debug/attribution", self._debug_attribution),
                 web.get("/debug/hostplane", self._debug_hostplane),
                 web.get("/debug/kvfleet", self._debug_kvfleet),
                 web.get("/debug/requests", self._debug_requests),
@@ -230,15 +229,6 @@ class HttpService:
             "port": self.port,
         }
         return web.json_response(state)
-
-    async def _debug_attribution(self, request: web.Request) -> web.Response:
-        """Perf attribution (docs/observability.md "Perf attribution"):
-        the decode window's loss-bucket fractions, live roofline_frac,
-        per-bucket tokens-lost rates, recent per-step rows, and the
-        black-box capture state — the 'where do the tokens go' endpoint."""
-        from dynamo_tpu.telemetry.attribution import collect_attribution
-
-        return web.json_response(collect_attribution())
 
     def _hostplane_stanza(self) -> dict:
         """The frontend's /debug/hostplane provider: loop-lag window +

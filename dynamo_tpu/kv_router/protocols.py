@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from typing import Literal, Optional
 
-from pydantic import BaseModel, Field
+from pydantic import BaseModel, ConfigDict, Field
 
 
 class KvCacheEvent(BaseModel):
@@ -31,6 +31,10 @@ class RouterEvent(BaseModel):
 class ForwardPassMetrics(BaseModel):
     """Worker load snapshot (reference: protocols.rs ForwardPassMetrics)."""
 
+    # a rolling restart mixes worker versions on one feed: a key this
+    # version does not know is dropped, never an error
+    model_config = ConfigDict(extra="ignore")
+
     worker_id: int = 0
     request_active_slots: int = 0
     request_total_slots: int = 0
@@ -47,12 +51,6 @@ class ForwardPassMetrics(BaseModel):
     slo_enabled: bool = False
     slo_attainment: float = 1.0
     goodput_tokens_total: int = 0
-    # perf attribution (telemetry/attribution.py): live achieved-over-
-    # roofline ratio and the attribution window's dominant loss bucket.
-    # -1.0 = no decode window yet; aggregators exclude it from the
-    # fleet mean (`dynamo-tpu top` renders it per worker as ROOF%/LOSS).
-    roofline_frac: float = -1.0
-    top_loss_bucket: str = ""
 
 
 class KvHitRateEvent(BaseModel):

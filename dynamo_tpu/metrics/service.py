@@ -87,12 +87,6 @@ class MetricsService:
         self._g_goodput = r.gauge(
             "llm_goodput_tokens", "total goodput tokens (SLO-met "
             "completion tokens) across workers")
-        # perf-attribution rollup (telemetry/attribution.py signals on
-        # the same feed): fleet-mean achieved/roofline ratio over the
-        # workers that have a decode window (roofline_frac >= 0)
-        self._g_roofline = r.gauge(
-            "llm_roofline_frac", "mean live roofline fraction across "
-            "workers with decode activity")
 
     def build_app(self) -> web.Application:
         """The debug/metrics route table, separable from ``start()`` so
@@ -103,7 +97,6 @@ class MetricsService:
         app = web.Application()
         app.router.add_get("/metrics", self._handle_metrics)
         app.router.add_get("/debug/state", self._handle_debug_state)
-        app.router.add_get("/debug/attribution", self._handle_debug_attribution)
         app.router.add_get("/debug/hostplane", self._handle_debug_hostplane)
         app.router.add_get("/debug/kvfleet", self._handle_debug_kvfleet)
         app.router.add_get("/debug/requests", self._handle_debug_requests)
@@ -179,11 +172,6 @@ class MetricsService:
         attainment, goodput = aggregate_slo(fresh.values())
         self._g_slo_attainment.set(attainment)
         self._g_goodput.set(goodput)
-        roofs = [
-            m.roofline_frac for m in fresh.values()
-            if getattr(m, "roofline_frac", -1.0) >= 0.0
-        ]
-        self._g_roofline.set(sum(roofs) / len(roofs) if roofs else 0.0)
         return self.registry.render()
 
     async def _handle_metrics(self, _req: web.Request) -> web.Response:
@@ -199,25 +187,6 @@ class MetricsService:
         state["workers"] = {
             f"{wid:x}": m.model_dump() if hasattr(m, "model_dump")
             else dict(m.__dict__)
-            for wid, m in sorted(fresh.items())
-        }
-        return web.json_response(state)
-
-    async def _handle_debug_attribution(
-        self, _req: web.Request
-    ) -> web.Response:
-        """Worker-side perf attribution (in-process engines register
-        providers) plus the fleet's per-worker roofline/loss view from
-        the load feed."""
-        from dynamo_tpu.telemetry.attribution import collect_attribution
-
-        state = collect_attribution()
-        fresh = self.aggregator.fresh_metrics()
-        state["workers"] = {
-            f"{wid:x}": {
-                "roofline_frac": getattr(m, "roofline_frac", -1.0),
-                "top_loss_bucket": getattr(m, "top_loss_bucket", ""),
-            }
             for wid, m in sorted(fresh.items())
         }
         return web.json_response(state)

@@ -15,10 +15,11 @@ Four pieces (docs/observability.md is the operator-facing guide):
 - **Metrics** (metrics.py): one process registry of labeled counters/
   gauges/histograms with Prometheus text exposition and cardinality
   guard rails; the serving stack's catalog lives in instruments.py.
-- **Live introspection** (debug.py, recorder.py, hbm.py): the
-  ``/debug/state``/``/debug/profile`` provider registry, the engine's
-  step flight recorder with slow-step watchdog dumps, and HBM memory
-  accounting. ``dynamo-tpu top`` renders the fleet view.
+- **Live introspection** (debug.py, recorder.py, blackbox.py, hbm.py):
+  the ``/debug/state``/``/debug/profile`` provider registry, the
+  engine's step flight recorder with slow-step watchdog dumps, the
+  anomaly-triggered black-box bundle, and HBM memory accounting.
+  ``dynamo-tpu top`` renders the fleet view.
 - **SLO/goodput** (slo.py): per-request TTFT/ITL vs configured targets
   → ``dynamo_slo_attainment``/``dynamo_goodput_tokens_total``, riding
   the worker load feed for the Planner.
@@ -44,13 +45,7 @@ from dynamo_tpu.telemetry.debug import (  # noqa: F401
     unregister_count_provider,
     unregister_debug_provider,
 )
-from dynamo_tpu.telemetry.attribution import (  # noqa: F401
-    AttributionLedger,
-    BlackBox,
-    collect_attribution,
-    register_attribution_provider,
-    unregister_attribution_provider,
-)
+from dynamo_tpu.telemetry.blackbox import BlackBox  # noqa: F401
 from dynamo_tpu.telemetry.hbm import HbmAccountant, tree_bytes  # noqa: F401
 from dynamo_tpu.telemetry.hostplane import (  # noqa: F401
     HostCostLedger,

@@ -1,9 +1,9 @@
 """End-to-end request autopsy: tail-sampled per-request timelines.
 
 The serving stack's telemetry is rich but siloed — spans land in an
-opt-in ``DYN_TRACE_FILE``, the flight recorder and attribution ledger
-are step-centric, the hostplane ledger keeps stage EMAs, and
-migration/guided/kv-fabric outcomes each live in their own counters.
+opt-in ``DYN_TRACE_FILE``, the flight recorder is step-centric, the
+hostplane ledger keeps stage EMAs, and migration/guided/kv-fabric
+outcomes each live in their own counters.
 This module is the join layer: every request accumulates ONE compact
 in-memory record keyed by the ``X-Request-Id``/``Context.id`` that
 already rides the wire ctx frame, assembled from four sources:
@@ -59,7 +59,7 @@ MAX_ROUTER = 16
 MAX_SEGMENTS = 8
 
 # recompute the p99 retention thresholds every N finishes (the same
-# amortization discipline as the hostplane/attribution ledgers)
+# amortization discipline as the hostplane ledger)
 GAUGE_EVERY = 32
 
 # below this many finished requests in the rolling window the p99 is
@@ -522,7 +522,7 @@ def current_onboard_rid() -> Optional[str]:
 
 # ---------------------------------------------------------------------------
 # /debug/requests provider registry — the SAME machinery as
-# /debug/state, /debug/attribution, and /debug/hostplane: fourth instance
+# /debug/state and /debug/hostplane: third instance
 # ---------------------------------------------------------------------------
 from dynamo_tpu.telemetry.debug import ProviderRegistry  # noqa: E402
 

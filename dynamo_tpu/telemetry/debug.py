@@ -53,9 +53,9 @@ log = logging.getLogger("dynamo_tpu.telemetry.debug")
 
 class ProviderRegistry:
     """Named zero-arg snapshot providers behind one lock — the shape
-    both ``/debug/state`` and ``/debug/attribution`` share (one
-    implementation so fixes to the identity-checked unregister or the
-    error-stanza collect can't drift between them).
+    ``/debug/state``, ``/debug/hostplane`` and ``/debug/requests`` share
+    (one implementation so fixes to the identity-checked unregister or
+    the error-stanza collect can't drift between them).
 
     Cross-thread contract (dynalint DL103 vocabulary, docs/
     static_analysis.md): written from the event loop (engines

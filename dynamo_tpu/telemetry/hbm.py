@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import logging
 import threading
+from dataclasses import dataclass
 from typing import Any, Optional
 
 from dynamo_tpu.telemetry.instruments import (
@@ -31,6 +32,44 @@ from dynamo_tpu.telemetry.instruments import (
 )
 
 log = logging.getLogger("dynamo_tpu.telemetry.hbm")
+
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    hbm_bytes_per_s: float
+    bf16_flops_per_s: float
+    hbm_bytes: float
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM2e at 819 GB/s per chip. JAX reports a v5e
+# chip as "TPU v5 lite". An accelerator whose kind is not here is an
+# error (device_peaks), never a silent v5e.
+_V5E = DevicePeaks(hbm_bytes_per_s=819e9, bf16_flops_per_s=197e12,
+                   hbm_bytes=16e9)
+DEVICE_PEAKS: dict[str, DevicePeaks] = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+
+# CPU backends (tests, dev runs) have no peaks of their own: they keep
+# the v5e row as a NAMED default; nothing computed from it there is a
+# device metric.
+CPU_DEFAULT_KIND = "TPU v5 lite"
+
+
+def device_peaks(device) -> DevicePeaks:
+    """Peaks of ``device`` (a ``jax.Device``); raises for an
+    accelerator the table does not know."""
+    if device.platform == "cpu":
+        return DEVICE_PEAKS[CPU_DEFAULT_KIND]
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); add its datasheet row to "
+            f"telemetry.hbm.DEVICE_PEAKS (known: "
+            f"{sorted(DEVICE_PEAKS)})"
+        ) from None
 
 
 def tree_bytes(tree: Any) -> int:

@@ -1,11 +1,12 @@
 """Host data-plane observability: event-loop lag + per-stream cost.
 
-The engine side has the attribution ledger (telemetry/attribution.py)
-answering "where do the device's tokens go"; this module is its twin
-for the *frontend host plane* — the single-process asyncio loop that
-parses requests, sheds load, primes first chunks, and serializes SSE
-deltas, and that will saturate long before the chips do (ROADMAP
-item 4). Nothing here should be invisible before PR 18 shards it.
+The engine side has the step loop's phase clocks (telemetry/spans.py
+``StepClock``, in ``/debug/state``) answering "where does the step's
+time go"; this module is their twin for the *frontend host plane* —
+the single-process asyncio loop that parses requests, sheds load,
+primes first chunks, and serializes SSE deltas, and that will saturate
+long before the chips do (ROADMAP item 4). Nothing here should be
+invisible before PR 18 shards it.
 
 Three pieces, all surfaced at ``/debug/hostplane`` (HTTP frontend and
 metrics service) via the same :class:`ProviderRegistry` machinery as
@@ -64,8 +65,7 @@ log = logging.getLogger("dynamo_tpu.telemetry.hostplane")
 # ledger stage names (the bounded label set of dynamo_http_host_stage_seconds)
 STAGES = ("preprocess", "admission", "dispatch", "prime", "tool_parser")
 
-# refresh the derived gauges every N heartbeats / finished requests —
-# same amortization discipline as the attribution ledger's GAUGE_EVERY
+# refresh the derived gauges every N heartbeats / finished requests
 GAUGE_EVERY = 32
 
 
@@ -294,10 +294,9 @@ class HostCostLedger:
     the record into the histograms and the rolling window the
     ``/debug/hostplane`` snapshot reads.
 
-    Thread-safety matches the attribution ledger: stamped from the
-    event loop, read from arbitrary threads (debug endpoints) — one
-    lock, all accesses take it. Both the active table and the finished
-    window are bounded (DL007).
+    Thread-safety: stamped from the event loop, read from arbitrary
+    threads (debug endpoints) — one lock, all accesses take it. Both the
+    active table and the finished window are bounded (DL007).
     """
 
     def __init__(
@@ -528,7 +527,7 @@ def note_stage(rid: Optional[str], stage: str, seconds: float) -> None:
 
 # ---------------------------------------------------------------------------
 # /debug/hostplane provider registry — the SAME machinery as
-# /debug/state and /debug/attribution, third instance
+# /debug/state, second instance
 # ---------------------------------------------------------------------------
 from dynamo_tpu.telemetry.debug import ProviderRegistry  # noqa: E402
 

@@ -331,29 +331,11 @@ SLO_REQUESTS = REGISTRY.counter(
     labels=("outcome",),  # met | missed
 )
 
-# -- perf attribution (telemetry/attribution.py; docs/observability.md) -----
-STEP_TIME_FRAC = REGISTRY.gauge(
-    "dynamo_step_time_frac",
-    "Fraction of the rolling decode window's wall time attributed to "
-    "each loss bucket (queue_wait/plan/dispatch/sync/idle_gap + the "
-    "device split attention/mlp/lm_head/sampling); sums to ~1.0",
-    labels=("component",),
-)
-ROOFLINE_FRAC = REGISTRY.gauge(
-    "dynamo_roofline_frac",
-    "Achieved decode tok/s over the kv_dtype-aware byte-bound roofline "
-    "at the live geometry (telemetry/roofline.py)",
-)
-TOKENS_LOST_PER_S = REGISTRY.gauge(
-    "dynamo_tokens_lost_per_s",
-    "Tokens/s of roofline headroom attributed to each loss bucket — "
-    "'the other 60%' as a first-class per-component series",
-    labels=("component",),
-)
+# -- black-box capture (telemetry/blackbox.py; docs/observability.md) ------
 BLACKBOX_DUMPS = REGISTRY.counter(
     "dynamo_blackbox_dumps_total",
     "Anomaly-triggered black-box forensic bundles written, by trigger "
-    "(watchdog / roofline_drop / slo_miss / manual)",
+    "(watchdog / serve_compile / serve_transfer / slo_miss / loop_stall)",
     labels=("reason",),
 )
 

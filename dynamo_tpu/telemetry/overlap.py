@@ -2,7 +2,7 @@
 
 The overlapped decode pipeline (docs/performance.md) only pays off if
 the device is actually busy while the host plans, packs, and emits —
-and the roofline gap only closes if we can *measure* when it is not.
+and that gap only closes if we can *measure* when it is not.
 ``OverlapTracker`` is the engine-thread-side ledger of that overlap:
 
 - ``note_dispatch()`` marks a device step entering the queue. When the

@@ -16,7 +16,7 @@ from dynamo_tpu.protocols.common import FinishReason
 from dynamo_tpu.protocols.openai import ChatCompletionRequest, ChatDeltaGenerator
 from dynamo_tpu.runtime.engine import AsyncEngine, Context, EngineStream
 from dynamo_tpu.telemetry import REGISTRY
-from dynamo_tpu.telemetry.attribution import BlackBox
+from dynamo_tpu.telemetry.blackbox import BlackBox
 from dynamo_tpu.telemetry.hostplane import (
     LEDGER,
     STAGES,

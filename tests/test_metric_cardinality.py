@@ -22,7 +22,7 @@ _INSTRUMENTED_MODULES = [
     "dynamo_tpu.telemetry.recorder",
     "dynamo_tpu.telemetry.slo",
     "dynamo_tpu.telemetry.hbm",
-    "dynamo_tpu.telemetry.attribution",
+    "dynamo_tpu.telemetry.blackbox",
     "dynamo_tpu.telemetry.hostplane",
     "dynamo_tpu.telemetry.autopsy",
     "dynamo_tpu.http.service",
@@ -57,10 +57,7 @@ _REQUIRED_SERIES = [
     "dynamo_planner_replacements_total",
     "dynamo_planner_degradation_level",
     "dynamo_planner_connector_failures_total",
-    # ISSUE 10: the perf-attribution surface (telemetry/attribution.py)
-    "dynamo_step_time_frac",
-    "dynamo_roofline_frac",
-    "dynamo_tokens_lost_per_s",
+    # ISSUE 10: the black box (telemetry/blackbox.py)
     "dynamo_blackbox_dumps_total",
     # ISSUE 12: the overlapped spec pipeline surface
     "dynamo_spec_draft_hidden_frac",
@@ -164,14 +161,6 @@ def test_observability_series_are_registered():
     assert REGISTRY.get(
         "dynamo_planner_replacements_total"
     ).label_names == ("component",)
-    # the attribution families key on the bounded loss-bucket set
-    assert REGISTRY.get("dynamo_step_time_frac").label_names == (
-        "component",
-    )
-    assert REGISTRY.get("dynamo_tokens_lost_per_s").label_names == (
-        "component",
-    )
-    assert REGISTRY.get("dynamo_roofline_frac").label_names == ()
     assert REGISTRY.get("dynamo_blackbox_dumps_total").label_names == (
         "reason",
     )

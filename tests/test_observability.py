@@ -1,6 +1,5 @@
-"""Live introspection + performance attribution (ISSUE 4): flight
-recorder ring/dump semantics, SLO attainment math, HBM accounting
-fallback, the /debug/state + /debug/profile endpoints, the
+"""Live introspection (ISSUE 4): flight recorder ring/dump semantics,
+SLO attainment math, HBM accounting fallback, the /debug/state + /debug/profile endpoints, the
 `dynamo-tpu top` fleet view, and the e2e acceptance path — a slow
 request produces a JSONL flight dump whose offending step carries
 per-phase latency, while /debug/state and /metrics agree on KV-pool
@@ -290,6 +289,40 @@ async def test_debug_state_endpoint_schema():
         await service.stop()
 
 
+@pytest.mark.parametrize("which", ["frontend", "metrics-service"])
+async def test_debug_attribution_is_gone_and_state_answers(which):
+    """PR 48 took ``/debug/attribution`` from both services (no modelled
+    roofline is kept); ``/debug/state`` is where an operator reads the
+    step loop, on either port."""
+    from aiohttp import web
+
+    from dynamo_tpu.http.service import HttpService
+    from dynamo_tpu.metrics.service import MetricsService
+
+    if which == "frontend":
+        app = HttpService().app
+    else:
+        app = MetricsService(component=None, host="127.0.0.1", port=0).build_app()  # type: ignore[arg-type]
+    tdebug.register_debug_provider(
+        "engine", lambda: {"step_phases": {"record": {"calls": 1}}})
+    runner = web.AppRunner(app)
+    await runner.setup()
+    site = web.TCPSite(runner, "127.0.0.1", 0)
+    await site.start()
+    base = "http://127.0.0.1:%d" % site._server.sockets[0].getsockname()[1]
+    try:
+        async with aiohttp.ClientSession() as s:
+            async with s.get(f"{base}/debug/attribution") as r:
+                assert r.status == 404
+            async with s.get(f"{base}/debug/state") as r:
+                assert r.status == 200
+                state = await r.json()
+        assert state["engine"]["step_phases"]["record"]["calls"] == 1
+    finally:
+        tdebug.unregister_debug_provider("engine")
+        await runner.cleanup()
+
+
 async def test_debug_profile_endpoint(tmp_path, monkeypatch):
     monkeypatch.setenv("DYN_PROFILE_DIR", str(tmp_path))
     service, base = await _start_frontend()
@@ -531,115 +564,16 @@ def test_top_cli_parser_wiring():
 
 
 # ---------------------------------------------------------------------------
-# perf attribution: ledger units (telemetry/attribution.py)
+# black box (telemetry/blackbox.py): units, then the e2e fault stall
 # ---------------------------------------------------------------------------
-def _ledger(**kw):
-    from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.telemetry.attribution import AttributionLedger
-    from dynamo_tpu.telemetry.roofline import build_roofline
-
-    mc = ModelConfig(
-        vocab_size=128256, hidden_size=4096, intermediate_size=14336,
-        num_hidden_layers=32, num_attention_heads=32,
-        num_key_value_heads=8, max_position_embeddings=8192,
-    )
-    return AttributionLedger(build_roofline(mc, "int8", "int8"), **kw)
-
-
-def _tick(dt=0.01):
-    now = [0.0]
-
-    def clock():
-        now[0] += dt
-        return now[0]
-
-    return clock
-
-
-def test_ledger_partition_sums_to_wall_time():
-    led = _ledger(clock=_tick(0.010))
-    for _ in range(64):
-        led.note_step(
-            "decode", 0.010, batch=64, tokens=64, context_tokens=64 * 192,
-            plan_ms=2.0, dispatch_ms=1.0, sync_ms=0.5, idle_gap_ms=3.0,
-            overlapped=True,
-        )
-    w = led.window_summary()
-    assert sum(w["frac"].values()) == pytest.approx(1.0, abs=1e-6)
-    # overlapped: the 3 ms idle gap is the loss — 2 ms to plan, 1 ms to
-    # dispatch; sync rides alongside; the rest is device compute
-    assert w["frac"]["plan"] == pytest.approx(0.2, abs=0.01)
-    assert w["frac"]["dispatch"] == pytest.approx(0.1, abs=0.01)
-    assert w["frac"]["sync"] == pytest.approx(0.05, abs=0.01)
-    assert w["frac"]["queue_wait"] == 0.0
-    device = sum(w["frac"][k] for k in ("attention", "mlp", "lm_head",
-                                        "sampling"))
-    assert device == pytest.approx(0.65, abs=0.02)
-    assert w["roofline_frac"] is not None and w["roofline_frac"] > 0
-    assert w["achieved_tok_s"] == pytest.approx(6400.0, rel=0.01)
-
-
-def test_ledger_serial_partition_charges_sync_as_device():
-    led = _ledger(clock=_tick(0.010))
-    for _ in range(32):
-        led.note_step(
-            "decode", 0.010, batch=8, tokens=8, context_tokens=8 * 64,
-            plan_ms=2.0, dispatch_ms=1.0, sync_ms=5.0, idle_gap_ms=3.0,
-            overlapped=False,
-        )
-    w = led.window_summary()
-    assert sum(w["frac"].values()) == pytest.approx(1.0, abs=1e-6)
-    # serial: the harvest block IS the device executing; idle_gap would
-    # double count the plan/emit time and stays 0
-    assert w["frac"]["idle_gap"] == 0.0
-    assert w["frac"]["sync"] == 0.0
-    device = sum(w["frac"][k] for k in ("attention", "mlp", "lm_head",
-                                        "sampling"))
-    assert device == pytest.approx(0.5, abs=0.02)  # 5 ms of 10
-    assert w["frac"]["queue_wait"] == pytest.approx(0.2, abs=0.02)  # residual
-
-
-def test_ledger_note_idle_breaks_the_timeline():
-    clock = _tick(0.0)
-    led = _ledger(clock=clock)
-    led.note_step("decode", 0.010, batch=4, tokens=4, overlapped=True)
-    led.note_idle()
-    # a 100 s park with no work must NOT bill 100 s to the next step
-    for _ in range(10000):
-        clock()
-    led.note_step("decode", 0.010, batch=4, tokens=4, overlapped=True)
-    w = led.window_summary()
-    assert w["span_s"] < 1.0
-
-
-def test_ledger_anomaly_band_trips_on_roofline_drop():
-    led = _ledger(clock=_tick(0.010), anomaly_check_every=8)
-    kw = dict(batch=64, tokens=64, context_tokens=64 * 192, overlapped=True)
-
-    def run(n, dt):
-        led._clock = _tick(dt)
-        hits = []
-        for _ in range(n):
-            r = led.note_step("decode", dt, **kw)
-            if r:
-                hits.append(r)
-        return hits
-
-    assert run(64, 0.012) == []  # healthy baseline seeds the EMA
-    hits = run(64, 0.30)  # 25x slower: frac collapses under the band
-    assert hits and hits[0].startswith("roofline_drop:")
-
-
 def test_blackbox_bundle_contents_and_rate_limit(tmp_path):
-    from dynamo_tpu.telemetry.attribution import BlackBox
+    from dynamo_tpu.telemetry.blackbox import BlackBox
 
-    led = _ledger(clock=_tick(0.01))
-    led.note_step("decode", 0.01, batch=4, tokens=4, overlapped=True)
     rec = FlightRecorder(capacity=8)
     rec.record("decode", 0.001, batch=4)
     now = [0.0]
     bb = BlackBox(
-        recorder=rec, ledger=led, dump_dir=str(tmp_path),
+        recorder=rec, dump_dir=str(tmp_path),
         min_interval_s=60.0, clock=lambda: now[0], profile_ms=0,
     )
     d = bb.trigger("watchdog:decode")
@@ -648,8 +582,7 @@ def test_blackbox_bundle_contents_and_rate_limit(tmp_path):
     assert os.path.isdir(d)
     meta = json.load(open(os.path.join(d, "meta.json")))
     assert meta["reason"] == "watchdog:decode"
-    attr = json.load(open(os.path.join(d, "attribution.json")))
-    assert attr["window"]["steps"] == 1
+    assert sorted(os.listdir(d)) == ["flight.jsonl", "meta.json", "state.json"]
     flight = [
         json.loads(x)
         for x in open(os.path.join(d, "flight.jsonl")).read().splitlines()
@@ -661,110 +594,14 @@ def test_blackbox_bundle_contents_and_rate_limit(tmp_path):
     assert bb.trigger("watchdog:decode") is None
     assert bb.stats()["dumps"] == 1 and bb.stats()["suppressed"] == 1
     now[0] = 61.0
-    assert bb.trigger("roofline_drop:x") is not None
+    assert bb.trigger("manual") is not None
     bb.flush()
     assert bb.stats()["dumps"] == 2
 
 
-# ---------------------------------------------------------------------------
-# perf attribution: e2e — ledger under the real pipelines, endpoint,
-# metrics agreement, fault-stall black box
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("decode_steps", [1, 4])
-async def test_e2e_attribution_sums_under_pipelines(decode_steps):
-    """Acceptance bar: a steady decode window's component fractions sum
-    to 1.0 ± 0.05 under both the overlapped single-step pipeline
-    (decode_steps=1) and the fused window pipeline (decode_steps>1)."""
-    from dynamo_tpu.engine.engine import JaxEngine
-
-    engine = await JaxEngine.launch(_engine_cfg(decode_steps=decode_steps))
-    try:
-        await asyncio.gather(*[
-            _gen(engine, range(1, 24), max_tokens=24, request_id=f"a{i}")
-            for i in range(4)
-        ])
-        snap = engine.attribution.snapshot()
-        assert snap["configured"] is True
-        w = snap["window"]
-        assert w["steps"] >= 4
-        assert sum(w["frac"].values()) == pytest.approx(1.0, abs=0.05)
-        assert w["achieved_tok_s"] > 0
-        # decode happened, so the ceiling math engaged
-        assert w["roofline_frac"] is not None and w["roofline_frac"] > 0
-        assert w["top_loss_bucket"] != ""
-        assert sum(w["tokens_lost_per_s"].values()) >= 0
-        # the load feed carries the signals (metrics-service rollup input)
-        fpm = engine.stats()
-        assert fpm.roofline_frac == pytest.approx(w["roofline_frac"])
-        assert fpm.top_loss_bucket == w["top_loss_bucket"]
-    finally:
-        await engine.shutdown()
-
-
-async def test_e2e_debug_attribution_endpoint_and_metrics_agree():
-    """/debug/attribution schema + /metrics agreement: the gauge family
-    the ledger publishes must match the snapshot the endpoint serves."""
-    from dynamo_tpu.engine.engine import JaxEngine
-
-    engine = await JaxEngine.launch(_engine_cfg())
-    service = None
-    try:
-        await _gen(engine, range(1, 24), max_tokens=16, request_id="attr")
-        # the final harvest's attribution record can trail the stream
-        # close by an engine-thread tick (the pipelines emit before they
-        # record); snapshotting mid-trail compares two different windows
-        # — wait for the ledger to quiesce before fetching
-        await engine.wait_for_state(lambda e: not e.scheduler.has_work)
-        last = -1
-        while engine.attribution.steps_noted != last:
-            last = engine.attribution.steps_noted
-            await asyncio.sleep(0.05)
-        service, base = await _start_frontend()
-        async with aiohttp.ClientSession() as s:
-            async with s.get(f"{base}/debug/attribution") as r:
-                assert r.status == 200
-                state = await r.json()
-            async with s.get(f"{base}/metrics") as r:
-                metrics_text = await r.text()
-        eng = state["engine"]
-        attr, bb = eng["attribution"], eng["blackbox"]
-        assert attr["configured"] is True
-        w = attr["window"]
-        assert set(w["frac"]) == {
-            "queue_wait", "plan", "dispatch", "sync", "idle_gap",
-            "attention", "mlp", "lm_head", "sampling",
-        }
-        assert sum(w["frac"].values()) == pytest.approx(1.0, abs=0.05)
-        assert attr["recent"], "recent per-step rows missing"
-        assert {"kind", "interval_ms", "buckets_ms"} <= set(attr["recent"][0])
-        assert bb["dumps"] == 0 and "dump_dir" in bb
-        # /metrics agreement: the endpoint's provider refreshes the
-        # gauges, so the scrape and the snapshot describe one window
-        fams = prom_parse(metrics_text)
-        assert fams["dynamo_roofline_frac"].samples[
-            ("dynamo_roofline_frac", ())
-        ] == pytest.approx(w["roofline_frac"], rel=1e-6)
-        frac_samples = fams["dynamo_step_time_frac"].samples
-        for comp, frac in w["frac"].items():
-            got = frac_samples[
-                ("dynamo_step_time_frac", (("component", comp),))
-            ]
-            assert got == pytest.approx(frac, abs=1e-6), comp
-        assert fams["dynamo_tokens_lost_per_s"].type == "gauge"
-        # /debug/state carries the same stanza for `top`
-        async with aiohttp.ClientSession() as s:
-            async with s.get(f"{base}/debug/state") as r:
-                ds = await r.json()
-        assert ds["engine"]["attribution"]["window"]["steps"] == w["steps"]
-    finally:
-        if service is not None:
-            await service.stop()
-        await engine.shutdown()
-
-
 async def test_e2e_stall_fires_exactly_one_blackbox(tmp_path, monkeypatch):
     """An injected engine.step stall (DYN_FAULTS) trips the slow-step
-    watchdog; the black box bundles recorder tail + attribution window
+    watchdog; the black box bundles recorder tail + /debug/state
     exactly ONCE per rate-limit window despite repeated stalls."""
     from dynamo_tpu import faults
     from dynamo_tpu.engine.engine import JaxEngine
@@ -788,11 +625,12 @@ async def test_e2e_stall_fires_exactly_one_blackbox(tmp_path, monkeypatch):
         d = os.path.join(str(tmp_path), bundles[0])
         meta = json.load(open(os.path.join(d, "meta.json")))
         assert meta["reason"].startswith("watchdog:")
-        # recorder tail + attribution window both present (acceptance)
+        # recorder tail + engine state both present (acceptance)
         flight = open(os.path.join(d, "flight.jsonl")).read().splitlines()
         assert len(flight) >= 2
-        attr = json.load(open(os.path.join(d, "attribution.json")))
-        assert attr["window"]["steps"] >= 1
+        state = json.load(open(os.path.join(d, "state.json")))
+        assert "scheduler" in state["engine"]
+        assert not os.path.exists(os.path.join(d, "attribution.json"))
         assert engine.blackbox.stats()["dumps"] == 1
         assert engine.blackbox.stats()["suppressed"] >= 0
     finally:
@@ -801,47 +639,8 @@ async def test_e2e_stall_fires_exactly_one_blackbox(tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# top: ROOF%/LOSS columns, --watch-roofline, tok/s absence marker
+# top: tok/s absence marker
 # ---------------------------------------------------------------------------
-async def test_top_roofline_column_and_watch_sort():
-    from dynamo_tpu.cli.top import run_top
-
-    def eng(roof, bucket, toks):
-        return {
-            "model": "tiny", "max_batch_size": 8,
-            "tokens_generated_total": toks,
-            "scheduler": {"running": 1, "queue_depth": 0, "preemptions": 0},
-            "kv_pool": {"usage": 0.1},
-            "slo": {"enabled": False},
-            "hbm": {"bytes_in_use": 1024},
-            "flight_recorder": {"slow_steps": 0},
-            "attribution": {"window": {
-                "roofline_frac": roof, "top_loss_bucket": bucket,
-            }},
-        }
-
-    tdebug.register_debug_provider("engine", lambda: eng(0.37, "idle_gap", 5))
-    service, base = await _start_frontend()
-    tdebug.register_debug_provider(
-        "engine2", lambda: {"noise": True}  # second provider: ignored
-    )
-    try:
-        buf = io.StringIO()
-        rc = await run_top([base], interval=0.01, iterations=1,
-                           clear=False, out=buf, watch_roofline=True)
-        assert rc == 0
-        text = buf.getvalue()
-        assert "ROOF%" in text and "LOSS" in text
-        assert "37.0%" in text
-        assert "idle_gap" in text
-        # first poll: no token delta -> the absence marker, never 0.0
-        assert "       -" in text
-    finally:
-        tdebug.unregister_debug_provider("engine")
-        tdebug.unregister_debug_provider("engine2")
-        await service.stop()
-
-
 async def test_top_counter_reset_renders_absence_not_zero():
     """A worker restart rewinds tokens_generated_total; the rate must
     render `-` (no delta), not clamp to a fabricated 0.0."""
@@ -858,14 +657,6 @@ async def test_top_counter_reset_renders_absence_not_zero():
     assert ok["tok_s"] == pytest.approx(50.0)
 
 
-def test_top_watch_roofline_parser_wiring():
-    from dynamo_tpu.cli.main import build_parser
-
-    args = build_parser().parse_args(["top", "--watch-roofline", "--once"])
-    assert args.watch_roofline is True
-    assert build_parser().parse_args(["top"]).watch_roofline is False
-
-
 # ---------------------------------------------------------------------------
 # metrics service rollup
 # ---------------------------------------------------------------------------
@@ -876,16 +667,14 @@ def test_metrics_service_rolls_up_slo_signals():
     svc = MetricsService(component=None, host="127.0.0.1", port=0)  # type: ignore[arg-type]
     svc.aggregator.update(ForwardPassMetrics(
         worker_id=1, slo_enabled=True, slo_attainment=0.5,
-        goodput_tokens_total=100, roofline_frac=0.30,
-        top_loss_bucket="idle_gap",
+        goodput_tokens_total=100,
     ))
     svc.aggregator.update(ForwardPassMetrics(
         worker_id=2, slo_enabled=True, slo_attainment=1.0,
-        goodput_tokens_total=300, roofline_frac=0.50,
+        goodput_tokens_total=300,
     ))
     # a target-less worker reports the default 1.0 — it must NOT
-    # dilute the fleet attainment mean; its default roofline_frac of
-    # -1.0 (no decode window yet) is likewise excluded from the mean
+    # dilute the fleet attainment mean
     svc.aggregator.update(ForwardPassMetrics(worker_id=3))
     fams = prom_parse(svc.render())
     assert fams["llm_slo_attainment"].samples[
@@ -894,6 +683,46 @@ def test_metrics_service_rolls_up_slo_signals():
     assert fams["llm_goodput_tokens"].samples[
         ("llm_goodput_tokens", ())
     ] == 400
-    assert fams["llm_roofline_frac"].samples[
-        ("llm_roofline_frac", ())
-    ] == pytest.approx(0.40)
+
+
+async def test_metrics_service_rolls_up_a_parent_commit_workers_payload():
+    """Rolling restart: a worker of the commit before PR 48 still sends
+    ``roofline_frac`` / ``top_loss_bucket`` on the load feed. The
+    subscription the router and the metrics service share drops the two
+    keys (a refused payload would only be logged, and the worker lost
+    to both) and the rollup counts the worker."""
+    from dynamo_tpu.kv_router.protocols import ForwardPassMetrics
+    from dynamo_tpu.metrics.service import MetricsService
+
+    old_worker = {
+        "worker_id": 7, "request_active_slots": 3, "request_total_slots": 8,
+        "kv_active_blocks": 40, "kv_total_blocks": 100,
+        "num_requests_waiting": 2, "gpu_cache_usage_perc": 0.4,
+        "gpu_prefix_cache_hit_rate": 0.0, "slo_enabled": True,
+        "slo_attainment": 0.5, "goodput_tokens_total": 10,
+        "roofline_frac": 0.31, "top_loss_bucket": "idle_gap",
+    }
+    new_worker = ForwardPassMetrics(worker_id=8, kv_total_blocks=100)
+    assert "roofline_frac" not in new_worker.model_dump()
+
+    async def feed():
+        yield "load_metrics", old_worker
+        yield "load_metrics", new_worker.model_dump()
+
+    svc = MetricsService(component=None, host="127.0.0.1", port=0)  # type: ignore[arg-type]
+    svc.aggregator.start_consuming(feed())
+    await svc.aggregator._task
+    got = svc.aggregator.fresh_metrics()
+    assert sorted(got) == [7, 8]
+    assert set(got[7].model_dump()) == set(new_worker.model_dump())
+    fams = prom_parse(svc.render())
+    assert "llm_roofline_frac" not in fams
+    assert fams["llm_workers_reporting"].samples[
+        ("llm_workers_reporting", ())
+    ] == 2
+    assert fams["llm_kv_blocks_active"].samples[
+        ("llm_kv_blocks_active", ())
+    ] == 40
+    assert fams["llm_slo_attainment"].samples[
+        ("llm_slo_attainment", ())
+    ] == 0.5

@@ -269,7 +269,7 @@ async def test_a_blocking_stop_trace_leaves_the_event_loop_alone(
 
 async def test_black_box_and_debug_profile_captures_cannot_overlap(
         tmp_path, stub_profiler, caplog):
-    from dynamo_tpu.telemetry.attribution import BlackBox
+    from dynamo_tpu.telemetry.blackbox import BlackBox
 
     box = BlackBox(dump_dir=str(tmp_path), profile_ms=50)
     first = asyncio.ensure_future(

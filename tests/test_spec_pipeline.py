@@ -12,9 +12,8 @@ The load-bearing properties:
   from-scratch windowed scan proposes, across appends, unwinds and
   speculative suffixes;
 - late-detected stops discard in-flight spec tokens (blocks freed,
-  prefix cache clean), zero-proposal steps fall back without deadlock,
-  and the attribution ledger's fractions still sum to 1.0 over a
-  pipelined spec run.
+  prefix cache clean), and zero-proposal steps fall back without
+  deadlock.
 
 CPU-runnable tier-1, like tests/test_spec.py and tests/test_overlap.py.
 """
@@ -253,6 +252,14 @@ async def test_spec_overlap_bit_identical_vs_serial_spec():
         dbg = eng.debug_state()["spec"]
         assert dbg["pipelined"] is True
         assert dbg["predraft_hits"] + dbg["predraft_misses"] > 0
+        # the draft time hidden under the device's step is counted, and
+        # every pipelined step left a "spec" row in the recorder
+        assert dbg["draft_hidden_s"] >= 0.0
+        assert 0.0 <= dbg["draft_hidden_frac"] <= 1.0
+        assert any(r["kind"] == "spec" for r in eng.recorder.snapshot(64))
+        from dynamo_tpu.telemetry import REGISTRY
+
+        assert "dynamo_spec_draft_hidden_frac" in REGISTRY.render()
     finally:
         await eng.shutdown()
 
@@ -341,33 +348,6 @@ async def test_spec_pipeline_zero_proposal_falls_back_without_deadlock():
         assert not eng.scheduler.running
     finally:
         await eng.shutdown()
-
-
-async def test_spec_pipeline_attribution_fracs_sum_to_one():
-    """The ledger's partition stays exact under overlapped spec steps:
-    bucket fractions sum to 1.0 (±0.05) over an e2e pipelined run, the
-    window saw 'spec'-kind records, and the draft-hidden gauge is
-    exposed on /metrics."""
-    from dynamo_tpu.engine.engine import JaxEngine
-    from dynamo_tpu.telemetry import REGISTRY
-
-    eng = await JaxEngine.launch(_engine_config(overlap=True))
-    try:
-        await _decode_all(eng, max_tokens=12)
-        assert eng.spec_pipeline_steps > 0
-        w = eng.attribution.window_summary()
-        total = sum(w["frac"].values())
-        assert w["steps"] > 0
-        assert abs(total - 1.0) < 0.05, w["frac"]
-        snap = eng.attribution.snapshot()
-        assert any(r["kind"] == "spec" for r in snap["recent"])
-        dbg = eng.debug_state()["spec"]
-        assert dbg["draft_hidden_s"] >= 0.0
-        assert 0.0 <= dbg["draft_hidden_frac"] <= 1.0
-    finally:
-        await eng.shutdown()
-    text = REGISTRY.render()
-    assert "dynamo_spec_draft_hidden_frac" in text
 
 
 async def test_spec_pipeline_respects_block_pressure():

@@ -320,10 +320,12 @@ async def test_the_recorders_stamps_are_the_phases_elapsed(loop, monkeypatch):
     assert any("dispatch_ms" in r or "verify_ms" in r for r in steps)
 
 
-def test_the_step_loops_hold_no_second_clocking():
+async def test_the_step_loops_hold_no_second_clocking():
     """``plan_ms`` / ``dispatch_ms`` / ``sync_ms`` / ``draft_ms`` /
     ``verify_ms`` come from the phase objects: no ``time.monotonic()``
-    difference is rounded into one of them any more."""
+    difference is rounded into one of them any more. And no ledger
+    beside the clock re-derives a split of the step from them (the
+    modelled roofline went in PR 48)."""
     import inspect
     import re
 
@@ -336,6 +338,66 @@ def test_the_step_loops_hold_no_second_clocking():
     assert stamped == []
     assert "_capture_live" not in inspect.getsource(tspans.step_span)
     assert "_capture_live" not in inspect.getsource(tspans.StepClock.phase)
+    record = inspect.signature(eng.JaxEngine._record_step).parameters
+    assert not {"tokens", "overlapped"} & set(record)
+    engine = await _launch("overlapped-decode")
+    try:
+        await _drive(engine, "overlapped-decode", max_tokens=4)
+        assert not hasattr(engine, "attribution")
+        state = engine.debug_state()
+        assert "attribution" not in state
+        assert {"step_phases", "blackbox", "recent_steps"} <= set(state)
+    finally:
+        await engine.shutdown()
+
+
+async def test_the_record_phase_reads_no_row_of_the_batch(monkeypatch):
+    """``dyn.step.record`` costs the same at any batch: with 8 rows
+    running, no pass of it reads a row's ``num_computed`` (the attribution
+    ledger summed it over every running row on every dispatch)."""
+    from dynamo_tpu.engine.scheduler import Sequence
+
+    reads = {"record": 0, "elsewhere": 0}
+    rows: list = []
+    recording: list = []
+
+    def read(self):
+        reads["record" if recording else "elsewhere"] += 1
+        return self.__dict__.get("_num_computed", 0)
+
+    def write(self, v):
+        self.__dict__["_num_computed"] = v
+
+    monkeypatch.setattr(Sequence, "num_computed", property(read, write))
+    plain_enter = tspans.StepPhase.__enter__
+    plain_exit = tspans.StepPhase.__exit__
+    name = tspans.PHASE_PREFIX + "record"
+
+    def enter(self):
+        if self.name == name:
+            recording.append(True)
+            rows.append(engine.scheduler.num_running)
+        return plain_enter(self)
+
+    def leave(self, *exc):
+        plain_exit(self, *exc)
+        if self.name == name:
+            recording.clear()
+
+    monkeypatch.setattr(tspans.StepPhase, "__enter__", enter)
+    monkeypatch.setattr(tspans.StepPhase, "__exit__", leave)
+    engine = await _launch("overlapped-decode")
+    try:
+        await asyncio.gather(*[
+            _gen(engine, range(1 + i, 12 + i), max_tokens=16,
+                 request_id=f"row{i}")
+            for i in range(8)])
+        await _until_idle(engine)
+    finally:
+        await engine.shutdown()
+    assert max(rows) == 8, rows
+    assert reads["elsewhere"] > 0  # the property does count
+    assert reads["record"] == 0
 
 
 # ---------------------------------------------------------------------------
