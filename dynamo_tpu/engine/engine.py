@@ -4893,16 +4893,23 @@ class JaxEngine:
         )
         if not seq.t_prefill_done:
             return
-        tracer.record(
-            "engine.prefill", start_mono=seq.t_admit,
-            duration_s=seq.t_prefill_done - seq.t_admit, parent=parent,
-            attrs={"service": "engine",
+        prefill = {"service": "engine",
                    "prompt_tokens": len(seq.request.token_ids),
                    "cached_tokens": seq.num_cached_prompt,
                    "chunks": seq.prefill_chunks,
                    # window-plane pages its chunks handed back (0 for
                    # a model without that plane)
-                   "window_pages_released": seq.window_pages_released},
+                   "window_pages_released": seq.window_pages_released}
+        if getattr(self.model_config, "index_topk", 0):
+            # learned sparse attention: the keys its chunks' tokens were
+            # candidates among — token p scores p + 1 — a layer; cached
+            # tokens are not scored again
+            n, c = len(seq.request.token_ids), seq.num_cached_prompt
+            prefill["candidate_keys"] = (n * (n + 1) - c * (c + 1)) // 2
+        tracer.record(
+            "engine.prefill", start_mono=seq.t_admit,
+            duration_s=seq.t_prefill_done - seq.t_admit, parent=parent,
+            attrs=prefill,
         )
         decode = {"service": "engine", "tokens": seq.generated,
                   "finish_reason": str(reason.value)}
@@ -5420,6 +5427,14 @@ class JaxEngine:
                 "pages_in_use": pool["active_blocks"],
                 "pages_cached_reusable": pool["cached_free_blocks"],
             }
+            if isinstance(self.k_cache, dict) and len(self.k_cache) > 1:
+                # several per-token planes under ONE page id and table
+                # (models/glm_moe_dsa.py: latent rows and indexer keys):
+                # a page in use is in use in each of them
+                out["page_plane"]["bytes_by_plane"] = {
+                    name: tree_bytes(plane)
+                    for name, plane in self.k_cache.items()
+                }
         out["hbm"] = self.hbm.refresh()
         # the device this engine actually runs on, the kernel impls that
         # resolved there, and what start-up cost (chip_smoke.py reads it)

@@ -141,6 +141,19 @@ class ModelConfig:
     add_full_attention_sink_bias: bool = False
     sliding_window_size: Optional[int] = None
     attention_chunk_size: Optional[int] = None
+    # glm_moe_dsa (models/glm_moe_dsa.py): deepseek_v3's layers with a
+    # low-rank query (q_lora_rank) and a learned indexer a layer —
+    # index_n_heads heads of index_head_dim, the first qk_rope_head_dim of
+    # them rotated (pairs by indexer_rope_interleave) — whose index_topk
+    # best keys are all a query attends; rope_theta is read from
+    # rope_parameters where the top level has none;
+    # num_nextn_predict_layers (a multi-token-prediction layer) is read
+    # by nothing: the main model's logits do not depend on it
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    indexer_rope_interleave: bool = True
+    num_nextn_predict_layers: int = 0
 
     def __post_init__(self) -> None:
         if self.head_dim is None:
@@ -257,6 +270,15 @@ class ModelConfig:
             kwargs["sliding_window"] = None
         elif raw.get("use_sliding_window") is False:
             kwargs["sliding_window"] = None
+        # glm_moe_dsa keeps the rotary base in rope_parameters; a rope_type
+        # other than default is a scaled rotary (refused by the family)
+        rope = raw.get("rope_parameters")
+        if isinstance(rope, dict):
+            if "rope_theta" not in raw and "rope_theta" in rope:
+                kwargs["rope_theta"] = float(rope["rope_theta"])
+            if (rope.get("rope_type", "default") != "default"
+                    and raw.get("rope_scaling") is None):
+                kwargs["rope_scaling"] = dict(rope)
         # kimi_linear states its longest context as model_max_length
         if "max_position_embeddings" not in raw and "model_max_length" in raw:
             kwargs["max_position_embeddings"] = int(raw["model_max_length"])

@@ -76,10 +76,10 @@ class Geometry:
     """The sizes of one configuration, worked out once; what the family
     does not build raises here, by the key's name."""
 
-    def __init__(self, cfg: ModelConfig):
+    def __init__(self, cfg: ModelConfig, low_rank_query: bool = False):
         refused = {
             "q_lora_rank (a low-rank query projection)":
-                cfg.q_lora_rank is not None,
+                cfg.q_lora_rank is not None and not low_rank_query,
             "n_group / topk_group > 1 (group-limited routing)":
                 cfg.n_group != 1 or cfg.topk_group != 1,
             "rope_scaling (YaRN-scaled rotary)": cfg.rope_scaling is not None,
@@ -109,7 +109,11 @@ class Geometry:
         self.F = cfg.intermediate_size
         self.Fe = cfg.moe_intermediate_size
         self.Fs = cfg.moe_intermediate_size * cfg.n_shared_experts
+        # held here; the router scores E_all and this process holds the
+        # expert_shard_index-th run of E of them (1 / 0: all of them)
         self.E = cfg.n_routed_experts
+        self.E_all = self.E * cfg.expert_shards
+        self.e0 = cfg.expert_shard_index * self.E
         self.k = cfg.num_experts_per_tok
         self.dense_layers = list(range(min(cfg.first_k_dense_replace, self.L)))
         self.moe_layers = [i for i in range(self.L) if i not in self.dense_layers]
@@ -132,9 +136,12 @@ QUANT_AXIS = {
 }
 
 
-def param_shapes(cfg: ModelConfig) -> dict[str, tuple[tuple[int, ...], Any]]:
-    """name -> (shape, dtype); layer parameters are stacked per KIND."""
-    g = Geometry(cfg)
+def param_shapes(cfg: ModelConfig, g: Optional[Geometry] = None
+                 ) -> dict[str, tuple[tuple[int, ...], Any]]:
+    """name -> (shape, dtype); layer parameters are stacked per KIND.
+    ``g``: a geometry of this kind worked out already
+    (``models/glm_moe_dsa.py``)."""
+    g = g or Geometry(cfg)
     bf16, f32 = jnp.bfloat16, jnp.float32
     L, Ld, Le, D = g.L, len(g.dense_layers), len(g.moe_layers), g.D
     shapes: dict = {
@@ -157,8 +164,8 @@ def param_shapes(cfg: ModelConfig) -> dict[str, tuple[tuple[int, ...], Any]]:
         })
     if Le:
         shapes.update({
-            "router": ((Le, D, g.E), f32),
-            "router_bias": ((Le, g.E), f32),     # e_score_correction_bias
+            "router": ((Le, D, g.E_all), f32),
+            "router_bias": ((Le, g.E_all), f32),     # e_score_correction_bias
             "ws_gate": ((Le, D, g.Fs), bf16),
             "ws_up": ((Le, D, g.Fs), bf16),
             "ws_down": ((Le, g.Fs, D), bf16),
@@ -259,15 +266,16 @@ def moe_routing(cfg: ModelConfig, p: Params, x: jax.Array, idx: int):
 def moe_ffn(cfg: ModelConfig, g: Geometry, p: Params, h: jax.Array, idx: int,
             valid: Optional[jax.Array] = None,
             h_route: Optional[jax.Array] = None):
-    """The routed sum over all ``E`` experts (held whole) plus the shared
-    MLP, ungated. Returns (out float32, counts int32 [3]); ``h_route`` is
+    """The routed sum over the ``E`` experts held here (``g.e0`` on: all
+    of them unless the configuration states a share) plus the shared MLP,
+    ungated. Returns (out float32, counts int32 [3]); ``h_route`` is
     ``h`` before it was rounded to the activation dtype — the router's."""
     B, T, D = h.shape
     x = h.reshape(B * T, D)
     w, topi = moe_routing(
         cfg, p, x if h_route is None else h_route.reshape(B * T, D), idx)
     routed, counts = hybrid.moe_local(
-        p, x, w, topi, idx, 0, g.E, MOE_DENSE_TOKENS, valid)
+        p, x, w, topi, idx, g.e0, g.E, MOE_DENSE_TOKENS, valid)
     shared = hybrid.gated_mlp(p, ("ws_gate", "ws_up", "ws_down"), h, idx)
     return routed.reshape(B, T, D) + shared.astype(jnp.float32), counts
 
@@ -285,6 +293,50 @@ def prefill_counts(start: jax.Array, n_valid: jax.Array, layers: int):
     return jnp.stack([layers * jnp.sum(n),
                       layers * jnp.sum(pairs) // PAIR_UNIT,
                       jnp.int32(layers)])
+
+
+def decoder(cfg: ModelConfig, g: Geometry, params: Params, tokens: jax.Array,
+            positions: jax.Array, context_lens: jax.Array, attend):
+    """The layers of this geometry around an attention of the caller's:
+    ``attend(layer, h [B, T, D]) -> out`` (it threads its own pages).
+    Returns (the residual stream [B, T, D] float32 before the final
+    norm, the activation dtype, the expert counts int32 [3], start [B],
+    n_valid [B]); ``head``
+    makes the logits of it. Shared with ``models/glm_moe_dsa.py``."""
+    B, T = tokens.shape
+    eps = cfg.rms_norm_eps
+    start = positions[:, 0]
+    n_valid = jnp.clip(context_lens - start, 0, T)            # [B]
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]         # [B, T]
+    seen = jnp.zeros((len(hybrid.MOE_COUNT_NAMES),), jnp.int32)
+
+    x = llama.embed_lookup(params, tokens)
+    act = x.dtype
+    x = x.astype(jnp.float32)
+    for layer in range(g.L):
+        h = llama.rmsnorm(x, params["attn_norm"][layer], eps).astype(act)
+        x = x + attend(layer, h).astype(jnp.float32)
+        h32 = llama.rmsnorm(x, params["mlp_norm"][layer], eps)
+        h = h32.astype(act)
+        if layer in g.dense_layers:
+            out = hybrid.gated_mlp(params, ("w_gate", "w_up", "w_down"), h,
+                                   g.dense_layers.index(layer))
+        else:
+            out, c = moe_ffn(cfg, g, params, h, g.moe_layers.index(layer),
+                             valid, h32)
+            seen = seen + c
+        x = x + out.astype(jnp.float32)
+    return x, act, seen, start, n_valid
+
+
+def head(cfg: ModelConfig, params: Params, x: jax.Array, act,
+         last_token_idx: jax.Array) -> jax.Array:
+    """Final norm and the untied head at each row's last token, the
+    matmul's operand in the activation dtype ``act``: [B, V]."""
+    x = llama.rmsnorm(x, params["final_norm"], cfg.rms_norm_eps).astype(act)
+    x_last = jnp.take_along_axis(
+        x, last_token_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    return llama.lm_head(params, x_last)
 
 
 def forward(
@@ -310,44 +362,25 @@ def forward(
         raise NotImplementedError(
             "deepseek_v3: no injected embeddings, no all-position logits")
     g = Geometry(cfg)
-    B, T = tokens.shape
-    eps = cfg.rms_norm_eps
-    start = positions[:, 0]
-    n_valid = jnp.clip(context_lens - start, 0, T)            # [B]
-    valid = jnp.arange(T)[None, :] < n_valid[:, None]         # [B, T]
+    T = tokens.shape[1]
     latent = pages["latent"]
-    seen = jnp.zeros((len(hybrid.MOE_COUNT_NAMES),), jnp.int32)
 
     def rotate(x):
         return hybrid.rotary_pairs(x.astype(jnp.float32), positions,
                                    float(cfg.rope_theta), cfg.rope_interleave)
 
-    x = llama.embed_lookup(params, tokens)
-    act = x.dtype
-    x = x.astype(jnp.float32)
-    for layer in range(g.L):
-        h = llama.rmsnorm(x, params["attn_norm"][layer], eps).astype(act)
+    def attend(layer, h):
+        nonlocal latent
         out, latent = hybrid.mla_mixer(
-            params, h, layer, latent, g.latent, eps, positions, slot_mapping,
-            block_tables, context_lens, block_size, kernels_active(),
-            rotate=rotate,
+            params, h, layer, latent, g.latent, cfg.rms_norm_eps, positions,
+            slot_mapping, block_tables, context_lens, block_size,
+            kernels_active(), rotate=rotate,
             flash_prefill=True, attend_scope="mla_prefill_attend")
-        x = x + out.astype(jnp.float32)
-        h32 = llama.rmsnorm(x, params["mlp_norm"][layer], eps)
-        h = h32.astype(act)
-        if layer in g.dense_layers:
-            out = hybrid.gated_mlp(params, ("w_gate", "w_up", "w_down"), h,
-                                   g.dense_layers.index(layer))
-        else:
-            out, c = moe_ffn(cfg, g, params, h, g.moe_layers.index(layer),
-                             valid, h32)
-            seen = seen + c
-        x = x + out.astype(jnp.float32)
+        return out
 
+    x, act, seen, start, n_valid = decoder(
+        cfg, g, params, tokens, positions, context_lens, attend)
     attended = (prefill_counts(start, n_valid, g.L) if T > 1
                 else jnp.zeros((3,), jnp.int32))
-    x = llama.rmsnorm(x, params["final_norm"], eps).astype(act)
-    x_last = jnp.take_along_axis(
-        x, last_token_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return (llama.lm_head(params, x_last), {"latent": latent},
+    return (head(cfg, params, x, act, last_token_idx), {"latent": latent},
             {"counts": counts["counts"] + jnp.concatenate([seen, attended])})

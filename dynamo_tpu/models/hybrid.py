@@ -28,6 +28,7 @@ A family keeps what is its own: the layer plan, the mixers, the router,
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -407,7 +408,8 @@ def mla_mixer(p: Params, h: jax.Array, mi, latent: jax.Array, m: Latent,
               kernels: bool,
               rotate: Optional[Callable[[jax.Array], jax.Array]] = None,
               flash_prefill: bool = False,
-              attend_scope: str = "mla_attend"):
+              attend_scope: str = "mla_attend",
+              select: Optional[Callable[[jax.Array], jax.Array]] = None):
     """Latent attention of layer ``mi`` of the ``mla_*`` stacks over the
     paged latent plane ``latent [Lm, slots, Cpad]``: ``h [B, T, D]`` ->
     (out [B, T, D] float32, latent with this step's rows written).
@@ -423,10 +425,24 @@ def mla_mixer(p: Params, h: jax.Array, mi, latent: jax.Array, m: Latent,
     ``mla_decode_attention``; prefill through ``mla_prefill_attention``
     where ``flash_prefill`` (the row's own pages, a page a grid step, no
     score ever in HBM), else through plain XLA over the gathered table,
-    ``m.query_tokens`` query tokens at a time."""
+    ``m.query_tokens`` query tokens at a time.
+
+    A LOW-RANK QUERY where the stacks hold ``mla_wqa`` (``q = W_qb
+    RMSNorm(W_qa h)``; else ``q = W_q h``). ``select``, where given, is
+    handed the normalised query latent ``c_q`` [B, T, q_lora_rank]
+    float32 (``h`` itself without a low-rank query) once this step's rows
+    are written and returns marks [B, T, table columns x block_size]
+    float32: a query attends only the keys marked > 0.5 — every head
+    alike — through the same kernels (``sel``) or the same XLA lines."""
     B, T, _ = h.shape
     act = h.dtype
-    q = mm(p, "mla_wq", h, mi).reshape(B, T, m.H, m.nope + m.rope)
+    c_q = h
+    if "mla_wqa" in p:
+        c_q = llama.rmsnorm(mm(p, "mla_wqa", h, mi), p["mla_qnorm"][mi], eps)
+        q = mm(p, "mla_wqb", c_q.astype(act), mi)
+    else:
+        q = mm(p, "mla_wq", h, mi)
+    q = q.reshape(B, T, m.H, m.nope + m.rope)
     kv = mm(p, "mla_wkva", h, mi)                              # [B, T, C]
     c = llama.rmsnorm(kv[..., : m.rank], p["mla_kvnorm"][mi], eps)
     k_r = kv[..., m.rank:]
@@ -454,13 +470,19 @@ def mla_mixer(p: Params, h: jax.Array, mi, latent: jax.Array, m: Latent,
         o = jnp.einsum("bthc,chv->bthv", o_lat, wkvb[..., m.nope:])
         return mm(p, "mla_wo", o.reshape(B, T, m.H * m.vd).astype(act), mi)
 
+    sel = None if select is None else select(c_q)
+    marked = {} if sel is None else {"sel": sel[:, 0] if T == 1 else sel}
     if T == 1 and kernels:
         # flash decode over the row's own pages, each read once
         from dynamo_tpu.ops.mla import mla_decode_attention
 
-        o_lat = mla_decode_attention(
-            scaled(q_lat[:, 0]), latent, jnp.int32(mi), tables, context_lens,
-            block_size=block_size, rank=m.rank, interpret=interpret)[:, None]
+        # (the dense families' decode call carries no scope: their lowered
+        # programs are held to what they were)
+        with jax.named_scope(attend_scope) if marked else contextlib.nullcontext():
+            o_lat = mla_decode_attention(
+                scaled(q_lat[:, 0]), latent, jnp.int32(mi), tables,
+                context_lens, block_size=block_size, rank=m.rank,
+                interpret=interpret, **marked)[:, None]
         return out_of(o_lat), latent
     if flash_prefill and kernels:
         from dynamo_tpu.ops.mla import mla_prefill_attention
@@ -469,7 +491,8 @@ def mla_mixer(p: Params, h: jax.Array, mi, latent: jax.Array, m: Latent,
             o_lat = mla_prefill_attention(
                 scaled(q_lat), latent, jnp.int32(mi), tables, positions[:, 0],
                 context_lens,
-                block_size=block_size, rank=m.rank, interpret=interpret)
+                block_size=block_size, rank=m.rank, interpret=interpret,
+                **marked)
         return out_of(o_lat), latent
     S = tables.shape[1] * block_size
     slot_ids = (tables[:, :, None] * block_size
@@ -477,10 +500,12 @@ def mla_mixer(p: Params, h: jax.Array, mi, latent: jax.Array, m: Latent,
     rows = latent[mi, slot_ids].astype(act)                    # [B, S, Cpad]
     key_pos = jnp.arange(S, dtype=jnp.int32)[None, None, None, :]
 
-    def attend(q_blk, pos_blk):                                # [B, t, H, C]
+    def attend(q_blk, pos_blk, sel_blk=None):                  # [B, t, H, C]
         s = einsum_f32("bthc,bsc->bhts", q_blk, rows) * (1.0 / root)
         mask = (key_pos <= pos_blk[:, None, :, None]) & (
             key_pos < context_lens[:, None, None, None])
+        if sel_blk is not None:
+            mask &= sel_blk[:, None] > 0.5
         pr = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
         return jnp.einsum("bhts,bsc->bthc", pr.astype(act),
                           rows[..., : m.rank])
@@ -488,12 +513,14 @@ def mla_mixer(p: Params, h: jax.Array, mi, latent: jax.Array, m: Latent,
     with jax.named_scope(attend_scope):
         tq = max(1, min(T, m.query_tokens // B))
         if tq >= T or T % tq:
-            o_lat = attend(q_lat, positions)
+            o_lat = attend(q_lat, positions, sel)
         else:
             qb = jnp.moveaxis(q_lat.reshape(B, T // tq, tq, m.H, m.Cpad), 1, 0)
             pb = jnp.moveaxis(positions.reshape(B, T // tq, tq), 1, 0)
+            blocks = (qb, pb) if sel is None else (
+                qb, pb, jnp.moveaxis(sel.reshape(B, T // tq, tq, S), 1, 0))
             o_lat = jnp.moveaxis(jax.lax.map(
-                lambda a: attend(*a), (qb, pb)), 0, 1
+                lambda a: attend(*a), blocks), 0, 1
             ).reshape(B, T, m.H, m.rank)
     return out_of(o_lat.astype(act)), latent
 

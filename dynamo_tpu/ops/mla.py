@@ -63,9 +63,12 @@ def latent_pages_per_block(block_size: int, C: int, itemsize: int) -> int:
     return max(1, min(by_vmem, by_columns))
 
 
-def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, pages_hbm, o_ref,
-                   buf, sems, state, *, block_size: int, rank: int):
-    """One grid step a ROW over the stacked plane left in HBM as pages
+def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, *rest,
+                   block_size: int, rank: int, selected: bool):
+    """``rest``: (``sel_ref`` where ``selected``,) ``pages_hbm``, ``o_ref``,
+    ``buf``, ``sems``, ``state``.
+
+    One grid step a ROW over the stacked plane left in HBM as pages
     ``[Lm, N, bs, C]``. A row walks its LIVE pages only,
     ``ceil(ctx / bs)`` of them, ``P`` to a compute block, each page one
     DMA into the ``[2, P, bs, C]`` double buffer; the next block (and,
@@ -79,7 +82,13 @@ def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, pages_hbm, o_ref,
     One block is ONE dot of the ``H`` query rows against its ``P * bs``
     latent rows (all ``C`` lanes), one online-softmax update, one dot of
     the probabilities against the same rows' first ``rank`` lanes: the
-    block in VMEM is key and value of every head at once."""
+    block in VMEM is key and value of every head at once.
+
+    ``selected``: ``sel_ref`` [1, 1, >= pages x bs] float32 marks the keys
+    the row attends (> 0.5); the others are masked like positions past
+    the context. Every live page is still read: the masked WALK."""
+    sel_ref = rest[0] if selected else None
+    pages_hbm, o_ref, buf, sems, state = rest[1:] if selected else rest
     b = pl.program_id(0)
     B = pl.num_programs(0)
     H, C = q_ref.shape[1], q_ref.shape[2]
@@ -149,11 +158,16 @@ def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, pages_hbm, o_ref,
         s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         pos = i * cols + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-        s = jnp.where(pos < ctx, s, -1e30)
+        live = pos < ctx
+        if selected:
+            live &= sel_ref[0, :, pl.ds(pl.multiple_of(i * cols, cols), cols)] > 0.5
+        s = jnp.where(live, s, -1e30)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # every block holds a key of the row's live range, so m_new is a
         # real score and a masked column's exp is exactly 0
         p = jnp.exp(s - m_new)
+        if selected:    # a block may hold no selected key: m_new is then no score
+            p = jnp.where(live, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = jnp.dot(p.astype(rows.dtype), rows[:, :rank],
@@ -174,14 +188,17 @@ def _decode_kernel(layer_ref, tables_ref, ctx_ref, q_ref, pages_hbm, o_ref,
     "block_size", "rank", "interpret", "pages_per_block"))
 def mla_decode_attention(q, latent, layer, tables, context_lens, *,
                          block_size: int, rank: int, interpret: bool = False,
-                         pages_per_block: Optional[int] = None):
+                         pages_per_block: Optional[int] = None, sel=None):
     """``q`` [B, H, C] (key up-projection absorbed, softmax scale folded
     in); ``latent`` [Lm, slots, C], the whole stacked plane; ``layer``
     scalar int32; ``tables`` [B, W] page ids; ``context_lens`` [B].
     Returns the attention output IN LATENT SPACE, [B, H, rank]; a row of
     context 0 gets zeros. ``pages_per_block``: pages of one compute
     block, for tests; by default sized from the call's own geometry
-    (``latent_pages_per_block``)."""
+    (``latent_pages_per_block``). ``sel`` [B, W * block_size] float32,
+    where given: the row attends only the keys it marks > 0.5 (in table
+    order; ``ops/dsa.py`` ``select_topk``), and the kernel is named
+    ``dsa_decode_attention``."""
     B, H, C = q.shape
     Lm, slots, _ = latent.shape
     pages = latent.reshape(Lm, slots // block_size, block_size, C)
@@ -189,13 +206,21 @@ def mla_decode_attention(q, latent, layer, tables, context_lens, *,
         block_size, C, latent.dtype.itemsize)
     row = lambda width: pl.BlockSpec(  # noqa: E731
         (1, H, width), lambda b, lyr, t, c: (b, 0, 0))
+    marks, mark_specs = (), []
+    if sel is not None:
+        # whole compute blocks: the last one may reach past the table
+        S = -(-sel.shape[1] // (P * block_size)) * P * block_size
+        marks = (jnp.pad(sel.astype(jnp.float32),
+                         ((0, 0), (0, S - sel.shape[1]))).reshape(B, 1, S),)
+        mark_specs = [pl.BlockSpec((1, 1, S), lambda b, lyr, t, c: (b, 0, 0))]
 
     return pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=block_size, rank=rank),
+        functools.partial(_decode_kernel, block_size=block_size, rank=rank,
+                          selected=sel is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer, tables, contexts
             grid=(B,),
-            in_specs=[row(C), pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[row(C), *mark_specs, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row(rank),
             scratch_shapes=[
                 pltpu.VMEM((2, P, block_size, C), latent.dtype),
@@ -208,10 +233,10 @@ def mla_decode_attention(q, latent, layer, tables, context_lens, *,
             # rows run in order: a row starts the next row's first block
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_DECODE_VMEM_LIMIT_BYTES),
-        name="mla_decode_attention",
+        name="mla_decode_attention" if sel is None else "dsa_decode_attention",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
-      context_lens.astype(jnp.int32), q, pages)
+      context_lens.astype(jnp.int32), q, *marks, pages)
 
 
 # ---------------------------------------------------------------------------
@@ -223,14 +248,22 @@ _PREFILL_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
 def _prefill_kernel(layer_ref, starts_ref, tables_ref, ctx_ref, q_ref,
-                    page_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                    block_size: int, rank: int, tq: int, heads: int):
+                    page_ref, *rest,
+                    block_size: int, rank: int, tq: int, heads: int,
+                    selected: bool = False):
     """One tile of ``tq`` query tokens (all heads: ``tq * heads`` rows,
     token-major) against one page, causal, the online-softmax state in
     VMEM across the page axis. The chunk's own rows are read back from
     the pages (the caller writes them before attending), so a chunk at
     any start position attends its whole prefix — cached pages and the
-    earlier chunks' alike — and no ``[T, S]`` score exists in HBM."""
+    earlier chunks' alike — and no ``[T, S]`` score exists in HBM.
+
+    ``rest``: (``sel_ref`` where ``selected``,) ``o_ref``, ``acc_ref``,
+    ``m_ref``, ``l_ref``. ``sel_ref`` [1, tq, block] float32 marks, a
+    query TOKEN, the keys of this page it attends (> 0.5; one mark serves
+    all its heads); the page is read whatever it marks."""
+    sel_ref = rest[0] if selected else None
+    o_ref, acc_ref, m_ref, l_ref = rest[1:] if selected else rest
     b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -256,6 +289,14 @@ def _prefill_kernel(layer_ref, starts_ref, tables_ref, ctx_ref, q_ref,
         q_pos = q_lo + jax.lax.broadcasted_iota(
             jnp.int32, (tq * heads, 1), 0) // heads
         valid = (key_pos <= q_pos) & (key_pos < ctx)
+        if selected:
+            # a token's marks repeated for its heads (rows are token-major)
+            # by a 0 / 1 matrix on the MXU: exact, and no relayout
+            spread = (jax.lax.broadcasted_iota(jnp.int32, (tq * heads, tq), 0)
+                      // heads == jax.lax.broadcasted_iota(
+                          jnp.int32, (tq * heads, tq), 1)).astype(jnp.float32)
+            valid &= jnp.dot(spread, sel_ref[0],
+                             preferred_element_type=jnp.float32) > 0.5
         s = jnp.where(valid, s, -1e30)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -284,7 +325,8 @@ def prefill_tile_tokens(T: int, heads: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("block_size", "rank", "interpret"))
 def mla_prefill_attention(q, latent, layer, tables, start_pos, context_lens, *,
-                          block_size: int, rank: int, interpret: bool = False):
+                          block_size: int, rank: int, interpret: bool = False,
+                          sel=None):
     """``q`` [B, T, H, C] (key up-projection absorbed, softmax scale folded
     in): row ``b``'s token ``t`` sits at position ``start_pos[b] + t``;
     ``latent`` [Lm, slots, C] with this chunk's rows already written;
@@ -292,7 +334,10 @@ def mla_prefill_attention(q, latent, layer, tables, start_pos, context_lens, *,
     (tokens at or past it, and rows of context 0, attend nothing and read
     no page). Returns the output IN LATENT SPACE, [B, T, H, rank]. A tile
     walks only the pages up to its own last token: pages past it repeat
-    the last live one, which skips their copy."""
+    the last live one, which skips their copy. ``sel`` [B, T, W *
+    block_size] float32, where given: a query token attends only the keys
+    it marks > 0.5 (``ops/dsa.py`` ``select_topk``), every head alike, and
+    the kernel is named ``dsa_prefill_attention``."""
     B, T, H, C = q.shape
     Lm, slots, _ = latent.shape
     pages = latent.reshape(Lm, slots // block_size, block_size, C)
@@ -309,15 +354,25 @@ def mla_prefill_attention(q, latent, layer, tables, start_pos, context_lens, *,
     def tile_index(b, qi, j, lyr, st, t, c):
         return (b, qi, 0, 0)
 
+    def marks_index(b, qi, j, lyr, st, t, c):
+        tile_hi = jnp.minimum(st[b] + (qi + 1) * tq, c[b])
+        return (b, qi, jnp.minimum(j, jnp.maximum((tile_hi - 1) // block_size, 0)))
+
+    marks, mark_specs = (), []
+    if sel is not None:
+        marks = (sel.astype(jnp.float32),)
+        mark_specs = [pl.BlockSpec((1, tq, block_size), marks_index)]
+
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, block_size=block_size, rank=rank,
-                          tq=tq, heads=H),
+                          tq=tq, heads=H, selected=sel is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,  # layer, starts, tables, contexts
             grid=(B, n_tiles, W),
             in_specs=[
                 pl.BlockSpec((1, 1, tq * H, C), tile_index),
                 pl.BlockSpec((None, None, block_size, C), page_index),
+                *mark_specs,
             ],
             out_specs=pl.BlockSpec((1, 1, tq * H, rank), tile_index),
             scratch_shapes=[
@@ -330,9 +385,9 @@ def mla_prefill_attention(q, latent, layer, tables, start_pos, context_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_PREFILL_VMEM_LIMIT_BYTES),
-        name="mla_prefill_attention",
+        name="mla_prefill_attention" if sel is None else "dsa_prefill_attention",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       jnp.asarray(start_pos, jnp.int32), tables.astype(jnp.int32),
-      context_lens.astype(jnp.int32), q4, pages)
+      context_lens.astype(jnp.int32), q4, pages, *marks)
     return out.reshape(B, T, H, rank)

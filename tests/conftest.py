@@ -112,6 +112,13 @@ _STALE_OPEN_LISTS = {
     # keys, the four cells first and in order)
     ("test_inline_admit_share.py",
      "test_benchmark_lists_the_metric_for_its_cells"): "[open]",
+    # a ninth (PR 49): PR 44's test holds each ``.open`` list as EXACTLY the
+    # four cells before mimo's and mimo's; a sixth open-loop cell is appended.
+    # ``test_perf_glm_moe_dsa.py`` holds every assertion the case made, the
+    # lists as "begin with those five, in their order" (so that the next
+    # appended cell needs no skip)
+    ("test_perf_mimo_v2_flash.py",
+     "test_the_open_variants_keep_the_four_cells_before_this_one_in_their_order"): "",
 }
 # an eighth (PR 46): ``test_prefill_fill_share.test_the_nemotron_cell_keeps_its_listing``
 # lets a metric beyond PR 37's list the nemotron cell only if it lists EVERY

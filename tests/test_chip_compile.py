@@ -487,7 +487,7 @@ def test_ssm_decode_update_compiles_for_v5e(rows, one_chip, no_compile_cache):
 # the pool a cell runs with where its configuration pins none: what the
 # engine's own sizing gives on a 16 GB chip (PERF.md section 4)
 _STAGE_BLOCKS = {"kimi-linear-48b": 6175, "kanana-2-30b": 2048,
-                 "mimo-v2-flash": 3000}
+                 "mimo-v2-flash": 3000, "glm-5": 1700}
 
 
 def _stage(config: str):
@@ -505,9 +505,13 @@ def _stage(config: str):
 
 
 def _compiled_family_step(config, rows, T, one_chip, monkeypatch):
+    return _lowered_family_step(config, rows, T, one_chip, monkeypatch).compile()
+
+
+def _lowered_family_step(config, rows, T, one_chip, monkeypatch):
     """The served step (int8 weights, bf16 pages, the cell's pool, 65 state
     slots) of ``rows`` x ``T`` tokens of a recurrent-state family at its
-    benchmark configuration, for the described chip."""
+    benchmark configuration, lowered for the described chip."""
     from dynamo_tpu.models import family as model_family, hybrid
 
     cfg, num_blocks = _stage(config)
@@ -542,7 +546,7 @@ def _compiled_family_step(config, rows, T, one_chip, monkeypatch):
 
     return jax.jit(step, donate_argnums=(1, 2)).lower(
         params, pages, state, grid, grid, _sds((rows * T,), jnp.int32, one_chip),
-        _sds((rows, TABLE_W + 1), jnp.int32, one_chip), ids, ids).compile()
+        _sds((rows, TABLE_W + 1), jnp.int32, one_chip), ids, ids)
 
 
 def _compiled_nemotron_step(rows, T, one_chip, monkeypatch):
@@ -641,6 +645,10 @@ def test_mla_prefill_attention_compiles_for_v5e(
 
 
 def _compiled_deepseek_step(rows, T, one_chip, monkeypatch):
+    return _lowered_deepseek_step(rows, T, one_chip, monkeypatch).compile()
+
+
+def _lowered_deepseek_step(rows, T, one_chip, monkeypatch):
     """The served step (int8 weights, bf16 latent pages, no state plane)
     of ``rows`` x ``T`` tokens at the benchmark's configuration."""
     from dynamo_tpu.models import deepseek_v3 as ds, hybrid
@@ -672,7 +680,7 @@ def _compiled_deepseek_step(rows, T, one_chip, monkeypatch):
     return jax.jit(step, donate_argnums=(1, 2)).lower(
         params, pages, counts, grid, grid,
         _sds((rows * T,), jnp.int32, one_chip),
-        _sds((rows, DS_TABLE_W), jnp.int32, one_chip), ids, ids).compile()
+        _sds((rows, DS_TABLE_W), jnp.int32, one_chip), ids, ids)
 
 
 @pytest.mark.parametrize("rows", [4, 64])
@@ -966,3 +974,187 @@ def test_the_mimo_prefill_step_compiles_for_v5e_within_its_transients(
     assert "ragged-dot" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < mm.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# One chip: the glm_moe_dsa family (GLM-5) at its published widths — 64 heads
+# over 576-in-640-lane latent rows, a 32 x 128 indexer whose keys live in a
+# second plane under the same page ids, the top 2 048 of up to 24 576 keys —
+# under a 192-page table; and the two latent families it shares
+# ``hybrid.mla_mixer`` and ``ops/mla.py`` with, which the arguments this family
+# added must leave as they were
+# ---------------------------------------------------------------------------
+
+# the whole served step of the two latent families, lowered for the described
+# chip at the PARENT of PR 49 (commit 0fdda25): (family, rows, tokens) -> digest
+_LATENT_STEP_DIGESTS = {
+    "kanana-decode-B4": ("kanana-2-30b", 4, 1, "5d6bb4f77a73c64e"),
+    "kanana-prefill-1x512": ("kanana-2-30b", 1, 512, "917d5d7603c1ba1a"),
+    "kimi-decode-B4": ("kimi-linear-48b", 4, 1, "177d4d3cffd08c15"),
+    "kimi-prefill-1x512": ("kimi-linear-48b", 1, 512, "d8aab3f0dce4172d"),
+    # the buckets the two cells serve most: the 64-row decode program and a
+    # whole 1 024-token chunk (after review: no builder's run of either cell)
+    "kanana-decode-B64": ("kanana-2-30b", 64, 1, "f664c67f85b27506"),
+    "kanana-prefill-1x1024": ("kanana-2-30b", 1, 1024, "75d02edf4f8fc147"),
+    "kimi-decode-B64": ("kimi-linear-48b", 64, 1, "c21217639a1527d3"),
+    "kimi-prefill-1x1024": ("kimi-linear-48b", 1, 1024, "59e5151350d8fcde"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LATENT_STEP_DIGESTS))
+def test_kimis_and_kananas_steps_lower_to_the_programs_they_did(
+    case, one_chip, no_compile_cache, monkeypatch
+):
+    """A low-rank query, a selection handed to the attend step and the
+    ``sel`` argument of both latent kernels default to what was: the whole
+    decode and prefill steps of ``kimi-linear-48b`` and ``kanana-2-30b``
+    lower to the programs the parent commit lowered, bit for bit once the
+    kernels' source locations are dropped."""
+    config, rows, T, want = _LATENT_STEP_DIGESTS[case]
+    if config == "kanana-2-30b":
+        lowered = _lowered_deepseek_step(rows, T, one_chip, monkeypatch)
+    else:
+        lowered = _lowered_family_step(config, rows, T, one_chip, monkeypatch)
+    assert _lowered_digest(lowered.as_text()) == want
+
+
+GL_TABLE_W = 200   # max_model_len 24 576 -> 192 pages, + the bucket's margin
+GL_S = GL_TABLE_W * BS
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 1024), (4, 1024), (1, 128), (64, 1)])
+def test_the_index_score_and_the_exact_top_k_compile_for_v5e(
+    rows, tokens, one_chip, no_compile_cache
+):
+    """32 heads of 128 against 25 600 cached indexer keys: a [T, S] float32
+    score a layer is all the kernels hold in HBM (no temporary at one row;
+    the head-major copy of q^I beside it at four), and the top 2 048 of a
+    block of 8 queries' scores stays in VMEM through its 47 passes."""
+    from dynamo_tpu.ops import dsa
+
+    ids = _sds((rows,), jnp.int32, one_chip)
+    scores = _sds((rows, tokens, GL_S), jnp.float32, one_chip)
+    index = jax.jit(dsa.index_scores).lower(
+        _sds((rows, tokens, 32, 128), jnp.bfloat16, one_chip),
+        _sds((rows, tokens, 32), jnp.float32, one_chip),
+        _sds((rows, GL_S, 128), jnp.bfloat16, one_chip), ids, ids).compile()
+    kind = "decode" if tokens == 1 else "prefill"
+    assert f"dsa_index_{kind}" in index.as_text()
+    assert index.memory_analysis().temp_size_in_bytes < 64 << 20
+    select = jax.jit(functools.partial(dsa.select_topk, k=2048)).lower(
+        scores, ids).compile()
+    assert f"dsa_select_{kind}" in select.as_text()
+    assert select.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 1024), (4, 1024), (1, 128)])
+def test_the_masked_prefill_walk_compiles_for_v5e(
+    rows, tokens, one_chip, no_compile_cache
+):
+    """``mla_prefill_attention`` with marks at 64 heads (a tile of 16
+    query tokens) over a 9-layer plane and a 200-page table: the kernel is
+    named for the family, and the dense call beside it keeps its name."""
+    from dynamo_tpu.ops.mla import mla_prefill_attention
+
+    ids = _sds((rows,), jnp.int32, one_chip)
+    shapes = (
+        _sds((rows, tokens, 64, 640), jnp.bfloat16, one_chip),
+        _sds((9, _STAGE_BLOCKS["glm-5"] * BS, 640), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((rows, GL_TABLE_W), jnp.int32, one_chip),
+        ids, ids)
+    fn = functools.partial(mla_prefill_attention, block_size=BS, rank=512)
+    text = jax.jit(fn).lower(
+        *shapes, sel=_sds((rows, tokens, GL_S), jnp.float32, one_chip)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "dsa_prefill_attention" in text
+    assert "dsa_prefill_attention" not in _compile_text(fn, *shapes)
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_the_masked_decode_walk_compiles_for_v5e(rows, one_chip, no_compile_cache):
+    """``mla_decode_attention`` with a row of marks beside each row's
+    queries (100 KB of VMEM a row), at the dense kernel's VMEM budget."""
+    from dynamo_tpu.ops import paged_attention as pa
+    from dynamo_tpu.ops.mla import mla_decode_attention
+
+    text = jax.jit(functools.partial(
+        mla_decode_attention, block_size=BS, rank=512)).lower(
+        _sds((rows, 64, 640), jnp.bfloat16, one_chip),
+        _sds((9, _STAGE_BLOCKS["glm-5"] * BS, 640), jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((rows, GL_TABLE_W), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip),
+        sel=_sds((rows, GL_S), jnp.float32, one_chip)).compile().as_text()
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and "dsa_decode_attention" in line)
+    assert f'"size":"{pa._DECODE_VMEM_LIMIT_BYTES}"' in call
+
+
+def _compiled_glm_step(rows, T, one_chip, monkeypatch):
+    """The served step (int8 weights, both bf16 page planes) of ``rows`` x
+    ``T`` tokens at the benchmark's configuration under a 200-page table."""
+    from dynamo_tpu.models import glm_moe_dsa as glm, hybrid
+
+    cfg, num_blocks = _stage("glm-5")
+    monkeypatch.setattr(hybrid, "kernels_active", lambda: True)
+    monkeypatch.setattr(glm, "kernels_active", lambda: True)
+    monkeypatch.setattr(llama, "pallas_matmul_active", lambda: True)
+    monkeypatch.setattr(llama, "_qmm_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = {}
+    for name, (shape, dtype) in glm.param_shapes(cfg).items():
+        if name in glm.QUANT_AXIS:
+            params[name] = _sds(shape, jnp.int8, one_chip)
+            axis = glm.QUANT_AXIS[name] % len(shape)
+            params[name + "_scale"] = _sds(
+                shape[:axis] + shape[axis + 1:], jnp.float32, one_chip)
+        else:
+            params[name] = _sds(shape, dtype, one_chip)
+    pages = {name: _sds((9, num_blocks * BS, width), jnp.bfloat16, one_chip)
+             for name, width in glm.plane_widths(cfg).items()}
+    counts = {"counts": _sds((len(glm.COUNT_NAMES),), jnp.int32, one_chip)}
+    ids = _sds((rows,), jnp.int32, one_chip)
+    grid = _sds((rows, T), jnp.int32, one_chip)
+
+    def step(params, pages, counts, tokens, positions, slots, tables, ctx, last):
+        return glm.forward(cfg, params, pages, counts, tokens, positions, slots,
+                           tables, ctx, last, BS)
+
+    return jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, pages, counts, grid, grid,
+        _sds((rows * T,), jnp.int32, one_chip),
+        _sds((rows, GL_TABLE_W), jnp.int32, one_chip), ids, ids).compile()
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_the_glm_decode_step_compiles_for_v5e_within_its_transients(
+    rows, one_chip, no_compile_cache, monkeypatch
+):
+    """Nine layers of index score, exact top k and masked decode walk,
+    and no copy of either page plane at the program's edge."""
+    from dynamo_tpu.models import glm_moe_dsa as glm
+
+    compiled = _compiled_glm_step(rows, 1, one_chip, monkeypatch)
+    text = compiled.as_text()
+    for kernel in ("dsa_index_decode", "dsa_select_decode", "dsa_decode_attention"):
+        assert text.count(kernel) >= 9, kernel
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < glm.STEP_TRANSIENT_BYTES // 2, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("rows,tokens", [(4, 1024), (1, 1024), (1, 128)])
+def test_the_glm_prefill_step_compiles_for_v5e_within_its_transients(
+    rows, tokens, one_chip, no_compile_cache, monkeypatch
+):
+    """``max_prefill_tokens`` 4 096 as 4 rows of a whole 1 024-token chunk
+    under a 200-page table: the three selection kernels a layer, the
+    sorted-rows experts, and temporaries inside what the family reserves."""
+    from dynamo_tpu.models import glm_moe_dsa as glm
+
+    compiled = _compiled_glm_step(rows, tokens, one_chip, monkeypatch)
+    text = compiled.as_text()
+    for kernel in ("dsa_index_prefill", "dsa_select_prefill", "dsa_prefill_attention"):
+        assert text.count(kernel) >= 9, kernel
+    assert "ragged-dot" in text
+    mem = compiled.memory_analysis()
+    print("glm prefill temp", rows, tokens, mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < glm.STEP_TRANSIENT_BYTES, mem.temp_size_in_bytes
