@@ -19,7 +19,9 @@ and attend the cached rows themselves; the value half is applied to the
 output in latent space. Decode runs ``ops/mla.py``
 ``mla_decode_attention``. PREFILL runs ``mla_prefill_attention``, the
 same ABSORBED form as a flash kernel over the row's own pages: a tile of
-32 query tokens x 32 heads against a page a grid step, 2 x (640 + 512)
+32 query tokens x 32 heads walks its live pages eight to a compute block
+(6.9 ms a 1 024-token chunk over 14k keys on a v5e, 14.7 a page a grid
+step: PERF.md, PR 50), 2 x (640 + 512)
 FLOP a (query, key, head) where building ``k_h``, ``v_h`` a head would
 take 2 x (192 + 128) — chosen because nothing of size ``T x S`` or
 ``S x H x 256`` ever exists in HBM at S = 16 384 (the up-projected form

@@ -4,8 +4,9 @@
 compute block, each page its own DMA into a double buffer — the plan of
 ops/paged_attention.py's decode kernel (PR 30) with a body of its own,
 because here key and value are ONE plane. Prefill
-(``mla_prefill_attention``, at the file's end): a (row, tile, table
-column) grid, a page a step, under a tile of query tokens, causal.
+(``mla_prefill_attention``, at the file's end): the same walk under a
+TILE of query tokens, ``grid=(rows, tiles)``, causal — a tile's live
+pages, several to a block, one online-softmax update a block.
 
 The cache holds ONE row a token a layer: ``[c | k_r]``, the normalised
 latent (``rank`` values) and the shared key part (``rope`` values:
@@ -27,6 +28,13 @@ pages and 19 us at one row of 68, 68-72 % of the HBM floor of the 576
 published values a row (the stored 640 lanes bound it at 90), where the
 grid over the table took 0.72 ms and 93 us; flat from 6 to 16 pages a
 block; the scores as ``q @ rows^T`` beat ``rows @ q^T`` by 15-20 %.
+The prefill walk (PERF.md, PR 50) takes 1.65 us a further live page
+under a tile of 1 024 (token, head) rows — the tile's two dots are 1.53
+us of the MXU's peak — and ~20-30 us a tile beside them, where the grid
+over the table, a page a step, took 4.2: a 1 024-token chunk over 8k /
+14k / 20k keys 8.1 / 13.3 / 18.3 ms at 64 heads with marks (18.4 / 30.6
+/ 42.9) and 4.4 / 6.9 at 32 heads over 8k / 14k (8.8 / 14.7); flat from
+4 to 8 pages a block, 10-20 % slower at 16.
 """
 
 from __future__ import annotations
@@ -245,73 +253,155 @@ def mla_decode_attention(q, latent, layer, tables, context_lens, *,
 
 PREFILL_TILE_ROWS = 1024   # (query token, head) rows a grid step scores
 _PREFILL_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+# ceiling on a compute block's float32 score, [tile rows, P * block_size]:
+# the masked score, the probabilities and their bf16 copy are temporaries
+# of that shape beside it
+_PREFILL_SCORE_BYTES = 4 * 2**20
+_MASKED = -1e30     # a masked key's score
+_NO_SCORE = -1e29   # where a row's running max starts: above ``_MASKED``
 
 
-def _prefill_kernel(layer_ref, starts_ref, tables_ref, ctx_ref, q_ref,
-                    page_ref, *rest,
+def prefill_pages_per_block(block_size: int, C: int, itemsize: int,
+                            tile_rows: int) -> int:
+    """Pages of one compute block of the latent prefill kernel, from the
+    call's own geometry: as many as ``latent_pages_per_block``'s double
+    buffer holds of this plane's pages, while the block's
+    ``[tile_rows, P * block_size]`` float32 score stays within
+    ``_PREFILL_SCORE_BYTES``. At 1 024 tile rows (32 tokens x 32 heads,
+    16 x 64) of 128-token pages: 8; the v5e sweep was flat from 4 to 8
+    at both shapes and lost 10-20 % at 16 (PERF.md, PR 50)."""
+    by_vmem = _DECODE_KV_BUFFER_BYTES // (2 * block_size * C * itemsize)
+    by_score = _PREFILL_SCORE_BYTES // (tile_rows * block_size * 4)
+    return max(1, min(by_vmem, by_score))
+
+
+def _prefill_kernel(layer_ref, starts_ref, tables_ref, ctx_ref, q_ref, *rest,
                     block_size: int, rank: int, tq: int, heads: int,
-                    selected: bool = False):
-    """One tile of ``tq`` query tokens (all heads: ``tq * heads`` rows,
-    token-major) against one page, causal, the online-softmax state in
-    VMEM across the page axis. The chunk's own rows are read back from
-    the pages (the caller writes them before attending), so a chunk at
-    any start position attends its whole prefix — cached pages and the
-    earlier chunks' alike — and no ``[T, S]`` score exists in HBM.
+                    selected: bool):
+    """``rest``: (``sel_ref`` where ``selected``,) ``pages_hbm``, ``o_ref``,
+    ``buf``, ``sems``, ``acc_ref``, ``m_ref``, ``l_ref``.
 
-    ``rest``: (``sel_ref`` where ``selected``,) ``o_ref``, ``acc_ref``,
-    ``m_ref``, ``l_ref``. ``sel_ref`` [1, tq, block] float32 marks, a
-    query TOKEN, the keys of this page it attends (> 0.5; one mark serves
-    all its heads); the page is read whatever it marks."""
+    One grid step a TILE of ``tq`` query tokens (all heads: ``tq * heads``
+    rows, token-major) over the stacked plane left in HBM as pages
+    ``[Lm, N, bs, C]``. The tile walks its LIVE pages only — up to the
+    causal edge of its last real token — ``P`` to a compute block, each
+    page one DMA into the ``[2, P, bs, C]`` double buffer, the next block
+    in flight while this one is computed. Table columns past the live
+    range are never dereferenced; a row of context 0 and a tile at or
+    past its row's context start no copy and store zeros. The chunk's own
+    rows are read back from the pages (the caller writes them before
+    attending), so a chunk at any start position attends its whole prefix
+    — cached pages and the earlier chunks' alike — and no ``[T, S]``
+    score exists in HBM.
+
+    One block is ONE dot of the tile's rows against its ``P * bs`` latent
+    rows (all ``C`` lanes), one online-softmax update (state in VMEM
+    across the blocks), one dot of the probabilities against the same
+    rows' first ``rank`` lanes. Masks are made a TOKEN, ``[tq, P * bs]``,
+    and spread over the token's heads by a sublane broadcast (rows are
+    token-major: ``[tq * heads, keys]`` is ``[tq, heads, keys]`` where
+    ``heads`` fills whole sublane tiles; a 0 / 1 spread matmul measured
+    10 % slower at 64 heads); only the blocks that reach past the tile's
+    first query position compare positions at all.
+
+    ``selected``: ``sel_ref`` [1, tq, >= pages x bs] float32 marks, a
+    query TOKEN, the keys it attends (> 0.5; one mark serves all its
+    heads); every live page is read whatever it marks: the masked WALK."""
     sel_ref = rest[0] if selected else None
-    o_ref, acc_ref, m_ref, l_ref = rest[1:] if selected else rest
-    b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
+    pages_hbm, o_ref, buf, sems, acc_ref, m_ref, l_ref = (
+        rest[1:] if selected else rest)
+    b, qi = pl.program_id(0), pl.program_id(1)
+    P, bs, C = buf.shape[1], block_size, buf.shape[3]
+    W = tables_ref.shape[1]
+    cols = P * bs
+    lyr = layer_ref[0]
     ctx = ctx_ref[b]
     q_lo = starts_ref[b] + qi * tq
     q_hi = jnp.minimum(q_lo + tq, ctx)      # one past the tile's last real token
+    n_pages = jnp.where(q_lo < ctx, (q_hi + bs - 1) // bs, 0)
+    n_blocks = (n_pages + P - 1) // P
+    # blocks whose every key lies at or before the tile's first query
+    n_plain = jnp.minimum((q_lo + 1) // cols, n_blocks)
 
-    @pl.when((j * block_size < q_hi) & (q_lo < ctx))
-    def _page():
+    def block_copies(i, slot, fn):
+        """``fn`` (start or wait) on the copies of block ``i`` into
+        ``slot``: one a live page, none for the slots past the last."""
+        def page_copy(p, carry):
+            fn(pltpu.make_async_copy(
+                pages_hbm.at[lyr, tables_ref[b, i * P + p]],
+                buf.at[slot, p], sems.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(P, n_pages - i * P), page_copy, 0)
+
+    start = lambda *a: block_copies(*a, lambda c: c.start())  # noqa: E731
+    wait = lambda *a: block_copies(*a, lambda c: c.wait())  # noqa: E731
+
+    m_ref[...] = jnp.full_like(m_ref, _NO_SCORE)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_blocks > 0)
+    def _first_block():
+        # page slots the last block leaves unfilled are masked by position,
+        # but 0 x NaN is NaN in the PV dot: no slot may hold uninitialised
+        # VMEM (a filled slot holds cache values, which are finite)
+        buf[...] = jnp.zeros_like(buf)
+        start(0, 0)
+
+    # made once a tile: each query token's position
+    tok_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+
+    def block(i, carry, *, edge: bool):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start(i + 1, 1 - slot)
+
+        wait(i, slot)
         q = q_ref[0, 0]                                # [tq * H, C], scaled
-        rows = page_ref[...]                           # [block, C]
+        rows = buf[slot].reshape(cols, C)
         if rows.dtype != q.dtype:
             rows = rows.astype(q.dtype)
         s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        key_pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1)
-        q_pos = q_lo + jax.lax.broadcasted_iota(
-            jnp.int32, (tq * heads, 1), 0) // heads
-        valid = (key_pos <= q_pos) & (key_pos < ctx)
+        keep = None                                    # [tq, cols], a token
         if selected:
-            # a token's marks repeated for its heads (rows are token-major)
-            # by a 0 / 1 matrix on the MXU: exact, and no relayout
-            spread = (jax.lax.broadcasted_iota(jnp.int32, (tq * heads, tq), 0)
-                      // heads == jax.lax.broadcasted_iota(
-                          jnp.int32, (tq * heads, tq), 1)).astype(jnp.float32)
-            valid &= jnp.dot(spread, sel_ref[0],
-                             preferred_element_type=jnp.float32) > 0.5
-        s = jnp.where(valid, s, -1e30)
-        m_prev = m_ref[:]
+            # a page's marks; the slots of a last block past the table's
+            # width repeat its last column's (masked by position)
+            keep = jnp.concatenate([
+                sel_ref[0, :, pl.ds(pl.multiple_of(
+                    jnp.minimum(i * P + p, W - 1) * bs, bs), bs)]
+                for p in range(P)], axis=-1) > 0.5
+        if edge:
+            key_pos = i * cols + jax.lax.broadcasted_iota(
+                jnp.int32, (1, cols), 1)
+            causal = (key_pos <= tok_pos) & (key_pos < ctx)
+            keep = causal if keep is None else keep & causal
+        if keep is not None:
+            # _MASKED + s is _MASKED: a score is some 2**70 times too small
+            # to move it
+            bias = jnp.where(keep, 0.0, _MASKED)
+            s = (s.reshape(tq, heads, cols) + bias[:, None, :]).reshape(
+                tq * heads, cols)
+        m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        # m_new >= _NO_SCORE > _MASKED: a masked key's exp is exactly 0, in
+        # a block that holds no key of the row's too
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(rows.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(rows.dtype), rows[:, :rank],
             preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        m_ref[...] = m_new
+        return carry
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finish():
-        # tokens with no valid key (padding): clamp, not NaN
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-9)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_plain, functools.partial(block, edge=False), 0)
+    jax.lax.fori_loop(n_plain, n_blocks, functools.partial(block, edge=True), 0)
+    # tokens with no valid key (padding): clamp, not NaN
+    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-9)).astype(o_ref.dtype)
 
 
 def prefill_tile_tokens(T: int, heads: int) -> int:
@@ -323,59 +413,49 @@ def prefill_tile_tokens(T: int, heads: int) -> int:
     return tq
 
 
-@functools.partial(jax.jit, static_argnames=("block_size", "rank", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "rank", "interpret", "pages_per_block"))
 def mla_prefill_attention(q, latent, layer, tables, start_pos, context_lens, *,
                           block_size: int, rank: int, interpret: bool = False,
-                          sel=None):
+                          pages_per_block: Optional[int] = None, sel=None):
     """``q`` [B, T, H, C] (key up-projection absorbed, softmax scale folded
     in): row ``b``'s token ``t`` sits at position ``start_pos[b] + t``;
     ``latent`` [Lm, slots, C] with this chunk's rows already written;
     ``tables`` [B, W]; ``context_lens`` [B] counts the chunk's real tokens
-    (tokens at or past it, and rows of context 0, attend nothing and read
+    (a tile at or past it, and rows of context 0, attend nothing and read
     no page). Returns the output IN LATENT SPACE, [B, T, H, rank]. A tile
-    walks only the pages up to its own last token: pages past it repeat
-    the last live one, which skips their copy. ``sel`` [B, T, W *
+    walks only the pages up to its own last token, ``pages_per_block`` to
+    a compute block (for tests; by default sized from the call's own
+    geometry, ``prefill_pages_per_block``). ``sel`` [B, T, W *
     block_size] float32, where given: a query token attends only the keys
     it marks > 0.5 (``ops/dsa.py`` ``select_topk``), every head alike, and
     the kernel is named ``dsa_prefill_attention``."""
     B, T, H, C = q.shape
     Lm, slots, _ = latent.shape
     pages = latent.reshape(Lm, slots // block_size, block_size, C)
-    W = tables.shape[1]
     tq = prefill_tile_tokens(T, H)
     n_tiles = T // tq
-    q4 = q.reshape(B, n_tiles, tq * H, C)
-
-    def page_index(b, qi, j, lyr, st, t, c):
-        tile_hi = jnp.minimum(st[b] + (qi + 1) * tq, c[b])
-        last = jnp.maximum((tile_hi - 1) // block_size, 0)
-        return (lyr[0], t[b, jnp.minimum(j, last)], 0, 0)
-
-    def tile_index(b, qi, j, lyr, st, t, c):
-        return (b, qi, 0, 0)
-
-    def marks_index(b, qi, j, lyr, st, t, c):
-        tile_hi = jnp.minimum(st[b] + (qi + 1) * tq, c[b])
-        return (b, qi, jnp.minimum(j, jnp.maximum((tile_hi - 1) // block_size, 0)))
-
+    P = pages_per_block or prefill_pages_per_block(
+        block_size, C, latent.dtype.itemsize, tq * H)
+    tile = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, 1, tq * H, width), lambda b, qi, lyr, st, t, c: (b, qi, 0, 0))
     marks, mark_specs = (), []
     if sel is not None:
         marks = (sel.astype(jnp.float32),)
-        mark_specs = [pl.BlockSpec((1, tq, block_size), marks_index)]
+        mark_specs = [pl.BlockSpec(
+            (1, tq, sel.shape[2]), lambda b, qi, lyr, st, t, c: (b, qi, 0))]
 
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, block_size=block_size, rank=rank,
                           tq=tq, heads=H, selected=sel is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,  # layer, starts, tables, contexts
-            grid=(B, n_tiles, W),
-            in_specs=[
-                pl.BlockSpec((1, 1, tq * H, C), tile_index),
-                pl.BlockSpec((None, None, block_size, C), page_index),
-                *mark_specs,
-            ],
-            out_specs=pl.BlockSpec((1, 1, tq * H, rank), tile_index),
+            grid=(B, n_tiles),
+            in_specs=[tile(C), *mark_specs, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=tile(rank),
             scratch_shapes=[
+                pltpu.VMEM((2, P, block_size, C), latent.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((tq * H, rank), jnp.float32),
                 pltpu.VMEM((tq * H, 1), jnp.float32),
                 pltpu.VMEM((tq * H, 1), jnp.float32),
@@ -383,11 +463,12 @@ def mla_prefill_attention(q, latent, layer, tables, start_pos, context_lens, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, n_tiles, tq * H, rank), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_PREFILL_VMEM_LIMIT_BYTES),
         name="mla_prefill_attention" if sel is None else "dsa_prefill_attention",
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       jnp.asarray(start_pos, jnp.int32), tables.astype(jnp.int32),
-      context_lens.astype(jnp.int32), q4, pages, *marks)
+      context_lens.astype(jnp.int32), q.reshape(B, n_tiles, tq * H, C),
+      *marks, pages)
     return out.reshape(B, T, H, rank)

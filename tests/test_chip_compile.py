@@ -298,6 +298,48 @@ def test_conv_tail_update_compiles_for_v5e(family, rows, one_chip, no_compile_ca
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer
 
 
+def _with_kernels_printed(text: str) -> str:
+    """A lowered program's text, its serialised Mosaic kernels printed as
+    MLIR without source locations."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    def body(m):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            return ir.Module.parse(base64.b64decode(m.group(1))).operation.get_asm(
+                enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+
+
+def _assert_mla_prefill_walks_in_blocks(lowered, name, rows, tokens, heads, lanes):
+    """The prefill kernel ``name`` of a lowered call: its grid is (rows,
+    tiles) — the table's width is no axis of it — and compiled it asks
+    for its own scoped VMEM and no more (a kernel over it does not
+    compile), its double buffer and its block's float32 score at the
+    rule's pages a block inside the rule's two budgets."""
+    from dynamo_tpu.ops import mla
+    from dynamo_tpu.ops import paged_attention as pa
+
+    tq = mla.prefill_tile_tokens(tokens, heads)
+    assert (f"iteration_bounds = array<i64: {rows}, {tokens // tq}>"
+            in _with_kernels_printed(lowered.as_text()))
+    text = lowered.compile().as_text()
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line and name in line)
+    assert f'"size":"{mla._PREFILL_VMEM_LIMIT_BYTES}"' in call
+    P = mla.prefill_pages_per_block(BS, lanes, 2, tq * heads)
+    assert P > 1
+    assert 2 * P * BS * lanes * 2 <= pa._DECODE_KV_BUFFER_BYTES
+    assert tq * heads * P * BS * 4 <= mla._PREFILL_SCORE_BYTES
+    assert (pa._DECODE_KV_BUFFER_BYTES + 4 * mla._PREFILL_SCORE_BYTES
+            < mla._PREFILL_VMEM_LIMIT_BYTES)
+
+
 def _mla_decode_text(rows, lanes, layers, pool, table_w, one_chip, **kw) -> str:
     from dynamo_tpu.ops.mla import mla_decode_attention
 
@@ -632,16 +674,20 @@ def test_mla_decode_attention_compiles_at_a_16k_table(rows, one_chip, no_compile
 def test_mla_prefill_attention_compiles_for_v5e(
     rows, tokens, one_chip, no_compile_cache
 ):
+    """The prefill kernel at 32 heads (a tile of 32 query tokens) over a
+    12-layer plane and a table 136 pages wide: a grid of (rows, tiles),
+    inside its VMEM limit."""
     from dynamo_tpu.ops.mla import mla_prefill_attention
 
     ids = _sds((rows,), jnp.int32, one_chip)
-    text = _compile_text(
-        functools.partial(mla_prefill_attention, block_size=BS, rank=512),
+    lowered = jax.jit(functools.partial(
+        mla_prefill_attention, block_size=BS, rank=512)).lower(
         _sds((rows, tokens, 32, 640), jnp.bfloat16, one_chip),
         _sds((12, DS_POOL * BS, 640), jnp.bfloat16, one_chip),
         _sds((), jnp.int32, one_chip), _sds((rows, DS_TABLE_W), jnp.int32, one_chip),
         ids, ids)
-    assert "tpu_custom_call" in text and "mla_prefill_attention" in text
+    _assert_mla_prefill_walks_in_blocks(
+        lowered, "mla_prefill_attention", rows, tokens, 32, 640)
 
 
 def _compiled_deepseek_step(rows, T, one_chip, monkeypatch):
@@ -733,21 +779,9 @@ def _lowered_digest(text: str) -> str:
     """sha256 of a lowered program, its Mosaic kernels printed without
     source locations (the serialised kernel holds the line numbers of
     ``ops/paged_attention.py``, which any edit above a line moves)."""
-    import base64
     import hashlib
-    import re
 
-    from jax._src.lib.mlir import ir
-
-    def body(m):
-        ctx = ir.Context()
-        ctx.allow_unregistered_dialects = True
-        with ctx:
-            return ir.Module.parse(base64.b64decode(m.group(1))).operation.get_asm(
-                enable_debug_info=False)
-
-    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+    return hashlib.sha256(_with_kernels_printed(text).encode()).hexdigest()[:16]
 
 
 # (prefill, rows, tokens, int8 cache, H, Hk, Dh, window) -> the digest of the
@@ -986,16 +1020,19 @@ def test_the_mimo_prefill_step_compiles_for_v5e_within_its_transients(
 # ---------------------------------------------------------------------------
 
 # the whole served step of the two latent families, lowered for the described
-# chip at the PARENT of PR 49 (commit 0fdda25): (family, rows, tokens) -> digest
+# chip at the PARENT of PR 49 (commit 0fdda25): (family, rows, tokens) -> digest.
+# kanana's two prefill programs are PR 50's: it rewrote their attention kernel
+# (``mla_prefill_attention`` walks a tile's live pages in blocks); kimi's
+# prefill is plain XLA and kanana's decode does not hold the kernel
 _LATENT_STEP_DIGESTS = {
     "kanana-decode-B4": ("kanana-2-30b", 4, 1, "5d6bb4f77a73c64e"),
-    "kanana-prefill-1x512": ("kanana-2-30b", 1, 512, "917d5d7603c1ba1a"),
+    "kanana-prefill-1x512": ("kanana-2-30b", 1, 512, "cc6dae80fea24cd8"),
     "kimi-decode-B4": ("kimi-linear-48b", 4, 1, "177d4d3cffd08c15"),
     "kimi-prefill-1x512": ("kimi-linear-48b", 1, 512, "d8aab3f0dce4172d"),
     # the buckets the two cells serve most: the 64-row decode program and a
     # whole 1 024-token chunk (after review: no builder's run of either cell)
     "kanana-decode-B64": ("kanana-2-30b", 64, 1, "f664c67f85b27506"),
-    "kanana-prefill-1x1024": ("kanana-2-30b", 1, 1024, "75d02edf4f8fc147"),
+    "kanana-prefill-1x1024": ("kanana-2-30b", 1, 1024, "a4ddede9c675a798"),
     "kimi-decode-B64": ("kimi-linear-48b", 64, 1, "c21217639a1527d3"),
     "kimi-prefill-1x1024": ("kimi-linear-48b", 1, 1024, "59e5151350d8fcde"),
 }
@@ -1009,7 +1046,8 @@ def test_kimis_and_kananas_steps_lower_to_the_programs_they_did(
     ``sel`` argument of both latent kernels default to what was: the whole
     decode and prefill steps of ``kimi-linear-48b`` and ``kanana-2-30b``
     lower to the programs the parent commit lowered, bit for bit once the
-    kernels' source locations are dropped."""
+    kernels' source locations are dropped (kanana's prefill: to the
+    programs of PR 50, whose kernel it is)."""
     config, rows, T, want = _LATENT_STEP_DIGESTS[case]
     if config == "kanana-2-30b":
         lowered = _lowered_deepseek_step(rows, T, one_chip, monkeypatch)
@@ -1052,8 +1090,10 @@ def test_the_masked_prefill_walk_compiles_for_v5e(
     rows, tokens, one_chip, no_compile_cache
 ):
     """``mla_prefill_attention`` with marks at 64 heads (a tile of 16
-    query tokens) over a 9-layer plane and a 200-page table: the kernel is
-    named for the family, and the dense call beside it keeps its name."""
+    query tokens, its marks ``[16, 25 600]`` float32 beside it) over a
+    9-layer plane and a 200-page table: a grid of (rows, tiles) inside its
+    VMEM limit; the kernel is named for the family, and the dense call
+    beside it keeps its name."""
     from dynamo_tpu.ops.mla import mla_prefill_attention
 
     ids = _sds((rows,), jnp.int32, one_chip)
@@ -1063,10 +1103,10 @@ def test_the_masked_prefill_walk_compiles_for_v5e(
         _sds((), jnp.int32, one_chip), _sds((rows, GL_TABLE_W), jnp.int32, one_chip),
         ids, ids)
     fn = functools.partial(mla_prefill_attention, block_size=BS, rank=512)
-    text = jax.jit(fn).lower(
-        *shapes, sel=_sds((rows, tokens, GL_S), jnp.float32, one_chip)
-    ).compile().as_text()
-    assert "tpu_custom_call" in text and "dsa_prefill_attention" in text
+    _assert_mla_prefill_walks_in_blocks(
+        jax.jit(fn).lower(
+            *shapes, sel=_sds((rows, tokens, GL_S), jnp.float32, one_chip)),
+        "dsa_prefill_attention", rows, tokens, 64, 640)
     assert "dsa_prefill_attention" not in _compile_text(fn, *shapes)
 
 

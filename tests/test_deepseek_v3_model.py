@@ -281,9 +281,11 @@ def test_absorbed_decode_gives_the_references_heads(monkeypatch):
         np.testing.assert_allclose(np.asarray(out)[0, 0], want[0, 12], atol=2e-5)
 
 
-def test_flash_prefill_over_cached_pages_is_the_gathered_form():
+@pytest.mark.parametrize("pages_per_block", [1, 2, 3, None])
+def test_flash_prefill_over_cached_pages_is_the_gathered_form(pages_per_block):
     """A chunk that starts at position 16 over two cached pages, beside a
-    row that starts at 0 and a garbage row: the kernel (interpreted)
+    row that starts at 0 and a garbage row: the kernel (interpreted), at
+    one, two and three pages a compute block and at the rule's own,
     against plain XLA over the gathered table."""
     from dynamo_tpu.ops.mla import mla_prefill_attention
 
@@ -296,7 +298,7 @@ def test_flash_prefill_over_cached_pages_is_the_gathered_form():
     ctx = jnp.asarray([29, 11, 0], jnp.int32)
     got = np.asarray(mla_prefill_attention(
         q, latent, jnp.int32(1), tables, start, ctx, block_size=bs, rank=rank,
-        interpret=True))
+        interpret=True, pages_per_block=pages_per_block))
     rows = np.asarray(latent)[1][(np.asarray(tables)[:, :, None] * bs
                                   + np.arange(bs)).reshape(3, -1)]   # [3, S, C]
     for b in range(2):

@@ -6,6 +6,8 @@ index — and the masked page walk in prefill and decode against a softmax
 over the selected keys alone, with padded rows leading, trailing and
 between."""
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -132,7 +134,8 @@ def attend_selected(q, rows, keep):
     return (pr / pr.sum(-1, keepdims=True)) @ rows[keep, :RANK]
 
 
-def test_prefill_walks_every_page_and_attends_only_the_marked_keys():
+@pytest.mark.parametrize("pages_per_block", [1, 2, 3, None])
+def test_prefill_walks_every_page_and_attends_only_the_marked_keys(pages_per_block):
     rng = np.random.default_rng(1)
     T, S = 16, 5 * BS
     latent = jnp.asarray(rng.normal(size=(2, 10 * BS, C)), jnp.float32)
@@ -145,10 +148,12 @@ def test_prefill_walks_every_page_and_attends_only_the_marked_keys():
             sel[b, t, int(start[b]) + t] = 1
     sel[1, 2, :] = 0
     sel[1, 2, 3] = 1          # one early key: every later page holds none
-    got = np.asarray(mla_prefill_attention(
-        jnp.asarray(q), latent, jnp.int32(1), jnp.asarray(TABLES),
-        jnp.asarray(start), jnp.asarray(ctx), block_size=BS, rank=RANK,
-        interpret=True, sel=jnp.asarray(sel)))
+    walk = functools.partial(
+        mla_prefill_attention, jnp.asarray(q), latent, jnp.int32(1),
+        jnp.asarray(TABLES), jnp.asarray(start), jnp.asarray(ctx),
+        block_size=BS, rank=RANK, interpret=True,
+        pages_per_block=pages_per_block)
+    got = np.asarray(walk(sel=jnp.asarray(sel)))
     rows = np.asarray(latent)[1][(TABLES[:, :, None] * BS + np.arange(BS)).reshape(5, -1)]
     for b in (1, 3):
         for t in range(int(ctx[b] - start[b])):
@@ -158,15 +163,57 @@ def test_prefill_walks_every_page_and_attends_only_the_marked_keys():
                 got[b, t], attend_selected(q[b, t], rows[b], keep), atol=2e-5)
     assert np.isfinite(got).all()
     # all keys marked: the dense kernel's answer
-    dense = np.asarray(mla_prefill_attention(
-        jnp.asarray(q), latent, jnp.int32(1), jnp.asarray(TABLES),
+    every = np.asarray(walk(sel=jnp.ones((5, T, S), jnp.float32)))
+    np.testing.assert_allclose(every[[1, 3]], np.asarray(walk())[[1, 3]], atol=1e-6)
+
+
+@pytest.mark.parametrize("pages_per_block", [1, 2, 3, None])
+@pytest.mark.parametrize("marked", [False, True], ids=["dense", "marked"])
+def test_prefill_walks_a_tiles_live_pages_in_blocks(marked, pages_per_block):
+    """Three tiles of 32 tokens a row at 32 heads. Row 1's chunk starts
+    mid-page over cached pages and ends inside its second tile (the third
+    is past its context: zeros, no page read); row 3 fills all three, its
+    last block a partial one at 3 pages a block; rows of context 0 lead,
+    trail and sit between. Table columns past a row's live pages name a
+    page past the pool, which a copy would clamp to the pool's last page
+    — all NaN here: one dereferenced column and the output is NaN."""
+    heads, T, W, pool = 32, 96, 16, 40
+    rng = np.random.default_rng(3)
+    plane = rng.normal(size=(2, pool * BS, C)).astype(np.float32)
+    plane[:, -BS:] = np.nan
+    q = rng.normal(size=(5, T, heads, C)).astype(np.float32) * 0.2
+    start = np.array([0, 20, 0, 0, 0], np.int32)
+    ctx = np.array([0, 60, 0, 96, 0], np.int32)
+    tables = np.full((5, W), 10**6, np.int32)
+    tables[1, :8] = 3 + np.arange(8)            # ceil(60 / 8) live pages
+    tables[3, :12] = 20 + np.arange(12)
+    sel = None
+    if marked:
+        sel = (rng.random((5, T, W * BS)) < 0.3).astype(np.float32)
+        for b in (1, 3):
+            for t in range(T):
+                sel[b, t, int(start[b]) + t] = 1
+        sel[1, 5, 1:] = 0
+        sel[1, 5, 0] = 1      # the first page only: every later block holds none
+        sel[3, 70, :64] = 0   # no marked key before its fifth page
+    got = np.asarray(mla_prefill_attention(
+        jnp.asarray(q), jnp.asarray(plane), jnp.int32(1), jnp.asarray(tables),
         jnp.asarray(start), jnp.asarray(ctx), block_size=BS, rank=RANK,
-        interpret=True))
-    every = np.asarray(mla_prefill_attention(
-        jnp.asarray(q), latent, jnp.int32(1), jnp.asarray(TABLES),
-        jnp.asarray(start), jnp.asarray(ctx), block_size=BS, rank=RANK,
-        interpret=True, sel=jnp.ones((5, T, S), jnp.float32)))
-    np.testing.assert_allclose(every[[1, 3]], dense[[1, 3]], atol=1e-6)
+        interpret=True, pages_per_block=pages_per_block,
+        sel=None if sel is None else jnp.asarray(sel)))
+    assert np.isfinite(got).all()
+    assert not got[[0, 2, 4]].any()             # padded rows: zeros
+    assert not got[1, 64:].any()                # a tile past its row's context
+    for b in (1, 3):
+        live = tables[b, :-(-int(ctx[b]) // BS)]
+        rows = plane[1][(live[:, None] * BS + np.arange(BS)).reshape(-1)]
+        for t in range(0, int(ctx[b] - start[b]), 3):
+            p_abs = int(start[b]) + t
+            keep = np.arange(p_abs + 1)
+            if marked:
+                keep = keep[sel[b, t, :p_abs + 1] > 0.5]
+            np.testing.assert_allclose(
+                got[b, t], attend_selected(q[b, t], rows, keep), atol=2e-5)
 
 
 @pytest.mark.parametrize("pages_per_block", [1, 2, 3, None])
