@@ -18,9 +18,10 @@ ITS PLANES IS RELEASED BEHIND A WINDOW (``released_window``):
   table of it is the second half of ``block_tables``, indexed by the
   same ABSOLUTE column as the first half — a column behind the window
   reads 0 and is never dereferenced: the decode kernel walks from the
-  page of key ``ctx - window`` on, and prefill hands the prefill kernel
-  only the ``span`` columns a chunk's window can touch, gathered and
-  rebased (``window_columns``). The full-width table costs 4 B a column
+  page of key ``ctx - window`` on, the prefill kernel a tile from the
+  page of its first query's window edge on (the XLA path reads the
+  ``span`` columns a chunk's window can touch, rebased:
+  ``window_columns``). The full-width table costs 4 B a column
   a row (512 B at 128 columns) against a ring's modulus in every
   reader. Where a token's K and V go in this plane follows from the
   table and the token's position, on the device (``window_slots``);
@@ -83,12 +84,17 @@ MOE_DENSE_TOKENS = 64
 # experts (16 x 3 x 4 096 x 2 048 x 2 B = 0.75 GiB) and the rows of ALL
 # 4 096 x 8 assignments sorted by expert, held or not (``hybrid.
 # moe_local_grouped``: the rows in, gate, up, mid, down and the unsorted
-# result, 1.9 GiB) — beside which q, k, v, a few rows' gathered pages
-# (``PREFILL_GATHER_BYTES``) and the dense layer's 16 384-wide intermediate
-# are small. The described chip's compiler counts 2.71 GiB at 32 x 128,
-# 1.44 at 8 x 256, 0.92 at 1 x 1 024, 0.57 at 1 x 512, 0.04 at a 64-row
-# decode step (tests/test_chip_compile.py holds them under this bound)
-STEP_TRANSIENT_BYTES = 11 << 28
+# result, 1.9 GiB) — beside which q, k, v (the prefill kernel reads the
+# pages in place) and the dense layer's 16 384-wide intermediate are
+# small. The described chip's compiler counts 3.03 GiB at 32 x 128, 1.92
+# at 8 x 256, 0.92 at 1 x 1 024, 0.57 at 1 x 512, 0.04 at a 64-row decode
+# step (tests/test_chip_compile.py holds them under this bound). Until
+# PR 51 the many-row rectangles read 2.71 / 1.44: their attention ran as a
+# loop over groups of rows around a gather of the rows' pages, and XLA
+# scheduled nothing across that loop; without it the scheduler overlaps
+# more of a layer's neighbours with the expert matmuls (no buffer of the
+# attention's own is among the large ones)
+STEP_TRANSIENT_BYTES = 13 << 28
 ATTN_COUNT_NAMES = (
     "attn_full_pairs", "attn_window_pairs",
     "attn_full_decode_keys", "attn_window_decode_keys",
@@ -394,20 +400,6 @@ def window_columns(tables: jax.Array, start: jax.Array, window: int,
     return sub, first * block_size
 
 
-# the most bytes of gathered pages one prefill attention call holds
-PREFILL_GATHER_BYTES = 256 << 20
-
-
-def prefill_row_group(rows: int, row_bytes: int) -> int:
-    """How many of a rectangle's ``rows`` one prefill attention call
-    takes: all of them, or the largest halving of them whose gathered
-    pages (``row_bytes`` a row) stay within ``PREFILL_GATHER_BYTES``."""
-    group = rows
-    while group > 1 and group % 2 == 0 and group * row_bytes > PREFILL_GATHER_BYTES:
-        group //= 2
-    return group
-
-
 def window_slots(tables: jax.Array, positions: jax.Array,
                  slot_mapping: jax.Array, block_size: int) -> jax.Array:
     """Where each token's K and V go in the window plane: its position's
@@ -547,42 +539,23 @@ def forward(
                 sliding_window=window, sinks=sink, scale=scale,
                 interpret=interpret)[:, None]
             return attn
-        # the columns a call reads, and the position its first key has:
-        # every column of the full plane; of the window plane the span a
-        # chunk's window can touch, rebased
+        if kernels:
+            # the stored rows in place, as decode reads them: a tile walks
+            # its own live pages (under a window those its queries can see)
+            return PREFILL[kind](
+                q, kp.reshape(kp.shape[0], n_slots, hk, g.Dkp),
+                vp.reshape(vp.shape[0], n_slots, hk, g.Dv), jnp.int32(ai),
+                tables[kind], start, context_lens, block_size=block_size,
+                sliding_window=window, sinks=sink, scale=scale,
+                interpret=interpret)
+        # the columns the reference reads, and the position its first key
+        # has: every column of the full plane; of the window plane the span
+        # a chunk's window can touch, rebased
         if window is None:
             sub, base = tables[kind], jnp.zeros_like(start)
         else:
             sub, base = window_columns(tables[kind], start, window,
                                        block_size, T)
-        if kernels:
-            # the rows' own pages as a small cache of their own, in the
-            # sub-table's order: the prefill kernel reads a page as
-            # [bs, Hk, width], another tiling than the stored rows'. A
-            # few rows at a time where the copies of all would be large
-            # (32 rows under a 136-column table: 1.7 GB a full layer)
-            Ws, R = sub.shape[1], block_size * hk
-            n_own = block_size * Ws
-            Gr = prefill_row_group(
-                B, n_own * hk * (g.Dkp + g.Dv) * kp.dtype.itemsize)
-
-            def rows_of(args):
-                q_, sub_, start_, ctx_ = args
-                own = (sub_[:, :, None] * R + jnp.arange(R)).reshape(-1)
-                return PREFILL[kind](
-                    q_, kp[ai, own].reshape(1, Gr * n_own, hk, g.Dkp),
-                    vp[ai, own].reshape(1, Gr * n_own, hk, g.Dv), jnp.int32(0),
-                    jnp.arange(Gr * Ws, dtype=sub.dtype).reshape(Gr, Ws),
-                    start_, ctx_, block_size=block_size,
-                    sliding_window=window, sinks=sink, scale=scale,
-                    interpret=interpret)
-
-            args = (q, sub, start - base, jnp.maximum(context_lens - base, 0))
-            if Gr == B:
-                return rows_of(args)
-            out = jax.lax.map(rows_of, jax.tree.map(
-                lambda a: a.reshape(B // Gr, Gr, *a.shape[1:]), args))
-            return out.reshape(B, T, g.H, g.Dv)
         return llama.paged_attention_reference(
             q, kp[ai].reshape(n_slots, hk, g.Dkp),
             vp[ai].reshape(n_slots, hk, g.Dv), sub,

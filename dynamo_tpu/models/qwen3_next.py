@@ -484,17 +484,13 @@ def forward(
                 paged_attention_prefill_stacked,
             )
 
-            # the rows' own pages as a small cache of their own, in the
-            # table's order: the prefill kernel reads a page as
-            # [bs, Hk, Dh], another tiling than the stored rows'
-            W, R = tables.shape[1], block_size * g.Hk
-            own = (tables[:, :, None] * R + jnp.arange(R)).reshape(-1)
-            shape4 = (1, B * W * block_size, g.Hk, g.Dh)
+            # the stored rows in place, as decode reads them: a tile walks
+            # its own live pages, whatever the table's width
+            shape4 = (k_pages.shape[0], slots, g.Hk, g.Dh)
             attn = paged_attention_prefill_stacked(
-                q, k_pages[ai, own].reshape(shape4),
-                v_pages[ai, own].reshape(shape4), jnp.int32(0),
-                jnp.arange(B * W, dtype=tables.dtype).reshape(B, W), start,
-                context_lens, block_size=block_size, interpret=interpret)
+                q, k_pages.reshape(shape4), v_pages.reshape(shape4),
+                jnp.int32(ai), tables, start, context_lens,
+                block_size=block_size, interpret=interpret)
         else:
             attn = llama.paged_attention_reference(
                 q, k_pages[ai].reshape(slots, g.Hk, g.Dh),

@@ -458,18 +458,40 @@ def test_decode_attention_at_head_256_reads_the_stored_rows_in_place(
         or "ENTRY" in ln or "HloModule" in ln for ln in pool_ops), pool_ops[:3]
 
 
+def _assert_pool_read_in_place(text: str, *shapes: str):
+    """Every HLO line that names a page pool (by one of ``shapes``) is the
+    parameter, a bitcast of it or the kernel's call: no copy of a pool."""
+    pool_ops = [ln for ln in text.splitlines() if any(s in ln for s in shapes)]
+    assert pool_ops and all(
+        " bitcast(" in ln or " parameter(" in ln or "custom-call(" in ln
+        or "ENTRY" in ln or "HloModule" in ln for ln in pool_ops), pool_ops[:3]
+
+
 @pytest.mark.parametrize("rows,tokens", [(1, 1024), (4, 256)])
 def test_prefill_attention_at_head_256_compiles_for_v5e(
     rows, tokens, one_chip, no_compile_cache
 ):
-    """The rows' own pages, gathered: a cache of ``rows x 40`` pages."""
-    own = _sds((1, rows * TABLE_W * BS, QN_HK, QN_DH), jnp.bfloat16, one_chip)
+    """Since PR 51 the prefill kernel reads the pool ``[2, slots * Hk,
+    256]`` in place, as decode does (no gather of the rows' own pages):
+    its page view is a bitcast, ``grid=(rows, tiles)`` whatever the
+    table's width."""
+    slots = QN_PAGES * BS
+    pool = _sds((2, slots * QN_HK, QN_DH), jnp.bfloat16, one_chip)
+
+    def prefill(q, k, v, layer, tables, start, ctx):
+        shape4 = (2, slots, QN_HK, QN_DH)
+        return paged_attention_prefill_stacked(
+            q, k.reshape(shape4), v.reshape(shape4), layer, tables, start,
+            ctx, block_size=BS)
+
     text = _compile_text(
-        functools.partial(paged_attention_prefill_stacked, block_size=BS),
-        _sds((rows, tokens, QN_H, QN_DH), jnp.bfloat16, one_chip), own, own,
-        _sds((), jnp.int32, one_chip), _sds((rows, TABLE_W), jnp.int32, one_chip),
+        prefill, _sds((rows, tokens, QN_H, QN_DH), jnp.bfloat16, one_chip),
+        pool, pool, _sds((), jnp.int32, one_chip),
+        _sds((rows, TABLE_W), jnp.int32, one_chip),
         _sds((rows,), jnp.int32, one_chip), _sds((rows,), jnp.int32, one_chip))
     assert "tpu_custom_call" in text
+    _assert_pool_read_in_place(
+        text, f"bf16[2,{QN_PAGES},", f"bf16[2,{slots * QN_HK},")
 
 
 @pytest.mark.parametrize("rows", [8, 32, 64])
@@ -799,10 +821,14 @@ _DENSE_DIGESTS = {
     "decode-mistral-window-bf16-B64": ((False, 64, 1, False, 32, 8, 128, 4096), "f588bba7ba7c8baf"),
     "decode-mistral-window-bf16-B32": ((False, 32, 1, False, 32, 8, 128, 4096), "542c21c77fb4f801"),
     "decode-qwen-bf16-B32": ((False, 32, 1, False, 28, 4, 128, None), "04311eee5d519b66"),
-    "prefill-llama-bf16-1x1024": ((True, 1, 1024, False, 32, 8, 128, None), "9d5d2bd197cfb305"),
-    "prefill-mistral-window-bf16-4x1024": ((True, 4, 1024, False, 32, 8, 128, 4096), "cbeb2373e00c00c7"),
-    "prefill-llama-int8-32x128": ((True, 32, 128, True, 32, 8, 128, None), "7a4f751b8cbc2a89"),
-    "prefill-head256-bf16-1x1024": ((True, 1, 1024, False, 16, 2, 256, None), "409e017765d1a2af"),
+    # re-taken at PR 51 (on the parent commit 09794d7 they read 9d5d2bd1…,
+    # cbeb2373…, 7a4f751b…, 409e0177…): that PR rewrote the prefill kernel
+    # (``grid=(rows, tiles)``, a tile's live pages 8 to a block) below the
+    # decode wrapper's last line; the eight decode digests above held
+    "prefill-llama-bf16-1x1024": ((True, 1, 1024, False, 32, 8, 128, None), "58e26bc0ccadf8f1"),
+    "prefill-mistral-window-bf16-4x1024": ((True, 4, 1024, False, 32, 8, 128, 4096), "6da48775d19d3097"),
+    "prefill-llama-int8-32x128": ((True, 32, 128, True, 32, 8, 128, None), "80d4bba6b5632032"),
+    "prefill-head256-bf16-1x1024": ((True, 1, 1024, False, 16, 2, 256, None), "574d8972d62dbbaf"),
 }
 
 
@@ -909,32 +935,40 @@ def test_mimo_k_rows_stored_192_wide_are_refused_by_the_decode_kernel(
 def test_mimo_prefill_attention_compiles_for_v5e(
     kind, rows, tokens, one_chip, no_compile_cache
 ):
-    """The rows' own pages, gathered: all 136 columns of the full plane,
-    the window's live span of the window plane (10 columns under a
-    1 024-token chunk, 3 under 128)."""
+    """Since PR 51 both planes' stored rows ``[layers, slots * Hk,
+    width]`` in place, under the absolute 136-column table of either (no
+    gather, no rebased window span): the page view is a bitcast, and a
+    window layer's block holds the 3 pages a 32-token tile can see."""
     from dynamo_tpu.models import mimo_v2_flash as mm
+    from dynamo_tpu.ops import paged_attention as pa
 
-    _, hk, window, sinks, _ = MI_KINDS[kind]
-    cols = MI_TABLE_W if window is None else mm.window_span(
-        window, BS, tokens, MI_TABLE_W)
-    assert window is None or cols == {1024: 10, 256: 4, 128: 3}[tokens]
-    own_k = _sds((1, rows * cols * BS, hk, MI_DK), jnp.bfloat16, one_chip)
-    own_v = _sds((1, rows * cols * BS, hk, MI_DV), jnp.bfloat16, one_chip)
+    layers, hk, window, sinks, pages = MI_KINDS[kind]
+    slots = pages * BS
+    kpool = _sds((layers, slots * hk, MI_DK), jnp.bfloat16, one_chip)
+    vpool = _sds((layers, slots * hk, MI_DV), jnp.bfloat16, one_chip)
+    tq = pa.prefill_tile_tokens(tokens, MI_H)
+    assert tq == 32
+    assert pa.prefill_pages_per_block(
+        BS, hk, MI_DK, 2, MI_DV, tq * MI_H // hk, tq, window
+    ) == (8 if window is None else 2)
 
     def prefill(q, k, v, layer, tables, start, ctx, sink):
         return mm.PREFILL["win" if kind == "window" else "full"](
-            q, k, v, layer, tables, start, ctx, block_size=BS,
-            sliding_window=window, sinks=sink if sinks else None,
-            scale=192 ** -0.5)
+            q, k.reshape(layers, slots, hk, MI_DK),
+            v.reshape(layers, slots, hk, MI_DV), layer, tables, start, ctx,
+            block_size=BS, sliding_window=window,
+            sinks=sink if sinks else None, scale=192 ** -0.5)
 
     ids = _sds((rows,), jnp.int32, one_chip)
     text = _compile_text(
         prefill, _sds((rows, tokens, MI_H, MI_DK), jnp.bfloat16, one_chip),
-        own_k, own_v, _sds((), jnp.int32, one_chip),
-        _sds((rows, cols), jnp.int32, one_chip), ids, ids,
+        kpool, vpool, _sds((), jnp.int32, one_chip),
+        _sds((rows, MI_TABLE_W), jnp.int32, one_chip), ids, ids,
         _sds((MI_H,), jnp.float32, one_chip))
     assert "tpu_custom_call" in text
     assert f"paged_attention_prefill_stacked_{kind}" in text
+    _assert_pool_read_in_place(
+        text, f"bf16[{layers},{pages},", f"bf16[{layers},{slots * hk},")
 
 
 def _compiled_mimo_step(rows, T, one_chip, monkeypatch):

@@ -75,72 +75,219 @@ def test_decode_kernel_stacked_matches_per_layer(B, H, Hk, ctx_lens, window):
         )
 
 
+def _pallas_grids(fn, *shapes):
+    """The grid of every pallas_call ``fn`` traces to at ``shapes``."""
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    return found
+
+
+def _prefill(q, k, v, tables, starts, ctx, bs, tile_rows=None, **kw):
+    """The prefill kernel, interpreted. ``tile_rows``: the (token, head)
+    rows of a query tile for this call (the module's rule reads a
+    constant, so the unjitted wrapper is traced under another value) —
+    tiles of a few tokens keep several of them cheap to interpret."""
+    from dynamo_tpu.ops import paged_attention as pa
+
+    fn = pa.paged_attention_prefill_stacked
+    args = (q, k[None], v[None], jnp.int32(0), tables,
+            jnp.asarray(starts, jnp.int32), ctx, bs)
+    if tile_rows is None:
+        return fn(*args, interpret=True, **kw)
+    saved = pa._PREFILL_TILE_ROWS
+    pa._PREFILL_TILE_ROWS = tile_rows
+    try:
+        return fn.__wrapped__(*args, interpret=True, **kw)
+    finally:
+        pa._PREFILL_TILE_ROWS = saved
+
+
+def _assert_prefill_matches(out, q, k, v, tables, starts, ctx, bs, window,
+                            tol=2e-2):
+    """Real tokens (start + t < ctx) against the XLA reference; padded
+    tokens — a tile's tail past the context, whole tiles past it, rows
+    of context 0 — are zeros (the reference NaN-masks them instead)."""
+    B, T = q.shape[:2]
+    starts = np.asarray(starts, np.int32)
+    positions = jnp.asarray(starts[:, None] + np.arange(T, dtype=np.int32))
+    ref = paged_attention_reference(q, k, v, tables, positions, ctx, bs, window)
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    for b in range(B):
+        n = min(max(0, int(ctx[b]) - int(starts[b])), T)
+        np.testing.assert_allclose(
+            out[b, :n], np.asarray(ref, np.float32)[b, :n], rtol=tol, atol=tol)
+        np.testing.assert_array_equal(out[b, n:], 0.0)
+
+
 @pytest.mark.parametrize(
-    "B,H,Hk,T,starts,ctx_lens,window",
+    "B,H,Hk,T,starts,ctx_lens,window,pages,cache",
     [
         # full prefill from position 0, ragged lens, GQA
-        (2, 4, 2, 32, [0, 0], [30, 17], None),
-        # chunked: rows resume mid-prompt (prefix already in cache)
-        (2, 4, 2, 16, [20, 5], [36, 21], None),
+        (2, 4, 2, 32, [0, 0], [30, 17], None, None, "f32"),
+        # chunked: rows resume mid-prompt, mid-page (prefix already in
+        # cache)
+        (2, 4, 2, 16, [20, 5], [36, 21], None, None, "f32"),
         # MQA + block-aligned + a padded row (start 0 / ctx 0)
-        (3, 8, 1, 16, [0, 16, 0], [16, 32, 0], None),
+        (3, 8, 1, 16, [0, 16, 0], [16, 32, 0], None, None, "f32"),
         # sliding window across pages
-        (2, 4, 2, 32, [0, 24], [32, 56], 20),
-        # tile boundary: T = 2 tiles when tq divides (tiny tq via T=256
-        # would be slow interpreted; T=32 runs one tile — covered above)
+        (2, 4, 2, 32, [0, 24], [32, 56], 20, None, "f32"),
+        # a compute block of 1 page and of 3 (live ranges of 2 and 4
+        # pages: whole blocks, and a last block that is partial)
+        (2, 4, 2, 32, [0, 0], [30, 17], None, 1, "f32"),
+        (2, 4, 2, 16, [20, 40], [36, 56], None, 3, "f32"),
+        (2, 4, 2, 32, [0, 24], [32, 56], 20, 3, "f32"),
+        # padded rows leading, between and trailing: no copy starts for
+        # them, and the live row behind one starts its own first block
+        (5, 4, 2, 16, [0, 20, 0, 5, 0], [0, 36, 0, 21, 0], None, None, "f32"),
+        (5, 4, 2, 16, [0, 20, 0, 5, 0], [0, 36, 0, 21, 0], None, 1, "f32"),
+        (4, 4, 2, 16, [0, 0, 30, 0], [0, 0, 41, 0], 24, 3, "f32"),
+        # a window whose first live page is column 5, not 0
+        (1, 4, 2, 16, [100], [116], 20, None, "f32"),
+        (1, 4, 2, 16, [100], [116], 20, 1, "f32"),
+        # groups of 7 (Qwen2.5) and of 16 (nemotron, mimo's full layers)
+        (2, 28, 4, 16, [3, 0], [19, 9], None, None, "f32"),
+        (2, 32, 2, 16, [3, 0], [19, 9], None, 3, "f32"),
+        # 16-bit rows: two heads to a sublane word, taken apart through
+        # the 32-bit view; one head; heads that do not pair (staged f32)
+        (2, 8, 4, 16, [20, 5], [36, 21], None, None, "bf16"),
+        (2, 8, 4, 32, [0, 24], [32, 56], 20, 3, "bf16"),
+        (2, 28, 4, 16, [3, 0], [19, 9], None, 1, "bf16"),
+        (3, 8, 1, 16, [0, 16, 0], [16, 32, 0], None, None, "bf16"),
+        (2, 6, 3, 16, [20, 5], [36, 21], None, None, "bf16"),
     ],
 )
-def test_prefill_kernel_matches_reference(B, H, Hk, T, starts, ctx_lens, window):
+def test_prefill_kernel_matches_reference(
+    B, H, Hk, T, starts, ctx_lens, window, pages, cache
+):
     """Flash prefill over the paged cache (VERDICT r3 item 2: the T>1
     path must stop falling back to the XLA group-expand reference)."""
-    from dynamo_tpu.ops.paged_attention import paged_attention_prefill_stacked
-
     Dh, bs, num_blocks = 128, 16, 16
     rng = np.random.default_rng(11)
     _, k, v, tables, ctx = _setup(B, H, Hk, Dh, num_blocks, bs, ctx_lens)
     q = jnp.asarray(rng.standard_normal((B, T, H, Dh)).astype(np.float32))
-    starts_a = jnp.asarray(starts, np.int32)
-    out = paged_attention_prefill_stacked(
-        q, k[None], v[None], jnp.int32(0), tables, starts_a, ctx, bs,
-        sliding_window=window, interpret=True,
-    )
-    positions = starts_a[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    ref = paged_attention_reference(
-        q, k, v, tables, positions, ctx, bs, window
-    )
-    # compare only REAL tokens (start + t < ctx); padded tokens are
-    # discarded downstream (the reference NaN-masks differently)
-    for b in range(B):
-        n = max(0, int(ctx[b]) - int(starts[b]))
-        n = min(n, T)
-        if n == 0:
-            continue
-        np.testing.assert_allclose(
-            np.asarray(out)[b, :n], np.asarray(ref)[b, :n],
-            rtol=2e-2, atol=2e-2,
+    tol = 2e-2
+    if cache == "bf16":
+        q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+        tol = 1e-1
+    out = _prefill(q, k, v, tables, starts, ctx, bs,
+                   sliding_window=window, pages_per_block=pages)
+    assert out.dtype == q.dtype
+    _assert_prefill_matches(
+        out, q, k, v, tables, starts, ctx, bs, window, tol)
+
+
+@pytest.mark.parametrize(
+    "B,H,Hk,T,tile_rows,starts,ctx_lens,window,pages",
+    [
+        # two 128-token tiles
+        (1, 2, 1, 256, 256, [0], [256], None, None),
+        # 16-token tiles. Row 0: its context ends inside tile 1, tiles 2
+        # and 3 lie wholly past it (zeros, no copy); row 1: a chunk from
+        # mid-page whose context ends inside tile 1
+        (2, 4, 2, 64, 64, [0, 40], [20, 70], None, None),
+        (2, 4, 2, 64, 64, [0, 40], [20, 70], None, 1),
+        (2, 4, 2, 64, 64, [0, 40], [20, 70], None, 3),
+        # padded rows around and between rows of several tiles: a tile
+        # starts the next live tile's first block, across dead tiles not
+        (5, 4, 2, 32, 32, [0, 37, 0, 0, 0], [0, 69, 0, 25, 0], None, 2),
+        (5, 4, 2, 32, 32, [0, 37, 0, 0, 0], [0, 69, 0, 25, 0], 24, None),
+        # a window under several tiles: each tile's first live page its
+        # own (columns 4, 5, 6, 7 of 8), blocks of 1 and of 3
+        (1, 4, 2, 64, 64, [90], [154], 20, 1),
+        (1, 4, 2, 64, 64, [90], [154], 20, 3),
+        # groups of 7 and of 16 under 8-token tiles
+        (1, 28, 4, 32, 224, [13], [45], None, 2),
+        (1, 32, 2, 32, 256, [13], [45], 24, None),
+    ],
+)
+def test_prefill_kernel_multi_tile(
+    B, H, Hk, T, tile_rows, starts, ctx_lens, window, pages
+):
+    """T > tile size exercises the query-tile grid axis."""
+    Dh, bs, num_blocks = 128, 16, 20
+    rng = np.random.default_rng(3)
+    _, k, v, tables, ctx = _setup(B, H, Hk, Dh, num_blocks, bs, ctx_lens)
+    q = jnp.asarray(rng.standard_normal((B, T, H, Dh)).astype(np.float32))
+    out = _prefill(q, k, v, tables, starts, ctx, bs, tile_rows=tile_rows,
+                   sliding_window=window, pages_per_block=pages)
+    _assert_prefill_matches(out, q, k, v, tables, starts, ctx, bs, window)
+
+
+@pytest.mark.parametrize("fill", ["nan", "out_of_range"])
+@pytest.mark.parametrize("window", [None, 24])
+def test_prefill_kernel_never_reads_dead_table_columns(fill, window):
+    """Table columns outside a tile's live range — past the page of its
+    last real token, before the page of its first query's window edge —
+    are never dereferenced: NaN-filled pages there, or page ids far
+    outside the pool, leave the result as it was."""
+    Dh, bs, H, Hk, T = 128, 16, 4, 2, 32
+    starts, ctx_lens = [40, 0, 0, 64], [70, 0, 16, 96]
+    num_blocks, W = 24, 12
+    _, k, v, tables, ctx = _setup(4, H, Hk, Dh, num_blocks, bs, ctx_lens)
+    q = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (4, T, H, Dh)).astype(np.float32))
+    clean = np.zeros((4, W), np.int32)
+    clean[:, : tables.shape[1]] = np.asarray(tables)
+    kw = dict(tile_rows=32, sliding_window=window, pages_per_block=2)
+    want = _prefill(q, k, v, jnp.asarray(clean), starts, ctx, bs, **kw)
+    _assert_prefill_matches(
+        want, q, k, v, jnp.asarray(clean), starts, ctx, bs, window)
+    dirty = clean.copy()
+    nan_page = num_blocks - 1  # no row's live page
+    k = k.at[nan_page * bs:].set(jnp.nan)
+    v = v.at[nan_page * bs:].set(jnp.nan)
+    for b, (s, c) in enumerate(zip(starts, ctx_lens)):
+        lo = 0 if window is None else max(s - (window - 1), 0)
+        live = range(lo // bs, -(-c // bs)) if c else range(0)
+        for j in range(W):
+            if j not in live:
+                dirty[b, j] = nan_page if fill == "nan" else 2**30 + j
+    got = _prefill(q, k, v, jnp.asarray(dirty), starts, ctx, bs, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_prefill_kernel_grid_has_no_table_axis():
+    """The lowered pallas_call's grid is (rows, tiles), whatever the
+    block table's width: no step is spent on a dead column; and the tile
+    and the pages of a block follow from the call's geometry."""
+    from dynamo_tpu.ops import paged_attention as pa
+
+    def grids(W, T=256):
+        B, H, Hk, Dh, bs = 2, 64, 4, 128, 16
+        cache = jax.ShapeDtypeStruct((2, 64 * bs, Hk, Dh), jnp.bfloat16)
+        return _pallas_grids(
+            lambda q, kc, vc, lyr, t, s, c: pa.paged_attention_prefill_stacked(
+                q, kc, vc, lyr, t, s, c, block_size=bs, interpret=True
+            ),
+            jax.ShapeDtypeStruct((B, T, H, Dh), jnp.bfloat16), cache, cache,
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((B, W), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
         )
 
-
-def test_prefill_kernel_multi_tile():
-    """T > tile size exercises the query-tile grid axis (tq=128)."""
-    from dynamo_tpu.ops.paged_attention import paged_attention_prefill_stacked
-
-    B, H, Hk, Dh, bs = 1, 2, 1, 128, 16
-    T = 256  # two 128-token tiles
-    num_blocks = 20
-    rng = np.random.default_rng(3)
-    _, k, v, tables, ctx = _setup(B, H, Hk, Dh, num_blocks, bs, [256])
-    q = jnp.asarray(rng.standard_normal((B, T, H, Dh)).astype(np.float32))
-    starts = jnp.zeros((B,), jnp.int32)
-    out = paged_attention_prefill_stacked(
-        q, k[None], v[None], jnp.int32(0), tables, starts, ctx, bs,
-        interpret=True,
-    )
-    positions = jnp.arange(T, dtype=jnp.int32)[None, :]
-    ref = paged_attention_reference(q, k, v, tables, positions, ctx, bs)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), rtol=2e-2, atol=2e-2
-    )
+    assert grids(8) == grids(136) == [(2, 8)]
+    # the six served geometries: (H, T) -> tokens a tile
+    assert [pa.prefill_tile_tokens(T, H) for T, H in (
+        (1024, 64), (128, 64), (1024, 32), (512, 28), (512, 16), (16, 4),
+        (96, 64))] == [32, 32, 64, 64, 128, 16, 32]
+    # pages a block at 128-token pages of bf16: mimo's full layers (512
+    # rows a KV head), its window layers (a window of 128 under 32-token
+    # tiles sees 3 pages at most), Mistral / Llama 32 / 8, Qwen2.5 28 / 4
+    ppb = pa.prefill_pages_per_block
+    assert ppb(128, 4, 256, 2, 128, 512, 32) == 8
+    assert ppb(128, 8, 256, 2, 128, 256, 32, 128) == 2
+    assert ppb(128, 8, 128, 2, 128, 256, 64, 4096) == 8
+    assert ppb(128, 4, 128, 2, 128, 448, 64) == 8
 
 
 def _quantized(k, bs):
@@ -256,27 +403,15 @@ def test_decode_kernel_grid_has_no_table_axis():
     def grids(W):
         B, H, Hk, Dh, bs = 4, 8, 2, 128, 16
         cache = jax.ShapeDtypeStruct((2, 64 * bs, Hk, Dh), jnp.bfloat16)
-        jaxpr = jax.make_jaxpr(
+        return _pallas_grids(
             lambda q, kc, vc, lyr, t, c: paged_attention_decode_stacked(
                 q, kc, vc, lyr, t, c, block_size=bs, interpret=True
-            )
-        )(
+            ),
             jax.ShapeDtypeStruct((B, H, Dh), jnp.bfloat16), cache, cache,
             jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((B, W), jnp.int32),
             jax.ShapeDtypeStruct((B,), jnp.int32),
         )
-        found = []
-
-        def walk(jp):
-            for eqn in jp.eqns:
-                if eqn.primitive.name == "pallas_call":
-                    found.append(tuple(eqn.params["grid_mapping"].grid))
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub)
-
-        walk(jaxpr.jaxpr)
-        return found
 
     assert grids(8) == grids(40) == [(4,)]
 
